@@ -33,18 +33,18 @@ _CONV = re.compile(
 
 _CUSTOM_CALL = re.compile(r"stablehlo\.custom_call @([A-Za-z0-9_.]+)")
 
-# arg attributes may contain quoted strings with nested braces (the
-# mhlo.sharding attr of pjit-lowered modules prints as
-# ``mhlo.sharding = "{devices=[2,2]<=[4]}"``), so the attr body match
-# must treat quoted spans as opaque instead of stopping at the first
-# ``}`` — a plain ``[^}]*`` silently drops ``tf.aliasing_output`` on
-# every sharded module
-_ATTRS = r"((?:[^{}\"]|\"[^\"]*\")*)"
-_ARG = re.compile(r"%arg\d+: tensor<([^>]+)>(?: loc\([^)]*\))?"
-                  r"(?: \{" + _ATTRS + r"\})?")
-_RESULT = re.compile(r"tensor<([^>]+)>(?: \{" + _ATTRS + r"\})?")
-_SHARDING_ATTR = re.compile(r'mhlo\.sharding = "([^"]*)"')
-_SHARDING_DEVICES = re.compile(r"devices=\[([0-9,]+)\]")
+# Shardings print the Shardy way: one ``sdy.mesh @mesh = <["data"=2,
+# "model"=2]>`` per module, and per tensor ``sdy.sharding =
+# #sdy.sharding<@mesh, [{}, {"model"}]>`` — one ``{axes}`` per dim. The
+# attr body nests braces and quotes, so it is cut out by depth
+# (``_attrs_at``), never by a ``[^}]*`` regex, which would drop
+# ``tf.aliasing_output`` on every sharded module.
+_ARG = re.compile(r"%arg\d+: tensor<([^>]+)>(?: loc\([^)]*\))?")
+_RESULT = re.compile(r"tensor<([^>]+)>")
+_SDY_MESH = re.compile(r"sdy\.mesh @[\w.]+ = <\[([^\]]*)\]")
+_SDY_MESH_AXIS = re.compile(r'"([^"]+)"=(\d+)')
+_SDY_SHARDING = re.compile(r"#sdy\.sharding<@[\w.]+, (\[[^\]]*\])")
+_SDY_AXIS = re.compile(r'"([^"]+)"(?::\(\d+\)(\d+))?')
 
 # Ops that move data across the host↔device boundary, or host-compute
 # offload markers. Python host callbacks (jax.debug.print, io_callback,
@@ -153,62 +153,80 @@ def main_signature(text: str) -> str:
     return text[idx:text.index("\n", idx)]
 
 
+def _attrs_at(sig: str, i: int) -> str:
+    """The body of the ``{...}`` attribute dict that starts at
+    ``sig[i:]`` (after one space), or "" where there is none. Quoted
+    spans are opaque; braces nest."""
+    if not sig.startswith(" {", i):
+        return ""
+    depth, j, quoted = 0, i + 1, False
+    while j < len(sig):
+        c = sig[j]
+        if c == '"':
+            quoted = not quoted
+        elif not quoted:
+            if c == "{":
+                depth += 1
+            elif c == "}":
+                depth -= 1
+                if depth == 0:
+                    return sig[i + 2:j]
+        j += 1
+    return sig[i + 2:]
+
+
+def _sharding_of(attrs: str) -> Optional[str]:
+    m = _SDY_SHARDING.search(attrs)
+    return m.group(1) if m else None
+
+
 def main_args(text: str) -> List[dict]:
-    """Per-argument records from the @main signature: tensor type and
+    """Per-argument records from the @main signature: tensor type,
     whether lowering aliased it onto an output (actual donation — the
     ``tf.aliasing_output`` attr jax emits for donated, shape-matched
-    buffers; ``jax.buffer_donor`` marks donated-but-unmatched)."""
+    buffers; ``jax.buffer_donor`` marks donated-but-unmatched), and
+    the per-dim axis list of its ``sdy.sharding`` (None when absent)."""
     sig = main_signature(text)
     # only the input side: results also print as tensor<...> {attrs}
     sig = sig.split(" -> ")[0]
     args = []
     for m in _ARG.finditer(sig):
-        attrs = m.group(2) or ""
-        sharding = _SHARDING_ATTR.search(attrs)
+        attrs = _attrs_at(sig, m.end())
         args.append({
             "type": m.group(1),
             "aliased": "tf.aliasing_output" in attrs,
             "donor_only": "jax.buffer_donor" in attrs,
-            "sharding": sharding.group(1) if sharding else None,
+            "sharding": _sharding_of(attrs),
         })
     return args
 
 
 def main_results(text: str) -> List[dict]:
-    """Per-result records from the @main signature: tensor type and the
-    ``mhlo.sharding`` annotation pjit-lowered modules carry (None on
-    unsharded modules)."""
+    """Per-result records from the @main signature: tensor type and
+    the ``sdy.sharding`` dims sharded modules carry (None otherwise)."""
     sig = main_signature(text)
     _, _, results = sig.partition(" -> ")
-    out = []
-    for m in _RESULT.finditer(results):
-        attrs = m.group(2) or ""
-        sharding = _SHARDING_ATTR.search(attrs)
-        out.append({
-            "type": m.group(1),
-            "sharding": sharding.group(1) if sharding else None,
-        })
-    return out
+    return [{"type": m.group(1),
+             "sharding": _sharding_of(_attrs_at(results, m.end()))}
+            for m in _RESULT.finditer(results)]
 
 
-def sharding_factor(sharding: Optional[str]) -> int:
-    """Number of distinct shards a GSPMD sharding annotation splits a
-    tensor into: 1 means fully replicated (every device holds the whole
-    tensor). ``{replicated}``/absent → 1; ``{devices=[2,2]<=[4]}`` → 4;
-    a trailing ``last_tile_dim_replicate`` dim only replicates, so it
-    is excluded from the product."""
-    if not sharding or "replicated}" in sharding.replace(" ", "") \
-            and "devices=" not in sharding:
-        return 1
-    m = _SHARDING_DEVICES.search(sharding)
+def mesh_axes(text: str) -> Dict[str, int]:
+    """Axis sizes of the module's ``sdy.mesh`` ({} when unsharded)."""
+    m = _SDY_MESH.search(text)
     if not m:
-        return 1
-    dims = [int(d) for d in m.group(1).split(",")]
-    if "last_tile_dim_replicate" in sharding and len(dims) > 1:
-        dims = dims[:-1]
+        return {}
+    return {name: int(n) for name, n in _SDY_MESH_AXIS.findall(m.group(1))}
+
+
+def sharding_factor(sharding: Optional[str], axes: Dict[str, int]) -> int:
+    """Number of distinct shards a sharding's dims list splits a
+    tensor into: 1 means fully replicated (every device holds the whole
+    tensor). Absent or ``[{}, {}]`` → 1; ``[{"data"}, {"model"}]`` on a
+    2×2 mesh → 4; a sub-axis ``"data":(1)2`` counts its own size."""
     factor = 1
-    for d in dims:
-        factor *= d
+    for name, sub in _SDY_AXIS.findall(sharding or ""):
+        factor *= int(sub) if sub else axes[name]
     return factor
 
 
@@ -420,10 +438,5 @@ def text_hash(text: str) -> str:
     ``module_fingerprint``: trace-time leakage into the graph *body*
     (a timestamp constant, a host-RNG draw, an id() in a name) changes
     this hash while leaving the @main signature intact — and silently
-    zeroes the cache hit rate. Host-callback wrapper addresses are
-    canonicalized out first — they are fresh per lowering by
-    construction, and the cache already refuses to serialize
-    callback-bearing executables, so they are noise, not key."""
-    from perceiver_tpu.cache import canonicalize_hlo
-
-    return hashlib.sha256(canonicalize_hlo(text).encode()).hexdigest()
+    zeroes the cache hit rate."""
+    return hashlib.sha256(text.encode()).hexdigest()

@@ -21,9 +21,9 @@ ever runs the program.
 ``replication_check``
     A tensor the sharding rules declared sharded must not materialize
     fully replicated: the pass scans the @main boundary (args +
-    results) and mid-graph ``@Sharding`` custom calls of the StableHLO
-    for tensors at or above a size floor whose annotation replicates
-    them, modulo a per-target ``ReplicationAllow`` list (the audit
+    results) and mid-graph ``sdy.sharding_constraint`` ops of the
+    StableHLO for tensors at or above a size floor whose annotation
+    replicates them, modulo a per-target ``ReplicationAllow`` list (the audit
     trail for read-only tables that are replicated by design). This is
     the static form of "the step silently all-gathers the full
     parameter pytree" — the pjit scaling postmortem classic.
@@ -199,11 +199,11 @@ def collective_budget(compiled_text: Optional[str], mesh, *, where: str,
 # --- replication / resharding detector ---------------------------------------
 
 # mid-graph sharding constraints print as
-#   %2 = stablehlo.custom_call @Sharding(%1) {mhlo.sharding = "..."}
-#       : (tensor<...>) -> tensor<512x64xf32>
+#   %1 = sdy.sharding_constraint %0 <@mesh, [{"data"}, {}]>
+#       : tensor<512x64xf32>
 _MIDGRAPH_SHARDING = re.compile(
-    r'custom_call @Sharding\(.*?mhlo\.sharding = "([^"]*)"'
-    r'.*?->\s*tensor<([^>]+)>')
+    r"sdy\.sharding_constraint \S+ <@[\w.]+, (\[[^\]]*\])[^:\n]*"
+    r":\s*tensor<([^>]+)>")
 
 
 def replication_check(text: str, *, where: str,
@@ -213,18 +213,19 @@ def replication_check(text: str, *, where: str,
     """No tensor ≥ ``floor_bytes`` may be fully replicated at the
     @main boundary or resharded to replicated mid-graph, outside the
     allowlist. Runs on the StableHLO of a pjit-lowered module (where
-    every boundary tensor carries ``mhlo.sharding``)."""
+    every boundary tensor carries ``sdy.sharding``)."""
+    axes = hlo.mesh_axes(text)
     suspects: List[Tuple[str, str, str]] = []  # (site, type, sharding)
     for a in hlo.main_args(text):
         suspects.append(("arg", a["type"], a["sharding"]))
     for r in hlo.main_results(text):
         suspects.append(("result", r["type"], r["sharding"]))
     for m in _MIDGRAPH_SHARDING.finditer(text):
-        suspects.append(("mid-graph @Sharding", m.group(2), m.group(1)))
+        suspects.append(("mid-graph constraint", m.group(2), m.group(1)))
     budgets = {id(a): a.max_count for a in allowlist}
     violations = []
     for site, ty, sharding in suspects:
-        if hlo.sharding_factor(sharding) != 1:
+        if hlo.sharding_factor(sharding, axes) != 1:
             continue
         size = hlo.tensor_bytes(ty)
         if size < floor_bytes:

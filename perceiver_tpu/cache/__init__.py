@@ -17,14 +17,18 @@ corruption, version skew, or a missing entry always degrades to a
 real compile, never a crash. See docs/SERVING.md "Warm starts".
 """
 
+from perceiver_tpu.cache.compiles import (  # noqa: F401
+    compile_events,
+    enable_compile_cache,
+    register_compile_listener,
+    unregister_compile_listener,
+)
 from perceiver_tpu.cache.exec_cache import (  # noqa: F401
     CacheStats,
     ExecutableCache,
     aot_compile,
-    canonicalize_hlo,
     compile_lowered,
     default_cache,
-    enable_native_cache,
     has_host_callbacks,
     source_tree_digest,
     topology_fingerprint,

@@ -51,22 +51,8 @@ _ENV_VAR = "PERCEIVER_EXEC_CACHE"
 _DEFAULT_MAX_BYTES = 4 << 30  # 4 GiB — hundreds of serving buckets
 
 # Host-callback custom calls (jax.debug.print / io_callback /
-# pure_callback) bake the address of a per-lowering C++ wrapper into
-# the module — as an i64 constant operand and as backend_config text.
-_CALLBACK_PTR = re.compile(
-    r'custom_call @\S*callback\S*\([^\n]*?backend_config = "(\d+)"')
+# pure_callback) reach back into this process.
 _CALLBACK_CALL = re.compile(r"custom_call @\S*callback")
-
-
-def canonicalize_hlo(text: str) -> str:
-    """Key material from StableHLO text: host-callback wrapper
-    addresses are fresh every lowering (same process or not), so two
-    lowerings of the SAME program differ only in those digits — mask
-    exactly them. Only the pointer values harvested from callback
-    custom calls are replaced, never arbitrary numbers."""
-    for ptr in {m.group(1) for m in _CALLBACK_PTR.finditer(text)}:
-        text = text.replace(ptr, "<host-callback-ptr>")
-    return text
 
 
 def has_host_callbacks(text: str) -> bool:
@@ -127,27 +113,6 @@ def source_tree_digest(root: Optional[str] = None) -> str:
     return digest
 
 
-def enable_native_cache(path: str) -> bool:
-    """Point jax's own persistent compilation cache
-    (``jax_compilation_cache_dir``) at ``path`` — covers the compiles
-    we don't AOT through this cache (lazy jit fallbacks, helper fns).
-    Best-effort: unsupported backends/versions simply return False."""
-    import jax
-
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # flag name drifts across jax versions
-        return True
-    except Exception:
-        return False
-
-
 @dataclasses.dataclass
 class CacheStats:
     """Process-local counters (the serving metrics mirror these)."""
@@ -184,15 +149,12 @@ class ExecutableCache:
     }
 
     def __init__(self, path: str, *,
-                 max_bytes: int = _DEFAULT_MAX_BYTES,
-                 native: bool = True):
+                 max_bytes: int = _DEFAULT_MAX_BYTES):
         self.path = os.path.abspath(os.path.expanduser(str(path)))
         self.max_bytes = int(max_bytes)
         self.stats = CacheStats()
         self._lock = threading.Lock()
         os.makedirs(self.path, exist_ok=True)
-        if native:
-            enable_native_cache(os.path.join(self.path, "xla"))
 
     # -- keys -------------------------------------------------------------
 
@@ -208,7 +170,7 @@ class ExecutableCache:
             "topology": topology_fingerprint(backend),
             "donate": sorted(int(i) for i in donate_argnums),
             "hlo": hashlib.sha256(
-                canonicalize_hlo(lowered_text).encode()).hexdigest(),
+                lowered_text.encode()).hexdigest(),
             "extra": [str(x) for x in extra],
         }, sort_keys=True)
         return hashlib.sha256(material.encode()).hexdigest()
@@ -303,11 +265,17 @@ class ExecutableCache:
             with open(self._exe_path(key), "rb") as f:
                 blob = f.read()
             payload, in_tree, out_tree = pickle.loads(blob)
+            import jax
             from jax.experimental.serialize_executable import (
                 deserialize_and_load,
             )
 
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            # the installed jax loads for every device of the backend
+            # unless told which ones the executable was compiled for
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in side["device_ids"]])
         except Exception:
             # truncated/corrupt blob, or an executable this
             # backend/jaxlib cannot load — fall back to a fresh compile
@@ -338,6 +306,8 @@ class ExecutableCache:
         meta = {
             "jax": jax_v,
             "jaxlib": jaxlib_v,
+            "device_ids": [d.id for d in
+                           compiled.runtime_executable().local_devices()],
             "topology": topology_fingerprint(),
             "created": time.time(),
             "payload_bytes": len(blob),
@@ -405,7 +375,7 @@ class ExecutableCache:
         except OSError:
             return []
         for name in names:
-            if name.startswith(".tmp-") or name == "xla":
+            if name.startswith(".tmp-"):
                 continue
             key = name.split(".", 1)[0]
             groups.setdefault(key, []).append(

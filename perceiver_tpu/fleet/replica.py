@@ -128,7 +128,6 @@ class ReplicaServer:
         self._staged: Dict[str, tuple] = {}
         self._stop = threading.Event()
         self._compile_events: list = []
-        self._listener_registered = False
         self._register_compile_listener()
 
         # decode-arena tenancy (spec key "tenants" = list of TenantSpec
@@ -305,22 +304,12 @@ class ReplicaServer:
         return self.versions.get(self.default_model)
 
     def _register_compile_listener(self) -> None:
-        """Count XLA compile events from before engine construction —
-        the fleet's zero-compile-spin-up assertion reads this count
-        over RPC (``status``)."""
-        try:
-            import jax
+        """Count XLA compiles from before engine construction — the
+        fleet's zero-compile-spin-up assertion reads this count over
+        RPC (``status``)."""
+        from perceiver_tpu.cache import register_compile_listener
 
-            def listener(name, **kwargs):
-                if "compile" in name:
-                    self._compile_events.append(name)
-
-            jax.monitoring.register_event_listener(listener)
-            self._listener_registered = True
-        except Exception:  # pragma: no cover - jax.monitoring drift
-            # older/newer jax without the listener API: the compile
-            # count degrades to unknown (-1) rather than blocking spin-up
-            self._compile_events = None
+        register_compile_listener(self._compile_events.append)
 
     # -- RPC handler ------------------------------------------------------
 
@@ -462,8 +451,7 @@ class ReplicaServer:
             "model_inflight": model_inflight,
             "model_swapping": sorted(swapping_models),
             "model_staged": model_staged,
-            "compile_events": (len(self._compile_events)
-                               if self._compile_events is not None else -1),
+            "compile_events": len(self._compile_events),
             "breaker_open_buckets": (int(open_buckets.value)
                                      if open_buckets else 0),
             "faults_fired": faults.counts(),

@@ -78,8 +78,8 @@ from perceiver_tpu.ops.online_softmax import (
     online_softmax_init,
     online_softmax_update,
 )
-from perceiver_tpu.ops.ragged_attention import _resolve_interpret
 from perceiver_tpu.ops.tiling import round_up as _round_up
+from perceiver_tpu.utils.platform import resolve_interpret
 
 
 def _ragged_paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref,
@@ -148,7 +148,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
     queries with empty windows return exact zeros. Returns
     (R, H, Nq, D) in q's dtype.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     r, h, nq, d = q.shape
     num_pages, page_size = k_pages.shape[:2]
     pps = page_tables.shape[1]

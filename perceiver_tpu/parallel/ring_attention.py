@@ -53,7 +53,6 @@ from perceiver_tpu.ops.chunked_attention import (
     finalize_softmax,
     fold_block,
 )
-from perceiver_tpu.parallel.compat import axis_size, shard_map
 
 
 def _init_stats(b, h, lq, d):
@@ -71,7 +70,7 @@ def ring_attention(q, k, v, *, axis_name: str,
     over the FULL key sequence by rotating k/v (+ bias) around the ring
     one hop per step with ``lax.ppermute``.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, h, lq, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -142,7 +141,7 @@ def make_ring_attention(mesh: Mesh, seq_axis: str = "data", *,
     bias_spec = P(bspec, seq_axis)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(qspec, qspec, qspec, bias_spec),
         out_specs=qspec, check_vma=False)
     def _ring(q, k, v, bias):
@@ -150,7 +149,7 @@ def make_ring_attention(mesh: Mesh, seq_axis: str = "data", *,
                               scale=scale)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(qspec, qspec, qspec),
+        jax.shard_map, mesh=mesh, in_specs=(qspec, qspec, qspec),
         out_specs=qspec, check_vma=False)
     def _ring_nobias(q, k, v):
         return ring_attention(q, k, v, axis_name=seq_axis, scale=scale)
@@ -179,7 +178,7 @@ def make_seq_parallel_cross_attention(mesh: Mesh, seq_axis: str = "data", *,
     bias_spec = P(bspec, seq_axis)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, bias_spec),
         out_specs=q_spec, check_vma=False)
     def _xattn(q, k, v, bias):
@@ -187,7 +186,7 @@ def make_seq_parallel_cross_attention(mesh: Mesh, seq_axis: str = "data", *,
             q, k, v, axis_name=seq_axis, bias=bias, scale=scale)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        jax.shard_map, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec, check_vma=False)
     def _xattn_nobias(q, k, v):
         return seq_parallel_cross_attention(

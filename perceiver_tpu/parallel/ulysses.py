@@ -36,7 +36,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from perceiver_tpu.ops.chunked_attention import chunked_attention
-from perceiver_tpu.parallel.compat import axis_size, shard_map
 
 
 def ulysses_attention(q, k, v, *, axis_name: str,
@@ -51,7 +50,7 @@ def ulysses_attention(q, k, v, *, axis_name: str,
     O(B · H/N · L · D) + O(L · chunk) rather than the quadratic score
     matrix.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, h, lq_loc, d = q.shape
     if h % n != 0:
         raise ValueError(
@@ -92,7 +91,7 @@ def make_ulysses_attention(mesh: Mesh, seq_axis: str = "data", *,
     bias_spec = P(bspec, seq_axis)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(qspec, qspec, qspec, bias_spec),
         out_specs=qspec, check_vma=False)
     def _a2a(q, k, v, bias):
@@ -100,7 +99,7 @@ def make_ulysses_attention(mesh: Mesh, seq_axis: str = "data", *,
                                  scale=scale, kv_chunk_size=kv_chunk_size)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(qspec, qspec, qspec),
+        jax.shard_map, mesh=mesh, in_specs=(qspec, qspec, qspec),
         out_specs=qspec, check_vma=False)
     def _a2a_nobias(q, k, v):
         return ulysses_attention(q, k, v, axis_name=seq_axis, scale=scale,

@@ -1,43 +1,52 @@
 """ctypes bridge to the C++ WordPiece core (csrc/wordpiece.cpp).
 
-Builds the shared library on first use (g++ -O2, cached beside the
-source) — no pybind11 in this image, so the ABI is plain C. Falls back
-cleanly: callers catch ImportError/OSError and use the pure-Python
+Builds the shared library on first use (g++ -O2) — no pybind11 in this
+image, so the ABI is plain C. The library's file name carries a hash
+of the source, so a copy of the tree (which resets mtimes) can never
+load a library built from other source. It is kept beside the source
+unless ``load`` is given another directory. A failed build raises
+OSError; ``WordPieceTokenizer`` catches it and uses the pure-Python
 engine, which produces identical results (asserted by tests).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 from collections import Counter
-from typing import Iterable, List
+from typing import Iterable, List, Optional
 
 _SRC = os.path.join(os.path.dirname(__file__), "csrc", "wordpiece.cpp")
-_LIB = os.path.join(os.path.dirname(__file__), "csrc", "libwordpiece.so")
 _lock = threading.Lock()
 _lib = None
 
 
-def _load() -> ctypes.CDLL:
+def load(build_dir: Optional[str] = None) -> ctypes.CDLL:
+    """The loaded library, built first if ``build_dir`` (default: beside
+    the source) holds none for this source. The first call decides the
+    directory for the process."""
     global _lib
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        path = os.path.join(build_dir or os.path.dirname(_SRC),
+                            f"libwordpiece-{digest}.so")
+        if not os.path.exists(path):
             # Build to a process-unique temp path and rename into place:
             # rename is atomic, so concurrent processes (dataloader
             # workers on a cold cache) never dlopen a half-written ELF.
-            tmp = f"{_LIB}.{os.getpid()}.tmp"
+            tmp = f"{path}.{os.getpid()}.tmp"
             try:
                 subprocess.run(
                     ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
                      "-pthread", _SRC, "-o", tmp],
                     check=True, capture_output=True)
-                os.replace(tmp, _LIB)
+                os.replace(tmp, path)
             except subprocess.CalledProcessError as e:
                 # normalize to OSError so callers' documented fallback
                 # (except (ImportError, OSError)) catches compile failure
@@ -50,7 +59,7 @@ def _load() -> ctypes.CDLL:
                         os.remove(tmp)
                     except OSError:
                         pass
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
         lib.wp_vocab_create.restype = ctypes.c_void_p
         lib.wp_vocab_create.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int32]
@@ -94,7 +103,7 @@ class NativeVocab:
     """Vocab handle for repeated fast encodes."""
 
     def __init__(self, tokenizer):
-        lib = _load()
+        lib = load()
         self._lib = lib
         ordered = sorted(tokenizer.vocab.items(), key=lambda kv: kv[1])
         import numpy as np
@@ -235,7 +244,7 @@ def count_words(tokenizer, data: Iterable[str]) -> Counter:
 def native_train(tokenizer, data: Iterable[str], vocab_size: int,
                  special_tokens: List[str], min_frequency: int) -> dict:
     """Count words host-side, train merges in C++; returns vocab dict."""
-    lib = _load()
+    lib = load()
     items = sorted(count_words(tokenizer, data).items())  # deterministic
     words = (ctypes.c_char_p * len(items))(
         *[w.encode("utf-8") for w, _ in items])

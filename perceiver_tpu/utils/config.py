@@ -328,6 +328,9 @@ class CLI:
     # --- run -----------------------------------------------------------------
 
     def run(self):
+        from perceiver_tpu.cache import enable_compile_cache
+
+        enable_compile_cache()
         # predict preconditions fail before any heavy work (dataset
         # prep, param init): it needs a task with a predict path and a
         # trained checkpoint — random-init "predictions" would be

@@ -79,6 +79,10 @@ def main():
     args = parse_args()
 
     import jax
+
+    from perceiver_tpu.cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax.numpy as jnp
     import optax
 

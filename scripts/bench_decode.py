@@ -75,7 +75,6 @@ Examples::
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import math
 import os
@@ -86,6 +85,8 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+
+from perceiver_tpu.cache import compile_events  # noqa: E402
 
 
 def _tiny_decode_task(max_seq_len: int):
@@ -103,25 +104,6 @@ def _full_decode_task(max_seq_len: int):
     from perceiver_tpu.tasks import MaskedLanguageModelTask
     return MaskedLanguageModelTask(vocab_size=10003,
                                    max_seq_len=max_seq_len)
-
-
-@contextlib.contextmanager
-def _compile_events():
-    """Collect XLA compile events (jax.monitoring) inside the block."""
-    import jax
-    from jax._src import monitoring as _monitoring
-
-    events = []
-
-    def listener(name, **kwargs):
-        if "compile" in name:
-            events.append(name)
-
-    jax.monitoring.register_event_listener(listener)
-    try:
-        yield events
-    finally:
-        _monitoring._unregister_event_listener_by_callback(listener)
 
 
 def _pct(values, q):
@@ -200,7 +182,7 @@ def _run_speculative(args, task, geometry, plans):
             token_budget=args.token_budget or None,
             speculative=SpeculativeConfig() if spec else None)
         t0 = time.monotonic()
-        with _compile_events() as compiles:
+        with compile_events() as compiles:
             handles = []
             for prompt, max_new, _a in plans:
                 handles.append(
@@ -382,7 +364,7 @@ def _run_tenants(args, task, geometry, plans):
         t0 = time.monotonic()
         shed = 0
         bronze_handles = []
-        with _compile_events() as compiles:
+        with compile_events() as compiles:
             handles = []
             for i, (prompt, max_new, _a) in enumerate(plans):
                 if mixed:
@@ -729,7 +711,7 @@ def run(argv=None):
 
         arms = [arm for _, _, arm in plans]
         t0 = time.monotonic()
-        with _compile_events() as compiles:
+        with compile_events() as compiles:
             _fire([i for i, a in enumerate(arms) if a in ("cold",
                                                           "solo")])
             seed_idx = [i for i, a in enumerate(arms) if a == "seed"]
@@ -938,6 +920,9 @@ def run(argv=None):
 
 
 def main(argv=None) -> int:
+    from perceiver_tpu.cache import enable_compile_cache
+
+    enable_compile_cache()
     code, _ = run(argv)
     return code
 

@@ -39,7 +39,6 @@ Examples::
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -50,6 +49,8 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+
+from perceiver_tpu.cache import compile_events  # noqa: E402
 
 
 def _tiny_mlm_task():
@@ -109,25 +110,6 @@ def _parse_packed_buckets(spec: str):
         tokens, rows = part.lower().split("x")
         out.append((int(tokens), int(rows)))
     return tuple(out)
-
-
-@contextlib.contextmanager
-def _compile_events():
-    """Collect XLA compile events (jax.monitoring) inside the block."""
-    import jax
-    from jax._src import monitoring as _monitoring
-
-    events = []
-
-    def listener(name, **kwargs):
-        if "compile" in name:
-            events.append(name)
-
-    jax.monitoring.register_event_listener(listener)
-    try:
-        yield events
-    finally:
-        _monitoring._unregister_event_listener_by_callback(listener)
 
 
 def _run_arm(arm: str, args, task, texts, arrivals, *, seq_buckets,
@@ -202,7 +184,7 @@ def _run_arm(arm: str, args, task, texts, arrivals, *, seq_buckets,
     n = len(texts)
     print(f"[bench_serving] {arm}: offering {n} requests at "
           f"{args.rate} req/s (open loop)", file=sys.stderr)
-    with _compile_events() as compiles:
+    with compile_events() as compiles:
         start = time.perf_counter()
         for i in range(n):
             delay = start + arrivals[i] - time.perf_counter()
@@ -288,6 +270,9 @@ def _run_arm(arm: str, args, task, texts, arrivals, *, seq_buckets,
 
 
 def main() -> int:
+    from perceiver_tpu.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Poisson open-loop load generator for the serving "
                     "subsystem")

@@ -11,7 +11,10 @@ Measures the two startup bills the cache was built to kill:
 
 Each phase runs in a FRESH subprocess — executable caches only matter
 across processes, and an in-process re-run would hit jit's own live
-cache and prove nothing. The cold run starts from an empty cache
+cache and prove nothing. The parent never touches JAX (a chip belongs
+to one process) and runs its children one after another. JAX's own
+persistent compilation cache stays off here (no
+``enable_compile_cache()``): it would serve the cold arm. The cold run starts from an empty cache
 directory (and populates it); the warm run replays against it. Emits
 one ``bench.py``-format JSON line per phase pair::
 
@@ -66,12 +69,10 @@ def _buckets(preset: str):
 
 
 def _compile_event_counter():
-    import jax
+    from perceiver_tpu.cache import register_compile_listener
 
     events = []
-    jax.monitoring.register_event_listener(
-        lambda name, **kw: events.append(name)
-        if "compile" in name else None)
+    register_compile_listener(events.append)
     return events
 
 

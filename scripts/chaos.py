@@ -46,7 +46,7 @@ sys.path.insert(0, _REPO)
 # The determinism gates (race_*, prefix_evict_under_load) assert
 # bitwise token equality between replayed schedules and a serial
 # reference. A persistent XLA compilation cache inherited from the
-# host (bench.py exports one) deserializes executables compiled under
+# environment deserializes executables compiled under
 # a DIFFERENT flag environment, which shifts near-tied logits on the
 # degenerate scenario models — drop it before jax initializes so
 # every chaos process compiles its own executables from scratch.
@@ -1637,9 +1637,12 @@ def scenario_noisy_neighbor(tmp: str) -> dict:
       phases — tenancy is host-side state only;
     - **bitwise seeded replay**: the flooded run's full observable log
       (TTFTs, gaps, tokens, shed counts) replays identically."""
-    from jax import monitoring as jax_monitoring
-    from jax._src import monitoring as _monitoring_impl
     import numpy as np
+
+    from perceiver_tpu.cache import (
+        register_compile_listener,
+        unregister_compile_listener,
+    )
 
     from perceiver_tpu.obs import events as events_mod
     from perceiver_tpu.serving.decode import (
@@ -1681,10 +1684,6 @@ def scenario_noisy_neighbor(tmp: str) -> dict:
 
     compiles = []
 
-    def _compile_listener(name, **kwargs):
-        if "compile" in name:
-            compiles.append(name)
-
     shared_params = [None]
 
     def run_phase(seed: int, flood: bool):
@@ -1694,7 +1693,7 @@ def scenario_noisy_neighbor(tmp: str) -> dict:
         if shared_params[0] is None:
             shared_params[0] = engine.params
         engine.step()  # idle warmup — compiles counted only after this
-        jax_monitoring.register_event_listener(_compile_listener)
+        listener = register_compile_listener(compiles.append)
         try:
             step_no = [0]
             vrng = np.random.default_rng(seed)        # victim schedule
@@ -1763,8 +1762,7 @@ def scenario_noisy_neighbor(tmp: str) -> dict:
                     "victim_shed_metric": victim_shed,
                     "prom_text": prom_text}
         finally:
-            _monitoring_impl._unregister_event_listener_by_callback(
-                _compile_listener)
+            unregister_compile_listener(listener)
             engine.close()
 
     def p(xs, q):

@@ -1,0 +1,154 @@
+"""CPU rehearsals of ``chip_smoke.py`` and the compile-cache helper.
+
+The smoke's real run needs the chip; here it runs end to end at toy
+width (``--rehearse``) in a child process, so the paths, arguments and
+control flow of every phase are exercised by tier-1, and the contract
+of its last line and of what it leaves on disk is held.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO_ROOT, "chip_smoke.py")
+if REPO_ROOT not in sys.path:
+    sys.path.insert(0, REPO_ROOT)  # chip_smoke.py and bench.py live there
+
+
+def _tree_files():
+    """Every file under the checkout, outside git's own directory, the
+    compile cache and Python's bytecode."""
+    out = set()
+    for d, dirs, files in os.walk(REPO_ROOT):
+        dirs[:] = [x for x in dirs
+                   if x not in (".git", ".jax_cache", "__pycache__")]
+        out.update(os.path.join(d, f) for f in files)
+    return out
+
+
+@pytest.fixture
+def cache_config():
+    """Restore the cache flags the helper may set."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def _run_smoke(*argv, env=None, devices=1):
+    child_env = {k: v for k, v in os.environ.items()
+                 if k != "JAX_ENABLE_COMPILATION_CACHE"}
+    child_env["JAX_PLATFORMS"] = "cpu"
+    child_env["XLA_FLAGS"] = \
+        f"--xla_force_host_platform_device_count={devices}"
+    child_env.update(env or {})
+    return subprocess.run([sys.executable, SMOKE, *argv], env=child_env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=REPO_ROOT)
+
+
+def test_rehearsal_runs_every_phase_and_leaves_the_tree_alone(tmp_path):
+    cache = tmp_path / "cache"
+    before = _tree_files()
+    proc = _run_smoke("--rehearse",
+                      env={"JAX_COMPILATION_CACHE_DIR": str(cache)})
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    for phase in ("train", "checkpoint", "serve", "decode"):
+        assert any(ln.startswith(f"[smoke] phase {phase}: PASS")
+                   for ln in lines), (phase, proc.stdout[-3000:])
+    last = json.loads(lines[-1])
+    # the platform is not tpu, so ok is never true — phases passing
+    # shows in the exit code and the earlier lines
+    assert last == {"ok": False,
+                    "device": {"platform": "cpu", "kind": "cpu",
+                               "count": 1}}
+    assert '"ok": true' not in proc.stdout
+    # the compile cache went where the variable says and nowhere else;
+    # checkpoints, logs and exec-cache blobs left with the temp dir
+    assert any(cache.iterdir())
+    assert _tree_files() == before
+
+
+def test_without_a_tpu_the_plain_command_fails_and_prints_no_result():
+    proc = _run_smoke()
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "not a TPU" in proc.stderr
+
+
+def test_a_failing_phase_is_a_nonzero_exit(monkeypatch, capsys,
+                                           cache_config):
+    import chip_smoke
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("injected")
+
+    monkeypatch.setattr(chip_smoke, "phase_train", boom)
+    rc = chip_smoke.main(["--rehearse"])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc != 0
+    assert any("phase train: FAIL" in ln for ln in out)
+    assert json.loads(out[-1])["ok"] is False
+
+
+def test_four_device_rehearsal_shards_over_four_devices(tmp_path):
+    proc = _run_smoke("--rehearse", "--chips", "4", devices=4,
+                      env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    assert any("shards" in ln and "on devices [0, 1, 2, 3]" in ln
+               for ln in lines), proc.stdout[-3000:]
+    assert any("first-step loss agrees" in ln for ln in lines)
+    for phase in ("train_dp2_tp2", "train_one_chip"):
+        assert any(ln.startswith(f"[smoke] phase {phase}: PASS")
+                   for ln in lines)
+    # only the two train phases ran, and the count is 4
+    assert not any("phase serve" in ln or "phase decode" in ln
+                   for ln in lines)
+    assert json.loads(lines[-1])["device"]["count"] == 4
+
+
+# --- the compile-cache helper ------------------------------------------------
+
+
+def test_compile_cache_named_by_the_environment_sets_nothing(
+        monkeypatch, tmp_path, cache_config):
+    from perceiver_tpu.cache import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    # JAX read the variable itself at import; the code set nothing
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch,
+                                                cache_config):
+    from perceiver_tpu.cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO_ROOT, ".jax_cache")
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_bench_refuses_a_platform_that_is_not_the_one_asked_for(
+        monkeypatch):
+    """bench.py measures a TPU: on the CPU backend it exits at once
+    unless BENCH_PLATFORM=cpu asks for a smoke run."""
+    import bench
+
+    monkeypatch.delenv("BENCH_PLATFORM", raising=False)
+    with pytest.raises(SystemExit, match="measures platform 'tpu'"):
+        bench.require_platform()
+    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
+    bench.require_platform()

@@ -17,7 +17,6 @@ The load-bearing properties:
   blocking; deadlines shed typed; freed pages re-admit the queue.
 """
 
-import contextlib
 import threading
 import time
 
@@ -27,6 +26,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from perceiver_tpu.cache import compile_events
 from perceiver_tpu.obs import events as events_mod
 from perceiver_tpu.obs.events import EventLog
 from perceiver_tpu.ops.policy import Policy
@@ -45,24 +45,6 @@ from perceiver_tpu.serving.decode import (
 )
 from perceiver_tpu.serving.engine import RequestTooLarge
 from perceiver_tpu.tasks.mlm import MaskedLanguageModelTask
-
-
-@contextlib.contextmanager
-def compile_events():
-    """Collect XLA compile events (jax.monitoring) inside the block."""
-    from jax._src import monitoring as _monitoring
-
-    events = []
-
-    def listener(name, **kwargs):
-        if "compile" in name:
-            events.append(name)
-
-    jax.monitoring.register_event_listener(listener)
-    try:
-        yield events
-    finally:
-        _monitoring._unregister_event_listener_by_callback(listener)
 
 
 VOCAB = 211

@@ -44,7 +44,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cache(tmp_path, **kw):
-    kw.setdefault("native", False)
     return ExecutableCache(str(tmp_path / "ec"), **kw)
 
 
@@ -141,7 +140,7 @@ class TestExecutableEntries:
         # touch the oldest so the MIDDLE entry becomes LRU
         assert cache.load_executable(keys[0]) is not None
         time.sleep(0.02)
-        small = ExecutableCache(cache.path, native=False,
+        small = ExecutableCache(cache.path,
                                 max_bytes=2 * per_entry + per_entry // 2)
         small._evict()
         assert small.entry_bytes() <= small.max_bytes
@@ -184,10 +183,10 @@ class TestExecutableEntries:
                 np.arange(4.0))
         assert cache.stats.stores == 0 and cache.stats.hits == 0
 
-    def test_executable_key_canonicalizes_callback_ptrs(self, tmp_path):
-        """Two lowerings of the same callback-bearing program differ
-        only in the per-lowering wrapper address — keys must agree
-        (and only those digits are masked)."""
+    def test_executable_key_is_stable_across_lowerings(self, tmp_path):
+        """Two lowerings of the same callback-bearing program key the
+        same (the installed jax prints a callback index, not a
+        per-lowering wrapper address)."""
         cache = _cache(tmp_path)
 
         def make():
@@ -201,7 +200,6 @@ class TestExecutableEntries:
 
         t1 = jax.jit(make()).lower(*ARGS).as_text()
         t2 = jax.jit(make()).lower(*ARGS).as_text()
-        assert t1 != t2, "wrapper address should differ per lowering"
         assert cache.executable_key(t1) == cache.executable_key(t2)
         # a genuine program difference still keys differently
         t3 = jax.jit(lambda p, x: p * x + 1).lower(*ARGS).as_text()
@@ -419,8 +417,7 @@ class TestEngineIntegration:
         deserialized decode executable (zero XLA compiles)."""
         import re
 
-        from jax._src import monitoring as _monitoring
-
+        from perceiver_tpu.cache import compile_events
         from perceiver_tpu.ops.policy import Policy
         from perceiver_tpu.serving.decode import (
             DecodeEngine,
@@ -439,21 +436,12 @@ class TestEngineIntegration:
                             policy=Policy.fp32(), auto_step=False,
                             exec_cache=cache_dir)
         cold.close(timeout=2.0)
-        events = []
-
-        def listener(name, **kwargs):
-            if "compile" in name:
-                events.append(name)
-
-        jax.monitoring.register_event_listener(listener)
-        try:
+        with compile_events() as events:
             warm = DecodeEngine(_tiny_task(), geometry=geometry,
                                 policy=Policy.fp32(), auto_step=False,
                                 exec_cache=cache_dir,
                                 prefix_cache=PrefixCacheConfig())
             warm.close(timeout=2.0)
-        finally:
-            _monitoring._unregister_event_listener_by_callback(listener)
         assert events == [], (
             f"prefix caching forked the executable key: {events}")
 
@@ -479,10 +467,9 @@ task = MaskedLanguageModelTask(
 engine = ServingEngine(task, batch_buckets=(1, 2),
                        seq_buckets=(16, 32), warmup=False,
                        exec_cache=sys.argv[1])
+from perceiver_tpu.cache import register_compile_listener
 events = []
-jax.monitoring.register_event_listener(
-    lambda name, **kw: events.append(name) if "compile" in name
-    else None)
+register_compile_listener(events.append)
 engine.warmup()
 res = engine.dispatch({
     "input_ids": np.full((1, 10), 5, np.int32),

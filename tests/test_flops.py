@@ -27,6 +27,18 @@ def test_device_peak_flops_cpu_is_none():
     assert device_peak_flops() is None
 
 
+def test_device_peak_flops_unknown_tpu_kind_is_an_error():
+    import types
+
+    import pytest
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peak_flops(v5e) == 197e12
+    odd = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peak_flops(odd)
+
+
 def test_mfu_math_and_guards():
     assert mfu(1e12, 10, 1.0, 1, 197e12) == (1e13 / 197e12)
     assert mfu(None, 10, 1.0, 1, 197e12) is None

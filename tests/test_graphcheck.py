@@ -273,7 +273,7 @@ def test_cache_key_stability_across_lowering_cache(tmp_path):
     warm check.py --graph path."""
     from perceiver_tpu.cache import ExecutableCache
 
-    cache = ExecutableCache(str(tmp_path / "ec"), native=False)
+    cache = ExecutableCache(str(tmp_path / "ec"))
     target = StepTarget(name="tiny_stable_cached",
                         build=lambda: (_tiny_mlm(), _tiny_batch()))
     fresh = lower_target(target, cache=cache)

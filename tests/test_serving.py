@@ -16,7 +16,6 @@ The acceptance properties, each pinned here:
   the regression the old re-jitting helper failed.
 """
 
-import contextlib
 import threading
 import time
 
@@ -25,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from perceiver_tpu.cache import compile_events
 from perceiver_tpu.ops.policy import Policy
 from perceiver_tpu.serving import (
     MicroBatcher,
@@ -53,24 +53,6 @@ def tiny_mlm_task(**overrides):
         num_decoder_cross_attention_heads=1, loss_impl="dense")
     kwargs.update(overrides)
     return MaskedLanguageModelTask(**kwargs)
-
-
-@contextlib.contextmanager
-def compile_events():
-    """Collect XLA compile events (jax.monitoring) inside the block."""
-    from jax._src import monitoring as _monitoring
-
-    events = []
-
-    def listener(name, **kwargs):
-        if "compile" in name:
-            events.append(name)
-
-    jax.monitoring.register_event_listener(listener)
-    try:
-        yield events
-    finally:
-        _monitoring._unregister_event_listener_by_callback(listener)
 
 
 def request_arrays(batch, length, seed=0, mask_every=4):
@@ -1059,10 +1041,9 @@ def encode_fn(texts):
     pad_mask = np.arange(16)[None, :] >= lengths[:, None]
     return ids, pad_mask
 
+from perceiver_tpu.cache import register_compile_listener
 events = []
-jax.monitoring.register_event_listener(
-    lambda name, **kw: events.append(name) if "compile" in name
-    else None)
+register_compile_listener(events.append)
 preds = predict_masked_samples(
     ["the quick [MASK] jumps", "a [MASK] dog"], encode_fn, tok,
     model, params, num_predictions=2)

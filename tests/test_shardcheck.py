@@ -115,11 +115,36 @@ def test_collective_inventory_attributes_and_skips_singletons():
 
 
 def test_sharding_factor():
-    assert hlo.sharding_factor(None) == 1
-    assert hlo.sharding_factor("{replicated}") == 1
-    assert hlo.sharding_factor("{devices=[2,2]<=[4]}") == 4
-    assert hlo.sharding_factor(
-        "{devices=[2,1,2]<=[4] last_tile_dim_replicate}") == 2
+    axes = {"data": 2, "model": 2}
+    assert hlo.sharding_factor(None, axes) == 1
+    assert hlo.sharding_factor("[{}, {}]", axes) == 1
+    assert hlo.sharding_factor('[{"data"}, {"model"}]', axes) == 4
+    assert hlo.sharding_factor('[{}, {"model", ?}]', axes) == 2
+    assert hlo.sharding_factor('[{"data":(1)2}]', {"data": 4}) == 2
+
+
+def test_main_args_reads_shardy_attrs():
+    """The installed JAX prints shardings the Shardy way; the attr body
+    nests braces, and aliasing rides in the same dict."""
+    text = (
+        'module @jit_f {\n'
+        '  sdy.mesh @mesh = <["data"=2, "model"=2]>\n'
+        '  func.func public @main(%arg0: tensor<64x128xf32> '
+        '{sdy.sharding = #sdy.sharding<@mesh, [{}, {"model"}]>, '
+        'tf.aliasing_output = 0 : i32}, %arg1: tensor<32x64xf32> '
+        '{sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {}]>}) -> '
+        '(tensor<64x128xf32> {jax.result_info = "result[0]", '
+        'sdy.sharding = #sdy.sharding<@mesh, [{}, {"model"}]>}, '
+        'tensor<f32> {jax.result_info = "result[1]", '
+        'sdy.sharding = #sdy.sharding<@mesh, []>}) {\n  }\n}\n')
+    assert hlo.mesh_axes(text) == {"data": 2, "model": 2}
+    a0, a1 = hlo.main_args(text)
+    assert a0 == {"type": "64x128xf32", "aliased": True,
+                  "donor_only": False, "sharding": '[{}, {"model"}]'}
+    assert a1["sharding"] == '[{"data"}, {}]' and not a1["aliased"]
+    r0, r1 = hlo.main_results(text)
+    assert r0 == {"type": "64x128xf32", "sharding": '[{}, {"model"}]'}
+    assert r1 == {"type": "f32", "sharding": "[]"}
 
 
 # --- collective_budget ------------------------------------------------------
@@ -175,12 +200,16 @@ def test_collective_budget_fails_on_mesh_mismatch():
 # 8192x64xf32 = 2 MB (above the 1 MiB floor); 256x64xf32 = 64 KB below
 _REPLICATED_MAIN = (
     'module @jit_step {\n'
+    '  sdy.mesh @mesh = <["data"=2, "model"=2]>\n'
     '  func.func public @main('
-    '%arg0: tensor<8192x64xf32> {mhlo.sharding = "{replicated}"}, '
-    '%arg1: tensor<8192x64xf32> {mhlo.sharding = '
-    '"{devices=[2,2]<=[4]}"}, '
-    '%arg2: tensor<256x64xf32> {mhlo.sharding = "{replicated}"}) '
-    '-> (tensor<8192x64xf32> {mhlo.sharding = "{replicated}"}) {\n'
+    '%arg0: tensor<8192x64xf32> '
+    '{sdy.sharding = #sdy.sharding<@mesh, [{}, {}]>}, '
+    '%arg1: tensor<8192x64xf32> '
+    '{sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {"model"}]>}, '
+    '%arg2: tensor<256x64xf32> '
+    '{sdy.sharding = #sdy.sharding<@mesh, [{}, {}]>}) '
+    '-> (tensor<8192x64xf32> '
+    '{sdy.sharding = #sdy.sharding<@mesh, [{}, {}]>}) {\n'
     '  }\n'
     '}\n')
 
@@ -217,14 +246,13 @@ def test_replication_check_floor_excludes_small_tensors():
 def test_replication_check_catches_midgraph_reshard():
     text = _REPLICATED_MAIN.replace(
         "  }\n",
-        '    %2 = stablehlo.custom_call @Sharding(%1) '
-        '{mhlo.sharding = "{replicated}"} : '
-        '(tensor<512x1024xf32>) -> tensor<512x1024xf32>\n  }\n')
+        '    %2 = sdy.sharding_constraint %1 <@mesh, [{}, {}]> : '
+        'tensor<512x1024xf32>\n  }\n')
     allow = (ReplicationAllow(type="8192x64xf32", max_count=2,
                               reason="boundary tensors excused"),)
     vs = replication_check(text, where="t", allowlist=allow)
     assert len(vs) == 1
-    assert "mid-graph @Sharding tensor<512x1024xf32>" in vs[0].message
+    assert "mid-graph constraint tensor<512x1024xf32>" in vs[0].message
 
 
 # --- per_shard_hbm_budget ---------------------------------------------------
@@ -384,18 +412,14 @@ def test_mesh_spec_properties_and_build():
 
 
 def _tiny_spmd_target():
-    from perceiver_tpu.analysis.targets import (
-        _MLM_OVERFLOW_CALLBACK,
-        _build_mlm,
-    )
+    from perceiver_tpu.analysis.targets import _build_mlm
 
     def build():
         return _build_mlm(batch=8, channels=16, seq_len=32, vocab=128,
                           loss_impl="packed")
 
     return StepTarget(name="tiny_mlm_spmd_dp2_tp2", build=build,
-                      mesh=DP2_TP2,
-                      transfer_allow=_MLM_OVERFLOW_CALLBACK)
+                      mesh=DP2_TP2)
 
 
 def test_tiny_sharded_target_end_to_end(monkeypatch, tmp_path):
