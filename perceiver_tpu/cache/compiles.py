@@ -5,6 +5,15 @@ The cache directory is part of the cache's key, so it must not move
 between runs: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
 itself and nothing is set here; otherwise the cache is
 ``<checkout>/.jax_cache`` — never a temporary name, pid or time.
+
+A program's metadata is part of its key here, which is not JAX's
+default. By default the key leaves out operation names and source
+lines, so a program that differs from an earlier one in metadata alone
+is served the earlier executable *with the earlier metadata*: a step
+compiled before the layer scopes existed then shows no scope in any
+profile, and every metric read off the trace's name stacks reads
+nothing (seen on the chip, PERF.md Findings, PR 25). The price is a
+compile whenever a source line on the step's path moves.
 """
 
 from __future__ import annotations
@@ -25,11 +34,12 @@ _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process and
     return its directory. Call before the first device use."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path
-    import jax
-
     path = os.path.join(_REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     # keep every program: a cold start is many sub-second compiles

@@ -72,6 +72,13 @@ class PrefetchIterator:
         # would silently drop the rest of the epoch, so never restart
         self._restartable = not hasattr(inner, "__next__")
         self.restarts = 0  # total producer restarts (observability)
+        self._queue: Optional["queue.Queue"] = None  # the live epoch's
+
+    def queue_depth(self) -> int:
+        """Batches ready now (0: a pull would wait for the producer;
+        also 0 between epochs)."""
+        q = self._queue
+        return q.qsize() if q is not None else 0
 
     def __len__(self) -> int:
         return len(self.inner)
@@ -136,6 +143,7 @@ class PrefetchIterator:
         backoff = self.backoff_s
         while True:
             q: "queue.Queue" = queue.Queue(maxsize=self.depth)
+            self._queue = q
             stop = threading.Event()
             t = threading.Thread(target=self._produce,
                                  args=(q, stop, delivered), daemon=True)
@@ -169,6 +177,7 @@ class PrefetchIterator:
                 # GeneratorExit) too: halt the producer after at most
                 # its in-flight batch
                 stop.set()
+                self._queue = None
                 t.join(timeout=0.2 if failure is not None else 5.0)
             if finished:
                 return

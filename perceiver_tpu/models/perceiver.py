@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.models.masking import TextMasking
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.attention import (
     ATTENTION_IMPLS,
     DECODER_ATTENTION_IMPLS,
@@ -230,29 +231,33 @@ class PerceiverEncoder:
     def _layer_apply(self, params, latent, kv_heads, pad_mask, attn_mask,
                      rng, deterministic, policy):
         k_cross, k_selfs = jax.random.split(_rng_or_dummy(rng))
-        latent = cross_attention_layer_apply(
-            params["cross"], latent, None,
-            num_heads=self.num_cross_attention_heads,
-            key_padding_mask=pad_mask, attn_mask=attn_mask,
-            dropout_rate=self.dropout, rng=k_cross,
-            deterministic=deterministic, policy=policy,
-            impl=self.attention_impl, kv_chunk_size=self.kv_chunk_size,
-            spmd=self.spmd, kv_heads=kv_heads)
-        return self_attention_block_apply(
-            params["selfs"], latent,
-            num_heads=self.num_self_attention_heads,
-            dropout_rate=self.dropout, rng=k_selfs,
-            deterministic=deterministic, policy=policy)
+        with device_scope("enc_cross_attn"):
+            latent = cross_attention_layer_apply(
+                params["cross"], latent, None,
+                num_heads=self.num_cross_attention_heads,
+                key_padding_mask=pad_mask, attn_mask=attn_mask,
+                dropout_rate=self.dropout, rng=k_cross,
+                deterministic=deterministic, policy=policy,
+                impl=self.attention_impl, kv_chunk_size=self.kv_chunk_size,
+                spmd=self.spmd, kv_heads=kv_heads)
+        with device_scope("latent_self_attn"):
+            return self_attention_block_apply(
+                params["selfs"], latent,
+                num_heads=self.num_self_attention_heads,
+                dropout_rate=self.dropout, rng=k_selfs,
+                deterministic=deterministic, policy=policy)
 
     def apply(self, params, x, pad_mask=None, attn_mask=None, *, rng=None,
               deterministic: bool = True, policy: Policy = DEFAULT_POLICY):
         """Returns ``(x_latent, pad_mask)`` (reference model.py:189)."""
         b = x.shape[0]
-        x = self.input_adapter.apply(params["input_adapter"], x,
-                                     policy=policy)
-        latent = jnp.broadcast_to(
-            policy.cast_param(params["latent"])[None],
-            (b, *self.latent_shape))
+        with device_scope("input_adapter"):
+            x = self.input_adapter.apply(params["input_adapter"], x,
+                                         policy=policy)
+        with device_scope("enc_cross_attn"):
+            latent = jnp.broadcast_to(
+                policy.cast_param(params["latent"])[None],
+                (b, *self.latent_shape))
 
         k1, kn = jax.random.split(_rng_or_dummy(rng, deterministic))
 
@@ -261,9 +266,11 @@ class PerceiverEncoder:
             # projects the SAME input tokens with the SAME (shared)
             # weights in every scan iteration — compute once per
             # distinct parameter set, close over it in the scan body
-            return cross_attention_kv(
-                layer_params["cross"]["attn"], x,
-                num_heads=self.num_cross_attention_heads, policy=policy)
+            with device_scope("enc_cross_attn"):
+                return cross_attention_kv(
+                    layer_params["cross"]["attn"], x,
+                    num_heads=self.num_cross_attention_heads,
+                    policy=policy)
 
         def one_layer(layer_params, kv_heads, latent, k):
             return self._layer_apply(layer_params, latent, kv_heads,
@@ -363,24 +370,26 @@ class PerceiverDecoder:
                 f"Latent shape {tuple(d)} different from required shape "
                 f"{tuple(self.latent_shape)}")
 
-        if query_positions is not None:
-            if not return_hidden:
-                raise ValueError(
-                    "query_positions requires return_hidden=True")
-            query = jnp.take(policy.cast_param(params["query"]),
-                             query_positions, axis=0)
-        else:
-            query = jnp.broadcast_to(
-                policy.cast_param(params["query"])[None],
-                (b, *self.output_adapter.output_shape))
+        if query_positions is not None and not return_hidden:
+            raise ValueError("query_positions requires return_hidden=True")
+        with device_scope("dec_cross_attn"):
+            if query_positions is not None:
+                query = jnp.take(policy.cast_param(params["query"]),
+                                 query_positions, axis=0)
+            else:
+                query = jnp.broadcast_to(
+                    policy.cast_param(params["query"])[None],
+                    (b, *self.output_adapter.output_shape))
 
         def run(q, k):
-            return cross_attention_layer_apply(
-                params["cross"], q, x,
-                num_heads=self.num_cross_attention_heads,
-                dropout_rate=self.dropout, rng=k,
-                deterministic=deterministic, policy=policy,
-                impl=self.attention_impl, kv_chunk_size=self.kv_chunk_size)
+            with device_scope("dec_cross_attn"):
+                return cross_attention_layer_apply(
+                    params["cross"], q, x,
+                    num_heads=self.num_cross_attention_heads,
+                    dropout_rate=self.dropout, rng=k,
+                    deterministic=deterministic, policy=policy,
+                    impl=self.attention_impl,
+                    kv_chunk_size=self.kv_chunk_size)
 
         num_q = query.shape[1]
         cs = self.query_chunk_size
@@ -397,8 +406,9 @@ class PerceiverDecoder:
             out = run(query, _rng_or_dummy(rng, deterministic))
         if return_hidden:
             return out
-        return self.output_adapter.apply(params["output_adapter"], out,
-                                         policy=policy)
+        with device_scope("output_adapter"):
+            return self.output_adapter.apply(params["output_adapter"], out,
+                                             policy=policy)
 
 
 # --- composed models ---------------------------------------------------------
@@ -509,7 +519,9 @@ class PerceiverMLM:
             _rng_or_dummy(rng, deterministic), 3)
 
         if masking:
-            x_masked, labels = self.masking.apply(k_mask, x_input, pad_mask)
+            with device_scope("input_adapter"):
+                x_masked, labels = self.masking.apply(k_mask, x_input,
+                                                      pad_mask)
         else:
             x_masked, labels = x_input, None
 
@@ -517,8 +529,9 @@ class PerceiverMLM:
             params["encoder"], x_masked, pad_mask, rng=k_enc,
             deterministic=deterministic, policy=policy)
         if query_capacity is not None:
-            positions, labels_q, dropped = _pack_masked_positions(
-                labels, query_capacity)
+            with device_scope("dec_cross_attn"):
+                positions, labels_q, dropped = _pack_masked_positions(
+                    labels, query_capacity)
             hidden = self.decoder.apply(
                 params["decoder"], latent, rng=k_dec,
                 deterministic=deterministic, policy=policy,

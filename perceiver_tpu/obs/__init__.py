@@ -1,11 +1,13 @@
-"""One observability plane: request tracing, typed events, fleet
-metrics aggregation, training telemetry, on-demand profiling.
+"""One observability plane: request tracing, the step timeline and
+device scopes, typed events, fleet metrics aggregation, training
+telemetry, on-demand profiling.
 
 See docs/OBSERVABILITY.md for the schemas, the endpoint map, and the
 overhead budget.  Everything here is host-side and dependency-free:
-tracing and events never touch jax, so they can never change an XLA
+tracing and events do no device work, so they can never change an XLA
 cache key or add a compile (the same contract as
-``resilience/faults.py`` unarmed).
+``resilience/faults.py`` unarmed); ``jax.profiler.TraceAnnotation`` and
+``jax.named_scope`` are taken from a ``jax`` that is loaded already.
 """
 
 from perceiver_tpu.obs.events import (
@@ -17,32 +19,45 @@ from perceiver_tpu.obs.events import (
     validate_event,
 )
 from perceiver_tpu.obs.trace import (
+    DEVICE_SCOPES,
+    ENCLOSING_SPANS,
     PHASES,
+    TRAIN_PHASES,
     SpanCollector,
+    Timeline,
     TraceBuffer,
     TraceContext,
     attach,
     attached,
     default_buffer,
+    device_scope,
     enabled,
     from_wire,
     region,
     set_default_buffer,
     set_enabled,
+    set_timeline,
+    span,
     start_trace,
+    timeline,
 )
 
 __all__ = [
+    "DEVICE_SCOPES",
+    "ENCLOSING_SPANS",
     "PHASES",
     "SCHEMA",
+    "TRAIN_PHASES",
     "EventLog",
     "SpanCollector",
+    "Timeline",
     "TraceBuffer",
     "TraceContext",
     "attach",
     "attached",
     "default_buffer",
     "default_log",
+    "device_scope",
     "emit",
     "enabled",
     "from_wire",
@@ -50,6 +65,9 @@ __all__ = [
     "set_default_buffer",
     "set_default_log",
     "set_enabled",
+    "set_timeline",
+    "span",
     "start_trace",
+    "timeline",
     "validate_event",
 ]

@@ -51,8 +51,16 @@ class Telemetry:
             "training_steps_per_second", "optimizer steps per second")
         self._m_samples_per_sec = m.gauge(
             "training_samples_per_second", "training throughput")
-        self._m_tokens_per_sec = m.gauge(
-            "training_tokens_per_second", "token throughput")
+        self._m_input_wait = m.counter(
+            "training_input_wait_seconds_total",
+            "seconds the loop waited for its next batch (train/input_wait)")
+        self._m_fence_wait = m.counter(
+            "training_fence_wait_seconds_total",
+            "seconds the host waited for the device (train/fence)")
+        self._m_host_busy = m.counter(
+            "training_host_busy_seconds_total",
+            "seconds of the loop's own host work: shard, dispatch, "
+            "guard sync, logging")
         self._m_guard_skips = m.counter(
             "training_guard_skips_total", "non-finite steps skipped")
         self._m_rewinds = m.counter(
@@ -68,9 +76,14 @@ class Telemetry:
     def step(self, step: int, loss: float, *, steps_delta: int = 1,
              steps_per_sec: Optional[float] = None,
              samples_per_sec: Optional[float] = None,
-             tokens_per_sec: Optional[float] = None, **extra) -> dict:
+             input_wait_s: Optional[float] = None,
+             host_s: Optional[float] = None,
+             fence_s: Optional[float] = None, **extra) -> dict:
         """Record one logged step (values must already be host floats —
-        never pass device arrays; the trainer syncs first)."""
+        never pass device arrays; the trainer syncs first).
+        ``input_wait_s`` / ``host_s`` / ``fence_s`` are the seconds of
+        the trainer's phases (``obs.trace.TRAIN_PHASES``) since the last
+        logged step."""
         self._m_steps.inc(steps_delta)
         self._m_loss.set(loss)
         fields = {"step": int(step), "loss": float(loss)}
@@ -80,9 +93,13 @@ class Telemetry:
         if samples_per_sec is not None:
             self._m_samples_per_sec.set(samples_per_sec)
             fields["samples_per_sec"] = round(float(samples_per_sec), 4)
-        if tokens_per_sec is not None:
-            self._m_tokens_per_sec.set(tokens_per_sec)
-            fields["tokens_per_sec"] = round(float(tokens_per_sec), 4)
+        for key, counter, seconds in (
+                ("input_wait_s", self._m_input_wait, input_wait_s),
+                ("host_s", self._m_host_busy, host_s),
+                ("fence_s", self._m_fence_wait, fence_s)):
+            if seconds is not None:
+                counter.inc(seconds)
+                fields[key] = round(float(seconds), 6)
         for k, v in extra.items():
             try:
                 fields[k] = float(v)

@@ -12,26 +12,51 @@ them into one trace.  A retried request therefore yields a SINGLE
 trace with the failed hop, the ``retry`` span, and the sibling's
 server-side spans all visible.
 
-Everything here is host-side Python: no jax imports, no device work,
-so tracing can never change an XLA cache key or add a compile.  When
-tracing is disabled (``set_enabled(False)``), ``start_trace`` returns
-``None`` and the hot-path cost of an instrumented call site collapses
-to one thread-local attribute read.
+Everything here is host-side Python: no device work and no compile, so
+tracing can never change an XLA cache key.  The one thing taken from
+JAX is ``jax.profiler.TraceAnnotation``, imported lazily and only in a
+process that has loaded ``jax`` already.  When tracing is disabled
+(``set_enabled(False)``), ``start_trace`` returns ``None``, ``span`` is
+a no-op, and the hot-path cost of an instrumented call site collapses
+to one global read.
+
+Process-level spans (:func:`span`, :class:`Timeline`) are the second
+half: what the trainer (and later the decode engine) was doing, step by
+step, with no request to hang it on.  A span lands in a bounded ring on
+``time.monotonic`` and, while it is open, is a ``TraceAnnotation`` too,
+so in any profiler capture it lies on the host plane of the same
+``.xplane.pb`` as the device's operations: one timeline, the device's
+clock.  ``region`` goes through ``span``, so the wrap-style request
+phases reach the profiler as well; phases recorded retrospectively
+(``TraceContext.record(start=, end=)``: ``queue_wait``, ``batch_form``)
+were over before anybody knew, and stay host-only.
+
+*Annotations are leaves only.*  A reduction that gives each idle gap of
+the device to the host event covering most of it would give every gap
+to an enclosing ``train/step`` event.  So the spans of
+``ENCLOSING_SPANS`` go to the ring alone (as parents, carrying
+``step``) and only leaf phases are emitted as annotations, each with
+``step_num``; they tile their parent without nesting.
 
 Clock caveat: span ``start``/``end`` are ``time.monotonic`` values and
 are only comparable *within* one process.  Cross-process ordering uses
 the spans' ``wall`` field (coarse ``time.time``), durations are always
 trustworthy.
 
-This is *request* tracing; for XLA profiler traces (the other kind of
-"trace") see ``scripts/trace_analysis.py`` and the ``/profile``
-endpoint in :mod:`perceiver_tpu.obs.server`.
+Profiler captures themselves (the other kind of "trace") are taken by
+``TrainerConfig.profiler``, SIGUSR1 (:mod:`perceiver_tpu.obs.telemetry`)
+or the ``/profile`` endpoint of :mod:`perceiver_tpu.obs.server`, and
+reduced by ``benchmarks/trace_reduce.py`` and
+``benchmarks/scope_times.py``; docs/OBSERVABILITY.md, "Step timeline and
+device scopes".
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -39,6 +64,14 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "PHASES",
+    "TRAIN_PHASES",
+    "ENCLOSING_SPANS",
+    "DEVICE_SCOPES",
+    "Timeline",
+    "span",
+    "device_scope",
+    "timeline",
+    "set_timeline",
     "TraceContext",
     "TraceBuffer",
     "SpanCollector",
@@ -73,6 +106,48 @@ PHASES = (
     "verify",       # decode engine: target verification of drafted tokens
 )
 
+#: The trainer's phases (``training/trainer.py``), a closed set like
+#: ``PHASES``.  Per dispatch: ``train/step`` holds ``input_wait``,
+#: ``shard``, ``dispatch``, ``guard_sync`` (armed guard only), ``fence``
+#: and ``log`` (logged steps), and ``step_load`` on the first.
+TRAIN_PHASES = (
+    "train/step",         # one dispatch of the loop; ring only
+    "train/input_wait",   # the pull from the (prefetching) loader
+    "train/shard",        # np.stack of a multi-step group + device_put
+    "train/dispatch",     # the call of the jitted step (async: host cost)
+    "train/guard_sync",   # armed guard: per-step losses to the host
+    "train/fence",        # the host waiting for the device's metrics
+    "train/log",          # print, summary writer, telemetry line
+    "train/build_state",  # model.init + restore + optimizer; ring only
+    "train/model_init",   # eager model.init
+    "train/restore",      # task.restore_pretrained / checkpoint restore
+    "train/step_load",    # lower + compile or cache read of the step
+    "train/eval",         # a validation pass
+    "train/checkpoint",   # a checkpoint save handed to the hook
+    "train/anchor",       # the guard's last-good anchor save
+)
+
+#: Spans that hold other spans: recorded in the ring, never emitted as
+#: profiler annotations (module docstring, "leaves only").
+ENCLOSING_SPANS = ("train/step", "train/build_state")
+
+#: ``jax.named_scope`` names on the train step's device operations, at
+#: the layer boundaries.  Layers (outer): the two adapters and the three
+#: attention stacks.  Inside a layer: ``attn_core`` (scores, softmax,
+#: probabilities x values, in the forward AND the custom backward),
+#: ``attn_proj`` (q/k/v/out projections), ``mlp``.  ``loss`` and
+#: ``optimizer`` stand beside the layers.  The pass is not a scope: it
+#: is JAX's own ``jvp(``, ``transpose(`` and ``checkpoint`` /
+#: ``rematted_computation`` in the same name stack.
+DEVICE_SCOPES = (
+    "input_adapter", "enc_cross_attn", "latent_self_attn",
+    "dec_cross_attn", "output_adapter",
+    "attn_core", "attn_proj", "mlp", "loss", "optimizer",
+)
+
+_SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES)
+_RING_ONLY = frozenset(ENCLOSING_SPANS)
+
 _enabled = True
 
 
@@ -81,7 +156,8 @@ def enabled() -> bool:
 
 
 def set_enabled(flag: bool) -> None:
-    """Process-wide tracing switch (used by the overhead gate tests)."""
+    """Process-wide tracing switch (used by the overhead gate tests):
+    off, ``start_trace`` gives ``None`` and ``span`` records nothing."""
     global _enabled
     _enabled = bool(flag)
 
@@ -288,15 +364,224 @@ def attach(ctxs: Sequence[Optional[TraceContext]]):
 @contextlib.contextmanager
 def region(phase: str, **attrs):
     """Record ``phase`` over the wrapped block into every attached
-    trace.  Cost when nothing is attached: one getattr + tuple check."""
+    trace, as a :func:`span` (so it is in the timeline and, under a
+    capture, in the profile).  Cost when nothing is attached: one
+    getattr + tuple check."""
     ctxs = getattr(_tls, "ctxs", ())
     if not ctxs:
         yield
         return
-    start = time.monotonic()
+    if phase not in PHASES:
+        raise ValueError(
+            f"unknown trace phase {phase!r}; expected one of {PHASES}")
+    # a live request context means tracing was on when it began: the
+    # span is taken whatever the switch says now
+    sp = _Span(phase, dict(attrs))
     try:
-        yield
+        with sp:
+            yield
     finally:
-        end = time.monotonic()
         for c in ctxs:
-            c.record(phase, start=start, end=end, **attrs)
+            c.record(phase, start=sp.start, end=sp.end, **attrs)
+
+
+# --- process-level spans ----------------------------------------------------
+# What a process was doing when no request says: the trainer's step
+# phases.  One ring per process, written at span close.
+
+
+class Timeline:
+    """Bounded, preallocated ring of closed spans (thread-safe).
+
+    A span is ``{"id", "parent", "name", "start", "end", "duration_s",
+    "step", "thread", "attrs"}``: ``start``/``end`` on
+    ``time.monotonic``, ``parent`` the id of the span open around it on
+    the same thread (or None), ``step`` its own or its parent's.  When
+    the ring is full the oldest span is overwritten and ``dropped``
+    counts it."""
+
+    _GUARDED = {"_slots": "_lock", "_next": "_lock", "dropped": "_lock"}
+
+    def __init__(self, capacity: int = 4096) -> None:
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        self.capacity = int(capacity)
+        self._lock = threading.Lock()
+        self._slots: List[Optional[tuple]] = [None] * self.capacity
+        self._next = 0          # spans ever written
+        self.dropped = 0
+
+    def _add(self, record: tuple) -> None:
+        with self._lock:
+            i = self._next % self.capacity
+            if self._slots[i] is not None:
+                self.dropped += 1
+            self._slots[i] = record
+            self._next += 1
+
+    def spans(self, name: Optional[str] = None, *,
+              since: Optional[float] = None,
+              until: Optional[float] = None) -> List[dict]:
+        """The retained spans, oldest first; optionally those called
+        ``name`` and those that started in ``[since, until]``."""
+        with self._lock:
+            n, cap = self._next, self.capacity
+            records = [self._slots[i % cap]
+                       for i in range(max(0, n - cap), n)]
+        out = []
+        for sid, parent, nm, start, end, step, thread, attrs in records:
+            if name is not None and nm != name:
+                continue
+            if (since is not None and start < since) or \
+                    (until is not None and start > until):
+                continue
+            out.append({"id": sid, "parent": parent, "name": nm,
+                        "start": start, "end": end,
+                        "duration_s": end - start, "step": step,
+                        "thread": thread, "attrs": attrs})
+        return out
+
+    def __len__(self) -> int:
+        with self._lock:
+            return min(self._next, self.capacity)
+
+
+_timeline = Timeline()
+_span_ids = itertools.count(1)
+_now = time.monotonic   # the spans' clock, one name for tests to pin
+
+
+def timeline() -> Timeline:
+    return _timeline
+
+
+def set_timeline(tl: Timeline) -> Timeline:
+    global _timeline
+    prev = _timeline
+    _timeline = tl
+    return prev
+
+
+def _annotation(name: str, attrs: dict):
+    """``jax.profiler.TraceAnnotation`` if this process has ``jax``
+    loaded, else None: tracing itself never imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+class _Span:
+    """One open span; the context manager :func:`span` returns.  After
+    the block ``seconds`` is its length; ``cancel()`` inside the block
+    keeps it out of the ring (a pull that found the epoch over)."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "step", "start", "end",
+                 "_annotation", "_cancelled")
+
+    def __init__(self, name: str, attrs: dict) -> None:
+        self.name, self.attrs = name, attrs
+        self.start = self.end = 0.0
+        self._annotation = None
+        self._cancelled = False
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def cancel(self) -> None:
+        self._cancelled = True
+
+    def __enter__(self) -> "_Span":
+        stack = getattr(_tls, "spans", None)
+        if stack is None:
+            stack = _tls.spans = []
+        outer = stack[-1] if stack else None
+        self.id = next(_span_ids)
+        self.parent = outer.id if outer is not None else None
+        self.step = self.attrs.pop("step", None)
+        if self.step is None and outer is not None:
+            self.step = outer.step
+        stack.append(self)
+        if self.name not in _RING_ONLY:
+            notes = self.attrs if self.step is None \
+                else {"step_num": self.step, **self.attrs}
+            self._annotation = _annotation(self.name, notes)
+            if self._annotation is not None:
+                self._annotation.__enter__()
+        self.start = _now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = _now()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _tls.spans.pop()
+        if not self._cancelled:
+            _timeline._add((self.id, self.parent, self.name, self.start,
+                            self.end, self.step,
+                            threading.get_ident(), self.attrs))
+
+
+class _NoSpan:
+    """``span`` with tracing off: nothing recorded, nothing timed."""
+
+    __slots__ = ()
+    seconds = 0.0
+
+    def cancel(self) -> None:
+        pass
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NOOP = _NoSpan()
+
+
+def span(name: str, **attrs):
+    """Context manager: one process-level span called ``name`` (of
+    ``PHASES`` or ``TRAIN_PHASES``) around the block, into the
+    :func:`timeline` ring and, unless it is an enclosing span, the
+    profiler.  ``step=`` gives the span its step id; a span without one
+    takes its parent's.  Other keywords are the span's ``attrs`` (and
+    the annotation's).  With no capture running the cost is two clock
+    reads, a ring write and an inactive ``TraceMe``."""
+    if not _enabled:
+        return _NOOP
+    if name not in _SPAN_NAMES:
+        raise ValueError(
+            f"unknown span name {name!r}; expected one of "
+            f"{PHASES + TRAIN_PHASES}")
+    return _Span(name, attrs)
+
+
+class device_scope(contextlib.ContextDecorator):
+    """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES``, as a
+    context manager or a function decorator: the layer a device
+    operation belongs to, in its name stack.  Metadata only: the
+    compiled program does not change.  ``jax.named_scope`` is looked up
+    at entry, so a test can lower the same step without the scopes."""
+
+    def __init__(self, name: str) -> None:
+        if name not in DEVICE_SCOPES:
+            raise ValueError(
+                f"unknown device scope {name!r}; expected one of "
+                f"{DEVICE_SCOPES}")
+        self.name = name
+        self._scope = None
+
+    def _recreate_cm(self) -> "device_scope":
+        return device_scope(self.name)   # one per call: re-entrant
+
+    def __enter__(self) -> None:
+        import jax
+
+        self._scope = jax.named_scope(self.name)
+        self._scope.__enter__()
+
+    def __exit__(self, *exc):
+        return self._scope.__exit__(*exc)

@@ -36,6 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.initializers import uniform, xavier_uniform
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
@@ -206,6 +207,7 @@ DECODER_ATTENTION_IMPLS = (None, "einsum", "chunked", "flash")
 _SPMD_IMPLS = SPMD_IMPLS
 
 
+@device_scope("attn_proj")
 def mha_kv_heads(params, k, v, *, num_heads: int,
                  policy: Policy = DEFAULT_POLICY):
     """Project k/v and split heads: the loop-invariant half of
@@ -266,6 +268,24 @@ def mha_apply(params, q, k, v, *, num_heads: int,
         raise ValueError(
             f"impl={impl!r} needs spmd=(mesh, seq_axis, batch_axis)")
 
+    qh, kh, vh = _project_heads(params, q, k, v, num_heads, policy,
+                                kv_heads)
+    if impl in ("chunked", "flash", *_SPMD_IMPLS):
+        out = _streamed_core(qh, kh, vh, impl, key_padding_mask,
+                             dropout_rate, rng, deterministic,
+                             kv_chunk_size, spmd)
+    else:
+        out = _materialized_core(qh, kh, vh, key_padding_mask, attn_mask,
+                                 dropout_rate, rng, deterministic, policy)
+    b, lq = out.shape[0], out.shape[1]
+    out = out.reshape(b, lq, num_heads * qh.shape[-1])
+    with device_scope("attn_proj"):
+        return linear_apply(params["out"], out, policy=policy)
+
+
+@device_scope("attn_proj")
+def _project_heads(params, q, k, v, num_heads, policy, kv_heads):
+    """q/k/v projections split into heads, (B, L, H, D) each."""
     if kv_heads is not None:
         # pre-projected (kh, vh) from mha_kv_heads — the hoisted
         # loop-invariant path; only the q projection runs per call
@@ -294,54 +314,61 @@ def mha_apply(params, q, k, v, *, num_heads: int,
                           num_heads)
         vh = _split_heads(linear_apply(params["v"], v, policy=policy),
                           num_heads)
+    return qh, kh, vh
 
-    head_dim = qh.shape[-1]
-    if impl in ("chunked", "flash", *_SPMD_IMPLS):
-        import perceiver_tpu.ops.chunked_attention as _ca
-        bias = (_ca.pad_mask_to_bias(key_padding_mask)
-                if key_padding_mask is not None else None)
-        # (B, L, H, D) → (B, H, L, D)
-        qt, kt, vt = (x.swapaxes(1, 2) for x in (qh, kh, vh))
-        scale = 1.0 / (head_dim ** 0.5)
-        if impl == "chunked":
-            drop = dropout_rate if not deterministic else 0.0
-            if drop > 0.0 and rng is None:
-                # mirror the einsum path (ops/dropout.py): silently
-                # skipping configured dropout would be invisible
-                raise ValueError("dropout needs an rng when not "
-                                 "deterministic")
-            out = _ca.chunked_attention(qt, kt, vt, bias=bias, scale=scale,
-                                        chunk_size=kv_chunk_size,
-                                        dropout_rate=drop, rng=rng)
-        elif impl == "flash":
-            import perceiver_tpu.ops.pallas_attention as _pa
-            out = _pa.flash_attention(qt, kt, vt, bias=bias, scale=scale,
-                                      block_k=kv_chunk_size)
+
+@device_scope("attn_core")
+def _streamed_core(qh, kh, vh, impl, key_padding_mask, dropout_rate, rng,
+                   deterministic, kv_chunk_size, spmd):
+    """The attention core of the impls that never hold the weights:
+    chunked, flash and the shard_map kernels. (B, L, H, D) in and out."""
+    import perceiver_tpu.ops.chunked_attention as _ca
+    bias = (_ca.pad_mask_to_bias(key_padding_mask)
+            if key_padding_mask is not None else None)
+    # (B, L, H, D) → (B, H, L, D)
+    qt, kt, vt = (x.swapaxes(1, 2) for x in (qh, kh, vh))
+    scale = 1.0 / (qh.shape[-1] ** 0.5)
+    if impl == "chunked":
+        drop = dropout_rate if not deterministic else 0.0
+        if drop > 0.0 and rng is None:
+            # mirror the einsum path (ops/dropout.py): silently
+            # skipping configured dropout would be invisible
+            raise ValueError("dropout needs an rng when not "
+                             "deterministic")
+        out = _ca.chunked_attention(qt, kt, vt, bias=bias, scale=scale,
+                                    chunk_size=kv_chunk_size,
+                                    dropout_rate=drop, rng=rng)
+    elif impl == "flash":
+        import perceiver_tpu.ops.pallas_attention as _pa
+        out = _pa.flash_attention(qt, kt, vt, bias=bias, scale=scale,
+                                  block_k=kv_chunk_size)
+    else:
+        from perceiver_tpu.parallel.ring_attention import (
+            make_ring_attention,
+            make_seq_parallel_cross_attention,
+        )
+        from perceiver_tpu.parallel.ulysses import (
+            make_ulysses_attention,
+        )
+        mesh, seq_axis, batch_axis = spmd
+        if impl == "seqpar":
+            f = make_seq_parallel_cross_attention(
+                mesh, seq_axis, batch_axis=batch_axis, scale=scale)
+        elif impl == "ring":
+            f = make_ring_attention(mesh, seq_axis,
+                                    batch_axis=batch_axis, scale=scale)
         else:
-            from perceiver_tpu.parallel.ring_attention import (
-                make_ring_attention,
-                make_seq_parallel_cross_attention,
-            )
-            from perceiver_tpu.parallel.ulysses import (
-                make_ulysses_attention,
-            )
-            mesh, seq_axis, batch_axis = spmd
-            if impl == "seqpar":
-                f = make_seq_parallel_cross_attention(
-                    mesh, seq_axis, batch_axis=batch_axis, scale=scale)
-            elif impl == "ring":
-                f = make_ring_attention(mesh, seq_axis,
-                                        batch_axis=batch_axis, scale=scale)
-            else:
-                f = make_ulysses_attention(
-                    mesh, seq_axis, batch_axis=batch_axis, scale=scale,
-                    kv_chunk_size=kv_chunk_size)
-            out = f(qt, kt, vt, bias)
-        out = out.swapaxes(1, 2)
-        b, lq = out.shape[0], out.shape[1]
-        out = out.reshape(b, lq, num_heads * head_dim)
-        return linear_apply(params["out"], out, policy=policy)
+            f = make_ulysses_attention(
+                mesh, seq_axis, batch_axis=batch_axis, scale=scale,
+                kv_chunk_size=kv_chunk_size)
+        out = f(qt, kt, vt, bias)
+    return out.swapaxes(1, 2)
 
+
+@device_scope("attn_core")
+def _materialized_core(qh, kh, vh, key_padding_mask, attn_mask,
+                       dropout_rate, rng, deterministic, policy):
+    """The einsum attention core (``_sdpa_core``) under its mask bias."""
     # additive fp32 mask bias, broadcastable to (B, H, Lq, Lk): the
     # key-padding NEG_INF bias and any attn_mask fold into one tensor
     # the custom-VJP core treats as a non-trainable constant
@@ -365,11 +392,9 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     drop = dropout_rate if not deterministic else 0.0
     if drop > 0.0 and rng is None:
         raise ValueError("dropout needs an rng when not deterministic")
-    out = _sdpa_core(1.0 / math.sqrt(head_dim), drop, policy.norm_dtype,
-                     qh, kh, vh, bias, rng if drop > 0.0 else None)
-    b, lq = out.shape[0], out.shape[1]
-    out = out.reshape(b, lq, num_heads * head_dim)
-    return linear_apply(params["out"], out, policy=policy)
+    return _sdpa_core(1.0 / math.sqrt(qh.shape[-1]), drop,
+                      policy.norm_dtype, qh, kh, vh, bias,
+                      rng if drop > 0.0 else None)
 
 
 # --- pre-norm cross/self attention (reference model.py:77-116) ---------------

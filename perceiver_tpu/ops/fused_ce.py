@@ -31,9 +31,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
 
 
+@device_scope("loss")
 def pack_positions(hidden, labels, weight, capacity: int):
     """Scatter rows with nonzero ``weight`` into a ``capacity``-row buffer.
 
@@ -126,6 +128,7 @@ def _chunk_nll_bwd(policy, res, g):
 _chunk_nll_sum.defvjp(_chunk_nll_fwd, _chunk_nll_bwd)
 
 
+@device_scope("loss")
 def fused_linear_cross_entropy(linear_params, hidden, labels, weight, *,
                                chunk_size: int = 8192,
                                policy: Policy = DEFAULT_POLICY):

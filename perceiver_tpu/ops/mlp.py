@@ -21,6 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
@@ -60,6 +61,7 @@ def mlp_init(key, dim: int, widening_factor: int = 1, dtype=jnp.float32):
     }
 
 
+@device_scope("mlp")
 def mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
     h = layer_norm_apply(params["norm"], x, policy=policy)
     h = linear_apply(params["fc1"], h, policy=policy)

@@ -203,6 +203,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, h, nqp, dp), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_tables.astype(jnp.int32), kv_lens, qlens, qp, kp, vp)
     out = out[:, :, :nq, :d]
     return _zero_invalid_queries(out, kv_lens, qlens, causal)

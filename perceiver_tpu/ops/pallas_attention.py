@@ -141,6 +141,7 @@ def _flash_forward(q, k, v, bias, scale: float,
             pltpu.VMEM((block_q, dp), jnp.float32),    # unnormalized acc
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v, bias)
     return out[:, :, :lq, :d]
 
@@ -254,6 +255,7 @@ def _flash_forward_t(q, k, v, bias, scale: float,
             pltpu.VMEM((dp, block_q), jnp.float32),    # acc, q on lanes
         ],
         interpret=interpret,
+        name="flash_attention_fwd_t",
     )(qt, kt, vt, bias)
     return out[:, :, :d, :lq].swapaxes(2, 3)
 

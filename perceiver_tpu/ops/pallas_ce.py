@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.tiling import round_up as _round_up
 
 NEG = -1e30
@@ -186,6 +187,7 @@ def _fwd(h, w, b, labels, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 128), jnp.float32),  # gold logit
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(hp, wp, bp, yp)
     return nll[:n, 0], lse[:n, 0]
 
@@ -216,6 +218,7 @@ def _bwd(h, w, b, labels, lse, dnll, block_n, block_v, interpret):
         out_shape=jax.ShapeDtypeStruct((np_, c), h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, c), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_bwd_dh",
     )(hp, wp, bp, yp, lsep, dnllp)
 
     col_specs = [
@@ -243,6 +246,7 @@ def _bwd(h, w, b, labels, lse, dnll, block_n, block_v, interpret):
             pltpu.VMEM((8, block_v), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_ce_bwd_dw",
     )(hp, wp, bp, yp, lsep, dnllp)
     return dh[:n], dw[:, :v], db[0, :v]
 
@@ -268,6 +272,7 @@ def _nll_bwd(block_n, block_v, interpret, res, cot):
 _nll_and_lse.defvjp(_nll_fwd, _nll_bwd)
 
 
+@device_scope("loss")
 def pallas_linear_cross_entropy(linear_params, hidden, labels, weight, *,
                                 block_n: int = 512, block_v: int = 2048,
                                 policy=None, interpret=None):

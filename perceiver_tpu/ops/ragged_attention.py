@@ -163,6 +163,7 @@ def ragged_cross_attention(q, k, v, row_offsets, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, h, nqp, dp), q.dtype),
         interpret=interpret,
+        name="ragged_cross_attention",
     )(row_offsets.astype(jnp.int32), lengths.astype(jnp.int32),
       qp, kp, vp)
     return out[:, :, :nq, :d]
@@ -256,6 +257,7 @@ def ragged_decode_attention(q, k, v, rows, *, latents_per_row: int,
                                lambda hh, iq: (hh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((h, tp, dp), q.dtype),
         interpret=interpret,
+        name="ragged_decode_attention",
     )(qp, kp, vp, rows_p)
     return out[:, :t, :d]
 

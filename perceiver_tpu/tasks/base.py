@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.models.masking import IGNORE_INDEX
+from perceiver_tpu.obs.trace import device_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,6 +131,7 @@ def masked_mean(values, mask):
     return (values.astype(jnp.float32) * mask).sum() / denom
 
 
+@device_scope("loss")
 def cross_entropy(logits, labels, valid=None,
                   ignore_index: Optional[int] = None):
     """CE in fp32 with optional row mask and label ignore value."""
@@ -145,6 +147,7 @@ def cross_entropy(logits, labels, valid=None,
     return masked_mean(nll, mask)
 
 
+@device_scope("loss")
 def accuracy(logits, labels, valid=None):
     pred = jnp.argmax(logits, axis=-1)
     correct = (pred == labels)

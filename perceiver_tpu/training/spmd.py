@@ -42,6 +42,7 @@ def make_sharded_train_step(task, batch, mesh: Mesh):
     both uniformly."""
     import optax
 
+    from perceiver_tpu.obs.trace import device_scope
     from perceiver_tpu.ops.policy import Policy
 
     model = task.build()
@@ -67,7 +68,8 @@ def make_sharded_train_step(task, batch, mesh: Mesh):
             return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        with device_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
 
     return train_step, (params, opt_state, batch, jax.random.key(1))

@@ -34,6 +34,8 @@ import numpy as np
 import optax
 
 from perceiver_tpu.obs import events as events_mod
+from perceiver_tpu.obs.trace import device_scope, span
+from perceiver_tpu.obs.trace import enabled as tracing_enabled
 from perceiver_tpu.ops.policy import Policy
 from perceiver_tpu.resilience import faults
 from perceiver_tpu.resilience import guard as guard_mod
@@ -311,9 +313,11 @@ class Trainer:
         cfg = self.config
         rng = jax.random.key(cfg.seed)
         init_rng, state_rng = jax.random.split(rng)
-        params = self.model.init(init_rng)
+        with span("train/model_init"):
+            params = self.model.init(init_rng)
         if hasattr(self.task, "restore_pretrained"):
-            params = self.task.restore_pretrained(params)
+            with span("train/restore"):
+                params = self.task.restore_pretrained(params)
 
         labels = None
         if hasattr(self.task, "frozen_param_labels"):
@@ -396,9 +400,10 @@ class Trainer:
 
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
             (_, metrics), grads = grad_fn(state.params)
-            updates, opt_state = self.tx.update(grads, state.opt_state,
-                                                state.params)
-            params = optax.apply_updates(state.params, updates)
+            with device_scope("optimizer"):
+                updates, opt_state = self.tx.update(
+                    grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state,
                                    rng=rng, step=state.step + 1)
             return new_state, metrics
@@ -507,7 +512,8 @@ class Trainer:
         the deterministic data-stream position it was taken at."""
         if self._guard_ckpt is None or self.global_step == self._anchor_step:
             return
-        self._guard_ckpt.save(self.global_step, state, {})
+        with span("train/anchor"):
+            self._guard_ckpt.save(self.global_step, state, {})
         self._anchor_pos = (epoch, batches_done)
         self._anchor_step = self.global_step
 
@@ -567,6 +573,54 @@ class Trainer:
         if count == 0:
             return {}
         return {f"{prefix}_{k}": v / count for k, v in totals.items()}
+
+    def _log_step(self, metrics, *, dt: float, throughput: float,
+                  steps_since: int, n_dev: int,
+                  phase_s: Dict[str, float]) -> None:
+        """One logged step: console heartbeat, summary scalars and the
+        telemetry line. The caller fenced ``metrics``, so nothing here
+        waits for the device's step."""
+        if jax.process_index() == 0:
+            # console heartbeat: progress visibility for interactive
+            # runs and a liveness signal for watchdogs (a stalled device
+            # shows up as this line going quiet)
+            print(f"[step {self.global_step}] "
+                  + " ".join(f"{k}={float(v):.4f}"
+                             for k, v in metrics.items())
+                  + f" samples/s={throughput:.1f}",
+                  file=sys.stderr, flush=True)
+        for k, v in metrics.items():
+            self.writer.add_scalar(f"train_{k}", float(v), self.global_step)
+        # MultiSteps advances the schedule once per accumulation
+        # window, not per micro-step
+        opt_step = (max(self.global_step - self._lr_step_offset, 0)
+                    // max(self.config.accumulate_grad_batches, 1))
+        self.writer.add_scalar("lr", float(self.lr_fn(opt_step)),
+                               self.global_step)
+        if steps_since > 0:
+            self.writer.add_scalar("samples_per_sec", throughput,
+                                   self.global_step)
+        util = mfu(self._step_flops, steps_since, dt, num_devices=n_dev,
+                   peak_flops_per_device=self._peak_flops)
+        if util is not None:
+            self.writer.add_scalar("mfu", util, self.global_step)
+        if self._guard is not None:
+            self.writer.add_scalar("guard_skipped_steps",
+                                   float(self._guard.skipped_total),
+                                   self.global_step)
+        if self.telemetry is not None:
+            # the caller's fence() already pulled metrics to host:
+            # telemetry adds zero device syncs. The phases' seconds are
+            # those since the last line, that line's own logging among
+            # them; left out when tracing is switched off
+            phases = ({f"{k}_s": v for k, v in phase_s.items()}
+                      if tracing_enabled() else {})
+            self.telemetry.step(
+                self.global_step, float(metrics.get("loss", float("nan"))),
+                steps_delta=steps_since,
+                steps_per_sec=steps_since / max(dt, 1e-9),
+                samples_per_sec=throughput,
+                mfu=util if util is not None else 0.0, **phases)
 
     def fit(self) -> TrainState:
         """Train with SIGTERM (preemption) handling around the loop."""
@@ -655,14 +709,16 @@ class Trainer:
                     or os.path.join(self.log_dir, "checkpoints-guard"),
                     max_to_keep=1, monitor="", enable_async=False)
 
-        state = self._build_state()
+        with span("train/build_state"):
+            state = self._build_state()
         self._make_steps()
 
         if cfg.resume_from_checkpoint:
             hook = CheckpointHook(cfg.resume_from_checkpoint,
                                   monitor=cfg.checkpoint_monitor)
             try:
-                restored = hook.restore_latest(state)
+                with span("train/restore"):
+                    restored = hook.restore_latest(state)
             except (ValueError, KeyError) as e:
                 # orbax raises ValueError (or, on the 0.7 line's
                 # flat-dict template matching, KeyError) on tree/shape
@@ -716,8 +772,9 @@ class Trainer:
 
         # sanity validation (trainer.yaml:53)
         if cfg.num_sanity_val_steps and not cfg.fast_dev_run:
-            self._run_eval(self.datamodule.val_dataloader(),
-                           cfg.num_sanity_val_steps, state, "sanity")
+            with span("train/eval"):
+                self._run_eval(self.datamodule.val_dataloader(),
+                               cfg.num_sanity_val_steps, state, "sanity")
 
         if cfg.profiler:
             jax.profiler.start_trace(os.path.join(self.log_dir, "profile"))
@@ -730,6 +787,9 @@ class Trainer:
 
         stop = False
         t0, samples_since, steps_since = time.time(), 0, 0
+        # seconds by phase since the last logged step (obs/trace.py:
+        # TRAIN_PHASES; "host" is shard + dispatch + guard_sync + log)
+        phase_s = {"input_wait": 0.0, "host": 0.0, "fence": 0.0}
         metrics = None
         epoch = 0
         replay_batches = 0  # rewind reposition within the next epoch
@@ -773,190 +833,177 @@ class Trainer:
                     # finished run) — never pull or train another batch
                     stop = True
                     break
-                group = list(itertools.islice(batch_iter,
-                                              min(spe, remaining)))
-                if not group:
-                    break
-                # local rows × process count = global rows per dispatch
-                # (each host contributes an equal per-host shard to the
-                # global batch), so samples_per_sec reports global
-                # training throughput, consistent with the mfu scalar
-                # count only real rows — a non-drop_last loader pads the
-                # final batch with invalid rows that do no training work
-                batch_size = (sum(int(b["valid"].sum()) for b in group)
-                              * jax.process_count())
-                prev_step = self.global_step
-                first_step = self._step_flops is None
-                # the single-step fn compiles separately from the
-                # multi-step one; its first run must also stay out of
-                # the throughput/MFU measurement window
-                first_single = (spe > 1 and len(group) < spe
-                                and not self._single_step_ran)
-                poison = faults.armed("train.nonfinite")
-                losses = None
-                if len(group) == spe and spe > 1:
-                    stacked = {key: np.stack([b[key] for b in group])
-                               for key in group[0]}
-                    if poison:
-                        for i in range(len(group)):
-                            if faults.fire("train.nonfinite"):
-                                self._poison_batch(stacked, index=i)
-                    sharded = self._shard_batch(stacked, stacked=True)
-                    if first_step:
-                        flops, self._train_step_multi = step_flops_and_fn(
-                            self._train_step_multi, state, sharded,
-                            num_devices=(self.mesh.devices.size
-                                         if self.mesh is not None else 1),
-                            cache=self._exec_cache,
-                            cache_label="trainer:train_step_multi")
-                        self._step_flops = flops or 0.0
-                    if self._guard is not None:
-                        state, metrics, losses = self._train_step_multi(
-                            state, sharded)
-                    else:
-                        state, metrics = self._train_step_multi(state,
-                                                                sharded)
-                else:
-                    # trailing (or single-step-mode) group, step by step
-                    losses = [] if self._guard is not None else None
-                    for b in group:
-                        if poison and faults.fire("train.nonfinite"):
-                            self._poison_batch(b)
-                        sharded = self._shard_batch(b)
-                        if self._step_flops is None:
-                            # cost analysis via lowering, or via the AOT
-                            # compile the first call would do anyway —
-                            # never an extra one
-                            flops, self._train_step = step_flops_and_fn(
-                                self._train_step, state, sharded,
-                                num_devices=(self.mesh.devices.size
-                                             if self.mesh is not None
-                                             else 1),
-                                cache=self._exec_cache,
-                                cache_label="trainer:train_step")
-                            self._step_flops = flops or 0.0
-                        if self._guard is not None:
-                            state, metrics, loss_i = self._train_step(
-                                state, sharded)
-                            losses.append(loss_i)
-                        else:
-                            state, metrics = self._train_step(state,
-                                                              sharded)
-                    self._single_step_ran = True
-                self.global_step += len(group)
-                batches_done += len(group)
-                samples_since += batch_size
-                steps_since += len(group)
-                # crash-of-one-host chaos window: a SIGKILL at the
-                # dispatch boundary — after steps are consumed, before
-                # the guard syncs or anchors — is the worst-case point
-                # the anchor/replay recovery must absorb
-                # (distributed/group.py re-forms; dist_kill_train_host)
-                faults.maybe_kill("train.kill")
-
-                if self._guard is not None:
-                    # per-dispatch host sync of the per-step losses:
-                    # the cost of an armed guard, and the one detection
-                    # path halt/skip/rewind all share
-                    if isinstance(losses, list):
-                        losses_host = np.concatenate(
-                            [np.asarray(x) for x in losses])
-                    else:
-                        losses_host = np.asarray(losses)
-                    skips_before = self._guard.skipped_total
-                    action = self._guard.observe(losses_host, prev_step)
-                    if self.telemetry is not None:
-                        for _ in range(self._guard.skipped_total
-                                       - skips_before):
-                            self.telemetry.guard_skip(self.global_step)
-                    if action == guard_mod.REWIND:
-                        if self.telemetry is not None:
-                            self.telemetry.guard_rewind(self.global_step)
-                        state = self._guard_rewind(state)
-                        epoch, replay_batches = self._anchor_pos
-                        metrics = None
-                        t0, samples_since, steps_since = \
-                            time.time(), 0, 0
-                        rewound = True
+                with span("train/step",
+                          step=self.global_step + 1) as step_span:
+                    # queue_depth 0: the producer starved the loop
+                    depth = ({"queue_depth": train_loader.queue_depth()}
+                             if cfg.prefetch_batches > 0 else {})
+                    with span("train/input_wait", **depth) as sp:
+                        group = list(itertools.islice(batch_iter,
+                                                      min(spe, remaining)))
+                    phase_s["input_wait"] += sp.seconds
+                    if not group:
+                        step_span.cancel()  # the epoch's end, not a step
                         break
-                    if (cfg.guard_anchor_every_n_steps > 0
-                            and bool(np.isfinite(losses_host).all())
-                            and self.global_step - self._anchor_step
-                            >= cfg.guard_anchor_every_n_steps):
-                        self._save_anchor(state, epoch, batches_done)
-                if first_step or first_single:
-                    # this dispatch paid a jit compilation; keep it
-                    # out of the throughput/MFU measurement window
-                    fence(metrics)
-                    t0, samples_since, steps_since = time.time(), 0, 0
-
-                crossed_log = (self.global_step // cfg.log_every_n_steps
-                               > prev_step // cfg.log_every_n_steps)
-                if crossed_log or cfg.fast_dev_run:
-                    # async dispatch: sync on the device before taking
-                    # dt, else the window measures host dispatch time
-                    # and over-reports throughput/MFU
-                    fence(metrics)
-                    dt = time.time() - t0
-                    throughput = samples_since / max(dt, 1e-9)
-                    if jax.process_index() == 0:
-                        # console heartbeat: progress visibility for
-                        # interactive runs and a liveness signal for
-                        # watchdogs (a stalled device shows up as this
-                        # line going quiet)
-                        print(f"[step {self.global_step}] "
-                              + " ".join(f"{k}={float(v):.4f}"
-                                         for k, v in metrics.items())
-                              + f" samples/s={throughput:.1f}",
-                              file=sys.stderr, flush=True)
-                    for k, v in metrics.items():
-                        self.writer.add_scalar(f"train_{k}", float(v),
-                                               self.global_step)
-                    # MultiSteps advances the schedule once per
-                    # accumulation window, not per micro-step
-                    opt_step = (max(self.global_step
-                                    - self._lr_step_offset, 0)
-                                // max(cfg.accumulate_grad_batches, 1))
-                    self.writer.add_scalar(
-                        "lr", float(self.lr_fn(opt_step)),
-                        self.global_step)
-                    if steps_since > 0:
-                        self.writer.add_scalar("samples_per_sec",
-                                               throughput,
-                                               self.global_step)
+                    # local rows × process count = global rows per dispatch
+                    # (each host contributes an equal per-host shard to the
+                    # global batch), so samples_per_sec reports global
+                    # training throughput, consistent with the mfu scalar
+                    # count only real rows — a non-drop_last loader pads the
+                    # final batch with invalid rows that do no training work
+                    batch_size = (sum(int(b["valid"].sum()) for b in group)
+                                  * jax.process_count())
+                    prev_step = self.global_step
+                    first_step = self._step_flops is None
+                    # the single-step fn compiles separately from the
+                    # multi-step one; its first run must also stay out of
+                    # the throughput/MFU measurement window
+                    first_single = (spe > 1 and len(group) < spe
+                                    and not self._single_step_ran)
+                    poison = faults.armed("train.nonfinite")
+                    losses = None
                     n_dev = (self.mesh.devices.size
                              if self.mesh is not None else 1)
-                    util = mfu(self._step_flops, steps_since, dt,
-                               num_devices=n_dev,
-                               peak_flops_per_device=self._peak_flops)
-                    if util is not None:
-                        self.writer.add_scalar("mfu", util,
-                                               self.global_step)
+                    if len(group) == spe and spe > 1:
+                        with span("train/shard") as sp:
+                            stacked = {
+                                key: np.stack([b[key] for b in group])
+                                for key in group[0]}
+                            if poison:
+                                for i in range(len(group)):
+                                    if faults.fire("train.nonfinite"):
+                                        self._poison_batch(stacked, index=i)
+                            sharded = self._shard_batch(stacked,
+                                                        stacked=True)
+                        phase_s["host"] += sp.seconds
+                        if first_step:
+                            with span("train/step_load"):
+                                flops, self._train_step_multi = \
+                                    step_flops_and_fn(
+                                        self._train_step_multi, state,
+                                        sharded, num_devices=n_dev,
+                                        cache=self._exec_cache,
+                                        cache_label=(
+                                            "trainer:train_step_multi"))
+                            self._step_flops = flops or 0.0
+                        with span("train/dispatch") as sp:
+                            if self._guard is not None:
+                                state, metrics, losses = \
+                                    self._train_step_multi(state, sharded)
+                            else:
+                                state, metrics = self._train_step_multi(
+                                    state, sharded)
+                        phase_s["host"] += sp.seconds
+                    else:
+                        # trailing (or single-step-mode) group, step by step
+                        losses = [] if self._guard is not None else None
+                        for b in group:
+                            with span("train/shard") as sp:
+                                if poison and faults.fire("train.nonfinite"):
+                                    self._poison_batch(b)
+                                sharded = self._shard_batch(b)
+                            phase_s["host"] += sp.seconds
+                            if self._step_flops is None:
+                                # cost analysis via lowering, or via the AOT
+                                # compile the first call would do anyway —
+                                # never an extra one
+                                with span("train/step_load"):
+                                    flops, self._train_step = \
+                                        step_flops_and_fn(
+                                            self._train_step, state, sharded,
+                                            num_devices=n_dev,
+                                            cache=self._exec_cache,
+                                            cache_label="trainer:train_step")
+                                self._step_flops = flops or 0.0
+                            with span("train/dispatch") as sp:
+                                if self._guard is not None:
+                                    state, metrics, loss_i = \
+                                        self._train_step(state, sharded)
+                                    losses.append(loss_i)
+                                else:
+                                    state, metrics = self._train_step(
+                                        state, sharded)
+                            phase_s["host"] += sp.seconds
+                        self._single_step_ran = True
+                    self.global_step += len(group)
+                    batches_done += len(group)
+                    samples_since += batch_size
+                    steps_since += len(group)
+                    # crash-of-one-host chaos window: a SIGKILL at the
+                    # dispatch boundary — after steps are consumed, before
+                    # the guard syncs or anchors — is the worst-case point
+                    # the anchor/replay recovery must absorb
+                    # (distributed/group.py re-forms; dist_kill_train_host)
+                    faults.maybe_kill("train.kill")
+
                     if self._guard is not None:
-                        self.writer.add_scalar(
-                            "guard_skipped_steps",
-                            float(self._guard.skipped_total),
-                            self.global_step)
-                    if self.telemetry is not None and metrics is not None:
-                        # the fence() above already pulled metrics to
-                        # host — telemetry adds zero device syncs
-                        self.telemetry.step(
-                            self.global_step,
-                            float(metrics.get("loss", float("nan"))),
-                            steps_delta=steps_since,
-                            steps_per_sec=steps_since / max(dt, 1e-9),
-                            samples_per_sec=throughput,
-                            mfu=util if util is not None else 0.0)
-                    t0, samples_since, steps_since = time.time(), 0, 0
+                        # per-dispatch host sync of the per-step losses:
+                        # the cost of an armed guard, and the one detection
+                        # path halt/skip/rewind all share
+                        with span("train/guard_sync") as sp:
+                            if isinstance(losses, list):
+                                losses_host = np.concatenate(
+                                    [np.asarray(x) for x in losses])
+                            else:
+                                losses_host = np.asarray(losses)
+                        phase_s["host"] += sp.seconds
+                        skips_before = self._guard.skipped_total
+                        action = self._guard.observe(losses_host, prev_step)
+                        if self.telemetry is not None:
+                            for _ in range(self._guard.skipped_total
+                                           - skips_before):
+                                self.telemetry.guard_skip(self.global_step)
+                        if action == guard_mod.REWIND:
+                            if self.telemetry is not None:
+                                self.telemetry.guard_rewind(self.global_step)
+                            state = self._guard_rewind(state)
+                            epoch, replay_batches = self._anchor_pos
+                            metrics = None
+                            t0, samples_since, steps_since = \
+                                time.time(), 0, 0
+                            rewound = True
+                            break
+                        if (cfg.guard_anchor_every_n_steps > 0
+                                and bool(np.isfinite(losses_host).all())
+                                and self.global_step - self._anchor_step
+                                >= cfg.guard_anchor_every_n_steps):
+                            self._save_anchor(state, epoch, batches_done)
+                    if first_step or first_single:
+                        # this dispatch paid a jit compilation; keep it
+                        # out of the throughput/MFU measurement window
+                        with span("train/fence") as sp:
+                            fence(metrics)
+                        phase_s["fence"] += sp.seconds
+                        t0, samples_since, steps_since = time.time(), 0, 0
 
-                if cfg.preempt_checkpoint and \
-                        self._handle_preemption(state):
-                    stop = True
-                    break
+                    crossed_log = (self.global_step // cfg.log_every_n_steps
+                                   > prev_step // cfg.log_every_n_steps)
+                    if crossed_log or cfg.fast_dev_run:
+                        # async dispatch: sync on the device before taking
+                        # dt, else the window measures host dispatch time
+                        # and over-reports throughput/MFU
+                        with span("train/fence") as sp:
+                            fence(metrics)
+                        phase_s["fence"] += sp.seconds
+                        dt = time.time() - t0
+                        throughput = samples_since / max(dt, 1e-9)
+                        with span("train/log") as sp:
+                            self._log_step(
+                                metrics, dt=dt, throughput=throughput,
+                                steps_since=steps_since, n_dev=n_dev,
+                                phase_s=phase_s)
+                            phase_s = dict.fromkeys(phase_s, 0.0)
+                        phase_s["host"] += sp.seconds
+                        t0, samples_since, steps_since = time.time(), 0, 0
 
-                if cfg.max_steps > 0 and self.global_step >= cfg.max_steps:
-                    stop = True
-                    break
+                    if cfg.preempt_checkpoint and \
+                            self._handle_preemption(state):
+                        stop = True
+                        break
+
+                    if cfg.max_steps > 0 and self.global_step >= cfg.max_steps:
+                        stop = True
+                        break
 
             if rewound:
                 # restart the loop at the anchor's epoch/batch without
@@ -966,9 +1013,10 @@ class Trainer:
 
             if (epoch % cfg.check_val_every_n_epoch == 0 or stop) \
                     and not self._preempted:  # grace window is short
-                val_metrics = self._run_eval(
-                    self.datamodule.val_dataloader(), limit_val, state,
-                    "val")
+                with span("train/eval"):
+                    val_metrics = self._run_eval(
+                        self.datamodule.val_dataloader(), limit_val, state,
+                        "val")
                 if val_metrics and jax.process_index() == 0:
                     print(f"[step {self.global_step}] "
                           + " ".join(f"{k}={float(v):.4f}"
@@ -979,7 +1027,9 @@ class Trainer:
                 if hasattr(self.task, "on_validation_epoch_end"):
                     self.task.on_validation_epoch_end(self, state)
                 if self._ckpt is not None and val_metrics:
-                    self._ckpt.save(self.global_step, state, val_metrics)
+                    with span("train/checkpoint"):
+                        self._ckpt.save(self.global_step, state,
+                                        val_metrics)
                 # eval/checkpoint wall time must not depress the next
                 # window's samples_per_sec / mfu scalars
                 t0, samples_since, steps_since = time.time(), 0, 0

@@ -35,6 +35,7 @@ def _tree_files():
 def cache_config():
     """Restore the cache flags the helper may set."""
     names = ("jax_compilation_cache_dir",
+             "jax_compilation_cache_include_metadata_in_key",
              "jax_persistent_cache_min_compile_time_secs",
              "jax_persistent_cache_min_entry_size_bytes")
     saved = {n: getattr(jax.config, n) for n in names}
@@ -127,8 +128,11 @@ def test_compile_cache_named_by_the_environment_sets_nothing(
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     before = jax.config.jax_compilation_cache_dir
     assert enable_compile_cache() == str(tmp_path)
-    # JAX read the variable itself at import; the code set nothing
+    # JAX read the variable itself at import; the code set no directory
     assert jax.config.jax_compilation_cache_dir == before
+    # a cached executable must carry this program's names, not an older
+    # program's: profiles are read by name stack
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compile_cache_defaults_to_the_checkout(monkeypatch,
@@ -139,6 +143,7 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch,
     want = os.path.join(REPO_ROOT, ".jax_cache")
     assert enable_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_bench_refuses_a_platform_that_is_not_the_one_asked_for(

@@ -1,0 +1,136 @@
+"""The layer scopes on the train step's device operations
+(``obs.trace.DEVICE_SCOPES``): every heavy operation of the compiled toy
+step lies under the vocabulary, in every pass, and the scopes change
+metadata and nothing else."""
+
+import contextlib
+import dataclasses
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from perceiver_tpu.tasks import ImageClassifierTask, MaskedLanguageModelTask
+from perceiver_tpu.training import Trainer, TrainerConfig
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# the benchmark's own reading of a name stack: what it cannot see in
+# the step is not scoped
+from benchmarks.scope_times import scopes_of as scopes_in  # noqa: E402
+
+LAYERS = {"input_adapter", "enc_cross_attn", "latent_self_attn",
+          "dec_cross_attn", "output_adapter", "loss", "optimizer"}
+INNER = {"attn_core", "attn_proj", "mlp"}
+HEAVY = ("dot", "exponential", "reduce")
+
+SMALL = dict(num_latents=8, num_latent_channels=16, num_encoder_layers=2,
+             num_encoder_self_attention_layers_per_block=2,
+             num_encoder_cross_attention_heads=2,
+             num_encoder_self_attention_heads=2)
+TASKS = {
+    "mlm": (MaskedLanguageModelTask(
+        vocab_size=110, max_seq_len=32,
+        num_decoder_cross_attention_heads=2, **SMALL),
+        {"input_ids": np.ones((4, 32), np.int32),
+         "pad_mask": np.zeros((4, 32), bool),
+         "valid": np.ones((4,), bool)}),
+    "img": (ImageClassifierTask(
+        image_shape=(8, 8, 1), num_classes=10, num_frequency_bands=4,
+        num_decoder_cross_attention_heads=1, **SMALL),
+        {"image": np.ones((4, 8, 8, 1), np.float32),
+         "label": np.ones((4,), np.int32),
+         "valid": np.ones((4,), bool)}),
+}
+
+
+def lower_step(task, batch, tmp_path):
+    trainer = Trainer(
+        task, None,
+        TrainerConfig(default_root_dir=str(tmp_path),
+                      enable_checkpointing=False),
+        optimizer_init={"class_path": "AdamW", "init_args": {"lr": 1e-3}})
+    state = trainer._build_state()
+    trainer._make_steps()
+    return trainer._train_step.lower(state, batch)
+
+
+@pytest.fixture(scope="module", params=[
+    (name, remat) for name in TASKS for remat in (False, True)],
+    ids=lambda p: f"{p[0]}-{'remat' if p[1] else 'plain'}")
+def named_ops(request, tmp_path_factory):
+    """(opcode, name stack) of every instruction of the compiled toy
+    step that carries one (the compiler's own rewrites carry none)."""
+    name, remat = request.param
+    task, batch = TASKS[name]
+    lowered = lower_step(dataclasses.replace(task, remat=remat), batch,
+                         tmp_path_factory.mktemp("scopes"))
+    text = lowered.compile().as_text()
+    ops = re.findall(
+        r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"', text)
+    assert len(ops) > 1000
+    return remat, ops
+
+
+def test_every_heavy_operation_is_under_one_layer(named_ops):
+    _, ops = named_ops
+    heavy = [(code, name) for code, name in ops if code in HEAVY]
+    assert len(heavy) > 100
+    for code, name in heavy:
+        found = scopes_in(name)
+        layers = [s for s in found if s in LAYERS]
+        inner = [s for s in found if s in INNER]
+        assert len(layers) == 1, (code, name)
+        assert len(inner) <= 1, (code, name)
+        # an inner scope lies inside an attention layer, never alone
+        assert not inner or layers[0].endswith("_attn"), (code, name)
+
+
+def test_attention_core_is_scoped_in_every_pass(named_ops):
+    remat, ops = named_ops
+    core = [name for code, name in ops
+            if code in HEAVY and "attn_core" in scopes_in(name)]
+    forward = [n for n in core if "transpose(" not in n]
+    # the custom VJP's backward: the recomputed softmax and the four
+    # gradient contractions carry the scope of the forward's call
+    backward = [n for n in core if "transpose(" in n
+                and "rematted_computation" not in n]
+    assert forward and backward
+    assert any("bhqk,bqhd->bkhd" in n for n in backward)    # dv, dk
+    for layer in ("enc_cross_attn", "latent_self_attn", "dec_cross_attn"):
+        assert any(layer in scopes_in(n) for n in forward), layer
+        assert any(layer in scopes_in(n) for n in backward), layer
+    recomputed = [n for n in core if "rematted_computation" in n]
+    assert bool(recomputed) == remat
+    if remat:   # the decoder is outside the checkpointed layers
+        assert not any("dec_cross_attn" in n for n in recomputed)
+
+
+def test_optimizer_and_loss_are_scoped(named_ops):
+    _, ops = named_ops
+    names = [name for _, name in ops]
+    update = [n for n in names if "optimizer" in scopes_in(n)]
+    assert len(update) > 50
+    assert not any("jvp(" in n or "transpose(" in n for n in update)
+    assert not any(set(scopes_in(n)) - {"optimizer"} for n in update)
+    loss = [n for code, n in ops
+            if code in HEAVY and "loss" in scopes_in(n)]
+    assert any("transpose(" in n for n in loss)
+    assert any("transpose(" not in n for n in loss)
+
+
+@pytest.mark.parametrize("name,remat", [("mlm", True), ("img", False)])
+def test_scopes_change_metadata_and_nothing_else(name, remat, tmp_path,
+                                                 monkeypatch):
+    task, batch = TASKS[name]
+    task = dataclasses.replace(task, remat=remat)
+    scoped = lower_step(task, batch, tmp_path)
+    assert "attn_core" in scoped.as_text(debug_info=True)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower_step(task, batch, tmp_path)
+    assert "attn_core" not in bare.as_text(debug_info=True)
+    assert scoped.as_text() == bare.as_text()

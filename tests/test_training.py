@@ -512,3 +512,144 @@ def test_resume_falls_back_to_params_when_optimizer_config_changed(tmp_path):
     # fresh optimizer state from the template, not the checkpoint
     assert jax.tree_util.tree_structure(got.opt_state) == \
         jax.tree_util.tree_structure(template.opt_state)
+
+
+# --- the step timeline (obs/trace.py: TRAIN_PHASES) --------------------------
+
+
+def _fit_with_timeline(tmp_path, **overrides):
+    from perceiver_tpu.obs import trace
+
+    dm = MNISTDataModule(data_dir=str(tmp_path / "nope"), batch_size=16,
+                         synthetic_train_size=64, synthetic_test_size=32)
+    cfg = dict(max_epochs=2, log_every_n_steps=1, num_sanity_val_steps=0,
+               default_root_dir=str(tmp_path / "logs"),
+               enable_checkpointing=False,
+               telemetry_dir=str(tmp_path / "telemetry"))
+    cfg.update(overrides)
+    timeline = trace.Timeline()
+    prev = trace.set_timeline(timeline)
+    try:
+        trainer = Trainer(small_image_task(), dm, TrainerConfig(**cfg),
+                          optimizer_init=ADAMW)
+        trainer.fit()
+    finally:
+        trace.set_timeline(prev)
+    return trainer, timeline.spans()
+
+
+@pytest.fixture(scope="module")
+def phase_fit(tmp_path_factory):
+    """One two-epoch fit for the tests that only read what it left."""
+    tmp_path = tmp_path_factory.mktemp("phase_fit")
+    return (tmp_path, *_fit_with_timeline(tmp_path))
+
+
+def test_fit_yields_leaf_phases_that_tile_each_step(phase_fit):
+    from perceiver_tpu.obs import trace
+
+    _, trainer, spans = phase_fit
+    assert {s["name"] for s in spans} <= set(trace.TRAIN_PHASES)
+    by_id = {s["id"]: s for s in spans}
+    steps = [s for s in spans if s["name"] == "train/step"]
+    n = trainer.global_step                       # two epochs' batches
+    assert n >= 6 and [s["step"] for s in steps] == list(range(1, n + 1))
+    every = {"train/input_wait", "train/shard", "train/dispatch",
+             "train/fence", "train/log"}
+    for step in steps:
+        kids = sorted((s for s in spans if s["parent"] == step["id"]),
+                      key=lambda s: s["start"])
+        names = [k["name"] for k in kids]
+        first = step["step"] == 1
+        assert set(names) == every | ({"train/step_load"} if first
+                                      else set()), names
+        assert names[:2] == ["train/input_wait", "train/shard"]
+        assert names[-1] == "train/log"
+        assert all(k["step"] == step["step"] for k in kids)
+        # side by side inside the step: no phase inside another
+        assert step["start"] <= kids[0]["start"]
+        assert kids[-1]["end"] <= step["end"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["end"] <= b["start"], (a["name"], b["name"])
+        assert not any(s["parent"] in {k["id"] for k in kids}
+                       for s in spans)
+        covered = sum(k["duration_s"] for k in kids)
+        assert covered >= 0.95 * step["duration_s"], (names, covered,
+                                                      step["duration_s"])
+    # the prefetch queue's depth rides the pull
+    waits = [s for s in spans if s["name"] == "train/input_wait"]
+    assert all(0 <= s["attrs"]["queue_depth"] <= 2 for s in waits)
+    # a pull that found the epoch over is no step: its span has no
+    # recorded parent
+    orphans = [s for s in waits if s["parent"] not in by_id]
+    assert len(orphans) == 2 and len(waits) == n + 2
+    # outside the loop
+    (build,) = [s for s in spans if s["name"] == "train/build_state"]
+    inside = {s["name"] for s in spans if s["parent"] == build["id"]}
+    assert inside == {"train/model_init", "train/restore"}
+    assert sum(s["name"] == "train/eval" for s in spans) == 2
+    assert sum(s["name"] == "train/step_load" for s in spans) == 1
+    # enclosing spans have children, leaves have none
+    parents = {s["parent"] for s in spans}
+    assert {by_id[p]["name"] for p in parents if p in by_id} == \
+        set(trace.ENCLOSING_SPANS)
+
+
+def test_fit_writes_phase_seconds_to_telemetry(phase_fit):
+    import json
+
+    tmp_path, trainer, spans = phase_fit
+    with open(tmp_path / "telemetry" / "telemetry.jsonl") as f:
+        lines = [json.loads(ln) for ln in f]
+    steps = [e for e in lines if e["type"] == "train_step"]
+    assert len(steps) == trainer.global_step
+    for e in steps:
+        assert e["input_wait_s"] >= 0 and e["host_s"] > 0 and e["fence_s"] > 0
+    registry = trainer.telemetry.registry
+    total = {n: sum(s["duration_s"] for s in spans if s["name"] == n)
+             for n in ("train/input_wait", "train/fence")}
+    # the counters are the spans' seconds, summed where the work happens
+    # (the last line's own logging and the epochs' last pulls come after
+    # the last line)
+    assert registry.get("training_fence_wait_seconds_total").value == \
+        pytest.approx(total["train/fence"], rel=1e-3)
+    assert 0 < registry.get("training_input_wait_seconds_total").value \
+        <= total["train/input_wait"] + 1e-9
+    assert registry.get("training_host_busy_seconds_total").value > 0
+
+
+def test_fit_with_tracing_off_records_nothing_and_omits_the_fields(tmp_path):
+    import json
+
+    from perceiver_tpu.obs import trace
+
+    try:
+        trace.set_enabled(False)
+        trainer, spans = _fit_with_timeline(tmp_path, max_epochs=1)
+    finally:
+        trace.set_enabled(True)
+    assert spans == [] and trainer.global_step >= 3
+    with open(tmp_path / "telemetry" / "telemetry.jsonl") as f:
+        steps = [json.loads(ln) for ln in f]
+    assert steps and not any("host_s" in e or "fence_s" in e for e in steps)
+
+
+def test_guard_sync_phase_only_under_an_armed_guard(tmp_path):
+    _, spans = _fit_with_timeline(tmp_path, max_epochs=1,
+                                  nonfinite_policy="halt")
+    steps = [s for s in spans if s["name"] == "train/step"]
+    syncs = [s for s in spans if s["name"] == "train/guard_sync"]
+    assert len(syncs) == len(steps) >= 3
+    assert {s["parent"] for s in syncs} == {s["id"] for s in steps}
+
+
+def test_multi_step_dispatch_is_one_step_span(tmp_path):
+    _, spans = _fit_with_timeline(tmp_path, max_epochs=1,
+                                  steps_per_execution=2)
+    steps = [s for s in spans if s["name"] == "train/step"]
+    # one span per dispatch, named by its first step; a trailing group
+    # smaller than two runs step by step inside one span
+    assert [s["step"] for s in steps][:2] == [1, 3]
+    names = [s["name"] for s in spans if s["parent"] == steps[0]["id"]]
+    assert names.count("train/dispatch") == 1
+    assert names.count("train/shard") == 1
