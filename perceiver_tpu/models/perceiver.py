@@ -122,13 +122,14 @@ def self_attention_layer_init(key, num_channels, num_heads,
 def self_attention_layer_apply(params, x, *, num_heads,
                                key_padding_mask=None, attn_mask=None,
                                dropout_rate=0.0, rng=None, deterministic=True,
-                               policy: Policy = DEFAULT_POLICY):
+                               policy: Policy = DEFAULT_POLICY,
+                               impl: Optional[str] = None):
     k_attn, k_r1, k_r2 = jax.random.split(_rng_or_dummy(rng, deterministic), 3)
     y = self_attention_apply(
         params["attn"], x, num_heads=num_heads,
         key_padding_mask=key_padding_mask, attn_mask=attn_mask,
         dropout_rate=dropout_rate, rng=k_attn, deterministic=deterministic,
-        policy=policy)
+        policy=policy, impl=impl)
     x = x + dropout(y, dropout_rate, rng=k_r1, deterministic=deterministic)
     y = mlp_apply(params["mlp"], x, policy=policy)
     return x + dropout(y, dropout_rate, rng=k_r2, deterministic=deterministic)
@@ -149,7 +150,8 @@ def self_attention_block_init(key, num_layers, num_channels, num_heads,
 
 def self_attention_block_apply(stacked, x, *, num_heads, dropout_rate=0.0,
                                rng=None, deterministic=True,
-                               policy: Policy = DEFAULT_POLICY):
+                               policy: Policy = DEFAULT_POLICY,
+                               impl: Optional[str] = None):
     num_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
     keys = jax.random.split(_rng_or_dummy(rng, deterministic), num_layers)
 
@@ -158,7 +160,7 @@ def self_attention_block_apply(stacked, x, *, num_heads, dropout_rate=0.0,
         out = self_attention_layer_apply(
             layer_params, carry, num_heads=num_heads,
             dropout_rate=dropout_rate, rng=k, deterministic=deterministic,
-            policy=policy)
+            policy=policy, impl=impl)
         return out, None
 
     x, _ = jax.lax.scan(body, x, (stacked, keys))
@@ -180,10 +182,14 @@ class PerceiverEncoder:
     num_self_attention_layers_per_block: int = 2
     dropout: float = 0.0
     widening_factor: int = 1
-    # Cross-attention kernel for the latent ← input step, the long-kv
-    # hot op: None/"einsum", "chunked" (lax.scan online softmax), or
-    # "flash" (fused Pallas TPU kernel). Self-attention over the small
-    # latent array always uses the einsum path.
+    # Attention core. None picks per call site from what it observes
+    # (ops/attention.pick_attention_core): the fused Pallas kernels on
+    # a TPU where the shapes tile well, else the materialized einsum
+    # core — cross-attention and the latent self-attention stack
+    # alike. "einsum" / "flash" force one core for every attention of
+    # the encoder; "chunked" (lax.scan online softmax) and the
+    # shard_map impls apply to the latent ← input cross-attention, the
+    # long-kv op, and leave the latent stack to pick.
     attention_impl: Optional[str] = None
     kv_chunk_size: int = 1024
     # For the shard_map sequence-parallel attention impls ("seqpar",
@@ -240,12 +246,16 @@ class PerceiverEncoder:
                 deterministic=deterministic, policy=policy,
                 impl=self.attention_impl, kv_chunk_size=self.kv_chunk_size,
                 spmd=self.spmd, kv_heads=kv_heads)
+        latent_impl = (self.attention_impl
+                       if self.attention_impl in ("einsum", "flash")
+                       else None)
         with device_scope("latent_self_attn"):
             return self_attention_block_apply(
                 params["selfs"], latent,
                 num_heads=self.num_self_attention_heads,
                 dropout_rate=self.dropout, rng=k_selfs,
-                deterministic=deterministic, policy=policy)
+                deterministic=deterministic, policy=policy,
+                impl=latent_impl)
 
     def apply(self, params, x, pad_mask=None, attn_mask=None, *, rng=None,
               deterministic: bool = True, policy: Policy = DEFAULT_POLICY):
@@ -268,9 +278,7 @@ class PerceiverEncoder:
             # distinct parameter set, close over it in the scan body
             with device_scope("enc_cross_attn"):
                 return cross_attention_kv(
-                    layer_params["cross"]["attn"], x,
-                    num_heads=self.num_cross_attention_heads,
-                    policy=policy)
+                    layer_params["cross"]["attn"], x, policy=policy)
 
         def one_layer(layer_params, kv_heads, latent, k):
             return self._layer_apply(layer_params, latent, kv_heads,

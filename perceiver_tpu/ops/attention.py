@@ -20,18 +20,23 @@ self-attention (``model.py:102-116``) pre-norms its single input. The
 embedding dim equals the number of q channels — the reference's stated
 simplification vs. the paper (``model.py:78-82``).
 
-Shapes are static and heads are a named einsum axis, so XLA tiles the
-two batched matmuls straight onto the MXU and fuses scale/mask/softmax
-between them. A fused Pallas kernel (``perceiver_tpu.ops.pallas_attention``)
-can replace the softmax path for long-kv shapes.
+Two attention cores. The fused one (``ops/pallas_attention``: Pallas
+kernels forward and backward, scores never in HBM) is taken wherever
+the call and its shapes allow — ``pick_attention_core`` decides from
+what it can observe; the materialised one (``_sdpa_core``: einsums XLA
+tiles onto the MXU with scale/mask/softmax fused between them) covers
+what the kernels do not: ``attn_mask``, attention-weight dropout,
+small shapes, a mesh, every backend but a TPU.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import math
 import warnings
 from functools import partial
-from typing import Optional
+from typing import Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -207,22 +212,106 @@ DECODER_ATTENTION_IMPLS = (None, "einsum", "chunked", "flash")
 _SPMD_IMPLS = SPMD_IMPLS
 
 
+# --- which core a call takes --------------------------------------------------
+# ``impl=None`` means "pick": the fused kernels where the backend is a
+# TPU, no attention-weight dropout is active, there is no ``attn_mask``,
+# the operands lie on one device and the shapes are ones the kernels
+# tile well; the materialised core otherwise, for the first reason that
+# holds. One algorithm with parameters from shape — not a user's choice
+# between two equals ("einsum" / "flash" force one for tests and A/B).
+# The shape floors are from chip runs (PERF.md, Findings, PR 26): the
+# kernels block queries by 128 and pay a fixed cost per call, so they
+# gain from about 512 x 512 scores a head upwards and lose below it
+# (256 x 256, 1024 queries over 128 keys, 32 latents over any number of
+# keys); the head dim sets no floor (16 and 32 gain as 64 and 128 do).
+
+FUSED_MIN_LQ = 128
+FUSED_MIN_LK = 512
+FUSED_MIN_SCORES = 512 * 512
+
+#: why a call site took the materialised core, in the order checked
+MATERIALIZED_REASONS = ("impl", "attn_mask", "dropout", "backend", "mesh",
+                        "shape")
+
+
+def pick_attention_core(*, backend: str, lq: int, lk: int,
+                        dropout_active: bool, has_attn_mask: bool,
+                        mesh_devices: int) -> Tuple[str, Optional[str]]:
+    """``("fused", None)`` or ``("materialized", reason)`` for an
+    ``impl=None`` call, from what the call site can observe."""
+    if has_attn_mask:
+        reason = "attn_mask"
+    elif dropout_active:
+        reason = "dropout"
+    elif backend != "tpu":
+        reason = "backend"
+    elif mesh_devices > 1:
+        # a Pallas call has no partitioning rule: under GSPMD its
+        # operands would be all-gathered into a replicated kernel
+        reason = "mesh"
+    elif (lq < FUSED_MIN_LQ or lk < FUSED_MIN_LK
+          or lq * lk < FUSED_MIN_SCORES):
+        reason = "shape"
+    else:
+        return "fused", None
+    return "materialized", reason
+
+
+def _backend() -> str:
+    """The backend the pick reads (a seam: tests drive the pick as a
+    TPU would take it while the kernels run interpreted)."""
+    return jax.default_backend()
+
+
+def _mesh_devices(x) -> int:
+    """Devices of the mesh ``x`` is laid out on, as its type carries it
+    (inside a trace too: jit hands the arguments' mesh down); 1 for an
+    array on one device."""
+    mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
+    return 1 if mesh is None or mesh.empty else mesh.size
+
+
+# Trace-time tally of attention call sites, keyed (path, reason):
+# ("fused", None), ("materialized", "shape"), ("chunked", None), ...
+# A call site inside a scanned or rematerialised layer counts once per
+# trace of its body, not once per execution.
+_PATH_TALLIES = []
+
+
+@contextlib.contextmanager
+def attention_paths() -> Iterator[collections.Counter]:
+    """Count the attention call sites traced inside the block by the
+    core they took, in the style of ``cache.compile_events()``."""
+    tally = collections.Counter()
+    _PATH_TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _PATH_TALLIES.remove(tally)
+
+
+def format_attention_paths(tally) -> str:
+    """``fused=39 materialized[shape]=1`` — one log line's worth."""
+    return " ".join(
+        f"{path}[{reason}]={n}" if reason else f"{path}={n}"
+        for (path, reason), n in sorted(
+            tally.items(), key=lambda kv: (kv[0][0], kv[0][1] or "")))
+
+
 @device_scope("attn_proj")
-def mha_kv_heads(params, k, v, *, num_heads: int,
-                 policy: Policy = DEFAULT_POLICY):
-    """Project k/v and split heads: the loop-invariant half of
-    cross-attention. The Perceiver encoder cross-attends the SAME
-    input tokens in every weight-shared layer, so the kv projections
-    (and the kv LayerNorm upstream, see ``cross_attention_kv``) are
-    identical across the layer scan — hoisting them out of the loop
-    removes a per-layer recompute AND the per-layer residual stacking
-    of the projected kv through the scan. Returns ``(kh, vh)`` shaped
-    (B, Lk, H, D) for ``mha_apply(..., kv_heads=...)``."""
-    kh = _split_heads(linear_apply(params["k"], k, policy=policy),
-                      num_heads)
-    vh = _split_heads(linear_apply(params["v"], v, policy=policy),
-                      num_heads)
-    return kh, vh
+def mha_kv_heads(params, k, v, *, policy: Policy = DEFAULT_POLICY):
+    """Project k/v: the loop-invariant half of cross-attention. The
+    Perceiver encoder cross-attends the SAME input tokens in every
+    weight-shared layer, so the kv projections (and the kv LayerNorm
+    upstream, see ``cross_attention_kv``) are identical across the
+    layer scan — hoisting them out of the loop removes a per-layer
+    recompute AND the per-layer residual stacking of the projected kv
+    through the scan. Returns ``(kh, vh)`` shaped (B, Lk, H·D), heads
+    side by side on the channel axis as the projection leaves them
+    (the fused core reads them so; the others split them), for
+    ``mha_apply(..., kv_heads=...)``."""
+    return (linear_apply(params["k"], k, policy=policy),
+            linear_apply(params["v"], v, policy=policy))
 
 
 def mha_apply(params, q, k, v, *, num_heads: int,
@@ -235,10 +324,12 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     q: (B, Lq, q_dim); k: (B, Lk, k_dim); v: (B, Lk, v_dim).
     key_padding_mask: (B, Lk) bool, True at padding.
     attn_mask: (Lq, Lk) or (B, Lq, Lk); bool (True = masked) or additive.
-    impl: None/"einsum" (materialized weights, supports dropout and
-    attn_mask), "chunked" (blockwise lax.scan, O(Lq·chunk) memory,
-    supports streamed attention dropout),
-    "flash" (fused Pallas TPU kernel; interpreter mode off-TPU), or one
+    impl: None (pick: the fused kernels where ``pick_attention_core``
+    allows, else the materialized core), "einsum" (materialized
+    weights, supports dropout and attn_mask), "chunked" (blockwise
+    lax.scan, O(Lq·chunk) memory, supports streamed attention dropout),
+    "flash" (the fused Pallas TPU kernels, forward and backward;
+    interpreter mode off-TPU), or one
     of the shard_map sequence-parallel kernels — "seqpar" (q replicated,
     kv sequence-sharded: the Perceiver cross-attention layout), "ring"
     (all of q/k/v sequence-sharded, ppermute kv rotation), "ulysses"
@@ -268,31 +359,50 @@ def mha_apply(params, q, k, v, *, num_heads: int,
         raise ValueError(
             f"impl={impl!r} needs spmd=(mesh, seq_axis, batch_axis)")
 
-    qh, kh, vh = _project_heads(params, q, k, v, num_heads, policy,
-                                kv_heads)
-    if impl in ("chunked", "flash", *_SPMD_IMPLS):
-        out = _streamed_core(qh, kh, vh, impl, key_padding_mask,
-                             dropout_rate, rng, deterministic,
-                             kv_chunk_size, spmd)
+    qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
+    if qh.shape[-1] % num_heads:
+        raise ValueError(f"q_dim {qh.shape[-1]} not divisible by "
+                         f"num_heads {num_heads}")
+    path, reason = impl, None
+    if impl is None:
+        path, reason = pick_attention_core(
+            backend=_backend(), lq=qh.shape[1], lk=kh.shape[1],
+            dropout_active=dropout_rate > 0.0 and not deterministic,
+            has_attn_mask=attn_mask is not None,
+            mesh_devices=_mesh_devices(qh))
+        if path == "fused":
+            impl = "flash"
+    elif impl == "einsum":
+        path, reason = "materialized", "impl"
+    elif impl == "flash":
+        path = "fused"
+    for tally in _PATH_TALLIES:
+        tally[path, reason] += 1
+    if impl == "flash":
+        out = _fused_core(qh, kh, vh, num_heads, key_padding_mask)
     else:
-        out = _materialized_core(qh, kh, vh, key_padding_mask, attn_mask,
-                                 dropout_rate, rng, deterministic, policy)
-    b, lq = out.shape[0], out.shape[1]
-    out = out.reshape(b, lq, num_heads * qh.shape[-1])
+        qh, kh, vh = (_split_heads(x, num_heads) for x in (qh, kh, vh))
+        if impl in ("chunked", *_SPMD_IMPLS):
+            out = _streamed_core(qh, kh, vh, impl, key_padding_mask,
+                                 dropout_rate, rng, deterministic,
+                                 kv_chunk_size, spmd)
+        else:
+            out = _materialized_core(qh, kh, vh, key_padding_mask,
+                                     attn_mask, dropout_rate, rng,
+                                     deterministic, policy)
+        out = out.reshape(*out.shape[:2], -1)
     with device_scope("attn_proj"):
         return linear_apply(params["out"], out, policy=policy)
 
 
 @device_scope("attn_proj")
-def _project_heads(params, q, k, v, num_heads, policy, kv_heads):
-    """q/k/v projections split into heads, (B, L, H, D) each."""
+def _project(params, q, k, v, policy, kv_heads):
+    """q/k/v projections, (B, L, H·D) each: heads unsplit."""
     if kv_heads is not None:
         # pre-projected (kh, vh) from mha_kv_heads — the hoisted
         # loop-invariant path; only the q projection runs per call
-        qh = _split_heads(linear_apply(params["q"], q, policy=policy),
-                          num_heads)
-        kh, vh = kv_heads
-    elif k is q and v is q:
+        return (linear_apply(params["q"], q, policy=policy), *kv_heads)
+    if k is q and v is q:
         # self-attention: pack the three projections into ONE matmul
         # (torch's in_proj). Identical numerics — the concatenated
         # weight produces the same three output blocks — but a single
@@ -305,29 +415,35 @@ def _project_heads(params, q, k, v, num_heads, policy, kv_heads):
         }
         qkv = linear_apply(packed, q, policy=policy)
         e = qkv.shape[-1] // 3
-        qh, kh, vh = (_split_heads(qkv[..., i * e:(i + 1) * e], num_heads)
-                      for i in range(3))
-    else:
-        qh = _split_heads(linear_apply(params["q"], q, policy=policy),
-                          num_heads)
-        kh = _split_heads(linear_apply(params["k"], k, policy=policy),
-                          num_heads)
-        vh = _split_heads(linear_apply(params["v"], v, policy=policy),
-                          num_heads)
-    return qh, kh, vh
+        return tuple(qkv[..., i * e:(i + 1) * e] for i in range(3))
+    return (linear_apply(params["q"], q, policy=policy),
+            linear_apply(params["k"], k, policy=policy),
+            linear_apply(params["v"], v, policy=policy))
+
+
+@device_scope("attn_core")
+def _fused_core(q, k, v, num_heads, key_padding_mask):
+    """The fused kernels, on the projections as they are: (B, L, H·D)
+    in and out, blocks from the shapes."""
+    import perceiver_tpu.ops.chunked_attention as _ca
+    import perceiver_tpu.ops.pallas_attention as _pa
+    bias = (_ca.pad_mask_to_bias(key_padding_mask)
+            if key_padding_mask is not None else None)
+    return _pa.flash_attention_channels(q, k, v, num_heads=num_heads,
+                                        bias=bias)
 
 
 @device_scope("attn_core")
 def _streamed_core(qh, kh, vh, impl, key_padding_mask, dropout_rate, rng,
                    deterministic, kv_chunk_size, spmd):
-    """The attention core of the impls that never hold the weights:
-    chunked, flash and the shard_map kernels. (B, L, H, D) in and out."""
+    """The attention core of the impls that stream the keys in XLA:
+    chunked and the shard_map kernels. (B, L, H, D) in and out."""
     import perceiver_tpu.ops.chunked_attention as _ca
     bias = (_ca.pad_mask_to_bias(key_padding_mask)
             if key_padding_mask is not None else None)
+    scale = 1.0 / (qh.shape[-1] ** 0.5)
     # (B, L, H, D) → (B, H, L, D)
     qt, kt, vt = (x.swapaxes(1, 2) for x in (qh, kh, vh))
-    scale = 1.0 / (qh.shape[-1] ** 0.5)
     if impl == "chunked":
         drop = dropout_rate if not deterministic else 0.0
         if drop > 0.0 and rng is None:
@@ -338,10 +454,6 @@ def _streamed_core(qh, kh, vh, impl, key_padding_mask, dropout_rate, rng,
         out = _ca.chunked_attention(qt, kt, vt, bias=bias, scale=scale,
                                     chunk_size=kv_chunk_size,
                                     dropout_rate=drop, rng=rng)
-    elif impl == "flash":
-        import perceiver_tpu.ops.pallas_attention as _pa
-        out = _pa.flash_attention(qt, kt, vt, bias=bias, scale=scale,
-                                  block_k=kv_chunk_size)
     else:
         from perceiver_tpu.parallel.ring_attention import (
             make_ring_attention,
@@ -411,16 +523,15 @@ def cross_attention_init(key, num_q_channels: int, num_kv_channels: int,
     }
 
 
-def cross_attention_kv(params, x_kv, *, num_heads: int,
-                       policy: Policy = DEFAULT_POLICY):
+def cross_attention_kv(params, x_kv, *, policy: Policy = DEFAULT_POLICY):
     """The loop-invariant half of ``cross_attention_apply``: pre-norm
-    the kv tokens and project them to heads, once. The encoder hoists
-    this out of its weight-shared layer scan (``models/perceiver.py``)
-    — the kv LayerNorm + projections over the full token array were
-    recomputed AND residual-stacked per layer before."""
+    the kv tokens and project them, once ((B, Lk, H·D) each). The
+    encoder hoists this out of its weight-shared layer scan
+    (``models/perceiver.py``) — the kv LayerNorm + projections over
+    the full token array were recomputed AND residual-stacked per
+    layer before."""
     xkv = layer_norm_apply(params["norm_kv"], x_kv, policy=policy)
-    return mha_kv_heads(params["mha"], xkv, xkv, num_heads=num_heads,
-                        policy=policy)
+    return mha_kv_heads(params["mha"], xkv, xkv, policy=policy)
 
 
 def cross_attention_apply(params, x_q, x_kv, *, num_heads: int,

@@ -1,9 +1,8 @@
 """The shared online-softmax body of the Pallas attention kernels.
 
-Flash attention (``pallas_attention``), ragged cross-attention
-(``ragged_attention``), and paged decode attention
-(``paged_attention``) all walk the kv axis block by block and carry
-the same three VMEM accumulators: the running row max ``m``, the
+Ragged cross-attention (``ragged_attention``) and paged decode
+attention (``paged_attention``) walk the kv axis block by block and
+carry the same three VMEM accumulators: the running row max ``m``, the
 running normalizer ``l``, and the unnormalized output accumulator
 ``acc`` (all fp32; m/l are stored lane-broadcast as ``(rows, 128)``
 so the scratch tiles stay hardware-shaped). The rescale-and-

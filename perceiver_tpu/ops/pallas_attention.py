@@ -1,45 +1,65 @@
-"""Fused flash-attention Pallas kernel for TPU.
+"""Fused flash-attention Pallas kernels for TPU, forward and backward.
 
-Single-pass online-softmax attention (FlashAttention recurrence) as a
-Pallas TPU kernel: for each query block, key/value blocks stream
-HBM → VMEM along the innermost grid dimension while running max ``m``,
-normalizer ``l``, and unnormalized output ``acc`` live in VMEM scratch.
-The (Lq, Lk) logit matrix never hits HBM — softmax, masking, and both
-matmuls fuse in one kernel, so HBM traffic is O(Lq·D + Lk·D) instead
-of O(Lq·Lk).
+The attention core whose scores never leave VMEM. Forward: online
+softmax (FlashAttention recurrence) over key blocks — for each query
+block, key/value blocks stream HBM → VMEM along the innermost grid
+dimension while the running max ``m``, normalizer ``l`` and
+unnormalized output ``acc`` live in VMEM scratch; where the keys fit
+one block the softmax is taken directly. Backward: one kernel that
+recomputes each block's probabilities from ``q``, ``k`` and the saved
+per-row log-sum-exp and produces ``dq``, ``dk``, ``dv`` (and the key
+bias's gradient). No ``(Lq, Lk)`` array reaches HBM in either pass, so
+HBM traffic is O(Lq·D + Lk·D) instead of O(Lq·Lk).
 
-This is the hot-op kernel for the encoder cross-attention at large
-input length M (reference ``model.py:150-160``): the 512×512 LArTPC
-config cross-attends 32 latents against M = 262,144 inputs
-(``run.py:79``), and the seq-2048 MLM config (BASELINE.md configs[4])
-streams 2048 kv tokens per layer.
+Arithmetic is the materialised core's (``ops/attention._sdpa_core``):
+operands in the caller's dtype (bf16 under the default policy) into
+every matmul, float32 scores, statistics and accumulators, the softmax
+scale folded into the small ``q`` / ``k`` tiles, the probabilities and
+``ds`` cast to the operand dtype before their contractions.
 
-Grid layout: ``(B, H, num_q_blocks, num_kv_blocks)`` — the kv axis is
-innermost because TPU grids execute sequentially, which is what makes
-carrying (m, l, acc) across kv steps in scratch legal.
+Layout. The kernels read and write heads where the projections leave
+them: ``(B, L, H·D)`` arrays, heads side by side on the channel axis,
+one block of 128 lanes (or ``D``, from 128 up) a program — no
+``(B, H, L, D)`` copy of any operand or result, no four-dimensional
+array for the compiler to lay out its own way, and no lane padding of
+narrow heads in HBM. A block of 128 lanes holds ``128 // D`` heads (two
+of 64, eight of 16). The kernel walks them: head ``g``'s scores are
+``(q ∘ mask_g)·kᵀ`` over the whole block — the MXU contracts 128 deep
+whatever ``D`` is, so the other heads' zeroed lanes cost nothing — and
+its ``p·v`` fills the block's 128 columns, of which ``mask_g`` keeps
+head ``g``'s. In the backward the masked ``do``, ``q`` and ``k`` tiles
+make ``dv``, ``dk`` and ``dq`` land in their own head's lanes, so the
+heads' results add up into one lane-dense block. Head dims that
+neither divide 128 nor are a multiple of it (or head counts the group
+does not divide) are zero-padded to 128 lanes a head.
 
-Two block layouts, selected by head dim:
+Orientation. The forward holds scores as ``(block_q, block_k)``: both
+matmuls are MXU-native (``q·kᵀ``, ``p·v``) and the key bias is a row.
+The backward holds them transposed, ``(block_k, block_q)``: ``k·qᵀ``
+and ``v·doᵀ`` are native, ``dv = pᵀ·do`` and ``dk = dsᵀ·q`` become
+plain products, the row statistics (log-sum-exp, ``delta``) are
+``(1, block_q)`` rows that broadcast along sublanes for free, and only
+``dq = ds·k`` needs one transpose of the score tile. The forward
+therefore emits the log-sum-exp as a lane-dense ``(1, Lq)`` row.
 
-- standard (``D > 32``): blocks are (L, D) with D padded to 128 lanes.
-- transposed (``D <= 32``): blocks are (D, L) — every 64-channel/
-  4-head BASELINE config has head dim 16, which the standard layout
-  would pad 8x in the lane axis; putting the huge kv axis on lanes and
-  the skinny head dim on sublanes (padded only to 16) cuts kv HBM
-  traffic ~8x. The (B,H,L,D) -> (B,H,D,L) relayout happens outside the
-  kernel, where XLA fuses it into the producing projection matmuls.
+Grids: forward ``(B, H/G, nq, nk)``, backward ``(B, H/G, nk, nq)`` —
+the last axis innermost; TPU grids execute sequentially, which is what
+makes carrying accumulators across steps legal. In the backward
+``dk``/``dv`` accumulate in scratch over the query blocks, and ``dq``
+is one float32 output block per ``(b, head group)`` that stays
+resident in VMEM across the whole ``(nk, nq)`` sweep (written straight
+out where the keys fit one block).
 
 Masking is an additive fp32 key bias ``(B, Lk)`` (``NEG_INF`` at
-padding), matching the einsum path's ``key_padding_mask`` semantics.
-Attention-weight dropout is not supported here (the reference default
-is dropout 0.0, ``lightning.py:40``); the einsum path covers the
-dropout>0 case.
+padding), matching the einsum path's ``key_padding_mask`` semantics. A
+row whose every key is masked attends uniformly, as there; its
+backward then weights each key 1 where the einsum path has 1/Lk (the
+log-sum-exp absorbs log Lk beside 1e30) — finite, and such a row
+carries no loss. A full ``attn_mask`` and attention-weight dropout are
+not supported; ``ops/attention.py`` keeps those on the materialised
+core.
 
-Backward pass: ``jax.custom_vjp`` whose reverse recomputes attention
-with the blockwise-scan implementation
-(``perceiver_tpu.ops.chunked_attention``) — exact, and memory-bounded
-like the forward.
-
-On non-TPU backends the kernel runs in Pallas interpreter mode, so
+On non-TPU backends the kernels run in Pallas interpreter mode, so
 tests exercise the identical code path on CPU.
 """
 
@@ -55,286 +75,533 @@ from jax.experimental.pallas import tpu as pltpu
 
 from perceiver_tpu.ops.tiling import round_up as _round_up
 
-from perceiver_tpu.ops.chunked_attention import NEG_INF, chunked_attention
-from perceiver_tpu.ops.online_softmax import (
-    online_softmax_finish,
-    online_softmax_init,
-    online_softmax_update,
-)
+from perceiver_tpu.ops.chunked_attention import NEG_INF
+
+_NN = (((1,), (0,)), ((), ()))  # A · B
+_NT = (((1,), (1,)), ((), ()))  # A · Bᵀ (the MXU loads B transposed)
+_TN = (((0,), (0,)), ((), ()))  # Aᵀ · B
+
+_LANES = 128
+
+# Blocks from shapes (chip runs, PERF.md Findings PR 26): keys in one
+# block up to 2048 (a plain softmax, no running state, dq written
+# straight out), else streamed by 1024; queries by up to 1024, with
+# the (block_q, block_k) float32 score tile, several of which are live
+# at once, held to 4 MiB under the limit below (a v5e core has 128 MiB;
+# at 512 x 2048 a streamed forward already lost a third to spills).
+_ONE_BLOCK_K = 2048
+_STREAM_BLOCK_K = 1024
+_MAX_BLOCK_Q = 1024
+_SCORE_TILE = 1024 * 1024
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, nk: int):
-    ib = pl.program_id(0)
-    ik = pl.program_id(3)
+def pick_blocks(lq: int, lk: int):
+    """``(block_q, block_k)`` for ``Lq`` queries over ``Lk`` keys."""
+    lk_p = _round_up(lk, _LANES)
+    block_k = lk_p if lk_p <= _ONE_BLOCK_K else _STREAM_BLOCK_K
+    block_q = min(_round_up(lq, _LANES), _MAX_BLOCK_Q,
+                  _SCORE_TILE // block_k)
+    return block_q, block_k
 
-    @pl.when(ik == 0)
-    def _():
-        online_softmax_init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0]  # (block_q, Dp)
-    k = k_ref[0, 0]  # (block_k, Dp)
-    v = v_ref[0, 0]  # (block_k, Dp)
+_VMEM_LIMIT = 96 * 1024 * 1024
+# the backward's resident float32 dq block, (Lq, 128) per head group
+_DQ_RESIDENT_MAX = 16 * 1024 * 1024
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (block_q, block_k)
-    # bias block spans the whole batch (Mosaic requires the sublane dim
-    # be 8-divisible or full); select this program's row dynamically
-    s = s + bias_ref[pl.ds(ib, 1), :]
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT)
 
-    online_softmax_update(s, v, m_ref, l_ref, acc_ref)
+
+def _col_to_row(col):
+    """(rows, 1) → (1, rows) through one tile-aligned (rows, 128) →
+    (128, rows) transpose (a standard Mosaic relayout; a (rows, 1)
+    vector is not)."""
+    wide = jnp.broadcast_to(col, (col.shape[0], _LANES))
+    return jax.lax.transpose(wide, (1, 0))[:1]
+
+
+def _head_masks(width: int, group: int):
+    """One (1, width) lane mask a head of the block; ``[None]`` for a
+    block that is one head."""
+    if group == 1:
+        return [None]
+    lane_head = jax.lax.broadcasted_iota(
+        jnp.int32, (1, width), 1) // (width // group)
+    return [lane_head == g for g in range(group)]
+
+
+def _only(mask, tile):
+    """``tile`` with the other heads' lanes zeroed."""
+    return tile if mask is None else jnp.where(mask, tile, 0)
+
+
+def _merge(mask, new, old):
+    """``new`` in this head's lanes, ``old`` in the others'."""
+    return new if mask is None or old is None else jnp.where(mask, new, old)
+
+
+# --- forward -----------------------------------------------------------------
+
+
+def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
+                save_lse: bool):
+    refs = iter(refs)
+    q_ref, k_ref, v_ref = next(refs), next(refs), next(refs)
+    bias_ref = next(refs) if has_bias else None
+    o_ref = next(refs)
+    lse_ref = next(refs) if save_lse else None
+    if nk > 1:
+        m_ref, l_ref, acc_ref = refs
+        ik = pl.program_id(3)
+
+        @pl.when(ik == 0)
+        def _():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0] * scale      # (block_q, W), operand dtype
+    k = k_ref[0]              # (block_k, W)
+    v = v_ref[0]
+    masks = _head_masks(q.shape[-1], group)
+
+    out = None
+    for g, mask in enumerate(masks):
+        s = jax.lax.dot_general(_only(mask, q), k, _NT,
+                                preferred_element_type=jnp.float32)
+        if has_bias:
+            s = s + bias_ref[0]   # (1, block_k) key bias row
+        if nk == 1:
+            # every key in this block: a plain softmax, no running state
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(p.astype(v.dtype), v, _NN,
+                                     preferred_element_type=jnp.float32)
+            out = _merge(mask, pv * (1.0 / l), out)
+            if save_lse:
+                lse_ref[0, g] = _col_to_row(m + jnp.log(l))
+            continue
+        m_prev = m_ref[g, :, :1]                         # (block_q, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_ref[g, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        acc = acc_ref[:]
+        acc_ref[:] = _merge(mask, acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN,
+            preferred_element_type=jnp.float32), acc)
+
+    if nk == 1:
+        o_ref[0] = out.astype(o_ref.dtype)
+        return
 
     @pl.when(ik == nk - 1)
     def _():
-        o_ref[0, 0] = online_softmax_finish(
-            m_ref, l_ref, acc_ref).astype(o_ref.dtype)
+        out = None
+        for g, mask in enumerate(masks):
+            l = l_ref[g, :, :1]
+            out = _merge(mask, acc_ref[:] * (1.0 / l), out)
+            if save_lse:
+                lse_ref[0, g] = _col_to_row(m_ref[g, :, :1] + jnp.log(l))
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _flash_forward(q, k, v, bias, scale: float,
-                   block_q: int, block_k: int, interpret: bool):
-    b, h, lq, d = q.shape
-    lk = k.shape[2]
+def _head_group(h: int, d: int):
+    """``(heads a block, lanes a head)``: narrow heads that tile 128
+    lanes share a block as they are; heads of a multiple of 128 lanes
+    are a block each; anything else is zero-padded up to the next 128
+    lanes a head (zero columns change neither logits nor outputs)."""
+    if d < _LANES and _LANES % d == 0 and h % (_LANES // d) == 0:
+        return _LANES // d, d
+    return 1, _round_up(d, _LANES)
 
-    # Pad to hardware-friendly tiles. Zero-padding D leaves logits and
-    # outputs unchanged; padded kv columns are killed by NEG_INF bias;
-    # padded query rows are sliced off after.
-    dp = _round_up(d, 128)
-    # 16-sublane rounding covers the strictest dtype tile (bf16 needs
-    # sublane multiples of 16; fp32 needs 8 — 16 satisfies both), e.g.
-    # the 1-query classification decoder under impl="flash"
-    block_q = min(block_q, _round_up(lq, 16))
-    block_k = _round_up(min(block_k, _round_up(lk, 128)), 128)
-    lq_p = _round_up(lq, block_q)
-    lk_p = _round_up(lk, block_k)
 
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, lq_p - lq), (0, dp - d)))
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, lk_p - lk), (0, dp - d)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, lk_p - lk), (0, dp - d)))
+def _pad_heads(x, h: int, dp: int):
+    """(B, L, H·D) → (B, L, H·dp), each head zero-padded to ``dp``."""
+    b, l, e = x.shape
+    if e == h * dp:
+        return x
+    x = x.reshape(b, l, h, e // h)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, dp - e // h)))
+    return x.reshape(b, l, h * dp)
+
+
+def _unpad_heads(x, h: int, d: int):
+    """The inverse: (B, L, H·dp) → (B, L, H·D)."""
+    b, l, e = x.shape
+    if e == h * d:
+        return x
+    return x.reshape(b, l, h, e // h)[..., :d].reshape(b, l, h * d)
+
+
+def _pad_rows(x, rows: int, value=0.0):
+    """Pad axis 1 up to ``rows``."""
+    if x.shape[1] == rows:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[1] = (0, rows - x.shape[1])
+    return jnp.pad(x, widths, constant_values=value)
+
+
+def _key_bias(bias, b: int, lk: int, lk_p: int):
+    """The (B, lk_p) float32 key bias: the caller's, NEG_INF on padded
+    key columns (made here where the caller gave none); None where
+    there is nothing to mask."""
+    if bias is None and lk_p == lk:
+        return None
     if bias is None:
         bias = jnp.zeros((b, lk), jnp.float32)
-    bias = jnp.pad(bias.astype(jnp.float32), ((0, 0), (0, lk_p - lk)),
-                   constant_values=NEG_INF)
+    return _pad_rows(bias.astype(jnp.float32), lk_p, NEG_INF)
 
+
+def _geometry(lq: int, lk: int, e: int, h: int, block_q: int,
+              block_k: int, q_rows: int):
+    """How a call tiles: ``(d, group, dp, width, block_q, block_k,
+    lq_p, lk_p)`` — head dim, heads a block, lanes a head, lanes a
+    block, the blocks clipped to the shapes (queries to a multiple of
+    ``q_rows``, keys to whole lanes) and the padded lengths."""
+    d = e // h
+    group, dp = _head_group(h, d)
+    block_q = min(block_q, _round_up(lq, q_rows))
+    block_k = _round_up(min(block_k, _round_up(lk, _LANES)), _LANES)
+    return (d, group, dp, group * dp, block_q, block_k,
+            _round_up(lq, block_q), _round_up(lk, block_k))
+
+
+def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
+                   block_k: int, interpret: bool, save_lse: bool):
+    """q (B, Lq, H·D), k/v (B, Lk, H·D) → ``o`` (B, Lq, H·D); with
+    ``save_lse`` (the differentiated call) ``o`` in float32 as the
+    kernel accumulated it and the per-row log-sum-exp as
+    (B, H, 1, Lq) float32."""
+    b, lq, e = q.shape
+    lk = k.shape[1]
+    # 16-sublane rounding covers the strictest dtype tile (bf16 needs
+    # sublane multiples of 16; fp32 needs 8), e.g. the 1-query
+    # classification decoder; the log-sum-exp row needs whole lanes
+    d, group, dp, width, block_q, block_k, lq_p, lk_p = _geometry(
+        lq, lk, e, h, block_q, block_k, _LANES if save_lse else 16)
+    # padded query rows are sliced off below; padded keys are masked
+    q = _pad_rows(_pad_heads(q, h, dp), lq_p)
+    k = _pad_rows(_pad_heads(k, h, dp), lk_p)
+    v = _pad_rows(_pad_heads(v, h, dp), lk_p)
+    bias = _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
-    grid = (b, h, nq, nk)
+    has_bias = bias is not None
 
+    in_specs = [
+        pl.BlockSpec((1, block_q, width),
+                     lambda ib, ih, iq, ik: (ib, iq, ih)),
+        pl.BlockSpec((1, block_k, width),
+                     lambda ib, ih, iq, ik: (ib, ik, ih)),
+        pl.BlockSpec((1, block_k, width),
+                     lambda ib, ih, iq, ik: (ib, ik, ih)),
+    ]
+    args = [q, k, v]
+    if has_bias:
+        in_specs.append(pl.BlockSpec((1, 1, block_k),
+                                     lambda ib, ih, iq, ik: (ib, 0, ik)))
+        args.append(bias[:, None, :])
+    out_specs = [pl.BlockSpec((1, block_q, width),
+                              lambda ib, ih, iq, ik: (ib, iq, ih))]
+    out_shape = [jax.ShapeDtypeStruct(
+        (b, lq_p, h * dp), jnp.float32 if save_lse else q.dtype)]
+    if save_lse:
+        out_specs.append(pl.BlockSpec(
+            (1, group, 1, block_q),
+            lambda ib, ih, iq, ik: (ib, ih, 0, iq)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((b, h, 1, lq_p), jnp.float32))
+    scratch = [] if nk == 1 else [
+        pltpu.VMEM((group, block_q, _LANES), jnp.float32),  # running max
+        pltpu.VMEM((group, block_q, _LANES), jnp.float32),  # normalizer
+        pltpu.VMEM((block_q, width), jnp.float32),   # unnormalized acc
+    ]
     out = pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, dp),
-                         lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, dp),
-                         lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, dp),
-                         lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((b, block_k),
-                         lambda ib, ih, iq, ik: (0, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, dp),
-                               lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, lq_p, dp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),   # normalizer l
-            pltpu.VMEM((block_q, dp), jnp.float32),    # unnormalized acc
-        ],
+        functools.partial(_fwd_kernel, scale=scale, nk=nk, group=group,
+                          has_bias=has_bias, save_lse=save_lse),
+        grid=(b, h // group, nq, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_attention_fwd",
-    )(q, k, v, bias)
-    return out[:, :, :lq, :d]
+    )(*args)
+    o = _unpad_heads(out[0][:, :lq], h, d)
+    return (o, out[1][..., :lq]) if save_lse else o
 
 
-def _flash_kernel_t(q_ref, k_ref, v_ref, bias_ref, o_ref,
-                    m_ref, l_ref, acc_ref, *, scale: float, nk: int):
-    """Transposed-layout kernel: q/k/v/o are (..., D, L) so the HUGE
-    kv axis is the 128-lane minor dim and the skinny head dim (16 for
-    every 64-channel/4-head BASELINE config) rides the sublane axis
-    unpadded — 8x less HBM traffic than padding D up to 128 lanes."""
-    ib = pl.program_id(0)
-    ik = pl.program_id(3)
+# --- backward ----------------------------------------------------------------
 
-    @pl.when(ik == 0)
+
+def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
+                group: int, has_bias: bool):
+    refs = iter(refs)
+    q_ref, k_ref, v_ref, do_ref = (next(refs) for _ in range(4))
+    kbar_ref, vbar_ref = next(refs), next(refs)
+    lse_ref, delta_ref = next(refs), next(refs)
+    bias_ref = next(refs) if has_bias else None
+    dq_ref, dk_ref, dv_ref = next(refs), next(refs), next(refs)
+    db_ref = next(refs) if has_bias else None
+    iq = pl.program_id(3)
+    ik = pl.program_id(2)
+
+    q = q_ref[0] * scale      # (block_q, W), operand dtype
+    k = k_ref[0]              # (block_k, W)
+    do = do_ref[0]            # (block_q, W)
+    # keys and values about their means over the keys, for the two
+    # contractions a common component would spoil (_flash_backward)
+    kc = ((k - kbar_ref[0]) * scale).astype(k.dtype)
+    vc = (v_ref[0] - vbar_ref[0]).astype(k.dtype)
+    masks = _head_masks(q.shape[-1], group)
+
+    dq = dk = dv = db = None
+    for g, mask in enumerate(masks):
+        # this head's lanes of the small tiles, zeros elsewhere: its
+        # gradients then land in its own lanes and the heads' add up
+        qg, dog, kg = _only(mask, q), _only(mask, do), _only(mask, kc)
+        # scores transposed, keys on sublanes: (block_k, block_q)
+        s = jax.lax.dot_general(k, qg, _NT,
+                                preferred_element_type=jnp.float32)
+        if has_bias:
+            s = s + bias_ref[0]   # (block_k, 1) key bias column
+        p = jnp.exp(s - lse_ref[0, g])                   # rows (1, block_q)
+        dp = jax.lax.dot_general(vc, dog, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, g])
+        dsb = ds.astype(q.dtype)
+        dv_g = jax.lax.dot_general(p.astype(do.dtype), dog, _NN,
+                                   preferred_element_type=jnp.float32)
+        dk_g = jax.lax.dot_general(dsb, qg, _NN,
+                                   preferred_element_type=jnp.float32)
+        # the one contraction over keys: Mosaic transposes the score tile
+        dq_g = jax.lax.dot_general(dsb, kg, _TN,
+                                   preferred_element_type=jnp.float32)
+        dq = dq_g if dq is None else dq + dq_g
+        dk = dk_g if dk is None else dk + dk_g
+        dv = dv_g if dv is None else dv + dv_g
+        if has_bias:
+            # the key bias's gradient: every head's, a column here
+            db_g = jnp.sum(ds, axis=1, keepdims=True)
+            db = db_g if db is None else db + db_g
+
+    if nk == 1:
+        dq_ref[0] = dq.astype(dq_ref.dtype)
+    else:
+        # float32 block of the whole (b, head group), resident across
+        # the sweep
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+
+        @pl.when(ik == 0)
+        def _():
+            dq_ref[0, rows, :] = dq
+
+        @pl.when(ik > 0)
+        def _():
+            dq_ref[0, rows, :] += dq
+
+    if nq == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        if has_bias:
+            db_ref[0, 0] = _col_to_row(db)   # a lane-dense row in HBM
+        return
+
+    dk_acc, dv_acc = next(refs), next(refs)
+    db_acc = next(refs) if has_bias else None
+
+    @pl.when(iq == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        dk_acc[:] = dk
+        dv_acc[:] = dv
+        if has_bias:
+            db_acc[:] = db
 
-    qt = q_ref[0, 0]  # (Dp, block_q)
-    kt = k_ref[0, 0]  # (Dp, block_k)
-    vt = v_ref[0, 0]  # (Dp, block_k)
-
-    s = jax.lax.dot_general(
-        qt, kt, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (block_q, block_k)
-    s = s + bias_ref[pl.ds(ib, 1), :]
-
-    m_prev = m_ref[:, :1]                                # (block_q, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-    # acc wants q on the LANE axis; softmax stats have q on SUBLANE.
-    # Cross the orientations with one tile-aligned (block_q, 128) →
-    # (128, block_q) transpose per kv step (a standard Mosaic relayout;
-    # both dims are tile multiples, unlike a (block_q, 1) vector); its
-    # rows are all identical, so row 0 broadcasts to any Dp.
-    alpha_t = jax.lax.transpose(
-        jnp.broadcast_to(alpha, (alpha.shape[0], 128)), (1, 0))
-    acc_ref[:] = (acc_ref[:]
-                  * jnp.broadcast_to(alpha_t[:1], acc_ref.shape)
-                  + jax.lax.dot_general(
-                      vt, p.astype(vt.dtype), (((1,), (1,)), ((), ())),
-                      preferred_element_type=jnp.float32))  # (Dp, block_q)
-
-    @pl.when(ik == nk - 1)
+    @pl.when(iq > 0)
     def _():
-        l_t = jax.lax.transpose(l_ref[:], (1, 0))        # (128, block_q)
-        o_ref[0, 0] = (acc_ref[:] /
-                       jnp.maximum(jnp.broadcast_to(l_t[:1],
-                                                    acc_ref.shape),
-                                   1e-30)).astype(o_ref.dtype)
+        dk_acc[:] += dk
+        dv_acc[:] += dv
+        if has_bias:
+            db_acc[:] += db
+
+    @pl.when(iq == nq - 1)
+    def _():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        if has_bias:
+            db_ref[0, 0] = _col_to_row(db_acc[:])
 
 
-def _flash_forward_t(q, k, v, bias, scale: float,
-                     block_q: int, block_k: int, interpret: bool):
-    """Forward via the transposed kernel. Takes standard (B, H, L, D)
-    arrays; the (D, L) relayout happens outside the kernel where XLA
-    fuses it into the producing projection matmuls."""
-    b, h, lq, d = q.shape
-    lk = k.shape[2]
-
-    # sublane-pad D to the strictest tile (16 covers bf16 and fp32);
-    # lane-pad both L axes to their block sizes. Both L blocks are the
-    # MINOR dim of their arrays here, so Mosaic requires them to be
-    # 128-multiples — round the user's block_q UP (the standard layout
-    # only needs sublane-rounding for it).
-    dp = _round_up(d, 16)
-    block_q = _round_up(min(block_q, _round_up(lq, 128)), 128)
-    block_k = _round_up(min(block_k, _round_up(lk, 128)), 128)
-    lq_p = _round_up(lq, block_q)
-    lk_p = _round_up(lk, block_k)
-
-    qt = jnp.pad(q.swapaxes(2, 3), ((0, 0), (0, 0), (0, dp - d),
-                                    (0, lq_p - lq)))
-    kt = jnp.pad(k.swapaxes(2, 3), ((0, 0), (0, 0), (0, dp - d),
-                                    (0, lk_p - lk)))
-    vt = jnp.pad(v.swapaxes(2, 3), ((0, 0), (0, 0), (0, dp - d),
-                                    (0, lk_p - lk)))
-    if bias is None:
-        bias = jnp.zeros((b, lk), jnp.float32)
-    bias = jnp.pad(bias.astype(jnp.float32), ((0, 0), (0, lk_p - lk)),
-                   constant_values=NEG_INF)
-
+def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
+                    block_q: int, block_k: int, interpret: bool):
+    """``dq, dk, dv`` (and ``dbias`` (B, Lk) where a bias was given)
+    from the saved float32 output and log-sum-exp row; all
+    (B, L, H·D)."""
+    b, lq, e = q.shape
+    lk = k.shape[1]
+    d, group, dp, width, block_q, block_k, lq_p, lk_p = _geometry(
+        lq, lk, e, h, block_q, block_k, _LANES)
+    # ds = p * (dp - delta), with dp = do·v and delta = rowsum(do * o),
+    # is a difference of two numbers that share whatever the values
+    # have in common, and dq = ds·k then weighs every rounding error
+    # left in it by whatever the keys have in common (normed tokens
+    # behind one bias have a lot). Both contractions are unchanged by
+    # a shift of all keys' rows (sum_k p = 1, sum_k ds = 0), so they
+    # take v and k about their means over the keys: what is left to
+    # round is the spread, as in the materialised core, whose float32
+    # delta cancels exactly. delta is (B, H, 1, Lq) rows beside the
+    # log-sum-exp.
+    kbar = jnp.mean(k.astype(jnp.float32), axis=1, keepdims=True)
+    vbar = jnp.mean(v.astype(jnp.float32), axis=1, keepdims=True)
+    delta = (do.astype(jnp.float32) * (o - vbar)).reshape(
+        b, lq, h, d).sum(-1)
+    delta = _pad_rows(delta, lq_p).swapaxes(1, 2)[:, :, None, :]
+    lse = jnp.pad(lse, ((0, 0), (0, 0), (0, 0), (0, lq_p - lq)))
+    bias_grad = bias is not None
+    q = _pad_rows(_pad_heads(q, h, dp), lq_p)
+    do = _pad_rows(_pad_heads(do, h, dp), lq_p)
+    k = _pad_rows(_pad_heads(k, h, dp), lk_p)
+    v = _pad_rows(_pad_heads(v, h, dp), lk_p)
+    kbar, vbar = _pad_heads(kbar, h, dp), _pad_heads(vbar, h, dp)
+    bias = _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
-    grid = (b, h, nq, nk)
+    has_bias = bias is not None
+    if nk > 1 and lq_p * width * 4 > _DQ_RESIDENT_MAX:
+        raise NotImplementedError(
+            f"flash attention backward keeps dq ({lq_p} x {width} "
+            f"float32) in VMEM while the keys stream; over "
+            f"{_DQ_RESIDENT_MAX} bytes use impl='chunked', or chunk the "
+            "queries")
 
-    out = pl.pallas_call(
-        functools.partial(_flash_kernel_t, scale=scale, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, dp, block_q),
-                         lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
-            pl.BlockSpec((1, 1, dp, block_k),
-                         lambda ib, ih, iq, ik: (ib, ih, 0, ik)),
-            pl.BlockSpec((1, 1, dp, block_k),
-                         lambda ib, ih, iq, ik: (ib, ih, 0, ik)),
-            pl.BlockSpec((b, block_k),
-                         lambda ib, ih, iq, ik: (0, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, dp, block_q),
-                               lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
-        out_shape=jax.ShapeDtypeStruct((b, h, dp, lq_p), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),   # normalizer l
-            pltpu.VMEM((dp, block_q), jnp.float32),    # acc, q on lanes
-        ],
+    q_spec = pl.BlockSpec((1, block_q, width),
+                          lambda ib, ih, ik, iq: (ib, iq, ih))
+    k_spec = pl.BlockSpec((1, block_k, width),
+                          lambda ib, ih, ik, iq: (ib, ik, ih))
+    row_spec = pl.BlockSpec((1, group, 1, block_q),
+                            lambda ib, ih, ik, iq: (ib, ih, 0, iq))
+    mean_spec = pl.BlockSpec((1, 1, width),
+                             lambda ib, ih, ik, iq: (ib, 0, ih))
+    in_specs = [q_spec, k_spec, k_spec, q_spec, mean_spec, mean_spec,
+                row_spec, row_spec]
+    args = [q, k, v, do, kbar, vbar, lse, delta]
+    if has_bias:
+        in_specs.append(pl.BlockSpec((1, block_k, 1),
+                                     lambda ib, ih, ik, iq: (ib, ik, 0)))
+        args.append(bias[:, :, None])
+    if nk == 1:
+        dq_spec, dq_dtype = q_spec, q.dtype
+    else:
+        dq_spec = pl.BlockSpec((1, lq_p, width),
+                               lambda ib, ih, ik, iq: (ib, 0, ih))
+        dq_dtype = jnp.float32
+    out_specs = [dq_spec, k_spec, k_spec]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, dq_dtype),
+                 jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch = [] if nq == 1 else [
+        pltpu.VMEM((block_k, width), jnp.float32),   # dk accumulator
+        pltpu.VMEM((block_k, width), jnp.float32),   # dv accumulator
+    ]
+    if has_bias:
+        out_specs.append(pl.BlockSpec(
+            (1, 1, 1, block_k), lambda ib, ih, ik, iq: (ib, ih, 0, ik)))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (b, h // group, 1, lk_p), jnp.float32))
+        if nq > 1:
+            scratch.append(pltpu.VMEM((block_k, 1), jnp.float32))
+
+    dq, dk, dv, *db = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, nq=nq, nk=nk,
+                          block_q=block_q, group=group,
+                          has_bias=has_bias),
+        grid=(b, h // group, nk, nq),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name="flash_attention_fwd_t",
-    )(qt, kt, vt, bias)
-    return out[:, :, :d, :lq].swapaxes(2, 3)
+        name="flash_attention_bwd",
+    )(*args)
+
+    def trim(x, rows):
+        return _unpad_heads(x[:, :rows], h, d)
+
+    dbias = db[0][:, :, 0, :lk].sum(axis=1) if bias_grad else None
+    return trim(dq, lq).astype(k.dtype), trim(dk, lk), trim(dv, lk), dbias
 
 
-# D at or below this uses the transposed kernel: the padding ratio
-# 128/D makes the standard layout waste >=4x HBM bandwidth on kv
-_SKINNY_D = 32
+# --- the differentiable core -------------------------------------------------
 
 
-def _pick_layout(d: int) -> str:
-    """'transposed' or 'standard'; PERCEIVER_TPU_FLASH_LAYOUT overrides
-    the D-based auto choice (for on-chip A/B benchmarking)."""
-    import os
-    env = os.environ.get("PERCEIVER_TPU_FLASH_LAYOUT", "auto")
-    if env in ("standard", "transposed"):
-        return env
-    if env != "auto":
-        # a typo'd override would silently measure the auto layout in
-        # both arms of a chip-time A/B — reject like any other config
-        raise ValueError(
-            f"PERCEIVER_TPU_FLASH_LAYOUT={env!r}; expected 'auto', "
-            "'standard', or 'transposed'")
-    return "transposed" if d <= _SKINNY_D else "standard"
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, bias, h, scale, block_q, block_k, interpret):
+    # forward-only use: no residual output leaves the kernel
+    return _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
+                          interpret, False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, bias, scale, block_q, block_k, interpret):
-    return _flash_forward_any(q, k, v, bias, scale, block_q, block_k,
-                              interpret)
+def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret):
+    # the residual output stays float32: the backward's delta =
+    # rowsum(do * o) must cancel sum_k(dp * p) to float32 rounding, or
+    # every key of a row gets the same push and dq drifts along the
+    # keys' common component (seen as update_norm_gap, PERF.md PR 26)
+    o, lse = _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
+                            interpret, True)
+    return o.astype(q.dtype), (q, k, v, bias, o, lse)
 
 
-def _flash_forward_any(q, k, v, bias, scale, block_q, block_k, interpret):
-    if _pick_layout(q.shape[-1]) == "transposed":
-        return _flash_forward_t(q, k, v, bias, scale, block_q, block_k,
-                                interpret)
-    return _flash_forward(q, k, v, bias, scale, block_q, block_k, interpret)
-
-
-def _flash_fwd(q, k, v, bias, scale, block_q, block_k, interpret):
-    out = _flash_forward_any(q, k, v, bias, scale, block_q, block_k,
-                             interpret)
-    return out, (q, k, v, bias)
-
-
-def _flash_bwd(scale, block_q, block_k, interpret, res, g):
-    q, k, v, bias = res
-    # Exact recompute through the blockwise scan — backward stays
-    # memory-bounded on BOTH axes: kv streams through the scan
-    # (rematerialized), and the query axis is blocked like the forward
-    # kernel grid (matters for the 262k-query decoder config).
-    if bias is None:
-        _, vjp = jax.vjp(
-            lambda a, b_, c: chunked_attention(
-                a, b_, c, scale=scale, chunk_size=block_k,
-                q_chunk_size=block_q * 8),
-            q, k, v)
-        return (*vjp(g), None)
-    # bias is differentiable (a learned additive key bias trains the
-    # same under impl="flash" as under "chunked"/"einsum")
-    _, vjp = jax.vjp(
-        lambda a, b_, c, bi: chunked_attention(
-            a, b_, c, bias=bi, scale=scale, chunk_size=block_k,
-            q_chunk_size=block_q * 8),
-        q, k, v, bias)
-    return vjp(g)
+def _flash_bwd(h, scale, block_q, block_k, interpret, res, g):
+    q, k, v, bias, o, lse = res
+    dq, dk, dv, dbias = _flash_backward(
+        q, k, v, bias, o, lse, g.astype(q.dtype), h, scale, block_q,
+        block_k, interpret)
+    if dbias is not None:
+        # a learned additive key bias trains the same as under
+        # "chunked"/"einsum"; a mask's cotangent is dropped by its caller
+        dbias = dbias.astype(bias.dtype)
+    return dq, dk, dv, dbias
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, bias: Optional[jax.Array] = None,
-                    scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 512,
-                    interpret: Optional[bool] = None):
-    """Fused attention. q: (B, H, Lq, D); k, v: (B, H, Lk, D);
+def flash_attention_channels(q, k, v, *, num_heads: int, bias=None,
+                             scale: Optional[float] = None,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
+                             interpret: Optional[bool] = None):
+    """Fused attention on heads as the projections leave them, side by
+    side on the channel axis. q: (B, Lq, H·D); k, v: (B, Lk, H·D);
     bias: optional (B, Lk) additive key bias (NEG_INF at padding).
-    Returns (B, H, Lq, D) in q's dtype."""
+    Blocks come from the shapes (``pick_blocks``) unless given.
+    Returns (B, Lq, H·D) in q's dtype."""
     from perceiver_tpu.utils.platform import resolve_interpret
+    if q.shape[-1] % num_heads:
+        raise ValueError(f"{q.shape[-1]} channels do not split into "
+                         f"{num_heads} heads")
     if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _flash(q, k, v, bias, float(scale), int(block_q), int(block_k),
+        scale = 1.0 / ((q.shape[-1] // num_heads) ** 0.5)
+    auto_q, auto_k = pick_blocks(q.shape[1], k.shape[1])
+    return _flash(q, k, v, bias, int(num_heads), float(scale),
+                  int(auto_q if block_q is None else block_q),
+                  int(auto_k if block_k is None else block_k),
                   resolve_interpret(interpret))
+
+
+def flash_attention(q, k, v, **kwargs):
+    """``flash_attention_channels`` for (B, H, L, D) operands."""
+    b, h, lq, d = q.shape
+
+    def channels(x):
+        return x.swapaxes(1, 2).reshape(b, x.shape[2], h * d)
+
+    out = flash_attention_channels(channels(q), channels(k), channels(v),
+                                   num_heads=h, **kwargs)
+    return out.reshape(b, lq, h, d).swapaxes(1, 2)

@@ -394,7 +394,7 @@ def build_decode_graph(model, geometry: DecodeGeometry, *,
         def append(layer_params, kpool, vpool):
             kh, vh = cross_attention_kv(
                 layer_params["cross"]["attn"], emb,
-                num_heads=enc_heads, policy=policy)  # (R, Q, H, Dh)
+                policy=policy)                       # (R, Q, H*Dh)
             kh = kh.reshape(-1, enc_heads, head_dim)
             vh = vh.reshape(-1, enc_heads, head_dim)
             kpool = kpool.at[page, slot].set(kh.astype(kpool.dtype))

@@ -329,9 +329,13 @@ def _packed_encoder_apply(encoder, params, packed_ids, positions,
 
     def layer_kv(layer_params):
         kh, vh = cross_attention_kv(layer_params["cross"]["attn"], x_kv,
-                                    num_heads=num_heads, policy=policy)
-        # (1, T, H, Dh) → (H, T, Dh)
-        return kh[0].swapaxes(0, 1), vh[0].swapaxes(0, 1)
+                                    policy=policy)
+
+        # (1, T, H*Dh) → (H, T, Dh)
+        def heads(x):
+            return x[0].reshape(x.shape[1], num_heads, -1).swapaxes(0, 1)
+
+        return heads(kh), heads(vh)
 
     def one_layer(layer_params, kv, lat):
         attn = layer_params["cross"]["attn"]

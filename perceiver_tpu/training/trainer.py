@@ -454,6 +454,25 @@ class Trainer:
             self._train_step_multi = jit_step(train_step_multi, 1)
         self._eval_step = jax.jit(eval_step)
 
+    def _load_step(self, step_fn, state, sharded, n_dev, cache_label):
+        """Lower the step once (cost analysis, and the compile or cache
+        read the first call would do anyway), say which attention core
+        each call site of the traced step took, and return the function
+        to call from now on."""
+        from perceiver_tpu.ops.attention import (
+            attention_paths,
+            format_attention_paths,
+        )
+        with span("train/step_load"), attention_paths() as paths:
+            flops, step_fn = step_flops_and_fn(
+                step_fn, state, sharded, num_devices=n_dev,
+                cache=self._exec_cache, cache_label=cache_label)
+        self._step_flops = flops or 0.0
+        print(f"[step_load] attention call sites: "
+              f"{format_attention_paths(paths)}", file=sys.stderr,
+              flush=True)
+        return step_fn
+
     def _preemption_pending(self) -> bool:
         """Single-process: the SIGTERM flag. Multi-host: the orbax save
         below is a collective, so hosts must agree on the step — defer
@@ -877,15 +896,9 @@ class Trainer:
                                                         stacked=True)
                         phase_s["host"] += sp.seconds
                         if first_step:
-                            with span("train/step_load"):
-                                flops, self._train_step_multi = \
-                                    step_flops_and_fn(
-                                        self._train_step_multi, state,
-                                        sharded, num_devices=n_dev,
-                                        cache=self._exec_cache,
-                                        cache_label=(
-                                            "trainer:train_step_multi"))
-                            self._step_flops = flops or 0.0
+                            self._train_step_multi = self._load_step(
+                                self._train_step_multi, state, sharded,
+                                n_dev, "trainer:train_step_multi")
                         with span("train/dispatch") as sp:
                             if self._guard is not None:
                                 state, metrics, losses = \
@@ -907,14 +920,9 @@ class Trainer:
                                 # cost analysis via lowering, or via the AOT
                                 # compile the first call would do anyway —
                                 # never an extra one
-                                with span("train/step_load"):
-                                    flops, self._train_step = \
-                                        step_flops_and_fn(
-                                            self._train_step, state, sharded,
-                                            num_devices=n_dev,
-                                            cache=self._exec_cache,
-                                            cache_label="trainer:train_step")
-                                self._step_flops = flops or 0.0
+                                self._train_step = self._load_step(
+                                    self._train_step, state, sharded,
+                                    n_dev, "trainer:train_step")
                             with span("train/dispatch") as sp:
                                 if self._guard is not None:
                                     state, metrics, loss_i = \

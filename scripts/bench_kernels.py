@@ -9,9 +9,7 @@ and ``kv_chunk_size``; on CPU it validates the harness (flash runs the
 Pallas kernel in interpreter mode and is expected to be slow there).
 
 Usage: python scripts/bench_kernels.py [impl ...]
-       impls: einsum chunked flash flash_std flash_t
-       (flash_std/flash_t pin the flash block layout; plain flash
-       auto-picks by head dim)
+       impls: einsum chunked flash
 Env:   BENCH_PLATFORM=cpu   KERNEL_SHAPES=mlm,seg   KERNEL_REPS=20
 """
 
@@ -71,22 +69,10 @@ def main():
         q = jnp.zeros((b, nq, c), jnp.bfloat16)
         kv = jax.random.normal(jax.random.key(1), (b, nkv, c),
                                jnp.bfloat16)
-        caller_layout = os.environ.get("PERCEIVER_TPU_FLASH_LAYOUT")
         for impl in impls:
-            # pseudo-impls flash_std / flash_t pin the flash kernel's
-            # block layout (auto picks by head dim) for on-chip A/B;
-            # plain impls keep the caller's own env pin, if any
-            layout = {"flash_std": "standard", "flash_t": "transposed"
-                      }.get(impl, caller_layout)
-            real_impl = "flash" if impl.startswith("flash") else impl
-            if layout:
-                os.environ["PERCEIVER_TPU_FLASH_LAYOUT"] = layout
-            else:
-                os.environ.pop("PERCEIVER_TPU_FLASH_LAYOUT", None)
-
             def fwd(p, q, kv):
                 return cross_attention_apply(
-                    p, q, kv, num_heads=h, impl=real_impl).sum()
+                    p, q, kv, num_heads=h, impl=impl).sum()
 
             grad = jax.jit(jax.grad(fwd))
             fj = jax.jit(fwd)

@@ -58,25 +58,33 @@ def lower_step(task, batch, tmp_path):
     return trainer._train_step.lower(state, batch)
 
 
+# the same steps with every attention forced onto the fused kernels
+# (interpreted here: the kernel bodies are ordinary operations under
+# the kernel's name)
+FUSED = dict(attention_impl="flash", decoder_attention_impl="flash")
+
+
 @pytest.fixture(scope="module", params=[
-    (name, remat) for name in TASKS for remat in (False, True)],
-    ids=lambda p: f"{p[0]}-{'remat' if p[1] else 'plain'}")
+    (name, remat, fused) for name in TASKS for remat in (False, True)
+    for fused in (False, True)],
+    ids=lambda p: (f"{p[0]}-{'remat' if p[1] else 'plain'}"
+                   f"{'-fused' if p[2] else ''}"))
 def named_ops(request, tmp_path_factory):
     """(opcode, name stack) of every instruction of the compiled toy
     step that carries one (the compiler's own rewrites carry none)."""
-    name, remat = request.param
+    name, remat, fused = request.param
     task, batch = TASKS[name]
-    lowered = lower_step(dataclasses.replace(task, remat=remat), batch,
-                         tmp_path_factory.mktemp("scopes"))
+    task = dataclasses.replace(task, remat=remat, **(FUSED if fused else {}))
+    lowered = lower_step(task, batch, tmp_path_factory.mktemp("scopes"))
     text = lowered.compile().as_text()
     ops = re.findall(
         r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"', text)
     assert len(ops) > 1000
-    return remat, ops
+    return remat, fused, ops
 
 
 def test_every_heavy_operation_is_under_one_layer(named_ops):
-    _, ops = named_ops
+    _, _, ops = named_ops
     heavy = [(code, name) for code, name in ops if code in HEAVY]
     assert len(heavy) > 100
     for code, name in heavy:
@@ -90,16 +98,23 @@ def test_every_heavy_operation_is_under_one_layer(named_ops):
 
 
 def test_attention_core_is_scoped_in_every_pass(named_ops):
-    remat, ops = named_ops
+    remat, fused, ops = named_ops
     core = [name for code, name in ops
             if code in HEAVY and "attn_core" in scopes_in(name)]
     forward = [n for n in core if "transpose(" not in n]
     # the custom VJP's backward: the recomputed softmax and the four
-    # gradient contractions carry the scope of the forward's call
+    # gradient contractions (or the backward kernel) carry the scope
+    # of the forward's call
     backward = [n for n in core if "transpose(" in n
                 and "rematted_computation" not in n]
     assert forward and backward
-    assert any("bhqk,bqhd->bkhd" in n for n in backward)    # dv, dk
+    if fused:
+        # the kernels' names sit under the scope, in every pass
+        assert all("flash_attention_fwd" in n for n in forward)
+        # (beside the backward kernel: delta's and the bias's sums)
+        assert any("flash_attention_bwd" in n for n in backward)
+    else:
+        assert any("bhqk,bqhd->bkhd" in n for n in backward)    # dv, dk
     for layer in ("enc_cross_attn", "latent_self_attn", "dec_cross_attn"):
         assert any(layer in scopes_in(n) for n in forward), layer
         assert any(layer in scopes_in(n) for n in backward), layer
@@ -110,7 +125,7 @@ def test_attention_core_is_scoped_in_every_pass(named_ops):
 
 
 def test_optimizer_and_loss_are_scoped(named_ops):
-    _, ops = named_ops
+    _, _, ops = named_ops
     names = [name for _, name in ops]
     update = [n for n in names if "optimizer" in scopes_in(n)]
     assert len(update) > 50

@@ -16,7 +16,10 @@ from perceiver_tpu.ops.chunked_attention import (
     chunked_attention,
     pad_mask_to_bias,
 )
-from perceiver_tpu.ops.pallas_attention import flash_attention
+from perceiver_tpu.ops.pallas_attention import (
+    flash_attention,
+    flash_attention_channels,
+)
 from perceiver_tpu.ops.policy import Policy
 
 
@@ -130,12 +133,12 @@ class TestFlash:
         np.testing.assert_allclose(out, _reference_attention(q, k, v),
                                    atol=1e-5, rtol=1e-5)
 
-    @pytest.mark.parametrize("d", [16, 64])
-    def test_both_layouts_match_reference(self, d):
-        """d=16 exercises the transposed (skinny-head) kernel, d=64 the
-        standard D-in-lanes kernel; both must match, incl. with a pad
-        mask and through the VJP."""
-        q, k, v = _qkv(jax.random.key(9), lq=32, lk=96, d=d)
+    @pytest.mark.parametrize("h,d", [(8, 16), (2, 64)])
+    def test_shared_lane_blocks_match_reference(self, h, d):
+        """Eight heads of 16 and two of 64 share one 128-lane block;
+        each must see its own lanes only, incl. with a pad mask and
+        through the VJP."""
+        q, k, v = _qkv(jax.random.key(9), h=h, lq=32, lk=96, d=d)
         pad = jnp.arange(96)[None, :] >= jnp.array([80, 96])[:, None]
         bias = pad_mask_to_bias(pad)
         out = flash_attention(q, k, v, bias=bias, block_q=16, block_k=32)
@@ -154,23 +157,22 @@ class TestFlash:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
-    @pytest.mark.parametrize("layout,d", [("standard", 16),
-                                          ("transposed", 64),
-                                          ("transposed", 192)])
-    def test_forced_layout_matches_reference(self, monkeypatch, layout, d):
-        """PERCEIVER_TPU_FLASH_LAYOUT pins the block layout regardless
-        of head dim (the on-chip A/B knob) — numerics must hold in the
-        non-default pairing too, incl. transposed at D > 128."""
-        monkeypatch.setenv("PERCEIVER_TPU_FLASH_LAYOUT", layout)
-        q, k, v = _qkv(jax.random.key(13), lq=32, lk=64, d=d)
+    @pytest.mark.parametrize("h,d", [(2, 16), (3, 64), (2, 192)],
+                             ids=["two_of_16", "three_of_64",
+                                  "two_of_192"])
+    def test_heads_off_the_lane_grid_match_reference(self, h, d):
+        """Head counts a 128-lane block does not divide, and a head
+        dim that is no multiple of 128, are zero-padded to whole lanes
+        a head — numerics must hold there too."""
+        q, k, v = _qkv(jax.random.key(13), h=h, lq=32, lk=64, d=d)
         out = flash_attention(q, k, v, block_q=16, block_k=32)
         np.testing.assert_allclose(out, _reference_attention(q, k, v),
                                    atol=1e-5, rtol=1e-5)
 
-    def test_skinny_layout_bf16(self):
-        """bf16 through the transposed kernel (16-sublane tiles)."""
+    def test_skinny_heads_bf16(self):
+        """bf16 through a block of eight 16-wide heads."""
         q, k, v = (x.astype(jnp.bfloat16) for x in
-                   _qkv(jax.random.key(10), lq=32, lk=64, d=16))
+                   _qkv(jax.random.key(10), h=8, lq=32, lk=64, d=16))
         out = flash_attention(q, k, v, block_q=16, block_k=32)
         ref = _reference_attention(q.astype(jnp.float32),
                                    k.astype(jnp.float32),
@@ -394,3 +396,108 @@ class TestQueryChunking:
         g2 = jax.grad(loss_b, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# --- the fused core against the materialised one ------------------------------
+
+# the two shape families of the benchmark's cells, scaled down:
+# latent self-attention (Lq == Lk, head dim 64) and the image
+# cross-attention (Lq << Lk, head dim 128, Lk off the key block);
+# blocks small enough that queries and keys both take several
+FAMILIES = {
+    "self_d64": dict(lq=256, lk=256, d=64, block_q=128, block_k=128),
+    "cross_d128": dict(lq=128, lk=300, d=128, block_q=128, block_k=128),
+}
+# valid key counts per batch row: none masked; ragged padding; the
+# whole last key block of row 0 padded
+KEY_LENGTHS = {"nobias": None, "padded": (0.7, 1.0), "padded_tail": (0.4, 1.0)}
+FUSED_CASES = [(f, m, t) for f in FAMILIES for m in KEY_LENGTHS
+               for t in ("bfloat16", "float32")]
+
+
+def _fused_case(family, masking, dtype):
+    from perceiver_tpu.ops.attention import _sdpa_core
+
+    cfg = FAMILIES[family]
+    lq, lk, d = cfg["lq"], cfg["lk"], cfg["d"]
+    kq, kk, kv, kg = jax.random.split(jax.random.key(17), 4)
+    # (B, L, H, D), as mha_apply holds the heads
+    q, k, v, g = (jax.random.normal(kx, (2, length, 2, d)).astype(dtype)
+                  for kx, length in ((kq, lq), (kk, lk), (kv, lk),
+                                     (kg, lq)))
+    bias = None
+    if KEY_LENGTHS[masking] is not None:
+        lengths = jnp.asarray([int(f * lk) for f in KEY_LENGTHS[masking]])
+        bias = pad_mask_to_bias(jnp.arange(lk)[None, :] >= lengths[:, None])
+    scale = 1.0 / d ** 0.5
+
+    def fused(q, k, v):
+        # heads side by side on the channel axis, as the model hands
+        # them over
+        out = flash_attention_channels(
+            *(x.reshape(*x.shape[:2], -1) for x in (q, k, v)), num_heads=2,
+            bias=bias, scale=scale, block_q=cfg["block_q"],
+            block_k=cfg["block_k"])
+        return out.reshape(q.shape)
+
+    def materialised(q, k, v):
+        b4 = None if bias is None else bias[:, None, None, :]
+        return _sdpa_core(scale, 0.0, jnp.float32, q, k, v, b4, None)
+
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    return fused, materialised, (q, k, v), g, tol
+
+
+@pytest.mark.parametrize("family,masking,dtype", FUSED_CASES)
+def test_fused_output_matches_materialised_core(family, masking, dtype):
+    fused, materialised, qkv, _, tol = _fused_case(family, masking, dtype)
+    out, ref = fused(*qkv), materialised(*qkv)
+    assert out.dtype == ref.dtype and out.shape == ref.shape
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               ref.astype(jnp.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("family,masking,dtype", FUSED_CASES)
+def test_fused_gradients_match_materialised_core(family, masking, dtype):
+    """dq, dk, dv of the kernel backward against ``_sdpa_bwd``, under
+    one random cotangent."""
+    fused, materialised, qkv, g, tol = _fused_case(family, masking, dtype)
+    grads = jax.vjp(fused, *qkv)[1](g)
+    refs = jax.vjp(materialised, *qkv)[1](g)
+    for name, a, b in zip("qkv", grads, refs):
+        assert a.dtype == b.dtype, name
+        scale = float(jnp.abs(b.astype(jnp.float32)).max())
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b.astype(jnp.float32),
+            atol=tol * max(scale, 1.0), rtol=tol, err_msg=f"d{name}")
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("h,d", [(8, 16), (2, 64)],
+                         ids=["eight_heads_a_block", "two_heads_a_block"])
+def test_forward_only_use_emits_no_residual(h, d):
+    """Inference pays for one output: the log-sum-exp row leaves the
+    kernel only under differentiation, where the backward kernel (one
+    call for dq, dk and dv) reads it."""
+    q, k, v = _qkv(jax.random.key(21), h=h, lq=128, lk=128, d=d)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v)
+
+    (fwd,) = _pallas_calls(jax.make_jaxpr(f)(q, k, v).jaxpr)
+    assert len(fwd.outvars) == 1
+    calls = _pallas_calls(jax.make_jaxpr(
+        lambda *a: jax.vjp(f, *a)[1](jnp.ones_like(q)))(q, k, v).jaxpr)
+    assert [len(c.outvars) for c in calls] == [2, 3]
+    assert [c.params["name"] for c in calls] == ["flash_attention_fwd",
+                                                 "flash_attention_bwd"]

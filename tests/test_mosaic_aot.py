@@ -47,46 +47,61 @@ def _struct(sharding):
 
 
 @pytest.mark.parametrize("lq,lk,heads,dim,bias", [
-    (512, 512, 8, 64, False),   # standard (L, D) layout
-    (512, 512, 4, 16, True),    # D=16: transposed layout + bias sublane
-], ids=["std_d64", "transposed_d16_bias"])
+    (512, 512, 8, 64, False),   # two heads a 128-lane block
+    (512, 512, 8, 16, True),    # eight heads a block, bias row
+    (512, 512, 4, 16, True),    # a block does not divide 4: lane-padded
+], ids=["d64", "d16_bias", "d16_padded_bias"])
 def test_flash_forward_compiles(one_chip, lq, lk, heads, dim, bias):
-    from perceiver_tpu.ops.pallas_attention import flash_attention
+    from perceiver_tpu.ops.pallas_attention import (
+        flash_attention_channels as flash_attention,
+    )
 
     s = _struct(one_chip)
-    q = s((2, heads, lq, dim), jnp.bfloat16)
-    k = s((2, heads, lk, dim), jnp.bfloat16)
+    q = s((2, lq, heads * dim), jnp.bfloat16)
+    k = s((2, lk, heads * dim), jnp.bfloat16)
     if bias:
         _compiles_to_mosaic(
-            lambda q, k, v, b: flash_attention(q, k, v, bias=b,
-                                               interpret=False),
+            lambda q, k, v, b: flash_attention(q, k, v, num_heads=heads,
+                                               bias=b, interpret=False),
             q, k, k, s((2, lk), jnp.float32))
     else:
         _compiles_to_mosaic(
-            lambda q, k, v: flash_attention(q, k, v, interpret=False),
+            lambda q, k, v: flash_attention(q, k, v, num_heads=heads,
+                                            interpret=False),
             q, k, k)
 
 
-@pytest.mark.parametrize("lq,lk", [(1024, 2048), (1024, 1024),
-                                   (2048, 1024)],
-                         ids=["encoder_cross", "latent_self",
-                              "decoder_cross"])
-def test_flash_forward_backward_compiles_at_lm_shapes(one_chip, lq, lk):
-    """The three attention shapes of the Perceiver-LM config (1024
-    latents, seq 2048, 8 heads of 64)."""
-    from perceiver_tpu.ops.pallas_attention import flash_attention
+@pytest.mark.parametrize("lq,lk,heads,dim,bias", [
+    (1024, 2048, 8, 64, True), (1024, 1024, 8, 64, False),
+    (2048, 1024, 8, 64, False), (512, 50176, 4, 128, False),
+    (512, 512, 4, 128, False), (512, 2048, 8, 16, True)],
+    ids=["lm_encoder_cross", "lm_latent_self", "lm_decoder_cross",
+         "img_encoder_cross", "img_latent_self", "eight_heads_of_16"])
+def test_flash_forward_backward_compiles_at_cell_shapes(one_chip, lq, lk,
+                                                        heads, dim, bias):
+    """The attention shapes of the benchmark's two configurations
+    (Perceiver-LM: 1024 latents, seq 2048, 8 heads of 64; the image
+    classifier: 512 latents over 50,176 pixels, 4 heads of 128), with
+    the blocks the code picks for them, forward and backward kernels,
+    on (B, L, H·D) operands as the model hands them over."""
+    from perceiver_tpu.ops.pallas_attention import (
+        flash_attention_channels,
+    )
 
     s = _struct(one_chip)
-    q = s((2, 8, lq, 64), jnp.bfloat16)
-    k = s((2, 8, lk, 64), jnp.bfloat16)
+    q = s((2, lq, heads * dim), jnp.bfloat16)
+    k = s((2, lk, heads * dim), jnp.bfloat16)
+    args = (q, k, k) + ((s((2, lk), jnp.float32),) if bias else ())
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v, interpret=False).astype(
-            jnp.float32).sum()
+    def loss(q, k, v, *b):
+        return flash_attention_channels(
+            q, k, v, num_heads=heads, bias=b[0] if b else None,
+            interpret=False).astype(jnp.float32).sum()
 
     # the value keeps the forward kernel live beside the backward pass
-    _compiles_to_mosaic(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                        q, k, k)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
 
 
 # --- fused projection + cross-entropy ----------------------------------------
