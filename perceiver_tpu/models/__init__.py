@@ -1,4 +1,5 @@
-"""Model library: Perceiver encoder/decoder/IO/MLM and text masking."""
+"""Model library: Perceiver encoder/decoder/IO/MLM, text masking, and
+the looped causal language model."""
 
 from perceiver_tpu.models.perceiver import (  # noqa: F401
     PerceiverEncoder,
@@ -8,3 +9,4 @@ from perceiver_tpu.models.perceiver import (  # noqa: F401
 )
 from perceiver_tpu.models.masking import TextMasking  # noqa: F401
 from perceiver_tpu.models.uresnet import UResNet  # noqa: F401
+from perceiver_tpu.models.looped_lm import LoopedLM  # noqa: F401

@@ -143,6 +143,8 @@ DEVICE_SCOPES = (
     "input_adapter", "enc_cross_attn", "latent_self_attn",
     "dec_cross_attn", "output_adapter",
     "attn_core", "attn_proj", "mlp", "loss", "optimizer",
+    # a weight-shared decoder stack run several times (models/looped_lm)
+    "loop_stack", "decoder_layer", "exit_gate", "exit_loss",
 )
 
 _SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES)

@@ -27,6 +27,14 @@ what it can observe; the materialised one (``_sdpa_core``: einsums XLA
 tiles onto the MXU with scale/mask/softmax fused between them) covers
 what the kernels do not: ``attn_mask``, attention-weight dropout,
 small shapes, a mesh, every backend but a TPU.
+
+``causal=True`` is a property of the call, not a mask handed in: the
+fused kernels have a causal mode (blocks above the diagonal skipped),
+so a causal call is picked like any other; the materialised core
+builds the triangle itself. Projections without biases (a tree with no
+``b``) and rotary positions (``rope=(cos, sin)``, applied to the
+projected queries and keys) are what a decoder stack adds to the same
+``mha_apply``.
 """
 
 from __future__ import annotations
@@ -42,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
+from perceiver_tpu.ops.fourier import rope_apply
 from perceiver_tpu.ops.initializers import uniform, xavier_uniform
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
@@ -52,7 +61,7 @@ NEG_INF = -1e30  # large-negative bias; safe in fp32 softmax accumulation
 
 def mha_init(key, q_dim: int, num_heads: int,
              k_dim: Optional[int] = None, v_dim: Optional[int] = None,
-             dtype=jnp.float32):
+             dtype=jnp.float32, bias: bool = True):
     """Init q/k/v/out projections (torch MultiheadAttention scheme).
 
     torch distinguishes the packed case: with ``kdim == vdim ==
@@ -60,7 +69,8 @@ def mha_init(key, q_dim: int, num_heads: int,
     xavier-inits THAT (bound √(6/4E)); per-matrix xavier on each E×E
     slice would be √2 larger (VERDICT r3 weak #5). With asymmetric
     dims torch xavier-inits the three matrices separately — matching
-    the per-matrix scheme below.
+    the per-matrix scheme below. ``bias=False`` leaves the four
+    biases out of the tree (``linear_apply`` then adds none).
     """
     if q_dim % num_heads != 0:
         raise ValueError(f"q_dim {q_dim} not divisible by num_heads {num_heads}")
@@ -76,7 +86,7 @@ def mha_init(key, q_dim: int, num_heads: int,
     else:
         def proj(k, shape):
             return xavier_uniform(k, shape, dtype)
-    return {
+    params = {
         # torch: xavier-uniform projection weights, zero in-proj bias
         "q": {"w": proj(kq, (q_dim, q_dim)),
               "b": jnp.zeros((q_dim,), dtype)},
@@ -86,6 +96,9 @@ def mha_init(key, q_dim: int, num_heads: int,
               "b": jnp.zeros((q_dim,), dtype)},
         "out": {"w": out["w"], "b": jnp.zeros((q_dim,), dtype)},
     }
+    if not bias:
+        params = {name: {"w": p["w"]} for name, p in params.items()}
+    return params
 
 
 def _split_heads(x, num_heads: int):
@@ -236,10 +249,15 @@ MATERIALIZED_REASONS = ("impl", "attn_mask", "dropout", "backend", "mesh",
 
 def pick_attention_core(*, backend: str, lq: int, lk: int,
                         dropout_active: bool, has_attn_mask: bool,
-                        mesh_devices: int) -> Tuple[str, Optional[str]]:
+                        mesh_devices: int, causal: bool = False,
+                        has_key_padding_mask: bool = False
+                        ) -> Tuple[str, Optional[str]]:
     """``("fused", None)`` or ``("materialized", reason)`` for an
-    ``impl=None`` call, from what the call site can observe."""
-    if has_attn_mask:
+    ``impl=None`` call, from what the call site can observe. ``causal``
+    is the call's own property and sends it nowhere: the kernels have
+    the mode. Only beside a key padding mask (the causal kernels take no
+    bias) do the two make a mask the materialised core builds."""
+    if has_attn_mask or (causal and has_key_padding_mask):
         reason = "attn_mask"
     elif dropout_active:
         reason = "dropout"
@@ -318,12 +336,17 @@ def mha_apply(params, q, k, v, *, num_heads: int,
               key_padding_mask=None, attn_mask=None,
               dropout_rate: float = 0.0, rng=None, deterministic: bool = True,
               policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None,
-              kv_chunk_size: int = 1024, spmd=None, kv_heads=None):
+              kv_chunk_size: int = 1024, spmd=None, kv_heads=None,
+              causal: bool = False, rope=None):
     """Scaled dot-product multi-head attention.
 
     q: (B, Lq, q_dim); k: (B, Lk, k_dim); v: (B, Lk, v_dim).
     key_padding_mask: (B, Lk) bool, True at padding.
     attn_mask: (Lq, Lk) or (B, Lq, Lk); bool (True = masked) or additive.
+    causal: query i sees keys 0..i (Lq == Lk; no ``attn_mask`` beside
+    it); the fused kernels' causal mode or the materialized core's own
+    triangle. rope: ``(cos, sin)`` tables (L, D) of
+    ``ops.fourier.rope_tables``, applied to the projected q and k.
     impl: None (pick: the fused kernels where ``pick_attention_core``
     allows, else the materialized core), "einsum" (materialized
     weights, supports dropout and attn_mask), "chunked" (blockwise
@@ -358,18 +381,29 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     if impl in _SPMD_IMPLS and spmd is None:
         raise ValueError(
             f"impl={impl!r} needs spmd=(mesh, seq_axis, batch_axis)")
+    if causal and (attn_mask is not None
+                   or impl in ("chunked", *_SPMD_IMPLS)):
+        raise NotImplementedError(
+            "causal attention runs on the fused or the materialized "
+            "core and is its own mask: no attn_mask beside it, not "
+            f"impl={impl!r}")
 
     qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
     if qh.shape[-1] % num_heads:
         raise ValueError(f"q_dim {qh.shape[-1]} not divisible by "
                          f"num_heads {num_heads}")
+    if rope is not None:
+        with device_scope("attn_proj"):
+            qh = rope_apply(qh, *rope, num_heads)
+            kh = rope_apply(kh, *rope, num_heads)
     path, reason = impl, None
     if impl is None:
         path, reason = pick_attention_core(
             backend=_backend(), lq=qh.shape[1], lk=kh.shape[1],
             dropout_active=dropout_rate > 0.0 and not deterministic,
             has_attn_mask=attn_mask is not None,
-            mesh_devices=_mesh_devices(qh))
+            mesh_devices=_mesh_devices(qh), causal=causal,
+            has_key_padding_mask=key_padding_mask is not None)
         if path == "fused":
             impl = "flash"
     elif impl == "einsum":
@@ -379,7 +413,7 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     for tally in _PATH_TALLIES:
         tally[path, reason] += 1
     if impl == "flash":
-        out = _fused_core(qh, kh, vh, num_heads, key_padding_mask)
+        out = _fused_core(qh, kh, vh, num_heads, key_padding_mask, causal)
     else:
         qh, kh, vh = (_split_heads(x, num_heads) for x in (qh, kh, vh))
         if impl in ("chunked", *_SPMD_IMPLS):
@@ -387,6 +421,9 @@ def mha_apply(params, q, k, v, *, num_heads: int,
                                  dropout_rate, rng, deterministic,
                                  kv_chunk_size, spmd)
         else:
+            if causal:   # True above the diagonal = masked
+                attn_mask = ~jnp.tril(jnp.ones(
+                    (qh.shape[1], kh.shape[1]), jnp.bool_))
             out = _materialized_core(qh, kh, vh, key_padding_mask,
                                      attn_mask, dropout_rate, rng,
                                      deterministic, policy)
@@ -411,8 +448,10 @@ def _project(params, q, k, v, policy, kv_heads):
         packed = {
             "w": jnp.concatenate([params[n]["w"] for n in ("q", "k", "v")],
                                  axis=1),
-            "b": jnp.concatenate([params[n]["b"] for n in ("q", "k", "v")]),
         }
+        if "b" in params["q"]:
+            packed["b"] = jnp.concatenate(
+                [params[n]["b"] for n in ("q", "k", "v")])
         qkv = linear_apply(packed, q, policy=policy)
         e = qkv.shape[-1] // 3
         return tuple(qkv[..., i * e:(i + 1) * e] for i in range(3))
@@ -422,7 +461,7 @@ def _project(params, q, k, v, policy, kv_heads):
 
 
 @device_scope("attn_core")
-def _fused_core(q, k, v, num_heads, key_padding_mask):
+def _fused_core(q, k, v, num_heads, key_padding_mask, causal=False):
     """The fused kernels, on the projections as they are: (B, L, H·D)
     in and out, blocks from the shapes."""
     import perceiver_tpu.ops.chunked_attention as _ca
@@ -430,7 +469,7 @@ def _fused_core(q, k, v, num_heads, key_padding_mask):
     bias = (_ca.pad_mask_to_bias(key_padding_mask)
             if key_padding_mask is not None else None)
     return _pa.flash_attention_channels(q, k, v, num_heads=num_heads,
-                                        bias=bias)
+                                        bias=bias, causal=causal)
 
 
 @device_scope("attn_core")

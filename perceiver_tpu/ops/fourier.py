@@ -1,4 +1,4 @@
-"""Fourier position encodings, precomputed host-side.
+"""Fourier position encodings and rotary tables, precomputed host-side.
 
 Behavioral parity with the reference image adapter
 (``perceiver/adapter.py:53-97``):
@@ -74,3 +74,40 @@ def _fourier_cached(spatial_shape, num_bands, max_frequencies,
 
     enc = np.concatenate(parts, axis=-1).astype(dtype)
     return enc.reshape(-1, enc.shape[-1])
+
+
+# --- rotary positions --------------------------------------------------------
+# The same idea turned on its side: position i rotates the pair of
+# channels (j, j + D/2) of every head by the angle i * theta^(-2j/D).
+# The tables are computed in fp64 NumPy at model-build time like the
+# encodings above; applying them is one fused elementwise pass.
+
+
+@functools.lru_cache(maxsize=8)
+def rope_tables(seq_len: int, head_dim: int, theta: float
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """``(cos, sin)``, each (seq_len, head_dim) float32, over the whole
+    head dim: column j and column j + D/2 carry the same angle."""
+    if head_dim % 2:
+        raise ValueError(f"rotary positions pair channels: head_dim "
+                         f"{head_dim} is odd")
+    inv_freq = float(theta) ** (
+        -np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    angles = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq
+    angles = np.concatenate([angles, angles], axis=-1)
+    return (np.cos(angles).astype(np.float32),
+            np.sin(angles).astype(np.float32))
+
+
+def rope_apply(x, cos, sin, num_heads: int):
+    """Rotate (B, L, H·D) heads-side-by-side channels by the tables
+    (L, D): ``x * cos + rotate_half(x) * sin`` with ``rotate_half`` of
+    a head ``[-x2, x1]``. fp32 inside, ``x``'s dtype out."""
+    import jax.numpy as jnp
+
+    b, l, e = x.shape
+    xh = x.reshape(b, l, num_heads, e // num_heads).astype(jnp.float32)
+    x1, x2 = jnp.split(xh, 2, axis=-1)
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    out = xh * cos[None, :l, None, :] + rotated * sin[None, :l, None, :]
+    return out.reshape(b, l, e).astype(x.dtype)

@@ -22,6 +22,10 @@ levers, both exact w.r.t. the dense computation:
    the only approximation is the static capacity, chosen so overflow
    has negligible probability (a Chernoff bound at capacity 1.5× the
    expected count is astronomically small for B·M ≥ 2¹⁵).
+
+``fused_linear_nll`` is lever 1 with the per-position NLL handed back
+unreduced, for losses whose weights take a gradient (the exit
+probabilities of ``models/looped_lm.py``).
 """
 
 from __future__ import annotations
@@ -161,3 +165,107 @@ def fused_linear_cross_entropy(linear_params, hidden, labels, weight, *,
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
                             (hidden, labels, weight))
     return total / jnp.maximum(weight.sum(), 1.0)
+
+
+# --- per-position NLL --------------------------------------------------------
+# The sibling for losses that are not a weighted mean with constant
+# weights: a looped LM reads the head once a pass and mixes the passes'
+# NLLs with exit probabilities that take a gradient themselves, and
+# divides by the number of labelled positions. Returning the NLL per
+# position leaves the mixing (and its gradient to the weights) to plain
+# autodiff outside; the logits stay one chunk at a time as above. The
+# backward pass is written out, not the transpose of the forward scan:
+# that keeps the head's fp32 gradient twice (the running sum and each
+# chunk's term) and carries the fp32 head through both loops, which
+# costs a copy of it a loop once the step donates its parameters. Here
+# the head is cast once, the loops carry the cast, and the gradient is
+# one fp32 accumulator added into in place.
+
+
+def _nll_logits(policy, w, b, h):
+    logits = jnp.dot(policy.cast_compute(h), w,
+                     preferred_element_type=jnp.float32)
+    return logits if b is None else logits + b.astype(jnp.float32)
+
+
+def _lse_and_picked(logits, y):
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1, keepdims=True))
+    return lse, jnp.take_along_axis(logits, jnp.clip(y, 0)[:, None], axis=1)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _nll_chunks(policy, params, hidden, labels):
+    """``nll(linear(h), y)`` per row, fp32: hidden (K, chunk, C),
+    labels (K, chunk) -> (K, chunk)."""
+    return _nll_chunks_fwd(policy, params, hidden, labels)[0]
+
+
+def _nll_chunks_fwd(policy, params, hidden, labels):
+    w, b = policy.cast_param(params["w"]), params.get("b")
+
+    def body(_, xs):
+        lse, picked = _lse_and_picked(_nll_logits(policy, w, b, xs[0]),
+                                      xs[1])
+        return None, (lse - picked)[:, 0]
+
+    _, nll = jax.lax.scan(body, None, (hidden, labels))
+    return nll, (params, w, hidden, labels)
+
+
+def _nll_chunks_bwd(policy, res, g):
+    """The logits of a chunk are recomputed once; (softmax - onehot) x
+    the rows' cotangents goes in the compute dtype into the two
+    contractions, as in ``_chunk_nll_bwd``."""
+    params, w, hidden, labels = res
+    b = params.get("b")
+
+    def body(acc, xs):
+        h, y, g_rows = xs
+        logits = _nll_logits(policy, w, b, h)
+        lse, _ = _lse_and_picked(logits, y)
+        onehot = (jnp.arange(logits.shape[-1])[None, :]
+                  == jnp.clip(y, 0)[:, None])
+        dlogits = (jnp.exp(logits - lse) - onehot) \
+            * g_rows.astype(jnp.float32)[:, None]
+        dl = dlogits.astype(policy.compute_dtype)
+        acc = dict(acc, w=acc["w"] + jnp.dot(
+            policy.cast_compute(h).T, dl,
+            preferred_element_type=jnp.float32))
+        if b is not None:
+            acc["b"] = acc["b"] + jnp.sum(dlogits, axis=0)
+        return acc, jnp.dot(dl, w.T).astype(h.dtype)
+
+    acc, dh = jax.lax.scan(
+        body, {k: jnp.zeros(v.shape, jnp.float32)
+               for k, v in params.items()}, (hidden, labels, g))
+    return ({k: v.astype(params[k].dtype) for k, v in acc.items()}, dh,
+            None)
+
+
+_nll_chunks.defvjp(_nll_chunks_fwd, _nll_chunks_bwd)
+
+
+@device_scope("loss")
+def fused_linear_nll(linear_params, hidden, labels, *,
+                     chunk_size: int = 8192,
+                     policy: Policy = DEFAULT_POLICY):
+    """Per-position NLL of ``linear(hidden)`` vs ``labels``, chunked.
+
+    hidden: (N, C); labels: (N,) int (clipped at 0: give unlabelled
+    rows any id and weight them out). ``linear_params`` may lack ``b``.
+    Returns (N,) fp32. Differentiable in ``hidden`` and the head; what
+    the caller multiplies the rows by takes its gradient from autodiff.
+    Peak memory is one ``(chunk, V)`` logits block in either pass.
+    """
+    n, c = hidden.shape
+    chunk_size = min(chunk_size, n)
+    pad = -n % chunk_size
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad))
+    k = (n + pad) // chunk_size
+    nll = _nll_chunks(policy, linear_params,
+                      hidden.reshape(k, chunk_size, c),
+                      labels.reshape(k, chunk_size))
+    return nll.reshape(-1)[:n]

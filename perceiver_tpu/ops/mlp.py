@@ -67,3 +67,25 @@ def mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
     h = linear_apply(params["fc1"], h, policy=policy)
     h = gelu_exact(h)
     return linear_apply(params["fc2"], h, policy=policy)
+
+
+# --- gated (SwiGLU) MLP ------------------------------------------------------
+# (silu(x Wg) * (x Wu)) Wd, no biases, no norm of its own: the decoder
+# layer that uses it norms before and after (models/looped_lm.py).
+
+
+def gated_mlp_init(key, dim: int, hidden: int, dtype=jnp.float32):
+    kg, ku, kd = jax.random.split(key, 3)
+    return {
+        "gate": linear_init(kg, dim, hidden, dtype, bias=False),
+        "up": linear_init(ku, dim, hidden, dtype, bias=False),
+        "down": linear_init(kd, hidden, dim, dtype, bias=False),
+    }
+
+
+@device_scope("mlp")
+def gated_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
+    gate = linear_apply(params["gate"], x, policy=policy)
+    up = linear_apply(params["up"], x, policy=policy)
+    return linear_apply(params["down"], jax.nn.silu(gate) * up,
+                        policy=policy)

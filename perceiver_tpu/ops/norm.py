@@ -1,4 +1,4 @@
-"""LayerNorm as pure init/apply functions.
+"""LayerNorm and RMSNorm as pure init/apply functions.
 
 Statistics are computed in fp32 regardless of the compute dtype —
 bf16 mean/variance accumulation loses precision the MXU gains nothing
@@ -73,3 +73,24 @@ def layer_norm_apply(params, x, eps: float = 1e-5,
                      policy: Policy = DEFAULT_POLICY):
     return _ln_core(eps, policy.compute_dtype, params["scale"],
                     params["bias"], x)
+
+
+# --- RMSNorm -----------------------------------------------------------------
+# x / sqrt(mean(x^2) + eps) * scale: no mean subtracted, no bias.
+# Statistics in fp32 as above. No custom VJP: its callers recompute a
+# whole layer on the backward pass (``remat``), so autodiff's fp32
+# residuals live for one layer at a time; a trace has not asked for
+# more.
+
+
+def rms_norm_init(dim: int, dtype=jnp.float32):
+    return {"scale": jnp.ones((dim,), dtype)}
+
+
+def rms_norm_apply(params, x, eps: float = 1e-6,
+                   policy: Policy = DEFAULT_POLICY):
+    xf = x.astype(jnp.float32)
+    rstd = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                         + eps)
+    y = xf * rstd * params["scale"].astype(jnp.float32)
+    return y.astype(policy.compute_dtype)

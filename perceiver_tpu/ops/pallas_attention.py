@@ -59,6 +59,17 @@ carries no loss. A full ``attn_mask`` and attention-weight dropout are
 not supported; ``ops/attention.py`` keeps those on the materialised
 core.
 
+Causal mode (``causal=True``, square scores, no key bias): query ``i``
+sees keys ``0..i``. Blocks that lie wholly above the diagonal are
+neither loaded (the index maps hold the last block that is needed, so
+the pipeline fetches nothing new) nor computed (``pl.when``); blocks
+the diagonal crosses are masked in the kernel from two iotas; blocks
+below it run the unmasked body. Padded keys lie above every real row,
+so the mask covers them too. The calls are named
+``causal_attention_fwd`` / ``causal_attention_bwd``: a reader that
+counts a ``flash_attention_*`` call in full never sees them. A
+non-causal call traces exactly what it traced before the mode existed.
+
 On non-TPU backends the kernels run in Pallas interpreter mode, so
 tests exercise the identical code path on CPU.
 """
@@ -93,12 +104,18 @@ _ONE_BLOCK_K = 2048
 _STREAM_BLOCK_K = 1024
 _MAX_BLOCK_Q = 1024
 _SCORE_TILE = 1024 * 1024
+_CAUSAL_BLOCK_K = 1024
 
 
-def pick_blocks(lq: int, lk: int):
-    """``(block_q, block_k)`` for ``Lq`` queries over ``Lk`` keys."""
+def pick_blocks(lq: int, lk: int, causal: bool = False):
+    """``(block_q, block_k)`` for ``Lq`` queries over ``Lk`` keys. A
+    causal call streams its keys from ``_CAUSAL_BLOCK_K`` up: only
+    blocks can be skipped, and one block of 2048 keys skips none."""
     lk_p = _round_up(lk, _LANES)
-    block_k = lk_p if lk_p <= _ONE_BLOCK_K else _STREAM_BLOCK_K
+    if causal:
+        block_k = min(lk_p, _CAUSAL_BLOCK_K)
+    else:
+        block_k = lk_p if lk_p <= _ONE_BLOCK_K else _STREAM_BLOCK_K
     block_q = min(_round_up(lq, _LANES), _MAX_BLOCK_Q,
                   _SCORE_TILE // block_k)
     return block_q, block_k
@@ -141,16 +158,36 @@ def _merge(mask, new, old):
     return new if mask is None or old is None else jnp.where(mask, new, old)
 
 
+def _causal_mask(s, first_row, first_col, transposed: bool):
+    """``s`` with NEG_INF where the key lies after the query. ``s`` is
+    (queries, keys), or (keys, queries) where ``transposed``; the
+    tile's first query and key index are ``first_row``/``first_col``."""
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    rows = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    cols = first_col + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+    return jnp.where(cols <= rows, s, NEG_INF)
+
+
+def _when_needed(needed, crossed, body):
+    """Run ``body(masked)`` for a causal tile: not at all above the
+    diagonal, masked where the diagonal crosses it, plain below."""
+    pl.when(jnp.logical_and(needed, crossed))(lambda: body(True))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(
+        lambda: body(False))
+
+
 # --- forward -----------------------------------------------------------------
 
 
 def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
-                save_lse: bool):
+                save_lse: bool, causal: bool = False, block_q: int = 0,
+                block_k: int = 0):
     refs = iter(refs)
     q_ref, k_ref, v_ref = next(refs), next(refs), next(refs)
     bias_ref = next(refs) if has_bias else None
     o_ref = next(refs)
     lse_ref = next(refs) if save_lse else None
+    ik = 0
     if nk > 1:
         m_ref, l_ref, acc_ref = refs
         ik = pl.program_id(3)
@@ -165,38 +202,56 @@ def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
     k = k_ref[0]              # (block_k, W)
     v = v_ref[0]
     masks = _head_masks(q.shape[-1], group)
+    if causal:
+        first_q = pl.program_id(2) * block_q
+        first_k = ik * block_k
 
-    out = None
-    for g, mask in enumerate(masks):
-        s = jax.lax.dot_general(_only(mask, q), k, _NT,
-                                preferred_element_type=jnp.float32)
-        if has_bias:
-            s = s + bias_ref[0]   # (1, block_k) key bias row
+    def tile(masked: bool):
+        out = None
+        for g, mask in enumerate(masks):
+            s = jax.lax.dot_general(_only(mask, q), k, _NT,
+                                    preferred_element_type=jnp.float32)
+            if has_bias:
+                s = s + bias_ref[0]   # (1, block_k) key bias row
+            if masked:
+                s = _causal_mask(s, first_q, first_k, False)
+            if nk == 1:
+                # every key in this block: a plain softmax, no running
+                # state
+                m = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(p.astype(v.dtype), v, _NN,
+                                         preferred_element_type=jnp.float32)
+                out = _merge(mask, pv * (1.0 / l), out)
+                if save_lse:
+                    lse_ref[0, g] = _col_to_row(m + jnp.log(l))
+                continue
+            m_prev = m_ref[g, :, :1]                         # (block_q, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_ref[g, :, :1] \
+                + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            acc = acc_ref[:]
+            acc_ref[:] = _merge(mask, acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, _NN,
+                preferred_element_type=jnp.float32), acc)
         if nk == 1:
-            # every key in this block: a plain softmax, no running state
-            m = jnp.max(s, axis=-1, keepdims=True)
-            p = jnp.exp(s - m)
-            l = jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(p.astype(v.dtype), v, _NN,
-                                     preferred_element_type=jnp.float32)
-            out = _merge(mask, pv * (1.0 / l), out)
-            if save_lse:
-                lse_ref[0, g] = _col_to_row(m + jnp.log(l))
-            continue
-        m_prev = m_ref[g, :, :1]                         # (block_q, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_ref[g, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-        acc = acc_ref[:]
-        acc_ref[:] = _merge(mask, acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, _NN,
-            preferred_element_type=jnp.float32), acc)
+            o_ref[0] = out.astype(o_ref.dtype)
 
+    if not causal:
+        tile(False)
+    elif nk == 1:
+        tile(True)
+    else:
+        # the tile holds keys a query of it may see; the diagonal
+        # crosses it where its last key lies after its first query
+        _when_needed(first_k <= first_q + block_q - 1,
+                     first_k + block_k - 1 > first_q, tile)
     if nk == 1:
-        o_ref[0] = out.astype(o_ref.dtype)
         return
 
     @pl.when(ik == nk - 1)
@@ -273,7 +328,8 @@ def _geometry(lq: int, lk: int, e: int, h: int, block_q: int,
 
 
 def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
-                   block_k: int, interpret: bool, save_lse: bool):
+                   block_k: int, interpret: bool, save_lse: bool,
+                   causal: bool = False):
     """q (B, Lq, H·D), k/v (B, Lk, H·D) → ``o`` (B, Lq, H·D); with
     ``save_lse`` (the differentiated call) ``o`` in float32 as the
     kernel accumulated it and the per-row log-sum-exp as
@@ -289,17 +345,23 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
     q = _pad_rows(_pad_heads(q, h, dp), lq_p)
     k = _pad_rows(_pad_heads(k, h, dp), lk_p)
     v = _pad_rows(_pad_heads(v, h, dp), lk_p)
-    bias = _key_bias(bias, b, lk, lk_p)
+    # the causal mask covers padded keys: they lie after every real row
+    bias = None if causal else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
+
+    def k_index(ib, ih, iq, ik):
+        if causal:
+            # above the diagonal hold the last block a query of this
+            # block sees: the pipeline fetches nothing it has
+            ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+        return ib, ik, ih
 
     in_specs = [
         pl.BlockSpec((1, block_q, width),
                      lambda ib, ih, iq, ik: (ib, iq, ih)),
-        pl.BlockSpec((1, block_k, width),
-                     lambda ib, ih, iq, ik: (ib, ik, ih)),
-        pl.BlockSpec((1, block_k, width),
-                     lambda ib, ih, iq, ik: (ib, ik, ih)),
+        pl.BlockSpec((1, block_k, width), k_index),
+        pl.BlockSpec((1, block_k, width), k_index),
     ]
     args = [q, k, v]
     if has_bias:
@@ -323,7 +385,8 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
     ]
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, nk=nk, group=group,
-                          has_bias=has_bias, save_lse=save_lse),
+                          has_bias=has_bias, save_lse=save_lse,
+                          causal=causal, block_q=block_q, block_k=block_k),
         grid=(b, h // group, nq, nk),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -331,7 +394,7 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name="flash_attention_fwd",
+        name="causal_attention_fwd" if causal else "flash_attention_fwd",
     )(*args)
     o = _unpad_heads(out[0][:, :lq], h, d)
     return (o, out[1][..., :lq]) if save_lse else o
@@ -341,7 +404,8 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
 
 
 def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
-                group: int, has_bias: bool):
+                group: int, has_bias: bool, causal: bool = False,
+                block_k: int = 0):
     refs = iter(refs)
     q_ref, k_ref, v_ref, do_ref = (next(refs) for _ in range(4))
     kbar_ref, vbar_ref = next(refs), next(refs)
@@ -349,8 +413,13 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
     bias_ref = next(refs) if has_bias else None
     dq_ref, dk_ref, dv_ref = next(refs), next(refs), next(refs)
     db_ref = next(refs) if has_bias else None
+    if nq > 1:
+        dk_acc, dv_acc = next(refs), next(refs)
+        db_acc = next(refs) if has_bias else None
     iq = pl.program_id(3)
     ik = pl.program_id(2)
+    # the first query block that sees this key block
+    first_iq = (ik * block_k) // block_q if causal else 0
 
     q = q_ref[0] * scale      # (block_q, W), operand dtype
     k = k_ref[0]              # (block_k, W)
@@ -361,74 +430,85 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
     vc = (v_ref[0] - vbar_ref[0]).astype(k.dtype)
     masks = _head_masks(q.shape[-1], group)
 
-    dq = dk = dv = db = None
-    for g, mask in enumerate(masks):
-        # this head's lanes of the small tiles, zeros elsewhere: its
-        # gradients then land in its own lanes and the heads' add up
-        qg, dog, kg = _only(mask, q), _only(mask, do), _only(mask, kc)
-        # scores transposed, keys on sublanes: (block_k, block_q)
-        s = jax.lax.dot_general(k, qg, _NT,
-                                preferred_element_type=jnp.float32)
-        if has_bias:
-            s = s + bias_ref[0]   # (block_k, 1) key bias column
-        p = jnp.exp(s - lse_ref[0, g])                   # rows (1, block_q)
-        dp = jax.lax.dot_general(vc, dog, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, g])
-        dsb = ds.astype(q.dtype)
-        dv_g = jax.lax.dot_general(p.astype(do.dtype), dog, _NN,
-                                   preferred_element_type=jnp.float32)
-        dk_g = jax.lax.dot_general(dsb, qg, _NN,
-                                   preferred_element_type=jnp.float32)
-        # the one contraction over keys: Mosaic transposes the score tile
-        dq_g = jax.lax.dot_general(dsb, kg, _TN,
-                                   preferred_element_type=jnp.float32)
-        dq = dq_g if dq is None else dq + dq_g
-        dk = dk_g if dk is None else dk + dk_g
-        dv = dv_g if dv is None else dv + dv_g
-        if has_bias:
-            # the key bias's gradient: every head's, a column here
-            db_g = jnp.sum(ds, axis=1, keepdims=True)
-            db = db_g if db is None else db + db_g
+    def tile(masked: bool):
+        dq = dk = dv = db = None
+        for g, mask in enumerate(masks):
+            # this head's lanes of the small tiles, zeros elsewhere: its
+            # gradients then land in its own lanes and the heads' add up
+            qg, dog, kg = _only(mask, q), _only(mask, do), _only(mask, kc)
+            # scores transposed, keys on sublanes: (block_k, block_q)
+            s = jax.lax.dot_general(k, qg, _NT,
+                                    preferred_element_type=jnp.float32)
+            if has_bias:
+                s = s + bias_ref[0]   # (block_k, 1) key bias column
+            if masked:
+                s = _causal_mask(s, iq * block_q, ik * block_k, True)
+            p = jnp.exp(s - lse_ref[0, g])               # rows (1, block_q)
+            dp = jax.lax.dot_general(vc, dog, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[0, g])
+            dsb = ds.astype(q.dtype)
+            dv_g = jax.lax.dot_general(p.astype(do.dtype), dog, _NN,
+                                       preferred_element_type=jnp.float32)
+            dk_g = jax.lax.dot_general(dsb, qg, _NN,
+                                       preferred_element_type=jnp.float32)
+            # the one contraction over keys: Mosaic transposes the score
+            # tile
+            dq_g = jax.lax.dot_general(dsb, kg, _TN,
+                                       preferred_element_type=jnp.float32)
+            dq = dq_g if dq is None else dq + dq_g
+            dk = dk_g if dk is None else dk + dk_g
+            dv = dv_g if dv is None else dv + dv_g
+            if has_bias:
+                # the key bias's gradient: every head's, a column here
+                db_g = jnp.sum(ds, axis=1, keepdims=True)
+                db = db_g if db is None else db + db_g
 
-    if nk == 1:
-        dq_ref[0] = dq.astype(dq_ref.dtype)
+        if nk == 1:
+            dq_ref[0] = dq.astype(dq_ref.dtype)
+        else:
+            # float32 block of the whole (b, head group), resident
+            # across the sweep; every query block sees key block 0
+            rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+
+            @pl.when(ik == 0)
+            def _():
+                dq_ref[0, rows, :] = dq
+
+            @pl.when(ik > 0)
+            def _():
+                dq_ref[0, rows, :] += dq
+
+        if nq == 1:
+            dk_ref[0] = dk.astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+            if has_bias:
+                db_ref[0, 0] = _col_to_row(db)   # a lane-dense row in HBM
+            return
+
+        @pl.when(iq == first_iq)
+        def _():
+            dk_acc[:] = dk
+            dv_acc[:] = dv
+            if has_bias:
+                db_acc[:] = db
+
+        @pl.when(iq > first_iq)
+        def _():
+            dk_acc[:] += dk
+            dv_acc[:] += dv
+            if has_bias:
+                db_acc[:] += db
+
+    if not causal:
+        tile(False)
+    elif nq == 1:
+        tile(True)     # one query block: every key block writes its own
     else:
-        # float32 block of the whole (b, head group), resident across
-        # the sweep
-        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
-
-        @pl.when(ik == 0)
-        def _():
-            dq_ref[0, rows, :] = dq
-
-        @pl.when(ik > 0)
-        def _():
-            dq_ref[0, rows, :] += dq
-
+        _when_needed(iq >= first_iq,
+                     ik * block_k + block_k - 1 > iq * block_q, tile)
     if nq == 1:
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
-        if has_bias:
-            db_ref[0, 0] = _col_to_row(db)   # a lane-dense row in HBM
         return
-
-    dk_acc, dv_acc = next(refs), next(refs)
-    db_acc = next(refs) if has_bias else None
-
-    @pl.when(iq == 0)
-    def _():
-        dk_acc[:] = dk
-        dv_acc[:] = dv
-        if has_bias:
-            db_acc[:] = db
-
-    @pl.when(iq > 0)
-    def _():
-        dk_acc[:] += dk
-        dv_acc[:] += dv
-        if has_bias:
-            db_acc[:] += db
 
     @pl.when(iq == nq - 1)
     def _():
@@ -439,7 +519,8 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
 
 
 def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
-                    block_q: int, block_k: int, interpret: bool):
+                    block_q: int, block_k: int, interpret: bool,
+                    causal: bool = False):
     """``dq, dk, dv`` (and ``dbias`` (B, Lk) where a bias was given)
     from the saved float32 output and log-sum-exp row; all
     (B, L, H·D)."""
@@ -469,7 +550,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
     k = _pad_rows(_pad_heads(k, h, dp), lk_p)
     v = _pad_rows(_pad_heads(v, h, dp), lk_p)
     kbar, vbar = _pad_heads(kbar, h, dp), _pad_heads(vbar, h, dp)
-    bias = _key_bias(bias, b, lk, lk_p)
+    bias = None if causal else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
     if nk > 1 and lq_p * width * 4 > _DQ_RESIDENT_MAX:
@@ -479,12 +560,20 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
             f"{_DQ_RESIDENT_MAX} bytes use impl='chunked', or chunk the "
             "queries")
 
+    def seen(ik, iq):
+        """The query block read at grid step (ik, iq): before the first
+        that sees this key block, that first one (nothing new to
+        fetch)."""
+        if causal and nq > 1:
+            iq = jnp.maximum(iq, (ik * block_k) // block_q)
+        return iq
+
     q_spec = pl.BlockSpec((1, block_q, width),
-                          lambda ib, ih, ik, iq: (ib, iq, ih))
+                          lambda ib, ih, ik, iq: (ib, seen(ik, iq), ih))
     k_spec = pl.BlockSpec((1, block_k, width),
                           lambda ib, ih, ik, iq: (ib, ik, ih))
     row_spec = pl.BlockSpec((1, group, 1, block_q),
-                            lambda ib, ih, ik, iq: (ib, ih, 0, iq))
+                            lambda ib, ih, ik, iq: (ib, ih, 0, seen(ik, iq)))
     mean_spec = pl.BlockSpec((1, 1, width),
                              lambda ib, ih, ik, iq: (ib, 0, ih))
     in_specs = [q_spec, k_spec, k_spec, q_spec, mean_spec, mean_spec,
@@ -495,6 +584,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
                                      lambda ib, ih, ik, iq: (ib, ik, 0)))
         args.append(bias[:, :, None])
     if nk == 1:
+        # one key block: no query block is skipped, seen() is iq
         dq_spec, dq_dtype = q_spec, q.dtype
     else:
         dq_spec = pl.BlockSpec((1, lq_p, width),
@@ -519,7 +609,8 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
     dq, dk, dv, *db = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, nq=nq, nk=nk,
                           block_q=block_q, group=group,
-                          has_bias=has_bias),
+                          has_bias=has_bias, causal=causal,
+                          block_k=block_k),
         grid=(b, h // group, nk, nq),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -527,7 +618,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name="flash_attention_bwd",
+        name="causal_attention_bwd" if causal else "flash_attention_bwd",
     )(*args)
 
     def trim(x, rows):
@@ -540,28 +631,29 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
 # --- the differentiable core -------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, bias, h, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, bias, h, scale, block_q, block_k, interpret, causal):
     # forward-only use: no residual output leaves the kernel
     return _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
-                          interpret, False)
+                          interpret, False, causal)
 
 
-def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret,
+               causal):
     # the residual output stays float32: the backward's delta =
     # rowsum(do * o) must cancel sum_k(dp * p) to float32 rounding, or
     # every key of a row gets the same push and dq drifts along the
     # keys' common component (seen as update_norm_gap, PERF.md PR 26)
     o, lse = _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
-                            interpret, True)
+                            interpret, True, causal)
     return o.astype(q.dtype), (q, k, v, bias, o, lse)
 
 
-def _flash_bwd(h, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(h, scale, block_q, block_k, interpret, causal, res, g):
     q, k, v, bias, o, lse = res
     dq, dk, dv, dbias = _flash_backward(
         q, k, v, bias, o, lse, g.astype(q.dtype), h, scale, block_q,
-        block_k, interpret)
+        block_k, interpret, causal)
     if dbias is not None:
         # a learned additive key bias trains the same as under
         # "chunked"/"einsum"; a mask's cotangent is dropped by its caller
@@ -573,26 +665,33 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention_channels(q, k, v, *, num_heads: int, bias=None,
+                             causal: bool = False,
                              scale: Optional[float] = None,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
                              interpret: Optional[bool] = None):
     """Fused attention on heads as the projections leave them, side by
     side on the channel axis. q: (B, Lq, H·D); k, v: (B, Lk, H·D);
-    bias: optional (B, Lk) additive key bias (NEG_INF at padding).
+    bias: optional (B, Lk) additive key bias (NEG_INF at padding);
+    ``causal``: query i sees keys 0..i (Lq == Lk, no bias).
     Blocks come from the shapes (``pick_blocks``) unless given.
     Returns (B, Lq, H·D) in q's dtype."""
     from perceiver_tpu.utils.platform import resolve_interpret
     if q.shape[-1] % num_heads:
         raise ValueError(f"{q.shape[-1]} channels do not split into "
                          f"{num_heads} heads")
+    if causal and (bias is not None or q.shape[1] != k.shape[1]):
+        raise ValueError(
+            "causal attention is over square scores with no key bias: "
+            f"{q.shape[1]} queries, {k.shape[1]} keys, bias "
+            f"{'given' if bias is not None else 'None'}")
     if scale is None:
         scale = 1.0 / ((q.shape[-1] // num_heads) ** 0.5)
-    auto_q, auto_k = pick_blocks(q.shape[1], k.shape[1])
+    auto_q, auto_k = pick_blocks(q.shape[1], k.shape[1], causal)
     return _flash(q, k, v, bias, int(num_heads), float(scale),
                   int(auto_q if block_q is None else block_q),
                   int(auto_k if block_k is None else block_k),
-                  resolve_interpret(interpret))
+                  resolve_interpret(interpret), bool(causal))
 
 
 def flash_attention(q, k, v, **kwargs):

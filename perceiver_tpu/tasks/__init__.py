@@ -5,3 +5,4 @@ from perceiver_tpu.tasks.image import ImageClassifierTask  # noqa: F401
 from perceiver_tpu.tasks.text import TextClassifierTask  # noqa: F401
 from perceiver_tpu.tasks.mlm import MaskedLanguageModelTask  # noqa: F401
 from perceiver_tpu.tasks.segmentation import SegmentationTask  # noqa: F401
+from perceiver_tpu.tasks.causal_lm import CausalLMTask  # noqa: F401

@@ -634,6 +634,10 @@ class Trainer:
             # them; left out when tracing is switched off
             phases = ({f"{k}_s": v for k, v in phase_s.items()}
                       if tracing_enabled() else {})
+            # the task's other scalars (a packed loss's overflow, a
+            # looped model's exit gate) ride the same line
+            phases.update((k, float(v)) for k, v in metrics.items()
+                          if k != "loss")
             self.telemetry.step(
                 self.global_step, float(metrics.get("loss", float("nan"))),
                 steps_delta=steps_since,

@@ -149,3 +149,98 @@ def test_scopes_change_metadata_and_nothing_else(name, remat, tmp_path,
     bare = lower_step(task, batch, tmp_path)
     assert "attn_core" not in bare.as_text(debug_info=True)
     assert scoped.as_text() == bare.as_text()
+
+
+# --- a weight-shared decoder stack run several times ------------------------
+# (models/looped_lm.py): loop_stack around the passes, decoder_layer
+# around one layer application, exit_gate and exit_loss beside them; the
+# inner scopes are the shared ones, so the class readers read this model
+# unedited. Its backward pass is written by hand: the recomputed
+# forward carries JAX's own mark.
+
+from benchmarks.scope_times import names_of  # noqa: E402
+from perceiver_tpu.obs.trace import DEVICE_SCOPES  # noqa: E402
+from perceiver_tpu.tasks import CausalLMTask  # noqa: E402
+
+LOOPED = CausalLMTask(vocab_size=96, hidden_size=32, num_hidden_layers=2,
+                      num_attention_heads=2, head_dim=16,
+                      intermediate_size=48, max_seq_len=128,
+                      total_ut_steps=3, remat=True, ce_chunk_size=128)
+LOOPED_BATCH = {"input_ids": np.ones((2, 128), np.int32),
+                "valid": np.ones((2,), bool)}
+LOOPED_LAYERS = {"input_adapter", "loop_stack", "exit_gate", "exit_loss",
+                 "optimizer"}
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["materialized", "fused"])
+def looped_ops(request, tmp_path_factory):
+    task = dataclasses.replace(
+        LOOPED, attention_impl="flash" if request.param else "einsum")
+    lowered = lower_step(task, LOOPED_BATCH,
+                         tmp_path_factory.mktemp("looped"))
+    ops = re.findall(
+        r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"',
+        lowered.compile().as_text())
+    assert len(ops) > 500
+    return request.param, ops
+
+
+def looped_scopes(name):
+    return [s for s in names_of(name) if s in DEVICE_SCOPES]
+
+
+def test_the_new_scopes_are_in_the_vocabulary():
+    assert {"loop_stack", "decoder_layer", "exit_gate",
+            "exit_loss"} <= set(DEVICE_SCOPES)
+
+
+def test_looped_every_heavy_operation_is_under_one_layer(looped_ops):
+    _, ops = looped_ops
+    heavy = [(code, name) for code, name in ops if code in HEAVY]
+    assert len(heavy) > 60
+    for code, name in heavy:
+        found = looped_scopes(name)
+        layers = [s for s in found if s in LOOPED_LAYERS]
+        assert len(layers) == 1, (code, name)
+        if "decoder_layer" in found:
+            assert layers == ["loop_stack"], (code, name)
+        if set(found) & {"attn_core", "attn_proj", "mlp"}:
+            assert "decoder_layer" in found, (code, name)
+        if "loss" in found:      # the fused CE's own scope stays inside
+            assert layers == ["exit_loss"], (code, name)
+
+
+def test_looped_passes_are_marked_in_the_hand_written_backward(looped_ops):
+    fused, ops = looped_ops
+    core = [n for code, n in ops
+            if code in HEAVY and "attn_core" in looped_scopes(n)]
+    recomputed = [n for n in core if "rematted_computation" in n]
+    backward = [n for n in core if "transpose(" in n
+                and "rematted_computation" not in n]
+    forward = [n for n in core if "transpose(" not in n
+               and "rematted_computation" not in n]
+    assert forward and recomputed and backward
+    if fused:
+        assert all("causal_attention_fwd" in n for n in forward)
+        assert all("causal_attention_fwd" in n for n in recomputed)
+        assert any("causal_attention_bwd" in n for n in backward)
+        assert not any("flash_attention_" in n for n in core)
+    mlp = [n for code, n in ops if code == "dot"
+           and "mlp" in looped_scopes(n)]
+    assert any("rematted_computation" in n for n in mlp)
+    assert any("transpose(" in n and "rematted_computation" not in n
+               for n in mlp)
+
+
+def test_looped_exit_loss_gate_and_optimizer_are_scoped(looped_ops):
+    _, ops = looped_ops
+    loss = [n for code, n in ops
+            if code in HEAVY and "exit_loss" in looped_scopes(n)]
+    assert any("transpose(" in n for n in loss)
+    assert any("transpose(" not in n for n in loss)
+    assert any("loss" in looped_scopes(n) for n in loss)
+    assert any("exit_gate" in looped_scopes(n) for _, n in ops)
+    update = [n for _, n in ops if "optimizer" in looped_scopes(n)]
+    assert len(update) > 30
+    assert not any(set(looped_scopes(n)) - {"optimizer"} for n in update)

@@ -104,6 +104,31 @@ def test_flash_forward_backward_compiles_at_cell_shapes(one_chip, lq, lk,
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("rows,seq,heads,dim", [
+    (2, 4096, 16, 128),     # ouro_train: 1024 x 1024 blocks, 10 of 16 run
+    (2, 1000, 8, 64),       # a padded last block, two heads a lane block
+    (1, 2048, 4, 128),      # streamed where the full kernels take one block
+], ids=["ouro_train", "ragged_d64", "two_blocks"])
+def test_causal_forward_backward_compiles(one_chip, rows, seq, heads, dim):
+    """The causal mode (blocks above the diagonal skipped by clamped
+    index maps and ``pl.when``, the crossed ones masked from iotas) on
+    the chip's own compiler, under its own kernel names."""
+    from perceiver_tpu.ops.pallas_attention import (
+        flash_attention_channels as flash_attention,
+    )
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, num_heads=heads, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    x = _struct(one_chip)((rows, seq, heads * dim), jnp.bfloat16)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "causal_attention_fwd" in text and "causal_attention_bwd" in text
+    assert "flash_attention_fwd" not in text
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 
