@@ -50,13 +50,16 @@ from perceiver_tpu.ops.attention import (
     cross_attention_init,
     cross_attention_apply,
     cross_attention_kv,
+    data_shards,
     self_attention_init,
     self_attention_apply,
+    untallied,
 )
 from perceiver_tpu.ops.dropout import dropout
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.mlp import mlp_init, mlp_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
+from perceiver_tpu.ops.remat import REMAT_NAMES, choose_keeps, reckoning
 
 
 def _rng_or_dummy(rng, deterministic: bool = True):
@@ -151,17 +154,22 @@ def self_attention_block_init(key, num_layers, num_channels, num_heads,
 def self_attention_block_apply(stacked, x, *, num_heads, dropout_rate=0.0,
                                rng=None, deterministic=True,
                                policy: Policy = DEFAULT_POLICY,
-                               impl: Optional[str] = None):
+                               impl: Optional[str] = None, layer=None):
+    """``layer``: the scan's body in place of the plain layer, a
+    ``(layer_params, x, key) -> x`` (the encoder hands in its
+    checkpointed one under ``remat``)."""
     num_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
     keys = jax.random.split(_rng_or_dummy(rng, deterministic), num_layers)
+    if layer is None:
+        def layer(layer_params, x, k):
+            return self_attention_layer_apply(
+                layer_params, x, num_heads=num_heads,
+                dropout_rate=dropout_rate, rng=k,
+                deterministic=deterministic, policy=policy, impl=impl)
 
     def body(carry, layer_in):
         layer_params, k = layer_in
-        out = self_attention_layer_apply(
-            layer_params, carry, num_heads=num_heads,
-            dropout_rate=dropout_rate, rng=k, deterministic=deterministic,
-            policy=policy, impl=impl)
-        return out, None
+        return layer(layer_params, carry, k), None
 
     x, _ = jax.lax.scan(body, x, (stacked, keys))
     return x
@@ -197,10 +205,15 @@ class PerceiverEncoder:
     # the input token axis is laid out across devices. None for the
     # single-device / pure-GSPMD paths.
     spmd: Optional[tuple] = None
-    # Rematerialize each perceiver layer (cross-attn + self-attn block)
-    # on the backward pass: activations inside a layer are recomputed
-    # instead of stored, trading FLOPs for HBM — the lever that fits
-    # the seq-2048 / 12-block configs (BASELINE.md configs[4]).
+    # Do not hold a layer's activations: every attention layer (the
+    # cross-attention, each self-attention of the block) recomputes on
+    # the backward pass what is cheap to recompute — norms, GELU,
+    # casts, residual sums — from its input and from the dear values
+    # that cross the boundary by name: the kernels' outputs, the
+    # projections, the MLP's hidden layer (ops/remat.py; as many of
+    # them as fit the device's memory, reckoned from the shapes). The
+    # lever that fits the seq-2048 / 12-block configs (BASELINE.md
+    # configs[4]) without computing the encoder twice.
     remat: bool = False
 
     def __post_init__(self):
@@ -234,28 +247,37 @@ class PerceiverEncoder:
             params["layer_n"] = self._layer_init(kn)
         return params
 
-    def _layer_apply(self, params, latent, kv_heads, pad_mask, attn_mask,
-                     rng, deterministic, policy):
-        k_cross, k_selfs = jax.random.split(_rng_or_dummy(rng))
-        with device_scope("enc_cross_attn"):
-            latent = cross_attention_layer_apply(
-                params["cross"], latent, None,
-                num_heads=self.num_cross_attention_heads,
-                key_padding_mask=pad_mask, attn_mask=attn_mask,
-                dropout_rate=self.dropout, rng=k_cross,
-                deterministic=deterministic, policy=policy,
-                impl=self.attention_impl, kv_chunk_size=self.kv_chunk_size,
-                spmd=self.spmd, kv_heads=kv_heads)
-        latent_impl = (self.attention_impl
-                       if self.attention_impl in ("einsum", "flash")
-                       else None)
-        with device_scope("latent_self_attn"):
-            return self_attention_block_apply(
-                params["selfs"], latent,
-                num_heads=self.num_self_attention_heads,
-                dropout_rate=self.dropout, rng=k_selfs,
-                deterministic=deterministic, policy=policy,
-                impl=latent_impl)
+    def _remat_policy(self, cross_layer, self_layer, layer_params, kv_heads,
+                      latent, key):
+        """The save list of this encoder's checkpointed layers: each
+        layer differentiated once for its shapes alone (a custom VJP
+        names values in its forward rule) says what its names would
+        hold; ``choose_keeps`` takes the bytes of all layer
+        applications on one device (rows split over a mesh's ``data``
+        axis) against the device's memory."""
+        one_self = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+            layer_params["selfs"])
+
+        def named_by(layer, *args):
+            with untallied(), reckoning() as held:
+                jax.eval_shape(
+                    lambda *a: jax.vjp(lambda *b: layer(*b, key), *a)[0],
+                    *args)
+            return held
+
+        cross = named_by(cross_layer, layer_params["cross"], kv_heads,
+                         latent)
+        selfs = named_by(self_layer, one_self, latent)
+        n_cross = self.num_layers
+        n_self = n_cross * self.num_self_attention_layers_per_block
+        shards = data_shards(latent)
+        held = {name: (n_cross * cross[name] + n_self * selfs[name]) // shards
+                for name in REMAT_NAMES}
+        layer_in = ((n_cross + n_self) * latent.size
+                    * latent.dtype.itemsize // shards)
+        return jax.checkpoint_policies.save_only_these_names(
+            *choose_keeps(held, layer_in))
 
     def apply(self, params, x, pad_mask=None, attn_mask=None, *, rng=None,
               deterministic: bool = True, policy: Policy = DEFAULT_POLICY):
@@ -280,19 +302,57 @@ class PerceiverEncoder:
                 return cross_attention_kv(
                     layer_params["cross"]["attn"], x, policy=policy)
 
-        def one_layer(layer_params, kv_heads, latent, k):
-            return self._layer_apply(layer_params, latent, kv_heads,
-                                     pad_mask, attn_mask, k,
-                                     deterministic, policy)
+        def cross_layer(cross_params, kv_heads, latent, k):
+            with device_scope("enc_cross_attn"):
+                return cross_attention_layer_apply(
+                    cross_params, latent, None,
+                    num_heads=self.num_cross_attention_heads,
+                    key_padding_mask=pad_mask, attn_mask=attn_mask,
+                    dropout_rate=self.dropout, rng=k,
+                    deterministic=deterministic, policy=policy,
+                    impl=self.attention_impl,
+                    kv_chunk_size=self.kv_chunk_size, spmd=self.spmd,
+                    kv_heads=kv_heads)
 
+        latent_impl = (self.attention_impl
+                       if self.attention_impl in ("einsum", "flash")
+                       else None)
+
+        def self_layer(layer_params, latent, k):
+            return self_attention_layer_apply(
+                layer_params, latent,
+                num_heads=self.num_self_attention_heads,
+                dropout_rate=self.dropout, rng=k,
+                deterministic=deterministic, policy=policy,
+                impl=latent_impl)
+
+        kv_1 = layer_kv(params["layer_1"])
         if self.remat:
-            one_layer = jax.checkpoint(one_layer)
+            # One boundary a layer, not one around a whole block: a
+            # layer's recomputed values are used where they are made,
+            # and the save list says what crosses the boundary beside
+            # the layer's input. One checkpointed function for layer_1
+            # and layer_n alike: its body is traced once.
+            keep = self._remat_policy(cross_layer, self_layer,
+                                      params["layer_1"], kv_1, latent, k1)
+            cross_layer = jax.checkpoint(cross_layer, policy=keep)
+            self_layer = jax.checkpoint(self_layer, policy=keep,
+                                        prevent_cse=False)  # scanned only
 
-        latent = one_layer(params["layer_1"], layer_kv(params["layer_1"]),
-                           latent, k1)
+        def one_layer(layer_params, kv_heads, latent, k):
+            k_cross, k_selfs = jax.random.split(_rng_or_dummy(k))
+            latent = cross_layer(layer_params["cross"], kv_heads, latent,
+                                 k_cross)
+            with device_scope("latent_self_attn"):
+                return self_attention_block_apply(
+                    layer_params["selfs"], latent,
+                    num_heads=self.num_self_attention_heads,
+                    rng=k_selfs, layer=self_layer)
+
+        latent = one_layer(params["layer_1"], kv_1, latent, k1)
         if self.num_layers > 1:
-            # Weight-shared recurrence (model.py:186-187): one compiled
-            # body, scanned num_layers-1 times over per-iteration keys.
+            # Weight-shared recurrence (model.py:186-187) over
+            # per-iteration keys.
             keys = jax.random.split(kn, self.num_layers - 1)
             layer_n = params["layer_n"]
             kv_n = layer_kv(layer_n)
@@ -304,7 +364,19 @@ class PerceiverEncoder:
                 return one_layer(layer_n, kv_n,
                                  policy.cast_compute(carry), k), None
 
-            latent, _ = jax.lax.scan(body, latent, keys)
+            if self.remat:
+                # Unrolled: what a block's scan saves for the backward
+                # pass (a stack a name) would be copied into this
+                # scan's stacks on the way in and out again on the way
+                # back, a pass over every saved byte each (PERF.md,
+                # PR 29: 19 ms of lm_train's 252 ms step). The blocks'
+                # own scans stay: unrolled too they run faster still,
+                # in a program four times the size (PERF.md, PR 29).
+                for k in keys:
+                    latent, _ = body(latent, k)
+            else:
+                # one compiled body, scanned num_layers-1 times
+                latent, _ = jax.lax.scan(body, latent, keys)
         return latent, pad_mask
 
 

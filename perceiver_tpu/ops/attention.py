@@ -55,6 +55,7 @@ from perceiver_tpu.ops.initializers import uniform, xavier_uniform
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
+from perceiver_tpu.ops.remat import dear
 
 NEG_INF = -1e30  # large-negative bias; safe in fp32 softmax accumulation
 
@@ -281,12 +282,28 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def _mesh_devices(x) -> int:
-    """Devices of the mesh ``x`` is laid out on, as its type carries it
-    (inside a trace too: jit hands the arguments' mesh down); 1 for an
-    array on one device."""
+def _mesh_of(x):
+    """The mesh ``x`` is laid out on, as its type carries it (inside a
+    trace too: jit hands the arguments' mesh down); None for an array
+    on one device."""
     mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
-    return 1 if mesh is None or mesh.empty else mesh.size
+    return None if mesh is None or mesh.empty else mesh
+
+
+def _mesh_devices(x) -> int:
+    """Devices of the mesh ``x`` is laid out on; 1 on one device."""
+    mesh = _mesh_of(x)
+    return 1 if mesh is None else mesh.size
+
+
+def data_shards(x) -> int:
+    """Devices the rows of ``x`` are split over: the size of the
+    ``data`` axis of its mesh (the batch's axis,
+    ``parallel/mesh.make_mesh``); 1 on one device. What else a mesh
+    splits (heads and hidden units over ``model``) depends on what
+    divides, and is not counted on."""
+    mesh = _mesh_of(x)
+    return 1 if mesh is None else mesh.shape.get("data", 1)
 
 
 # Trace-time tally of attention call sites, keyed (path, reason):
@@ -306,6 +323,18 @@ def attention_paths() -> Iterator[collections.Counter]:
         yield tally
     finally:
         _PATH_TALLIES.remove(tally)
+
+
+@contextlib.contextmanager
+def untallied() -> Iterator[None]:
+    """A trace for shapes alone (a ``remat`` encoder reckons what its
+    names would hold from one): its call sites are the real trace's,
+    seen a second time, and are not counted."""
+    held, _PATH_TALLIES[:] = _PATH_TALLIES[:], []
+    try:
+        yield
+    finally:
+        _PATH_TALLIES[:] = held
 
 
 def format_attention_paths(tally) -> str:
@@ -438,7 +467,8 @@ def _project(params, q, k, v, policy, kv_heads):
     if kv_heads is not None:
         # pre-projected (kh, vh) from mha_kv_heads — the hoisted
         # loop-invariant path; only the q projection runs per call
-        return (linear_apply(params["q"], q, policy=policy), *kv_heads)
+        return (dear(linear_apply(params["q"], q, policy=policy), "qkv"),
+                *kv_heads)
     if k is q and v is q:
         # self-attention: pack the three projections into ONE matmul
         # (torch's in_proj). Identical numerics — the concatenated
@@ -452,7 +482,9 @@ def _project(params, q, k, v, policy, kv_heads):
         if "b" in params["q"]:
             packed["b"] = jnp.concatenate(
                 [params[n]["b"] for n in ("q", "k", "v")])
-        qkv = linear_apply(packed, q, policy=policy)
+        # named before it is sliced: one buffer for a ``remat`` layer
+        # to hold, not three slices the compiler may copy
+        qkv = dear(linear_apply(packed, q, policy=policy), "qkv")
         e = qkv.shape[-1] // 3
         return tuple(qkv[..., i * e:(i + 1) * e] for i in range(3))
     return (linear_apply(params["q"], q, policy=policy),

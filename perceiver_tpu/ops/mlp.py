@@ -25,6 +25,7 @@ from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
+from perceiver_tpu.ops.remat import dear
 
 _INV_SQRT2 = 1.0 / math.sqrt(2.0)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
@@ -64,7 +65,7 @@ def mlp_init(key, dim: int, widening_factor: int = 1, dtype=jnp.float32):
 @device_scope("mlp")
 def mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
     h = layer_norm_apply(params["norm"], x, policy=policy)
-    h = linear_apply(params["fc1"], h, policy=policy)
+    h = dear(linear_apply(params["fc1"], h, policy=policy), "mlp_hidden")
     h = gelu_exact(h)
     return linear_apply(params["fc2"], h, policy=policy)
 

@@ -84,6 +84,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.tiling import round_up as _round_up
 
 from perceiver_tpu.ops.chunked_attention import NEG_INF
@@ -646,6 +647,10 @@ def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret,
     # keys' common component (seen as update_norm_gap, PERF.md PR 26)
     o, lse = _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
                             interpret, True, causal)
+    # named for a ``remat`` layer's save list (ops/remat.py): with the
+    # pair saved the backward does not run this kernel again; the bf16
+    # output below is a cast of it and is recomputed, not held too
+    o, lse = dear(o, "attn_out"), dear(lse, "attn_out")
     return o.astype(q.dtype), (q, k, v, bias, o, lse)
 
 

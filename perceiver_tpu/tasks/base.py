@@ -32,8 +32,11 @@ class TaskConfig:
     num_encoder_self_attention_layers_per_block: int = 6
     num_decoder_cross_attention_heads: int = 4
     dropout: float = 0.0
-    # rematerialize encoder layers on backward (memory ↔ FLOPs trade
-    # for the large configs; see PerceiverEncoder.remat)
+    # do not hold the encoder layers' activations: the backward pass
+    # recomputes what is cheap (norms, GELU, casts) and keeps by name
+    # what is dear (kernel outputs, projections, the MLP's hidden
+    # layer), as much as the device's memory takes (memory ↔ time for
+    # the large configs; see PerceiverEncoder.remat, ops/remat.py)
     remat: bool = False
     # encoder cross-attention kernel (PerceiverEncoder.attention_impl):
     # None/"einsum", "chunked", "flash", or — given a mesh with a "seq"

@@ -457,20 +457,23 @@ class Trainer:
     def _load_step(self, step_fn, state, sharded, n_dev, cache_label):
         """Lower the step once (cost analysis, and the compile or cache
         read the first call would do anyway), say which attention core
-        each call site of the traced step took, and return the function
-        to call from now on."""
+        each call site of the traced step took and what its ``remat``
+        layers keep, and return the function to call from now on."""
         from perceiver_tpu.ops.attention import (
             attention_paths,
             format_attention_paths,
         )
-        with span("train/step_load"), attention_paths() as paths:
+        from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
+        with span("train/step_load"), attention_paths() as paths, \
+                remat_keeps() as keeps:
             flops, step_fn = step_flops_and_fn(
                 step_fn, state, sharded, num_devices=n_dev,
                 cache=self._exec_cache, cache_label=cache_label)
         self._step_flops = flops or 0.0
         print(f"[step_load] attention call sites: "
-              f"{format_attention_paths(paths)}", file=sys.stderr,
-              flush=True)
+              f"{format_attention_paths(paths)}\n"
+              f"[step_load] remat keeps: {format_remat_keeps(keeps)}",
+              file=sys.stderr, flush=True)
         return step_fn
 
     def _preemption_pending(self) -> bool:

@@ -16,7 +16,15 @@ estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-Usage: python scripts/aot_memcheck.py [224 | lm | bench | all]
+``lm`` and ``224`` run ``remat: true``: beside XLA's sizes they print
+which dear values the encoder's layers keep and the bytes it reckoned
+for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
+that read the backend are made as the described chip would make them
+(the fused attention core; the chip's memory, ``DESCRIBED_MEMORY``).
+
+Usage: python scripts/aot_memcheck.py [224 | lm | bench | all] [rows]
+       (``rows``: the per-chip batch of ``224`` / ``lm`` in place of
+       the preset's)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -52,6 +60,11 @@ def _mem_analysis(compiled):
     return out
 
 
+# ``memory_stats()["bytes_limit"]`` of the chips a topology name can
+# describe here (a v5e reports 16.91 GB; my chip run, PR 29)
+DESCRIBED_MEMORY = {"TPU v5 lite": 16_909_336_064}
+
+
 def _topology_sharding():
     """When MEMCHECK_TOPOLOGY is set (e.g. ``v5e:2x2``), AOT-compile
     against that real TPU target via the local libtpu instead of the
@@ -63,9 +76,17 @@ def _topology_sharding():
     import jax
     from jax.experimental import topologies
 
+    import perceiver_tpu.ops.attention as attention
+    import perceiver_tpu.ops.remat as remat
+
     topo = topologies.get_topology_desc(name, platform="tpu")
-    print(f"[memcheck] target topology {name}: "
-          f"{topo.devices[0].device_kind}", file=sys.stderr, flush=True)
+    kind = topo.devices[0].device_kind
+    print(f"[memcheck] target topology {name}: {kind}", file=sys.stderr,
+          flush=True)
+    # a described chip is no backend: what the program reads off the
+    # backend is given as that chip would report it
+    attention._backend = lambda: "tpu"
+    remat._memory_limit = lambda: DESCRIBED_MEMORY[kind]
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
@@ -107,16 +128,27 @@ def _compile_train_step(task, batch, label):
               for k, v in batch.items()}
     rng_sds = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
                                    sharding=topo_sh)
+    from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
+
     print(f"[{label}] lowering ...", file=sys.stderr, flush=True)
-    lowered = train_step.lower(params, opt_state, shapes, rng_sds)
+    with remat_keeps() as keeps:
+        lowered = train_step.lower(params, opt_state, shapes, rng_sds)
     print(f"[{label}] compiling ...", file=sys.stderr, flush=True)
     compiled = lowered.compile()  # graphcheck: ignore — AOT memory diagnostic, compilation IS the measurement
-    return _mem_analysis(compiled)
+    out = _mem_analysis(compiled)
+    if keeps:
+        out["remat_keeps"] = format_remat_keeps(keeps)
+        out["remat_reckoned_mb"] = {
+            name: round(n / 2**20, 1)
+            for name, n in keeps[0]["bytes"].items()}
+    return out
 
 
-def check_224(per_chip_batch: int = 4):
-    """224×224/512-latent classifier; v5e-8 runs dp8, so the per-chip
-    shard is global_batch/8 (preset batch 32 → 4/chip)."""
+def check_224(per_chip_batch: int = 8):
+    """224×224/512-latent classifier of
+    scripts/configs/imagenet_scale_v5e8.yaml; v5e-8 runs dp8, so the
+    per-chip shard is global_batch/8 (preset batch 64 → 8/chip, the
+    benchmark's ``img_train``)."""
     import jax.numpy as jnp
 
     from perceiver_tpu.tasks import ImageClassifierTask
@@ -124,12 +156,8 @@ def check_224(per_chip_batch: int = 4):
     task = ImageClassifierTask(
         image_shape=(224, 224, 3), num_classes=1000,
         num_frequency_bands=64, num_latents=512, num_latent_channels=512,
-        num_encoder_layers=6,
-        num_encoder_self_attention_layers_per_block=6,
-        num_encoder_cross_attention_heads=8,
-        num_encoder_self_attention_heads=8,
-        num_decoder_cross_attention_heads=8,
-        remat=True, attention_impl="chunked", kv_chunk_size=4096)
+        num_encoder_layers=6, num_decoder_cross_attention_heads=1,
+        remat=True)
     batch = {
         "image": jnp.zeros((per_chip_batch, 224, 224, 3), jnp.float32),
         "label": jnp.zeros((per_chip_batch,), jnp.int32),
@@ -137,11 +165,12 @@ def check_224(per_chip_batch: int = 4):
     return _compile_train_step(task, batch, "224")
 
 
-def check_lm(per_chip_batch: int = 2):
-    """v5p-16 Perceiver-LM preset per-chip shard: the mesh is dp4×tp4
-    (scripts/configs/perceiver_lm_v5p16.yaml); tensor-parallel weight
-    shards aren't modeled single-chip, so this is the CONSERVATIVE
-    (replicated-weights) bound."""
+def check_lm(per_chip_batch: int = 4):
+    """v5p-16 Perceiver-LM preset per-chip shard (batch 64 → 4/chip):
+    the mesh is dp4×sp2×tp2 (scripts/configs/perceiver_lm_v5p16.yaml);
+    tensor-parallel weight shards aren't modeled single-chip, so this
+    is the CONSERVATIVE (replicated-weights) bound. 24 rows are the
+    benchmark's ``lm_train``."""
     import jax.numpy as jnp
 
     from perceiver_tpu.tasks import MaskedLanguageModelTask
@@ -149,7 +178,6 @@ def check_lm(per_chip_batch: int = 2):
     task = MaskedLanguageModelTask(
         vocab_size=32000, max_seq_len=2048,
         num_latents=1024, num_latent_channels=512,
-        num_encoder_layers=2,
         num_encoder_self_attention_layers_per_block=12,
         num_encoder_cross_attention_heads=8,
         num_encoder_self_attention_heads=8,
@@ -202,13 +230,14 @@ def main():
     if want:
         jax.config.update("jax_platforms", want)
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
+    rows = {"per_chip_batch": int(sys.argv[2])} if len(sys.argv) > 2 else {}
 
     out = {"device": str(jax.devices()[0]),
            "topology": os.environ.get("MEMCHECK_TOPOLOGY")}
     if which in ("224", "all"):
-        out["classifier_224"] = check_224()
+        out["classifier_224"] = check_224(**rows)
     if which in ("lm", "all"):
-        out["perceiver_lm_v5p16_shard"] = check_lm()
+        out["perceiver_lm_v5p16_shard"] = check_lm(**rows)
     if which in ("seg", "all"):
         out["seg_512_262k_queries"] = check_seg()
     if which in ("bench", "all"):

@@ -146,7 +146,6 @@ def lowered_target_cache():
 # single variant is slow. Everything here still runs in the full suite.
 
 _SLOW = {
-    "test_models.py::test_remat_is_numerically_transparent",
     "test_models.py::test_attention_impl_parity_through_model",
     "test_models.py::test_dropout_only_active_in_training",
     "test_models.py::test_perceiver_io_image_classifier_shapes",

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from perceiver_tpu.adapters import (
     ClassificationOutputAdapter,
@@ -231,32 +232,54 @@ def test_attention_impl_parity_through_model():
                                    atol=1e-4, rtol=1e-4)
 
 
-def test_remat_is_numerically_transparent():
-    """remat=True must change memory behavior only: identical forward
-    outputs and gradients (PerceiverEncoder.remat, the lever for the
-    seq-2048 configs)."""
+def _square_loss(model, x):
+    def f(p):
+        return (model.apply(p, x, policy=FP32) ** 2).mean()
+    return f
+
+
+@pytest.fixture(scope="module", params=[None, "flash"],
+                ids=["picked_core", "fused_core"])
+def without_remat(request):
+    """The model, its input, and its output and gradients as plain
+    autodiff gives them."""
     import dataclasses
 
     model = make_image_io()
+    model = PerceiverIO(
+        dataclasses.replace(model.encoder, attention_impl=request.param),
+        model.decoder)
     params = model.init(jax.random.key(0))
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(2, 28, 28, 1)), jnp.float32)
+    return (model, params, x, model.apply(params, x, policy=FP32),
+            jax.grad(_square_loss(model, x))(params))
 
+
+@pytest.mark.parametrize("kept", range(4), ids=[
+    "keeps_nothing", "keeps_attn_out", "keeps_qkv_too",
+    "keeps_the_whole_list"])
+def test_remat_is_numerically_transparent(kept, without_remat, monkeypatch):
+    """remat=True must change memory behavior only: identical forward
+    outputs and gradients (PerceiverEncoder.remat, the lever for the
+    seq-2048 configs), whichever prefix of the dear values its layers
+    keep (ops/remat.py) and whichever core makes them."""
+    import dataclasses
+
+    import perceiver_tpu.models.perceiver as perceiver
+    from perceiver_tpu.ops.remat import REMAT_NAMES
+
+    monkeypatch.setattr(perceiver, "choose_keeps",
+                        lambda held, layer_in: REMAT_NAMES[:kept])
+    model, params, x, out_a, ga = without_remat
     remat_model = PerceiverIO(
         dataclasses.replace(model.encoder, remat=True), model.decoder)
 
-    def loss(m):
-        def f(p):
-            return (m.apply(p, x, policy=FP32) ** 2).mean()
-        return f
-
-    out_a = model.apply(params, x, policy=FP32)
     out_b = remat_model.apply(params, x, policy=FP32)
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
                                rtol=1e-6, atol=1e-6)
 
-    ga = jax.grad(loss(model))(params)
-    gb = jax.grad(loss(remat_model))(params)
+    gb = jax.grad(_square_loss(remat_model, x))(params)
     for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         # transparent up to fp32 reassociation: recomputation under
         # remat re-fuses the same ops, so ~1-ulp drift on small grad
