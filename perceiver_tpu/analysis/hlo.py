@@ -126,7 +126,7 @@ def iter_convs(text: str) -> Iterator[dict]:
 def dot_flop_summary(dots: List[dict], mxu_depth: int = 128) -> dict:
     """FLOP-weighted aggregates over ``iter_dots`` records: the MXU
     K-padding ceiling model and the bf16/fp32 FLOP split (the numbers
-    ``scripts/hlo_audit.py`` reports and ``dtype_policy`` gates on)."""
+    ``dtype_policy`` gates on)."""
     total = sum(d["flops"] for d in dots) or 1.0
     ceiling = sum(d["flops"] * min(d["k"], mxu_depth) / mxu_depth
                   for d in dots) / total

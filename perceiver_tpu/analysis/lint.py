@@ -1130,7 +1130,7 @@ def _expand(paths: Iterable[str]) -> List[str]:
     return out
 
 
-_REPO_LINT_DEFAULTS = ("perceiver_tpu", "scripts", "bench.py", "run.py")
+_REPO_LINT_DEFAULTS = ("perceiver_tpu", "scripts", "run.py")
 
 
 def default_lint_paths(repo_root: str) -> List[str]:

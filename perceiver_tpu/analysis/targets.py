@@ -1,7 +1,7 @@
 """Canonical lowering targets: one jitted train step per task, at the
 shapes the benchmarks and runbooks actually pin.
 
-Each target rebuilds, from scratch, the exact step ``bench.py`` times
+Each target rebuilds, from scratch, the step the trainer runs
 (forward + backward + AdamW, params and optimizer state donated) and
 lowers it on the CPU backend — StableHLO lowering is platform-
 independent, so the dtype/transfer/donation properties gated here are
@@ -402,8 +402,8 @@ def lower_target(target: StepTarget, cache=None,
 
 
 # --------------------------------------------------------------------------
-# Canonical configs. Shapes mirror bench.py's pinned/headline rungs and
-# the runbook configs; vocab/seq match the BASELINE MLM recipe.
+# Canonical configs. Shapes are the BASELINE MLM recipe's (vocab, seq)
+# at toy widths and the runbook configs' (ROADMAP D7).
 
 def _build_mlm(batch: int = 512, channels: int = 64, seq_len: int = 512,
                vocab: int = 10003, loss_impl: str = "packed"):
@@ -857,7 +857,7 @@ SHARDED_TARGETS = (
 )
 
 
-# The headline MLM rung (bench.py _LADDER[0]: B=512/C=64/packed) plus
+# The headline MLM target (B=512/C=64/packed) plus
 # one target per remaining task at its canonical shapes, plus the
 # serving targets. "fast" targets keep tracing under a few seconds for
 # the tier-1 subset; --all adds the expensive ones (the 262k-query

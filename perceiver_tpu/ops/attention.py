@@ -119,8 +119,8 @@ def _split_heads(x, num_heads: int):
 # passes instead of a stacked round trip through HBM. It also keeps
 # every grad contraction on bf16 operands under the bf16 policy (the
 # fp32 softmax cotangent used to drag the QK backward pair to the
-# fp32 MXU rate — ~9% of step FLOPs, graph audit
-# scripts/hlo_audit.py).
+# fp32 MXU rate — ~9% of step FLOPs, from the dot audit of
+# analysis/hlo.py:dot_flop_summary).
 
 
 def _sdpa_probs(scale, dropout_rate, stat_dtype, qh, kh, vh, bias, rng):

@@ -46,9 +46,8 @@ in-flight decode rows under one per-step token budget
 (``batcher.ContinuousBatchScheduler.plan_chunks``), so the engine
 owns exactly ONE compiled signature, token N costs the same as token
 1, and time-to-first-token collapses from one latent rebuild *per
-prompt token* to one per chunk — the decode bench
-(``scripts/bench_decode.py``) pins the O(1) ratio, a TTFT gate, and
-zero post-warmup compiles as merge gates.
+prompt token* to one per chunk — ``tests/test_decode.py`` pins the
+dispatches per token as a count, and zero compiles after warm-up.
 
 ``DecodeEngine`` drives the step host-side: a page allocator
 (:class:`PagePool`), unified continuous batching (streams join and

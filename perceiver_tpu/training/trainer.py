@@ -42,11 +42,6 @@ from perceiver_tpu.resilience import guard as guard_mod
 from perceiver_tpu.training.checkpoint import CheckpointHook
 from perceiver_tpu.training.optim import create_optimizer
 from perceiver_tpu.training.state import TrainState
-from perceiver_tpu.utils.flops import (
-    device_peak_flops,
-    mfu,
-    step_flops_and_fn,
-)
 from perceiver_tpu.utils.tb import SummaryWriter
 from perceiver_tpu.utils.timing import fence
 
@@ -295,9 +290,8 @@ class Trainer:
         # (config dir wins over the PERCEIVER_EXEC_CACHE env default)
         from perceiver_tpu.cache import default_cache
         self._exec_cache = default_cache(self.config.exec_cache_dir)
-        # MFU accounting (SURVEY §5 profiling; BASELINE.md north star)
-        self._step_flops: Optional[float] = None
-        self._peak_flops = device_peak_flops()
+        # set by the first _load_step: that dispatch pays the compile
+        self._step_loaded = False
 
     # --- setup ---------------------------------------------------------------
 
@@ -454,11 +448,14 @@ class Trainer:
             self._train_step_multi = jit_step(train_step_multi, 1)
         self._eval_step = jax.jit(eval_step)
 
-    def _load_step(self, step_fn, state, sharded, n_dev, cache_label):
-        """Lower the step once (cost analysis, and the compile or cache
-        read the first call would do anyway), say which attention core
-        each call site of the traced step took and what its ``remat``
-        layers keep, and return the function to call from now on."""
+    def _load_step(self, step_fn, state, sharded, label):
+        """Lower the step once, say which attention core each call site
+        of the traced step took and what its ``remat`` layers keep, and
+        return the function to call from now on: with an executable
+        cache the compiled step (``cache.aot_compile``: a cache read, or
+        the compile the first call would do anyway, stored), without
+        one the jitted function itself, which pins no shapes."""
+        from perceiver_tpu.cache import aot_compile
         from perceiver_tpu.ops.attention import (
             attention_paths,
             format_attention_paths,
@@ -466,10 +463,19 @@ class Trainer:
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         with span("train/step_load"), attention_paths() as paths, \
                 remat_keeps() as keeps:
-            flops, step_fn = step_flops_and_fn(
-                step_fn, state, sharded, num_devices=n_dev,
-                cache=self._exec_cache, cache_label=cache_label)
-        self._step_flops = flops or 0.0
+            try:
+                if self._exec_cache is None:
+                    step_fn.lower(state, sharded)
+                else:
+                    step_fn, _ = aot_compile(
+                        step_fn, (state, sharded), cache=self._exec_cache,
+                        label=label)
+            except Exception as e:
+                # the first dispatch of the jitted step raises the real
+                # error, with its traceback
+                print(f"[step_load] not loaded ahead of time: {e!r}",
+                      file=sys.stderr, flush=True)
+        self._step_loaded = True
         print(f"[step_load] attention call sites: "
               f"{format_attention_paths(paths)}\n"
               f"[step_load] remat keeps: {format_remat_keeps(keeps)}",
@@ -597,7 +603,7 @@ class Trainer:
         return {f"{prefix}_{k}": v / count for k, v in totals.items()}
 
     def _log_step(self, metrics, *, dt: float, throughput: float,
-                  steps_since: int, n_dev: int,
+                  steps_since: int,
                   phase_s: Dict[str, float]) -> None:
         """One logged step: console heartbeat, summary scalars and the
         telemetry line. The caller fenced ``metrics``, so nothing here
@@ -622,10 +628,6 @@ class Trainer:
         if steps_since > 0:
             self.writer.add_scalar("samples_per_sec", throughput,
                                    self.global_step)
-        util = mfu(self._step_flops, steps_since, dt, num_devices=n_dev,
-                   peak_flops_per_device=self._peak_flops)
-        if util is not None:
-            self.writer.add_scalar("mfu", util, self.global_step)
         if self._guard is not None:
             self.writer.add_scalar("guard_skipped_steps",
                                    float(self._guard.skipped_total),
@@ -645,8 +647,7 @@ class Trainer:
                 self.global_step, float(metrics.get("loss", float("nan"))),
                 steps_delta=steps_since,
                 steps_per_sec=steps_since / max(dt, 1e-9),
-                samples_per_sec=throughput,
-                mfu=util if util is not None else 0.0, **phases)
+                samples_per_sec=throughput, **phases)
 
     def fit(self) -> TrainState:
         """Train with SIGTERM (preemption) handling around the loop."""
@@ -874,22 +875,20 @@ class Trainer:
                     # local rows × process count = global rows per dispatch
                     # (each host contributes an equal per-host shard to the
                     # global batch), so samples_per_sec reports global
-                    # training throughput, consistent with the mfu scalar
+                    # training throughput
                     # count only real rows — a non-drop_last loader pads the
                     # final batch with invalid rows that do no training work
                     batch_size = (sum(int(b["valid"].sum()) for b in group)
                                   * jax.process_count())
                     prev_step = self.global_step
-                    first_step = self._step_flops is None
+                    first_step = not self._step_loaded
                     # the single-step fn compiles separately from the
                     # multi-step one; its first run must also stay out of
-                    # the throughput/MFU measurement window
+                    # the throughput measurement window
                     first_single = (spe > 1 and len(group) < spe
                                     and not self._single_step_ran)
                     poison = faults.armed("train.nonfinite")
                     losses = None
-                    n_dev = (self.mesh.devices.size
-                             if self.mesh is not None else 1)
                     if len(group) == spe and spe > 1:
                         with span("train/shard") as sp:
                             stacked = {
@@ -905,7 +904,7 @@ class Trainer:
                         if first_step:
                             self._train_step_multi = self._load_step(
                                 self._train_step_multi, state, sharded,
-                                n_dev, "trainer:train_step_multi")
+                                "trainer:train_step_multi")
                         with span("train/dispatch") as sp:
                             if self._guard is not None:
                                 state, metrics, losses = \
@@ -923,13 +922,10 @@ class Trainer:
                                     self._poison_batch(b)
                                 sharded = self._shard_batch(b)
                             phase_s["host"] += sp.seconds
-                            if self._step_flops is None:
-                                # cost analysis via lowering, or via the AOT
-                                # compile the first call would do anyway —
-                                # never an extra one
+                            if not self._step_loaded:
                                 self._train_step = self._load_step(
                                     self._train_step, state, sharded,
-                                    n_dev, "trainer:train_step")
+                                    "trainer:train_step")
                             with span("train/dispatch") as sp:
                                 if self._guard is not None:
                                     state, metrics, loss_i = \
@@ -985,7 +981,7 @@ class Trainer:
                             self._save_anchor(state, epoch, batches_done)
                     if first_step or first_single:
                         # this dispatch paid a jit compilation; keep it
-                        # out of the throughput/MFU measurement window
+                        # out of the throughput measurement window
                         with span("train/fence") as sp:
                             fence(metrics)
                         phase_s["fence"] += sp.seconds
@@ -996,7 +992,7 @@ class Trainer:
                     if crossed_log or cfg.fast_dev_run:
                         # async dispatch: sync on the device before taking
                         # dt, else the window measures host dispatch time
-                        # and over-reports throughput/MFU
+                        # and over-reports throughput
                         with span("train/fence") as sp:
                             fence(metrics)
                         phase_s["fence"] += sp.seconds
@@ -1005,7 +1001,7 @@ class Trainer:
                         with span("train/log") as sp:
                             self._log_step(
                                 metrics, dt=dt, throughput=throughput,
-                                steps_since=steps_since, n_dev=n_dev,
+                                steps_since=steps_since,
                                 phase_s=phase_s)
                             phase_s = dict.fromkeys(phase_s, 0.0)
                         phase_s["host"] += sp.seconds
@@ -1046,7 +1042,7 @@ class Trainer:
                         self._ckpt.save(self.global_step, state,
                                         val_metrics)
                 # eval/checkpoint wall time must not depress the next
-                # window's samples_per_sec / mfu scalars
+                # window's samples_per_sec scalar
                 t0, samples_since, steps_since = time.time(), 0, 0
             if stop:
                 break

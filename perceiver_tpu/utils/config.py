@@ -352,9 +352,8 @@ class CLI:
         self.trainer = trainer
         # config snapshot BEFORE running (reference cli.py:22
         # SaveConfigCallback writes at setup): a preempted / killed /
-        # still-running fit must still leave its config.yaml — the
-        # platform-labeling of evidence (quality_summary.py) and any
-        # post-mortem read it from the version dir
+        # still-running fit must still leave its config.yaml — any
+        # post-mortem reads it from the version dir
         os.makedirs(trainer.log_dir, exist_ok=True)
         with open(os.path.join(trainer.log_dir, "config.yaml"), "w") as f:
             yaml.safe_dump(self.config, f, sort_keys=True)

@@ -7,9 +7,7 @@ Compiles (compile ONLY — no execution) the full train step of:
    v5e-8 target) at its per-chip batch shard,
 2. the v5p-16 Perceiver-LM MLM preset (1024×512 latents, 12 self-attn
    layers/block, seq 2048; BASELINE configs[4]) at its per-chip shard,
-3. (``bench``) the headline bench MLM config at batch 512 (the top
-   ``bench.py`` ladder rung) and 1024 (a sweep/watcher point beyond
-   the ladder) — predicts whether those fit HBM,
+3. (``seg``) the 512×512 / 262,144-query segmentation config,
 
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
@@ -22,7 +20,7 @@ for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
 (the fused attention core; the chip's memory, ``DESCRIBED_MEMORY``).
 
-Usage: python scripts/aot_memcheck.py [224 | lm | bench | all] [rows]
+Usage: python scripts/aot_memcheck.py [224 | lm | seg | all] [rows]
        (``rows``: the per-chip batch of ``224`` / ``lm`` in place of
        the preset's)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
@@ -190,23 +188,6 @@ def check_lm(per_chip_batch: int = 4):
     return _compile_train_step(task, batch, "lm")
 
 
-def check_mlm_bench(batch: int):
-    """The headline bench config (bench.py: seq 512, vocab 10003,
-    64×64 latents, packed CE) at a candidate batch size — predicts
-    whether the big ladder rungs fit HBM before chip time is spent."""
-    import jax.numpy as jnp
-
-    from perceiver_tpu.tasks import MaskedLanguageModelTask
-
-    task = MaskedLanguageModelTask(vocab_size=10003, max_seq_len=512,
-                                   loss_impl="packed")
-    batch_arrs = {
-        "input_ids": jnp.zeros((batch, 512), jnp.int32),
-        "pad_mask": jnp.zeros((batch, 512), bool),
-    }
-    return _compile_train_step(task, batch_arrs, f"mlm_b{batch}")
-
-
 def check_seg(batch: int = 2, side: int = 512):
     """The 512×512 / 262,144-output-query LArTPC segmentation config
     (``run.py:72-112``) — the decoder query-chunking memory stress."""
@@ -240,9 +221,6 @@ def main():
         out["perceiver_lm_v5p16_shard"] = check_lm(**rows)
     if which in ("seg", "all"):
         out["seg_512_262k_queries"] = check_seg()
-    if which in ("bench", "all"):
-        for b in (512, 1024):
-            out[f"mlm_bench_b{b}"] = check_mlm_bench(b)
     print(json.dumps(out, indent=2))
 
 

@@ -12,7 +12,7 @@ unhandled exceptions, zero silent data loss. ``kill_save`` goes one
 step further and SIGKILLs a training victim mid-checkpoint-save in a
 grand-child process (crash-only checkpointing).
 
-Emits one ``bench.py``-format JSON line per scenario::
+Emits one JSON line per scenario::
 
     {"metric": "chaos_serve_dispatch", "value": 1.0, "unit":
      "survived", "vs_baseline": null, "detail": {"faults_fired": ...,

@@ -25,10 +25,10 @@ dispatch → device materialize — then proves, in one process:
 5. ``obs_tracing_overhead``: recording a span and the disabled-path
    ``start_trace`` both stay under generous pinned bounds.
 
-Emits one bench.py-format JSON line per check plus an ``obs_check``
-summary; exits non-zero iff any check failed.  ``--fast`` shrinks the
-traffic volume (tests/test_obs.py runs it as a tier-1 subprocess
-gate)::
+Emits one JSON line per check (``metric``, ``value``, ``unit``,
+``vs_baseline``, ``detail``) plus an ``obs_check`` summary; exits
+non-zero iff any check failed.  ``--fast`` shrinks the traffic volume
+(tests/test_obs.py runs it as a tier-1 subprocess gate)::
 
     JAX_PLATFORMS=cpu python scripts/obs_check.py --fast
 """

@@ -181,7 +181,6 @@ _SLOW = {
     "test_graphcheck.py::test_full_graph_sweep_is_clean",
     "test_graphcheck.py::test_full_lint_sweep_is_clean",
     "test_shardcheck.py::test_tiny_sharded_target_end_to_end",
-    "test_exec_cache.py::test_bench_startup_script_cold_warm",
     "test_resilience.py::test_trainer_skip_policy_survives_isolated_nan_steps",
     "test_resilience.py::test_trainer_streak_rewinds_from_verified_anchor",
     "test_resilience.py::test_terminate_on_nan_names_first_bad_step_in_block",

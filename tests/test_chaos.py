@@ -1,8 +1,8 @@
 """``scripts/chaos.py --fast`` as a literal subprocess gate — the
 check.py pattern (ISSUE 5 satellite): the tier-1 suite proves a fresh
 process, armed only through the ``PERCEIVER_FAULTS`` env seam,
-survives its fault matrix subset and emits well-formed bench.py-format
-JSON."""
+survives its fault matrix subset and emits one well-formed JSON record
+a scenario."""
 
 import json
 import os
@@ -21,7 +21,7 @@ def test_chaos_fast_matrix_survives():
 
     lines = [json.loads(ln) for ln in proc.stdout.strip().splitlines()]
     by_metric = {ln["metric"]: ln for ln in lines}
-    # bench.py-format records, every scenario survived
+    # one record a scenario, every scenario survived
     for line in lines:
         assert {"metric", "value", "unit", "vs_baseline",
                 "detail"} <= set(line)

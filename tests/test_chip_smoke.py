@@ -17,7 +17,7 @@ import pytest
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO_ROOT, "chip_smoke.py")
 if REPO_ROOT not in sys.path:
-    sys.path.insert(0, REPO_ROOT)  # chip_smoke.py and bench.py live there
+    sys.path.insert(0, REPO_ROOT)  # chip_smoke.py lives there
 
 
 def _tree_files():
@@ -144,16 +144,3 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch,
     assert enable_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
     assert jax.config.jax_compilation_cache_include_metadata_in_key
-
-
-def test_bench_refuses_a_platform_that_is_not_the_one_asked_for(
-        monkeypatch):
-    """bench.py measures a TPU: on the CPU backend it exits at once
-    unless BENCH_PLATFORM=cpu asks for a smoke run."""
-    import bench
-
-    monkeypatch.delenv("BENCH_PLATFORM", raising=False)
-    with pytest.raises(SystemExit, match="measures platform 'tpu'"):
-        bench.require_platform()
-    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
-    bench.require_platform()

@@ -533,6 +533,167 @@ def test_streams_join_and_leave_with_zero_new_compiles(engine):
     _idle(engine)
 
 
+# What the deleted wall-clock gates (a load generator's p95 ratios on
+# this toy) also held as counts, exact on any backend: one run of joins
+# and leaves stepped by hand, and one shared-prefix run of a cold arm,
+# a seed and a warm arm.
+
+_CHURN_PLANS = ((6, 24), (6, 12), (4, 14), (6, 16), (6, 10), (4, 9))
+
+
+@pytest.fixture(scope="module")
+def churn_run():
+    from perceiver_tpu.obs import trace as trace_mod
+
+    buf = trace_mod.TraceBuffer(max_traces=16, max_spans_per_trace=256)
+    prev = trace_mod.set_default_buffer(buf)
+    geometry = small_geometry(num_pages=49, max_chunk=4)
+    eng = DecodeEngine(small_task(), geometry=geometry,
+                       policy=Policy.fp32(), auto_step=False,
+                       exec_cache=False)
+    rng = np.random.default_rng(30)
+    steps_at = [[] for _ in _CHURN_PLANS]
+    handles = []
+
+    def submit(i):
+        prompt_len, max_new = _CHURN_PLANS[i]
+        handles.append(eng.submit(
+            rng.integers(0, VOCAB, size=prompt_len).astype(np.int32),
+            max_new_tokens=max_new,
+            on_token=lambda tok: steps_at[i].append(eng._m_steps.value)))
+
+    try:
+        with compile_events() as compiles:
+            submit(0), submit(1)
+            for _ in range(4):
+                eng.step()
+            # four slots: the last two wait for a stream to leave
+            for i in range(2, len(_CHURN_PLANS)):
+                submit(i)
+            eng.run_until_idle()
+        results = [h.result(timeout=1.0) for h in handles]
+        spans = [buf.get(h.trace_ctx.trace_id) for h in handles]
+        _idle(eng)
+    finally:
+        eng.close(timeout=2.0)
+        trace_mod.set_default_buffer(prev)
+    return {"descriptor": geometry.descriptor, "compiles": list(compiles),
+            "results": results, "steps_at": steps_at, "spans": spans}
+
+
+def _phase(spans, name):
+    return [s for s in spans if s["phase"] == name]
+
+
+def _zero_compiles_and_geometry_key(run):
+    assert run["compiles"] == [], run["compiles"]
+    for (_, max_new), r in zip(_CHURN_PLANS, run["results"]):
+        assert r.finished == "complete" and len(r.tokens) == max_new
+    # slots and chunk lanes are key material of the one executable
+    assert run["descriptor"] == "r4_p49x4_s48_q4"
+
+
+def _dispatches_per_token_do_not_grow(run):
+    # O(1) as a count: a stream's tokens 1-8 and its last 8 take one
+    # dispatch each, however long the stream already is
+    for (_, max_new), at in zip(_CHURN_PLANS, run["steps_at"]):
+        assert len(at) == max_new
+        assert at[8] - at[0] == 8 == at[-1] - at[-9], at
+
+
+def _one_queue_wait_and_first_decode_a_stream(run):
+    for (prompt_len, _), spans in zip(_CHURN_PLANS, run["spans"]):
+        assert len(_phase(spans, "queue_wait")) == 1
+        first_emit = min(s["end"] for s in _phase(spans, "token_emit"))
+        # the step that emitted token 0 is the chunk that completed
+        # the prompt: one such step a stream
+        chunks = _phase(spans, "prefill_chunk")
+        assert [s["end"] for s in chunks].count(first_emit) == 1
+        assert len(chunks) == -(-prompt_len // 4)
+
+
+def _single_chunk_prompt_reports_a_prefill_phase(run):
+    singles = [spans for (prompt_len, _), spans
+               in zip(_CHURN_PLANS, run["spans"]) if prompt_len <= 4]
+    assert len(singles) == 2
+    for spans in singles:
+        (chunk,) = _phase(spans, "prefill_chunk")
+        assert chunk["attrs"]["chunk"] == chunk["attrs"]["fed"] == 4
+
+
+@pytest.mark.parametrize("count", [
+    _zero_compiles_and_geometry_key, _dispatches_per_token_do_not_grow,
+    _one_queue_wait_and_first_decode_a_stream,
+    _single_chunk_prompt_reports_a_prefill_phase],
+    ids=lambda f: f.__name__.strip("_"))
+def test_churn_run_counts(churn_run, count):
+    count(churn_run)
+
+
+@pytest.fixture(scope="module")
+def shared_prefix_run():
+    from perceiver_tpu.serving.prefix_cache import PrefixCacheConfig
+
+    streams, prefix, tail = 8, 16, 6
+    eng = DecodeEngine(
+        small_task(),
+        geometry=small_geometry(max_streams=streams, num_pages=97),
+        policy=Policy.fp32(), auto_step=False, exec_cache=False,
+        prefix_cache=PrefixCacheConfig())
+    rng = np.random.default_rng(31)
+
+    def ids(n):
+        return rng.integers(0, VOCAB, size=n).astype(np.int32)
+
+    def arm(prefixes):
+        before = eng._m_prefill_tokens.value
+        handles = [eng.submit(np.concatenate([p, ids(tail)]),
+                              max_new_tokens=3) for p in prefixes]
+        eng.run_until_idle()
+        return ([h.result(timeout=1.0) for h in handles],
+                eng._m_prefill_tokens.value - before)
+
+    shared = ids(prefix)
+    try:
+        with compile_events() as compiles:
+            cold, cold_tokens = arm([ids(prefix) for _ in range(streams)])
+            arm([shared])      # the seed publishes the shared chain
+            warm, warm_tokens = arm([shared] * streams)
+        stats = eng.prefix_cache_stats()
+    finally:
+        eng.close(timeout=2.0)
+    return {"cold": cold, "warm": warm, "cold_tokens": cold_tokens,
+            "warm_tokens": warm_tokens, "stats": stats,
+            "compiles": list(compiles)}
+
+
+def _every_warm_stream_hits_the_seed(run):
+    assert all(r.cached_tokens == 0 for r in run["cold"])
+    hits = [r.cached_tokens for r in run["warm"]]
+    assert sum(1 for c in hits if c > 0) / len(hits) == 1.0
+    assert sum(hits) == 8 * 16 == 128
+
+
+def _pages_are_indexed_with_no_compile(run):
+    assert run["stats"]["pages_indexed"] > 0
+    assert run["compiles"] == [], run["compiles"]
+
+
+def _warm_arm_prefills_fewer_tokens(run):
+    # what the warm/cold time-to-first-token ratio stood for: the
+    # cached span is not fed through the step again
+    assert run["cold_tokens"] == 8 * (16 + 6)
+    assert run["warm_tokens"] == 8 * 6 < run["cold_tokens"]
+
+
+@pytest.mark.parametrize("count", [
+    _every_warm_stream_hits_the_seed, _pages_are_indexed_with_no_compile,
+    _warm_arm_prefills_fewer_tokens],
+    ids=lambda f: f.__name__.strip("_"))
+def test_shared_prefix_run_counts(shared_prefix_run, count):
+    count(shared_prefix_run)
+
+
 def test_steady_state_is_sync_free_except_next_token(engine):
     """One step = one device sync (the next_token materialize); the
     transfer guard in the graph gates covers the lowered step, this

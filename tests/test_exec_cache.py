@@ -15,9 +15,10 @@ The acceptance properties, each pinned here:
   entries first;
 - two engines sharing one cache directory don't race (atomic
   tempfile + rename publication);
-- ``step_flops_and_fn``'s cache path returns a deserialized
-  executable + sidecar flops on a hit (the trainer's zero-compile
-  first dispatch).
+- the trainer loads its step through ``aot_compile``: stored once, a
+  hit with zero compiles in the next process, an entry of the format
+  the trainer wrote before still a hit, a step with host callbacks
+  never stored, and without a cache the jitted function itself.
 """
 
 import json
@@ -304,29 +305,105 @@ class TestLoweringRecords:
             extra=(again.descriptor,)) in keys
 
 
-class TestStepFlopsCachePath:
-    def test_hit_returns_sidecar_flops_and_executable(self, tmp_path):
-        from perceiver_tpu.utils.flops import step_flops_and_fn
+class TestTrainerStepLoad:
+    """``Trainer._load_step`` loads the train step through
+    ``aot_compile``, the loader the serving engines use."""
 
-        cache = _cache(tmp_path)
-        jitted = jax.jit(lambda s, b: (s + b.sum(), b.mean()),
-                         donate_argnums=0)
-        args = (jnp.zeros(()), jnp.ones((8, 8)))
-        flops1, fn1 = step_flops_and_fn(jitted, *args, cache=cache,
-                                        cache_label="test")
-        assert cache.stats.stores == 1
-        flops2, fn2 = step_flops_and_fn(
-            jitted, jnp.zeros(()), jnp.ones((8, 8)), cache=cache)
-        assert cache.stats.hits == 1
-        assert flops2 == flops1 and flops2 is not None
-        s1, _ = fn1(jnp.zeros(()), jnp.ones((8, 8)))
-        s2, _ = fn2(jnp.zeros(()), jnp.ones((8, 8)))
-        assert float(s1) == float(s2) == 64.0
-        # without a cache the lowering-analysis path still returns
-        # the original jit fn (no behavior change)
-        flops3, fn3 = step_flops_and_fn(jitted, jnp.zeros(()),
-                                        jnp.ones((8, 8)))
-        assert fn3 is jitted and flops3 == flops1
+    BATCH = {"input_ids": np.arange(3, 67, dtype=np.int32).reshape(4, 16),
+             "pad_mask": np.zeros((4, 16), bool),
+             "valid": np.ones((4,), bool)}
+
+    def _trainer(self, tmp_path, cache_dir):
+        from perceiver_tpu.training import Trainer, TrainerConfig
+
+        trainer = Trainer(
+            _tiny_task(), None,
+            TrainerConfig(default_root_dir=str(tmp_path / "logs"),
+                          enable_checkpointing=False,
+                          exec_cache_dir=cache_dir),
+            optimizer_init={"class_path": "AdamW",
+                            "init_args": {"lr": 1e-3}})
+        state = trainer._build_state()
+        trainer._make_steps()
+        return trainer, state, trainer._shard_batch(dict(self.BATCH))
+
+    def test_store_once_then_hit_with_zero_compiles(self, tmp_path):
+        from perceiver_tpu.cache import compile_events
+
+        cache_dir = str(tmp_path / "ec")
+        trainer, state, batch = self._trainer(tmp_path, cache_dir)
+        stats = trainer._exec_cache.stats
+        cold = trainer._load_step(trainer._train_step, state, batch, "t")
+        assert (stats.stores, stats.hits) == (1, 0)
+        assert cold is not trainer._train_step and trainer._step_loaded
+        _, cold_metrics = cold(state, batch)
+
+        trainer, state, batch = self._trainer(tmp_path, cache_dir)
+        assert trainer._exec_cache.stats is stats   # one cache a directory
+        with compile_events() as compiles:
+            warm = trainer._load_step(trainer._train_step, state, batch,
+                                      "t")
+        assert (stats.stores, stats.hits) == (1, 1)
+        assert compiles == [], compiles
+        _, warm_metrics = warm(state, batch)
+        assert float(warm_metrics["loss"]) == float(cold_metrics["loss"])
+
+    def test_entry_written_by_the_parent_commit_loads(self, tmp_path):
+        """Until PR 30 the trainer stored its step under
+        ``executable_key(text)`` with a sidecar of ``label`` and the
+        cost-analysis ``flops``: such an entry is a hit."""
+        from perceiver_tpu.cache import compile_lowered
+
+        trainer, state, batch = self._trainer(tmp_path,
+                                              str(tmp_path / "ec"))
+        cache = trainer._exec_cache
+        lowered = trainer._train_step.lower(state, batch)
+        cache.store_executable(
+            cache.executable_key(lowered.as_text()),
+            compile_lowered(lowered),
+            sidecar={"label": "trainer:train_step", "flops": 1.5e9})
+        step = trainer._load_step(trainer._train_step, state, batch,
+                                  "trainer:train_step")
+        assert (cache.stats.stores, cache.stats.hits) == (1, 1)
+        _, metrics = step(state, batch)
+        assert np.isfinite(float(metrics["loss"]))
+
+    def test_step_with_host_callbacks_is_never_stored_or_loaded(
+            self, tmp_path):
+        """No shipped task's step calls back to the host
+        (``tasks/mlm.py`` reports an overflow as a scalar for this
+        reason); one that does is compiled fresh in every process."""
+        from perceiver_tpu.cache import has_host_callbacks
+
+        for _ in range(2):
+            trainer, state, batch = self._trainer(tmp_path,
+                                                  str(tmp_path / "ec"))
+            inner = trainer._train_step
+
+            def noisy_step(state, batch):
+                jax.debug.print("step {}", state.step)
+                return inner(state, batch)
+
+            noisy = jax.jit(noisy_step)
+            assert has_host_callbacks(noisy.lower(state, batch).as_text())
+            step = trainer._load_step(noisy, state, batch, "t")
+            assert step is not noisy
+            stats = trainer._exec_cache.stats
+            assert (stats.stores, stats.hits, stats.misses) == (0, 0, 0)
+            assert os.listdir(trainer._exec_cache.path) == []
+            _, metrics = step(state, batch)
+            assert np.isfinite(float(metrics["loss"]))
+
+    def test_without_a_cache_the_jitted_function_stays(self, tmp_path,
+                                                       monkeypatch, capfd):
+        monkeypatch.delenv("PERCEIVER_EXEC_CACHE", raising=False)
+        trainer, state, batch = self._trainer(tmp_path, None)
+        assert trainer._exec_cache is None and not trainer._step_loaded
+        step = trainer._load_step(trainer._train_step, state, batch, "t")
+        assert step is trainer._train_step and trainer._step_loaded
+        # the trace still ran: the tallies have their call sites
+        assert "attention call sites: materialized[backend]=3" in \
+            capfd.readouterr().err
 
 
 # --- engine integration ------------------------------------------------------
@@ -520,27 +597,3 @@ def test_warm_start_full_grid_zero_compiles_across_processes(tmp_path):
     assert warm["buckets"] == [[1, 16], [1, 32], [2, 16], [2, 32]]
     assert warm["out0"] == cold["out0"], \
         "deserialized executables must reproduce compiled outputs"
-
-
-def test_bench_startup_script_cold_warm(tmp_path):
-    """scripts/bench_startup.py emits bench.py-format cold/warm JSON
-    with the warm serving phase compile-free. Slow-marked."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "bench_startup.py"),
-         "--cache-dir", str(tmp_path / "bc"), "--keep-cache"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
-        capture_output=True, text=True, timeout=900)
-    assert r.returncode == 0, f"\n{r.stdout}\n{r.stderr}"
-    lines = [json.loads(line) for line in r.stdout.splitlines()
-             if line.strip().startswith("{")]
-    by_metric = {obj["metric"]: obj for obj in lines}
-    assert set(by_metric) == {"serving_warm_start_speedup",
-                              "trainer_warm_start_speedup"}
-    for obj in lines:
-        assert set(obj) == {"metric", "value", "unit", "vs_baseline",
-                            "detail"}
-        assert obj["unit"] == "x" and obj["value"] > 0
-        assert obj["detail"]["warm_s"] < obj["detail"]["cold_s"]
-        assert obj["detail"]["warm_exec_cache_misses"] == 0
-        assert obj["detail"]["warm_xla_compiles"] == 0

@@ -7,9 +7,9 @@ a tiny synthetic module that violates it (fp32 dot, host callback,
 un-donated state, drifting compile key), a clean twin, and an
 allowlist round-trip where applicable; the lint rules get seeded
 source snippets. The headline-config regression pins
-``bf16_flop_fraction == 1.0`` on the exact B=512/C=64 step bench.py
-times, and the slow full sweep runs what ``scripts/check.py --all``
-gates at merge.
+``bf16_flop_fraction == 1.0`` on the B=512/C=64 headline target, and
+the slow full sweep runs what ``scripts/check.py --all`` gates at
+merge.
 """
 
 import itertools
@@ -1122,7 +1122,7 @@ def test_lint_fleet_package_is_clean():
 
 
 def test_headline_config_bf16_flop_fraction_is_one(lowered_target_cache):
-    """B=512/C=64 packed MLM (bench.py _LADDER[0]): every dot FLOP in
+    """B=512/C=64 packed MLM (the headline target): every dot FLOP in
     the lowered train step runs on bf16 operands — the round-4 audit's
     9.1%-at-fp32 regression, pinned forever."""
     target = CANONICAL_TARGETS[0]

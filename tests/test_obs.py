@@ -657,7 +657,7 @@ def test_obs_server_endpoints():
 def test_telemetry_jsonl_and_counters(tmp_path):
     telemetry = Telemetry(str(tmp_path))
     telemetry.step(10, 1.25, steps_delta=5, steps_per_sec=50.0,
-                   samples_per_sec=1600.0, mfu=0.31)
+                   samples_per_sec=1600.0, exit_entropy=0.31)
     telemetry.step(20, 1.10, steps_delta=10)
     telemetry.guard_skip(21)
     telemetry.guard_rewind(22)
@@ -670,7 +670,7 @@ def test_telemetry_jsonl_and_counters(tmp_path):
         validate_event(event)
     steps = [e for e in lines if e["type"] == "train_step"]
     assert [e["step"] for e in steps] == [10, 20]
-    assert steps[0]["mfu"] == pytest.approx(0.31)  # extras kept
+    assert steps[0]["exit_entropy"] == pytest.approx(0.31)  # extras kept
 
     registry = telemetry.registry
     assert registry.get("training_steps_total").value == 15

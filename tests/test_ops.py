@@ -80,7 +80,7 @@ def test_mha_bf16_backward_has_no_fp32_dots():
     """Under the bf16 policy EVERY attention matmul — including the
     QK backward pair fed by the fp32 softmax cotangent — must run with
     bf16 operands (the TPU executes fp32 dots at a fraction of the
-    bf16 MXU rate; graph audit scripts/hlo_audit.py found the backward
+    bf16 MXU rate; the dot audit of analysis/hlo.py found the backward
     pair at ~9% of headline-step FLOPs before the _qk_dot fix)."""
     import re
 

@@ -166,8 +166,7 @@ def test_mlm_cli_defaults_onecycle():
 def test_config_snapshot_written_before_fit(tmp_path, monkeypatch):
     """The config.yaml snapshot must exist BEFORE training runs
     (reference SaveConfigCallback timing): a preempted/killed run's
-    version dir still identifies its accelerator and hparams — the
-    platform-labeling of evidence (quality_summary.py) depends on it."""
+    version dir still identifies its accelerator and hparams."""
     import os
     import sys
 

@@ -220,7 +220,7 @@ def test_the_trainer_says_what_its_remat_layers_keep(tmp_path, capfd,
     trainer, state = make_trainer(task, tmp_path)
     batch = trainer._shard_batch(BATCHES["perceiver_lm"])
     with remat.remat_keeps() as outer:
-        trainer._load_step(trainer._train_step, state, batch, 1, "t")
+        trainer._load_step(trainer._train_step, state, batch, "t")
     (choice,) = outer
     assert choice["kept"] == REMAT_NAMES and not choice["dropped"]
     assert choice["memory_limit"] is None and choice["why"] is None
@@ -237,7 +237,7 @@ def test_the_trainer_says_what_its_remat_layers_keep(tmp_path, capfd,
     monkeypatch.setattr(remat, "_memory_limit",
                         lambda: int((fits + 1) / remat.KEEP_SHARE))
     trainer, state = make_trainer(task, tmp_path / "small")
-    trainer._load_step(trainer._train_step, state, batch, 1, "t")
+    trainer._load_step(trainer._train_step, state, batch, "t")
     err = capfd.readouterr().err
     assert re.search(
         r"\[step_load\] remat keeps: attn_out,qkv \+ layer_in 0\.00 GB of "

@@ -3,9 +3,9 @@ and its quota arithmetic, per-tenant admission in the unified
 scheduler, the decode engine's page-quota ledger, the fleet RPC error
 envelope, and demand-proportional replica allocation.
 
-The noisy-neighbor *behaviour* gates live in scripts/chaos.py
-(noisy_neighbor) and scripts/bench_decode.py (--tenants); this module
-pins the host-side mechanisms those gates are built from, including
+The noisy-neighbor *behaviour* gate lives in scripts/chaos.py
+(noisy_neighbor); this module pins the host-side mechanisms it is
+built from and the counts of a flooded run, including
 seeded InterleaveScheduler races proving the scheduler's per-tenant
 page budgets are conserved under adversarial interleavings.
 """
@@ -309,6 +309,18 @@ def test_take_quota_conservation_under_seeded_races():
 
 # --- decode engine: page-quota shed + ledger conservation --------------------
 
+def _tiny_task():
+    from perceiver_tpu.tasks import MaskedLanguageModelTask
+
+    return MaskedLanguageModelTask(
+        vocab_size=110, max_seq_len=32, num_latents=4,
+        num_latent_channels=8, num_encoder_layers=1,
+        num_encoder_self_attention_layers_per_block=1,
+        num_encoder_cross_attention_heads=1,
+        num_encoder_self_attention_heads=1,
+        num_decoder_cross_attention_heads=1, loss_impl="dense")
+
+
 def test_decode_engine_quota_shed_and_ledger_conservation():
     """A capped tenant's second concurrent request sheds typed at
     submit — before a slot, a page, or a device token is spent — and
@@ -321,15 +333,8 @@ def test_decode_engine_quota_shed_and_ledger_conservation():
         DecodeResult,
     )
     from perceiver_tpu.serving.engine import RequestTooLarge
-    from perceiver_tpu.tasks import MaskedLanguageModelTask
 
-    task = MaskedLanguageModelTask(
-        vocab_size=110, max_seq_len=32, num_latents=4,
-        num_latent_channels=8, num_encoder_layers=1,
-        num_encoder_self_attention_layers_per_block=1,
-        num_encoder_cross_attention_heads=1,
-        num_encoder_self_attention_heads=1,
-        num_decoder_cross_attention_heads=1, loss_impl="dense")
+    task = _tiny_task()
     geometry = DecodeGeometry(max_streams=2, num_pages=9, page_size=4,
                               max_seq_len=16, max_chunk=4)
     tenancy = TenantRegistry([
@@ -379,6 +384,68 @@ def test_decode_engine_quota_shed_and_ledger_conservation():
         assert len(shed_events) == shed_before + 1
         assert shed_events[-1]["tenant"] == "bronze"
         assert shed_events[-1]["reason"] == "tenant_quota"
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("arm", ["solo", "mixed"])
+def test_gold_loses_nothing_to_a_capped_flood(arm):
+    """Four gold streams alone, then the same four while a
+    best-effort tenant capped at two requests' pages submits two
+    requests before each of them: gold drops and sheds nothing in
+    either arm, the flood's surplus sheds typed before any compute,
+    and no arm compiles. Counts only: stepped by hand, no clock."""
+    from perceiver_tpu.cache import compile_events
+    from perceiver_tpu.serving.decode import DecodeEngine, DecodeGeometry
+
+    task = _tiny_task()
+    streams, flood = 4, 2
+    # 5 + 7 tokens: 3 pages a request; bronze may hold two requests'
+    geometry = DecodeGeometry(max_streams=streams + 2,
+                              num_pages=(streams + 2) * 3 + 1,
+                              page_size=4, max_seq_len=16, max_chunk=4)
+    tenancy = TenantRegistry([
+        TenantSpec(tenant="gold", weight=3.0),
+        TenantSpec(tenant="bronze", priority=PRIORITY_BEST_EFFORT,
+                   weight=1.0, max_pages=6)])
+    engine = DecodeEngine(task, geometry=geometry, tenancy=tenancy,
+                          auto_step=False, max_queue=32,
+                          exec_cache=False)
+    prompt = np.arange(3, 8, dtype=np.int32)
+    gold, bronze, shed = [], [], 0
+    try:
+        with compile_events() as compiles:
+            for _ in range(streams):
+                for _ in range(flood if arm == "mixed" else 0):
+                    try:
+                        bronze.append(engine.submit(
+                            prompt, max_new_tokens=7, tenant="bronze"))
+                    except Unavailable as e:
+                        assert e.reason == "tenant_quota"
+                        shed += 1
+                gold.append(engine.submit(prompt, max_new_tokens=7,
+                                          tenant="gold"))
+                engine.step()
+            engine.run_until_idle()
+        assert compiles == [], compiles
+        for handle in gold:
+            r = handle.result(1.0)
+            assert r.finished == "complete" and len(r.tokens) == 7
+        assert sum(engine._m_tenant_shed.value_of(tenant="gold", reason=r)
+                   for r in ("tenant_quota", "queue_full",
+                             "deadline")) == 0
+        assert engine._m_tenant_tokens.value_of(tenant="gold") == 7 * streams
+        completed = sum(h.result(1.0).finished == "complete"
+                        for h in bronze)
+        assert engine._m_tenant_shed.value_of(
+            tenant="bronze", reason="tenant_quota") == shed
+        if arm == "mixed":
+            assert shed >= 1
+            assert len(bronze) + shed == flood * streams
+            assert 1 <= completed <= flood * streams - shed
+        else:
+            assert (shed, completed) == (0, 0)
+        assert all(v == 0 for v in engine._tenant_pages.values())
     finally:
         engine.close()
 

@@ -618,6 +618,26 @@ def test_fit_writes_phase_seconds_to_telemetry(phase_fit):
     assert registry.get("training_host_busy_seconds_total").value > 0
 
 
+def test_fit_reports_throughput_and_no_utilisation(phase_fit):
+    """The program counts no FLOPs, so it writes no ``mfu``: not as a
+    summary scalar, not on the telemetry line. Throughput stays."""
+    import json
+
+    tmp_path, trainer, _ = phase_fit
+    with open(tmp_path / "telemetry" / "telemetry.jsonl") as f:
+        steps = [e for e in map(json.loads, f) if e["type"] == "train_step"]
+    assert steps and all("mfu" not in e for e in steps)
+    # the first line's window was reset after the step's compile
+    assert all(e["samples_per_sec"] > 0 and e["steps_per_sec"] > 0
+               for e in steps[1:])
+    (events,) = [n for n in os.listdir(trainer.log_dir)
+                 if n.startswith("events.out.tfevents")]
+    with open(os.path.join(trainer.log_dir, events), "rb") as f:
+        tags = f.read()       # a scalar's tag is plain bytes in its record
+    assert b"samples_per_sec" in tags and b"train_loss" in tags
+    assert b"mfu" not in tags
+
+
 def test_fit_with_tracing_off_records_nothing_and_omits_the_fields(tmp_path):
     import json
 
