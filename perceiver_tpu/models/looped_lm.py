@@ -27,10 +27,15 @@ compiled layer body, as the Perceiver encoder's ``selfs``); the passes
 are a second scan over the same tree, so the gradient of every layer's
 weights is the sum over the passes and the optimizer holds one copy.
 With ``remat`` every layer application is recomputed on the backward
-pass: what is saved is each application's input, ``T x L`` states of
+pass from what the forward saved of it: its input, ``T x L`` states of
 ``(B, S, C)`` in the compute dtype (and each pass's state before the
-final norm), and everything inside a layer (norms, projections, the
-attention kernel's forward, the MLP) runs again.
+final norm), and the dear values it names (``ops/remat.py``: the fused
+core's float32 output and log-sum-exp first, then the projections'
+product, then the MLP's two) as far as they fit the device beside what
+it already holds, chosen by the reckoning the Perceiver encoder uses
+(``LoopedLM._remat_keeps``). A kept value is not computed again; the
+rest of a layer (norms, rope, residual sums, the products not kept)
+is.
 
 ``remat`` is a hand-written backward pass (``_loop_stack``), not
 ``jax.checkpoint`` under autodiff, for the memory's sake: the
@@ -42,7 +47,12 @@ GB of temporaries a layer at the published widths, where one copy of
 the gradient is 0.2, and the step no longer fits the chip it was sized
 for. The backward here walks the ``T x L`` applications in reverse and
 adds each one's gradient into one stacked accumulator in place; the
-loops carry the matrices in the compute dtype, cast once.
+loops carry the matrices in the compute dtype, cast once. Only an
+application's input and its kept values go through the stacks: the
+vjp of an application is built on the backward pass
+(``remat.vjp_handing``), where its parameters are a slice of the
+stack that is there anyway (built on the forward pass, it would count
+them among its residuals, a copy a pass and a layer).
 
 Training never materialises the ``(T, B, S, V)`` logits: the task
 takes ``hidden_states`` and reads the head through
@@ -60,7 +70,13 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
-from perceiver_tpu.ops.attention import mha_apply, mha_init
+from perceiver_tpu.ops import remat
+from perceiver_tpu.ops.attention import (
+    data_shards,
+    mha_apply,
+    mha_init,
+    untallied,
+)
 from perceiver_tpu.ops.fourier import rope_tables
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.linear import linear_apply
@@ -110,69 +126,98 @@ def _cast_matrices(policy: Policy, layers):
         lambda x: policy.cast_param(x) if x.ndim >= 3 else x, layers)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _loop_stack(layer_fn, norm_fn, passes, policy, layers, norm, h):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _loop_stack(layer_fn, norm_fn, passes, policy, kept, layers, norm, h):
     """``passes`` times ``norm_fn(norm, scan(layer_fn over layers))``,
     each pass fed the one before: the normed state of every pass,
     ``(passes, *h.shape)``. ``layer_fn(layer_params, h)`` and
-    ``norm_fn(norm_params, h)`` close over nothing traced."""
-    return _loop_stack_fwd(layer_fn, norm_fn, passes, policy, layers, norm,
-                           h)[0]
+    ``norm_fn(norm_params, h)`` close over nothing traced. ``kept``:
+    the names (``ops/remat.py``) whose values the backward is handed
+    beside each application's input; it changes no value."""
+    return _loop_stack_fwd(layer_fn, norm_fn, passes, policy, kept, layers,
+                           norm, h)[0]
 
 
-def _loop_stack_fwd(layer_fn, norm_fn, passes, policy, layers, norm, h):
+def _loop_stack_fwd(layer_fn, norm_fn, passes, policy, kept, layers, norm,
+                    h):
     # the float32 parameters are read here and nowhere else: a donated
     # buffer that a while loop carries is copied for it
     compute = _cast_matrices(policy, layers)
-
-    def one_pass(h, _):
-        def layer(h, layer_params):
-            return layer_fn(layer_params, h), h    # saved: its input
-
-        x, inputs = jax.lax.scan(layer, h, compute)
-        out = norm_fn(norm, x)
-        return out, (out, inputs, x)
-
-    _, (states, inputs, before_norm) = jax.lax.scan(
-        one_pass, h, None, length=passes)
-    return states, (compute, norm, inputs, before_norm)
-
-
-def _loop_stack_bwd(layer_fn, norm_fn, passes, policy, res, ct_states):
-    compute, norm, inputs, before_norm = res
     num_layers = jax.tree.leaves(compute)[0].shape[0]
+    # What the backward is handed of every application, its input and
+    # its kept values: one stack a value over all ``passes x layers``
+    # applications, carried through both loops and written by slice.
+    # Left to the loops' own stacked outputs a value is copied again
+    # and again (a layer's into its pass's stack, a pass's stack into
+    # the stack of all, and back the same way): 27 ms a step for
+    # 2.2 GB where this takes 14 (PERF.md, PR 32).
+    with untallied():
+        saved = jax.eval_shape(
+            lambda p, x: (x, remat.taking(kept, layer_fn, p, x)[1]),
+            remat.layer_shapes(compute), h)
+    stacks = jax.tree.map(
+        lambda a: jnp.zeros((passes * num_layers, *a.shape), a.dtype), saved)
 
-    def layer_bwd(carry, xs):
-        g_layers, ct = carry
-        index, layer_params, h_in = xs
-        # jax.checkpoint for its mark alone: the forward it recomputes
-        # carries ``rematted_computation`` in its name stack and the
-        # transposed operations do not, as under autodiff; the primal
-        # pass of this vjp has no reader and is dropped as dead code
-        _, vjp = jax.vjp(jax.checkpoint(layer_fn), layer_params, h_in)
-        g, ct = vjp(ct)
-        # one stacked fp32 accumulator, added into in place: a slice
-        # read, added to and written back (``acc.at[index].add`` lowers
-        # to a scatter that passes over the whole stack every time)
-        g_layers = jax.tree.map(
-            lambda acc, x: jax.lax.dynamic_update_index_in_dim(
-                acc, jax.lax.dynamic_index_in_dim(
-                    acc, index, 0, keepdims=False) + x.astype(acc.dtype),
-                index, 0),
-            g_layers, g)
-        return (g_layers, ct), None
+    def one_pass(carry, t):
+        def layer(carry, xs):
+            h, stacks = carry
+            index, layer_params = xs
+            out, values = remat.taking(kept, layer_fn, layer_params, h)
+            stacks = jax.tree.map(
+                lambda stack, x: jax.lax.dynamic_update_index_in_dim(
+                    stack, x, t * num_layers + index, 0),
+                stacks, (h, values))
+            return (out, stacks), None
+
+        (x, stacks), _ = jax.lax.scan(
+            layer, carry, (jnp.arange(num_layers), compute))
+        out = norm_fn(norm, x)
+        return (out, stacks), (out, x)
+
+    (_, stacks), (states, before_norm) = jax.lax.scan(
+        one_pass, (h, stacks), jnp.arange(passes))
+    return states, (compute, norm, stacks, before_norm)
+
+
+def _loop_stack_bwd(layer_fn, norm_fn, passes, policy, kept, res,
+                    ct_states):
+    del kept    # what was kept is in the stacks
+    compute, norm, stacks, before_norm = res
+    num_layers = jax.tree.leaves(compute)[0].shape[0]
 
     def pass_bwd(carry, xs):
         g_layers, g_norm, ct_next = carry
-        ct_state, inputs_t, before_norm_t = xs
+        t, ct_state, before_norm_t = xs
+
+        def layer_bwd(carry, xs):
+            g_layers, ct = carry
+            index, layer_params = xs
+            h_in, values = jax.tree.map(
+                lambda stack: jax.lax.dynamic_index_in_dim(
+                    stack, t * num_layers + index, 0, keepdims=False),
+                stacks)
+            g, ct = remat.vjp_handing(values, layer_fn, layer_params,
+                                      h_in)(ct)
+            # one stacked fp32 accumulator, added into in place: a
+            # slice read, added to and written back (``acc.at[index]
+            # .add`` lowers to a scatter that passes over the whole
+            # stack every time)
+            g_layers = jax.tree.map(
+                lambda acc, x: jax.lax.dynamic_update_index_in_dim(
+                    acc, jax.lax.dynamic_index_in_dim(
+                        acc, index, 0, keepdims=False)
+                    + x.astype(acc.dtype), index, 0),
+                g_layers, g)
+            return (g_layers, ct), None
+
         # a pass's state is read by the head and the gate and feeds
         # the next pass
         _, vjp = jax.vjp(norm_fn, norm, before_norm_t)
         g, ct = vjp(ct_state + ct_next)
         g_norm = jax.tree.map(jnp.add, g_norm, g)
         (g_layers, ct), _ = jax.lax.scan(
-            layer_bwd, (g_layers, ct),
-            (jnp.arange(num_layers), compute, inputs_t), reverse=True)
+            layer_bwd, (g_layers, ct), (jnp.arange(num_layers), compute),
+            reverse=True)
         return (g_layers, g_norm, ct), None
 
     def zeros(tree):
@@ -182,18 +227,16 @@ def _loop_stack_bwd(layer_fn, norm_fn, passes, policy, res, ct_states):
     (g_layers, g_norm, ct_h), _ = jax.lax.scan(
         pass_bwd, (zeros(compute), zeros(norm),
                    jnp.zeros_like(ct_states[0])),
-        (ct_states, inputs, before_norm), reverse=True)
+        (jnp.arange(passes), ct_states, before_norm), reverse=True)
     return g_layers, g_norm, ct_h
 
 
 _loop_stack.defvjp(_loop_stack_fwd, _loop_stack_bwd)
 
 
-def _plain_loop_stack(layer_fn, norm_fn, passes, policy, layers, norm, h):
+def _plain_loop_stack(layer_fn, norm_fn, passes, layers, norm, h):
     """The same loop under plain autodiff (``remat`` off): every
     activation of every application is kept."""
-    del policy
-
     def one_pass(h, _):
         h, _ = jax.lax.scan(lambda h, p: (layer_fn(p, h), None), h, layers)
         h = norm_fn(norm, h)
@@ -229,7 +272,8 @@ class LoopedLM:
     total_ut_steps: int = 4
     rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
-    # recompute every layer application on the backward pass
+    # recompute every layer application on the backward pass, but for
+    # the dear values that fit the device (ops/remat.py)
     remat: bool = False
     # None picks the attention core per call site; "einsum"/"flash"
     # force one (ops/attention.py)
@@ -265,6 +309,21 @@ class LoopedLM:
                      "b": jnp.zeros((1,), jnp.float32)},
         }
 
+    def _remat_keeps(self, layer, layers, h):
+        """The names whose values the backward is handed, as
+        ``PerceiverEncoder._remat_policy`` chooses its save list: one
+        layer application differentiated for its shapes alone says what
+        its names would hold; ``choose_keeps`` takes the bytes of all
+        ``total_ut_steps x num_layers`` applications on one device
+        against what the device has left."""
+        with untallied():
+            named = remat.named_bytes(layer, remat.layer_shapes(layers), h)
+        count, shards = self.total_ut_steps * self.num_layers, data_shards(h)
+        held = {name: count * named[name] // shards
+                for name in remat.REMAT_NAMES}
+        return remat.choose_keeps(
+            held, count * h.size * h.dtype.itemsize // shards)
+
     def hidden_states(self, params, input_ids, *,
                       policy: Policy = DEFAULT_POLICY):
         """The normed state after every pass, ``(T, B, S, C)`` in the
@@ -287,9 +346,14 @@ class LoopedLM:
             return rms_norm_apply(norm_params, h, self.rms_norm_eps, policy)
 
         with device_scope("loop_stack"):
-            loop = _loop_stack if self.remat else _plain_loop_stack
-            return loop(layer, final_norm, self.total_ut_steps, policy,
-                        params["layers"], params["norm"], h)
+            if not self.remat:
+                return _plain_loop_stack(
+                    layer, final_norm, self.total_ut_steps, params["layers"],
+                    params["norm"], h)
+            kept = self._remat_keeps(layer, params["layers"], h)
+            return _loop_stack(layer, final_norm, self.total_ut_steps,
+                               policy, kept, params["layers"],
+                               params["norm"], h)
 
     @device_scope("exit_gate")
     def gate_logits(self, params, states):

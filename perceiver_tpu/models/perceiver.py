@@ -59,7 +59,12 @@ from perceiver_tpu.ops.dropout import dropout
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.mlp import mlp_init, mlp_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
-from perceiver_tpu.ops.remat import REMAT_NAMES, choose_keeps, reckoning
+from perceiver_tpu.ops.remat import (
+    REMAT_NAMES,
+    choose_keeps,
+    layer_shapes,
+    named_bytes,
+)
 
 
 def _rng_or_dummy(rng, deterministic: bool = True):
@@ -250,25 +255,18 @@ class PerceiverEncoder:
     def _remat_policy(self, cross_layer, self_layer, layer_params, kv_heads,
                       latent, key):
         """The save list of this encoder's checkpointed layers: each
-        layer differentiated once for its shapes alone (a custom VJP
-        names values in its forward rule) says what its names would
-        hold; ``choose_keeps`` takes the bytes of all layer
+        layer differentiated once for its shapes alone says what its
+        names would hold; ``choose_keeps`` takes the bytes of all layer
         applications on one device (rows split over a mesh's ``data``
-        axis) against the device's memory."""
-        one_self = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
-            layer_params["selfs"])
-
+        axis) against what the device has left."""
         def named_by(layer, *args):
-            with untallied(), reckoning() as held:
-                jax.eval_shape(
-                    lambda *a: jax.vjp(lambda *b: layer(*b, key), *a)[0],
-                    *args)
-            return held
+            with untallied():
+                return named_bytes(lambda *a: layer(*a, key), *args)
 
         cross = named_by(cross_layer, layer_params["cross"], kv_heads,
                          latent)
-        selfs = named_by(self_layer, one_self, latent)
+        selfs = named_by(self_layer, layer_shapes(layer_params["selfs"]),
+                         latent)
         n_cross = self.num_layers
         n_self = n_cross * self.num_self_attention_layers_per_block
         shards = data_shards(latent)

@@ -86,7 +86,7 @@ def gated_mlp_init(key, dim: int, hidden: int, dtype=jnp.float32):
 
 @device_scope("mlp")
 def gated_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
-    gate = linear_apply(params["gate"], x, policy=policy)
-    up = linear_apply(params["up"], x, policy=policy)
+    gate = dear(linear_apply(params["gate"], x, policy=policy), "mlp_hidden")
+    up = dear(linear_apply(params["up"], x, policy=policy), "mlp_hidden")
     return linear_apply(params["down"], jax.nn.silu(gate) * up,
                         policy=policy)

@@ -1,8 +1,6 @@
 """What crosses the recomputation boundary of a ``remat`` layer.
 
-``remat: true`` means "do not hold a layer's activations": each
-attention layer (cross or self, with its MLP) is a ``jax.checkpoint``
-of its own (``models/perceiver.PerceiverEncoder``), so the backward
+``remat: true`` means "do not hold a layer's activations": the backward
 pass recomputes one layer at a time from that layer's input. It does
 not mean "hold nothing": values that are dear to compute and cheap to
 hold are named where they are made (``dear``) and cross the boundary by
@@ -10,6 +8,21 @@ name; the backward recomputes what costs a pass over memory (norms,
 GELU, casts, slices, residual sums) and the one product whose saved
 copy would cost as much (the out-projection's). No arithmetic changes:
 a named value is the value, saved instead of computed again.
+
+Two stacks honour the names, each in the way its backward is made:
+
+- ``models/perceiver.PerceiverEncoder``: each attention layer (cross or
+  self, with its MLP) is a ``jax.checkpoint`` of its own under
+  autodiff, and the kept names are its save list
+  (``save_only_these_names``).
+- ``models/looped_lm.LoopedLM``: the backward over its passes is
+  written by hand (one gradient accumulator, added into by slice), so
+  the values go by hand too: the forward takes the kept names' values
+  out of a layer application (``taking``) and stacks them beside its
+  input, the backward builds the application's vjp with them handed
+  back in (``vjp_handing``).
+
+Which names those are is one reckoning for both (``choose_keeps``).
 
 ``REMAT_NAMES`` is the list in order of time bought per byte held
 (chip runs, PERF.md, PR 29: 3.0, 2.1 and 1.0 ms a step and GB in
@@ -23,7 +36,9 @@ a named value is the value, saved instead of computed again.
 - ``qkv``: the projections' product (``ops/attention._project``): the
   packed (B, L, 3E) buffer before it is sliced, the lone q projection
   where the keys and values are hoisted out of the layer.
-- ``mlp_hidden``: ``fc1``'s product, before the GELU (``ops/mlp``).
+- ``mlp_hidden``: ``fc1``'s product, before the GELU; in the gated MLP
+  the gate's and the up-projection's products, before the SiLU
+  (``ops/mlp``).
 
 Not on the list, because holding them bought no time on the chip: the
 latent after the attention's residual add (the out-projection's product
@@ -34,16 +49,19 @@ tally's line).
 
 Which names are kept is chosen, not configured (``pick_remat_keeps``):
 the longest prefix of the list whose bytes, reckoned over all layer
-applications from the shapes one traced layer reports, fit beside the
-layers' inputs in ``KEEP_SHARE`` of the device's memory as the backend
-reports it. A device that reports none (the CPU) keeps the whole list.
+applications from the shapes one traced layer reports (``named_bytes``),
+fit beside the layers' inputs in ``KEEP_SHARE`` of what the device has
+left: the memory the backend reports less the bytes it reports in use
+while the step is traced, which under ``Trainer._load_step`` are the
+built state (parameters and optimizer moments) and the waiting batches.
+A device that reports no memory (the CPU) keeps the whole list.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-from typing import Iterator, Mapping, Optional, Tuple
+from typing import Iterator, Mapping, Optional, Sequence, Tuple
 
 import jax
 from jax.ad_checkpoint import checkpoint_name
@@ -51,28 +69,38 @@ from jax.ad_checkpoint import checkpoint_name
 #: the dear values, dearest per byte first
 REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 
-#: The share of the device's memory the kept values and the layers'
-#: inputs may take together. From chip runs (PERF.md, Findings, PR 29):
-#: ``lm_train`` (24 rows of 1024 x 512 latents, 39 layers) reckons
-#: 6.75 GB of a v5e's 16.91 (its budget 10.15) and peaks at 9.7, the
-#: 3 GB on top being state, loss and one layer's recomputation; twice
-#: its rows keep ``attn_out`` alone, four times its rows nothing.
+#: The share of what the device has left that the kept values and the
+#: layers' inputs may take together. From chip runs (PERF.md, Findings,
+#: PR 29): ``lm_train`` (24 rows of 1024 x 512 latents, 39 layers)
+#: reckons 6.75 GB of a v5e's 16.91 beside 0.94 GB of state (its budget
+#: 9.58) and peaks at 9.7, the rest being state, loss and one layer's
+#: recomputation; twice its rows keep ``attn_out`` alone, four times
+#: its rows nothing. PR 32: ``ouro_train`` (7.35 GB of state) reckons
+#: 3.23 GB with ``attn_out`` against a budget of 5.74; ``qkv`` would
+#: make 6.45.
 KEEP_SHARE = 0.6
 
 # Bytes the names report while a layer is traced for its shapes, by
 # name; the innermost recorder of a trace counts (``reckoning``).
 _RECORDERS = []
 
+# What a hand-written backward does with a named value where it is
+# made, ``(x, name) -> x``; the innermost exchange of a trace acts
+# (``taking``, ``vjp_handing``).
+_EXCHANGES = []
+
 
 def dear(x, name: str):
     """``x``, named: under a ``remat`` layer's checkpoint it is saved if
-    ``name`` is kept and recomputed if not; anywhere else an identity
-    (no operation is lowered for it)."""
+    ``name`` is kept and recomputed if not; under ``taking`` it is taken
+    out, under ``vjp_handing`` the kept value stands in its place;
+    anywhere else an identity (no operation is lowered for it)."""
     if name not in REMAT_NAMES:
         raise ValueError(f"{name!r} is not one of {REMAT_NAMES}")
     if _RECORDERS:
         _RECORDERS[-1][name] += x.size * x.dtype.itemsize
-    return checkpoint_name(x, name)
+    x = checkpoint_name(x, name)
+    return _EXCHANGES[-1](x, name) if _EXCHANGES else x
 
 
 @contextlib.contextmanager
@@ -86,24 +114,134 @@ def reckoning() -> Iterator[collections.Counter]:
         _RECORDERS.remove(held)
 
 
+def layer_shapes(stacked):
+    """The shapes of one layer's parameters, cut from their stack (the
+    leading axis a scan runs over): what ``named_bytes`` takes."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
+
+
+def named_bytes(layer, *args) -> collections.Counter:
+    """Bytes by name of what one application of ``layer`` names:
+    differentiated once for its shapes alone, because a custom VJP
+    names values in its forward rule. ``args`` may be shapes."""
+    with reckoning() as held:
+        jax.eval_shape(lambda *a: jax.vjp(layer, *a)[0], *args)
+    return held
+
+
+# --- the values by hand, for a backward that is written by hand --------------
+
+
+@contextlib.contextmanager
+def _exchanging(exchange):
+    _EXCHANGES.append(exchange)
+    try:
+        yield
+    finally:
+        _EXCHANGES.remove(exchange)
+
+
+def taking(names: Sequence[str], layer, *args):
+    """``(layer(*args), values)``: the application's output and the
+    values it names with a name of ``names``, by name in the order they
+    are made. With a name to take, the application is run as the
+    forward half of its vjp, so that a custom VJP runs the forward rule
+    in which it names what it hands its backward (the fused core's
+    float32 output and log-sum-exp: one run of the kernel, in the form
+    that hands them out); the linear half has no reader and is dropped
+    as dead code. With none it is ``layer(*args)`` and nothing else."""
+    if not names:
+        return layer(*args), {}
+
+    def tapped(*a):
+        taken = {name: [] for name in names}
+
+        def take(x, name):
+            if name in taken:
+                taken[name].append(x)
+            return x
+
+        with _exchanging(take):
+            return layer(*a), taken
+
+    out, _, taken = jax.vjp(tapped, *args, has_aux=True)
+    return out, taken
+
+
+@jax.custom_vjp
+def _in_place_of(x, kept):
+    """``kept``, which is ``x`` as an earlier run made it: nothing that
+    only ``x``'s value needed runs again; the gradient is ``x``'s."""
+    del x
+    return kept
+
+
+_in_place_of.defvjp(lambda x, kept: (kept, None),
+                    lambda _, g: (g, None))
+
+
+def vjp_handing(values: Mapping[str, Sequence], layer, *args):
+    """The vjp function of ``layer`` at ``args`` with ``values`` (what
+    ``taking`` took from the same application) standing in place of
+    the values the recomputed forward would name: what made them is
+    not run again. Under ``jax.checkpoint``, as a ``remat`` layer under
+    autodiff is: the forward it recomputes carries
+    ``rematted_computation`` in its name stack, the transposed
+    operations do not; the primal pass of this vjp has no reader and
+    is dropped as dead code."""
+    left = {name: list(kept) for name, kept in values.items()}
+
+    def hand(x, name):
+        if name not in left:
+            return x
+        kept = left[name].pop(0)
+        if (kept.shape, kept.dtype) != (x.shape, x.dtype):
+            raise ValueError(
+                f"{name}: kept {kept.dtype}{list(kept.shape)} for a value "
+                f"{x.dtype}{list(x.shape)}: not the same application")
+        return _in_place_of(x, kept)
+
+    # the exchange is open while the vjp is built, not while ``layer``
+    # is first traced alone: a custom VJP's forward rule is traced when
+    # the checkpointed layer is differentiated. A function of its own
+    # every time: ``jax.checkpoint`` remembers a traced function, and
+    # this one closes over ``values``.
+    with _exchanging(hand):
+        _, vjp = jax.vjp(jax.checkpoint(lambda *a: layer(*a)), *args)
+    if any(left.values()):
+        raise ValueError(
+            f"kept values the application did not name again: "
+            f"{ {n: len(v) for n, v in left.items() if v} }")
+    return vjp
+
+
+# --- which names are kept ----------------------------------------------------
+
+
 def pick_remat_keeps(bytes_by_name: Mapping[str, int], *,
-                     layer_in_bytes: int, memory_limit: Optional[int]
+                     layer_in_bytes: int, memory_limit: Optional[int],
+                     memory_held: int = 0
                      ) -> Tuple[Tuple[str, ...], Optional[str]]:
     """``(kept, why_not_all)``: the longest prefix of ``REMAT_NAMES``
     whose bytes fit, beside the layers' inputs, in ``KEEP_SHARE`` of
-    ``memory_limit``; the reason names the first one dropped. All bytes
-    are one device's, over all layer applications. ``memory_limit``
-    None (a backend that reports no limit) keeps every name."""
+    what ``memory_held`` leaves of ``memory_limit``; the reason names
+    the first one dropped. All bytes are one device's, over all layer
+    applications. ``memory_limit`` None (a backend that reports no
+    limit) keeps every name."""
     if memory_limit is None:
         return REMAT_NAMES, None
-    budget = KEEP_SHARE * memory_limit
+    budget = KEEP_SHARE * (memory_limit - memory_held)
     total = layer_in_bytes
     for i, name in enumerate(REMAT_NAMES):
         total += bytes_by_name.get(name, 0)
         if total > budget:
-            return REMAT_NAMES[:i], (
-                f"{name} would make {total / 1e9:.2f} GB of "
-                f"{budget / 1e9:.2f}")
+            why = (f"{name} would make {total / 1e9:.2f} GB of "
+                   f"{budget / 1e9:.2f}")
+            if memory_held:
+                why += (f", {KEEP_SHARE:g} of what {memory_held / 1e9:.2f} "
+                        "GB in use leave")
+            return REMAT_NAMES[:i], why
     return REMAT_NAMES, None
 
 
@@ -114,16 +252,26 @@ def _memory_limit() -> Optional[int]:
     return stats.get("bytes_limit") if stats else None
 
 
-# Trace-time tally of what the ``remat`` encoders traced inside the
-# block chose, in the style of ``ops.attention.attention_paths``.
+def _memory_held() -> int:
+    """The bytes the first local device reports in use now, while the
+    step is traced: what the step will find there (under
+    ``Trainer._load_step`` the built state and the waiting batches). A
+    seam as above."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_in_use", 0) if stats else 0
+
+
+# Trace-time tally of what the ``remat`` stacks traced inside the block
+# chose, in the style of ``ops.attention.attention_paths``.
 _KEEP_TALLIES = []
 
 
 @contextlib.contextmanager
 def remat_keeps() -> Iterator[list]:
-    """The choices made inside the block, one dict an encoder traced:
-    ``kept``, ``dropped``, ``why``, ``bytes`` (by name, ``layer_in``
-    among them) and ``memory_limit``."""
+    """The choices made inside the block, one dict a stack traced (an
+    encoder, a looped decoder stack): ``kept``, ``dropped``, ``why``,
+    ``bytes`` (by name, ``layer_in`` among them), ``memory_limit`` and
+    ``memory_held``."""
     choices = []
     _KEEP_TALLIES.append(choices)
     try:
@@ -135,10 +283,10 @@ def remat_keeps() -> Iterator[list]:
 def choose_keeps(bytes_by_name: Mapping[str, int], layer_in_bytes: int
                  ) -> Tuple[str, ...]:
     """``pick_remat_keeps`` against this process's device, tallied."""
-    limit = _memory_limit()
+    limit, held = _memory_limit(), _memory_held()
     kept, why = pick_remat_keeps(bytes_by_name,
                                  layer_in_bytes=layer_in_bytes,
-                                 memory_limit=limit)
+                                 memory_limit=limit, memory_held=held)
     choice = {
         "kept": kept,
         "dropped": tuple(n for n in REMAT_NAMES if n not in kept),
@@ -146,6 +294,7 @@ def choose_keeps(bytes_by_name: Mapping[str, int], layer_in_bytes: int
         "bytes": {"layer_in": layer_in_bytes,
                   **{n: bytes_by_name.get(n, 0) for n in REMAT_NAMES}},
         "memory_limit": limit,
+        "memory_held": held,
     }
     for choices in _KEEP_TALLIES:
         choices.append(choice)
@@ -154,7 +303,7 @@ def choose_keeps(bytes_by_name: Mapping[str, int], layer_in_bytes: int
 
 def format_remat_keeps(choices) -> str:
     """``attn_out,qkv,mlp_hidden + layer_in 6.75 GB of 16.91`` — one
-    log line's worth; ``none traced`` where no encoder with ``remat``
+    log line's worth; ``none traced`` where no stack with ``remat``
     was."""
     parts = []
     for c in choices:

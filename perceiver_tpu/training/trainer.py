@@ -382,7 +382,10 @@ class Trainer:
                 for k, v in batch.items()}
 
     def _make_steps(self):
-        task, model, policy = self.task, self.model, self.policy
+        # the steps close over these and not over the trainer: a
+        # trainer its own jitted step refers back to is freed, with its
+        # state on the device, only when the cycle collector next runs
+        task, model, policy, tx = self.task, self.model, self.policy, self.tx
 
         def train_step(state: TrainState, batch):
             rng, step_rng = jax.random.split(state.rng)
@@ -395,7 +398,7 @@ class Trainer:
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
             (_, metrics), grads = grad_fn(state.params)
             with device_scope("optimizer"):
-                updates, opt_state = self.tx.update(
+                updates, opt_state = tx.update(
                     grads, state.opt_state, state.params)
                 params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state,

@@ -8,21 +8,25 @@ Compiles (compile ONLY — no execution) the full train step of:
 2. the v5p-16 Perceiver-LM MLM preset (1024×512 latents, 12 self-attn
    layers/block, seq 2048; BASELINE configs[4]) at its per-chip shard,
 3. (``seg``) the 512×512 / 262,144-query segmentation config,
+4. (``ouro``) the looped causal LM of the benchmark's ``ouro_train``
+   (``benchmarks/configs/ouro_2p6b.json``: 8 layers x 4 passes at the
+   published widths, 2 rows of 4096),
 
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm`` and ``224`` run ``remat: true``: beside XLA's sizes they print
-which dear values the encoder's layers keep and the bytes it reckoned
+``lm``, ``224`` and ``ouro`` run ``remat: true``: beside XLA's sizes
+they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
-(the fused attention core; the chip's memory, ``DESCRIBED_MEMORY``).
+(the fused attention core; the chip's memory, ``DESCRIBED_MEMORY``; in
+use on it, the parameters and optimizer state the step is handed).
 
-Usage: python scripts/aot_memcheck.py [224 | lm | seg | all] [rows]
-       (``rows``: the per-chip batch of ``224`` / ``lm`` in place of
-       the preset's)
+Usage: python scripts/aot_memcheck.py [224 | lm | seg | ouro | all] [rows]
+       (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` in
+       place of the preset's; ``all`` leaves ``ouro`` out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -105,6 +109,10 @@ def _compile_train_step(task, batch, label):
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=topo_sh), t)
         params, opt_state = retarget(params), retarget(opt_state)
+        import perceiver_tpu.ops.remat as remat
+        state_bytes = sum(x.size * x.dtype.itemsize
+                          for x in jax.tree.leaves((params, opt_state)))
+        remat._memory_held = lambda: state_bytes
         batch = retarget({k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                           for k, v in batch.items()})
 
@@ -204,6 +212,21 @@ def check_seg(batch: int = 2, side: int = 512):
     return _compile_train_step(task, batch_arrs, f"seg{side}_b{batch}")
 
 
+def check_ouro(per_chip_batch: int = 2):
+    """The benchmark's ``ouro_2p6b`` as ``ouro_train`` runs it: the
+    ``model`` group of its configuration file, full rows."""
+    import jax.numpy as jnp
+
+    from perceiver_tpu.tasks import CausalLMTask
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "ouro_2p6b.json")) as f:
+        model = json.load(f)["model"]
+    batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
+                                    jnp.int32)}
+    return _compile_train_step(CausalLMTask(**model), batch, "ouro")
+
+
 def main():
     import jax
 
@@ -221,6 +244,8 @@ def main():
         out["perceiver_lm_v5p16_shard"] = check_lm(**rows)
     if which in ("seg", "all"):
         out["seg_512_262k_queries"] = check_seg()
+    if which == "ouro":
+        out["ouro_2p6b_8_layers"] = check_ouro(**rows)
     print(json.dumps(out, indent=2))
 
 

@@ -162,8 +162,8 @@ def test_scopes_change_metadata_and_nothing_else(name, remat, tmp_path,
 # (models/looped_lm.py): loop_stack around the passes, decoder_layer
 # around one layer application, exit_gate and exit_loss beside them; the
 # inner scopes are the shared ones, so the class readers read this model
-# unedited. Its backward pass is written by hand: the recomputed
-# forward carries JAX's own mark.
+# unedited. Its backward pass is written by hand: what it recomputes of
+# the forward carries JAX's own mark.
 
 from benchmarks.scope_times import names_of  # noqa: E402
 from perceiver_tpu.obs.trace import DEVICE_SCOPES  # noqa: E402
@@ -227,12 +227,17 @@ def test_looped_passes_are_marked_in_the_hand_written_backward(looped_ops):
                 and "rematted_computation" not in n]
     forward = [n for n in core if "transpose(" not in n
                and "rematted_computation" not in n]
-    assert forward and recomputed and backward
+    assert forward and backward
     if fused:
+        # the kernel's float32 output and log-sum-exp are kept (the CPU
+        # keeps every name, ops/remat.py): its forward is not run again
+        assert not recomputed
         assert all("causal_attention_fwd" in n for n in forward)
-        assert all("causal_attention_fwd" in n for n in recomputed)
         assert any("causal_attention_bwd" in n for n in backward)
         assert not any("flash_attention_" in n for n in core)
+    else:
+        # the materialised core names no value: it is rebuilt
+        assert recomputed
     mlp = [n for code, n in ops if code == "dot"
            and "mlp" in looped_scopes(n)]
     assert any("rematted_computation" in n for n in mlp)

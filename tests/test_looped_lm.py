@@ -11,8 +11,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
+import perceiver_tpu.models.looped_lm as looped_lm
 import perceiver_tpu.ops.attention as attn
+import perceiver_tpu.ops.remat as remat
 from perceiver_tpu.models.looped_lm import (
     LoopedLM,
     decoder_layer_apply,
@@ -369,23 +372,106 @@ def test_weight_sharing_the_gradient_is_the_sum_over_the_passes(toy):
             assert rel(a, b) < 1e-4
 
 
+def kernel_calls(jaxpr, name, live_out=None):
+    """``(calls, inputs read)``: the Pallas calls named ``name`` that a
+    live output of ``jaxpr`` needs, a scan's body counted once an
+    iteration. Liveness goes through a custom VJP's call as through any
+    other (``remat._in_place_of`` reads one of its two arguments), which
+    JAX's own dead-code pass over a jaxpr leaves whole for XLA."""
+    live_out = [True] * len(jaxpr.outvars) if live_out is None else live_out
+    live = {v for v, on in zip(jaxpr.outvars, live_out)
+            if on and not isinstance(v, Literal)}
+    calls = 0
+    for eqn in reversed(jaxpr.eqns):
+        outs = [v in live for v in eqn.outvars]
+        if not any(outs):
+            continue
+        read = [True] * len(eqn.invars)
+        inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
+        inner = getattr(inner, "jaxpr", inner)
+        if eqn.primitive.name == "pallas_call":
+            calls += name in str(eqn.params["name"])
+        elif eqn.primitive.name == "scan":
+            calls += eqn.params["length"] * kernel_calls(inner, name)[0]
+        elif inner is not None:
+            n, read = kernel_calls(inner, name, outs)
+            calls += n
+        live.update(v for v, on in zip(eqn.invars, read)
+                    if on and not isinstance(v, Literal))
+    return calls, [v in live for v in jaxpr.invars]
+
+
+PREFIXES = [remat.REMAT_NAMES[:i] for i in range(len(remat.REMAT_NAMES) + 1)]
+
+
+@pytest.mark.parametrize("kept", PREFIXES, ids=lambda p: "+".join(p) or "none")
 @pytest.mark.parametrize("impl", ["einsum", "flash"])
-def test_the_hand_written_backward_is_autodiffs(toy, impl):
+def test_the_hand_written_backward_is_autodiffs(toy, impl, kept,
+                                                monkeypatch):
+    """Whatever the backward is handed beside the layers' inputs, the
+    loss and every gradient are plain autodiff's; a kept value is not
+    computed again; and nothing else goes through the stacks."""
     task, model, params, batch = toy
+    reckoned = {}
 
-    def grads(remat, policy):
-        t = dataclasses.replace(task, remat=remat, attention_impl=impl)
-        return jax.grad(lambda p: t.loss_and_metrics(
-            t.build(), p, batch, policy=policy)[0])(params)
+    def choose(held, layer_in):
+        reckoned.update(held, layer_in=layer_in)
+        return kept
 
-    for a, b in zip(jax.tree.leaves(grads(True, FP32)),
-                    jax.tree.leaves(grads(False, FP32))):
+    monkeypatch.setattr(remat, "choose_keeps", choose)
+
+    def loss_and_grads(remat_on, policy):
+        t = dataclasses.replace(task, remat=remat_on, attention_impl=impl)
+        return jax.value_and_grad(lambda p: t.loss_and_metrics(
+            t.build(), p, batch, policy=policy)[0])
+
+    (loss, g), (plain_loss, plain_g) = (
+        loss_and_grads(on, FP32)(params) for on in (True, False))
+    assert abs(float(loss) - float(plain_loss)) < 1e-5
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(plain_g)):
         assert rel(a, b) < 1e-4
     # in bfloat16 the accumulator is still float32, like the parameters
-    g = grads(True, Policy.bf16())
-    assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(g))
-    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(grads(True, FP32))):
+    g16 = loss_and_grads(True, Policy.bf16())(params)[1]
+    assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(g16))
+    for a, b in zip(jax.tree.leaves(g16), jax.tree.leaves(g)):
         assert rel(a, b) < 0.1
+
+    # the forward kernel: once an application with its output kept,
+    # once more under the backward without
+    applications = TOY["total_ut_steps"] * TOY["num_hidden_layers"]
+    step = jax.make_jaxpr(loss_and_grads(True, FP32))(params).jaxpr
+    fused = impl == "flash"
+    assert kernel_calls(step, "causal_attention_fwd")[0] == fused * (
+        applications if "attn_out" in kept else 2 * applications)
+    assert kernel_calls(step, "causal_attention_bwd")[0] == (
+        fused * applications)
+
+    # what the backward is handed, to the byte: the applications'
+    # inputs and the kept names' values, no copy of a layer's parameters
+    lm = dataclasses.replace(model, attention_impl=impl)
+    stacks = {}
+
+    def fwd(layer, norm, passes, policy, kept, layers, norm_params, h):
+        out, res = looped_lm._loop_stack_fwd(
+            layer, norm, passes, policy, kept, layers, norm_params, h)
+        stacks.update(zip(("inputs", "values"), res[2]))
+        return out
+
+    monkeypatch.setattr(looped_lm, "_loop_stack", fwd)
+    jax.eval_shape(lambda p: lm.hidden_states(
+        p, batch["input_ids"], policy=FP32), params)
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert nbytes(stacks["inputs"]) == reckoned["layer_in"] == (
+        applications * 2 * 24 * 32 * 4)
+    assert set(stacks["values"]) == set(kept)
+    for name in kept:
+        assert nbytes(stacks["values"][name]) == reckoned[name], name
+    assert reckoned["qkv"] == 3 * reckoned["layer_in"]
+    assert reckoned["mlp_hidden"] == applications * 2 * (2 * 24 * 48 * 4)
+    assert (reckoned["attn_out"] > reckoned["layer_in"]) == fused
 
 
 def test_the_model_rejects_what_it_cannot_be():

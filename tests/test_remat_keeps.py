@@ -1,9 +1,10 @@
 """What crosses the recomputation boundary of a ``remat`` layer
 (``ops/remat.py``): the dear values are kept by name and not computed
 again, the cheap ones are; which names are kept is chosen from the
-reckoned bytes and the device's memory; the reckoning is what autodiff
-saves; the trainer says what was chosen; and a model that only sees the
-names (the looped LM) lowers as if they were not there."""
+reckoned bytes and what the device has left; the reckoning is what
+autodiff saves; the trainer says what was chosen, for the encoder and
+for the looped LM's hand-written backward alike; and with nothing kept
+the looped LM lowers as if the names were not there."""
 
 import json
 import os
@@ -113,12 +114,35 @@ LM_IN = 981_467_136
 V5E = 16_909_336_064
 
 
+# ouro_train's: 32 layer applications of 2 rows x 4096 x 2048 (PR 32)
+OURO = {"attn_out": 32 * (2 * 4096 * 2048 * 4 + 2 * 16 * 4096 * 4),
+        "qkv": 32 * 2 * 4096 * 6144 * 2,
+        "mlp_hidden": 32 * 2 * (2 * 4096 * 5632 * 2)}
+OURO_IN = 32 * 2 * 4096 * 2048 * 2
+# parameters and AdamW moments, 12 bytes a parameter: what the device
+# reports in use when the trainer loads its step
+LM_STATE, IMG_STATE, OURO_STATE = 936_000_000, 287_000_000, 7_349_000_000
+
+
 def scaled(by):
     return {k: int(v * by) for k, v in LM.items()}, int(LM_IN * by)
 
 
-# (bytes by name, layer_in, memory limit) -> (kept, first name dropped)
+# (bytes by name, layer_in, memory limit[, bytes in use])
+#     -> (kept, first name dropped)
 CHOICES = {
+    "ouro_train_beside_its_state": ((OURO, OURO_IN, V5E, OURO_STATE),
+                                    (REMAT_NAMES[:1], "qkv")),
+    "ouro_train_blind_to_its_state": ((OURO, OURO_IN, V5E),
+                                      (REMAT_NAMES[:2], "mlp_hidden")),
+    "lm_train_beside_its_state": ((LM, LM_IN, V5E, LM_STATE),
+                                  (REMAT_NAMES, None)),
+    "img_train_beside_its_state": ((*scaled(0.09), V5E, IMG_STATE),
+                                   (REMAT_NAMES, None)),
+    "no_memory_report_beside_a_state": ((OURO, OURO_IN, None, OURO_STATE),
+                                        (REMAT_NAMES, None)),
+    "a_full_device_keeps_nothing": ((LM, LM_IN, V5E, V5E),
+                                    ((), "attn_out")),
     "lm_train_whole_list": ((LM, LM_IN, V5E), (REMAT_NAMES, None)),
     "img_train_whole_list": ((*scaled(0.09), V5E), (REMAT_NAMES, None)),
     "no_memory_report": ((*scaled(100), None), (REMAT_NAMES, None)),
@@ -137,18 +161,33 @@ CHOICES = {
 
 @pytest.mark.parametrize("case", CHOICES)
 def test_pick_remat_keeps(case):
-    (held, layer_in, limit), (kept, dropped) = CHOICES[case]
+    (held, layer_in, limit, *in_use), (kept, dropped) = CHOICES[case]
     got, why = pick_remat_keeps(held, layer_in_bytes=layer_in,
-                                memory_limit=limit)
+                                memory_limit=limit, memory_held=sum(in_use))
     assert got == tuple(kept)
     assert (why is None) == (dropped is None)
     if dropped:
         assert why.startswith(f"{dropped} would make ")
-    # a prefix of the list, and within the share when a limit is known
+        # what was in use is part of the reason where it is part of it
+        assert ("in use leave" in why) == bool(sum(in_use))
+    # a prefix of the list, and what is kept is within the share of
+    # what is left when a limit is known (the inputs are held anyway)
     assert got == REMAT_NAMES[:len(got)]
-    if limit is not None:
+    if limit is not None and got:
         assert (layer_in + sum(held.get(n, 0) for n in got)
-                <= remat.KEEP_SHARE * limit)
+                <= remat.KEEP_SHARE * (limit - sum(in_use)))
+
+
+def test_the_reason_gives_this_cells_numbers():
+    """``ouro_train`` as the issue reckons it: 3.23 GB kept with
+    ``attn_out``, 6.45 with ``qkv``, against 0.6 of the 9.56 GB that
+    7.35 GB of state leave of 16.91."""
+    kept, why = pick_remat_keeps(OURO, layer_in_bytes=OURO_IN,
+                                 memory_limit=V5E, memory_held=OURO_STATE)
+    assert kept == ("attn_out",)
+    assert why == ("qkv would make 6.46 GB of 5.74, 0.6 of what 7.35 GB "
+                   "in use leave")
+    assert round((OURO_IN + OURO["attn_out"]) / 1e9, 2) == 3.24
 
 
 def test_the_share_leaves_lm_train_a_margin():
@@ -288,32 +327,83 @@ def test_bytes_are_one_devices_under_a_mesh(tmp_path, monkeypatch):
         assert 2 * split[name] == one[name] > 0, name
 
 
-# --- (f) a model that only sees the names ------------------------------------
+# --- (f) the looped LM: the same choice, the values by hand ------------------
+
+LOOPED = dict(vocab_size=96, hidden_size=32, num_hidden_layers=2,
+              num_attention_heads=2, head_dim=16, intermediate_size=48,
+              max_seq_len=128, total_ut_steps=3, remat=True,
+              ce_chunk_size=128)
+LOOPED_BATCH = {"input_ids": np.ones((2, 128), np.int32),
+                "valid": np.ones((2,), bool)}
 
 
 @pytest.mark.parametrize("core", ["fused", "materialized"])
 def test_names_change_nothing_in_the_looped_lm(core, tmp_path, monkeypatch):
     """``models/looped_lm.py`` calls the same ``mha_apply``, ``_project``
-    and ``_flash_fwd``; its ``remat`` is a backward pass written by hand
-    around a bare ``jax.checkpoint``, where a name is an identity: its
-    lowered step is the one without the names."""
+    and ``_flash_fwd``; with nothing kept its hand-written backward
+    recomputes a whole layer under a bare ``jax.checkpoint``, where a
+    name is an identity: its lowered step is the one without the
+    names."""
     task = CausalLMTask(
-        vocab_size=96, hidden_size=32, num_hidden_layers=2,
-        num_attention_heads=2, head_dim=16, intermediate_size=48,
-        max_seq_len=128, total_ut_steps=3, remat=True, ce_chunk_size=128,
-        attention_impl="flash" if core == "fused" else "einsum")
-    batch = {"input_ids": np.ones((2, 128), np.int32),
-             "valid": np.ones((2,), bool)}
+        attention_impl="flash" if core == "fused" else "einsum", **LOOPED)
+    monkeypatch.setattr(remat, "_memory_limit", lambda: 0)
 
     def lowered():
         trainer, state = make_trainer(task, tmp_path)
-        with remat.reckoning() as seen:
-            text = trainer._train_step.lower(state, batch).as_text()
+        with remat.reckoning() as seen, remat.remat_keeps() as choices:
+            text = trainer._train_step.lower(state, LOOPED_BATCH).as_text()
+        assert [c["kept"] for c in choices] == [()]
         # private functions are numbered as they are made
         return re.sub(r"(@[A-Za-z_]+)_\d+", r"\1", text), set(seen)
 
     named, seen = lowered()
-    assert seen == ({"qkv", "attn_out"} if core == "fused" else {"qkv"})
+    assert seen == {"qkv", "mlp_hidden"} | (
+        {"attn_out"} if core == "fused" else set())
     monkeypatch.setattr(remat, "checkpoint_name", lambda x, name: x)
     bare, _ = lowered()
     assert named == bare
+
+
+def test_the_trainer_says_what_the_looped_stack_keeps(tmp_path, capfd,
+                                                      monkeypatch):
+    """The same line, tally and rule as the encoder's; what the device
+    already holds when the step is loaded is part of the choice."""
+    task = CausalLMTask(attention_impl="flash", **LOOPED)
+    applications = 3 * 2
+    tensor = 2 * 128 * 32 * 2           # a row of states, bfloat16
+    trainer, state = make_trainer(task, tmp_path)
+    with remat.remat_keeps() as outer, attn.attention_paths() as paths:
+        trainer._load_step(trainer._train_step, state, LOOPED_BATCH, "t")
+    (choice,) = outer
+    # the trace for shapes is not a call site: forward and backward
+    assert dict(paths) == {("fused", None): 2}
+    assert choice["kept"] == REMAT_NAMES and choice["memory_limit"] is None
+    assert choice["memory_held"] == 0
+    assert choice["bytes"] == {
+        "layer_in": applications * tensor,
+        # the float32 output and a log-sum-exp row a head
+        "attn_out": applications * (2 * tensor + 2 * 2 * 128 * 4),
+        "qkv": applications * 3 * tensor,
+        "mlp_hidden": applications * 2 * (2 * 128 * 48 * 2)}
+    assert ("[step_load] remat keeps: attn_out,qkv,mlp_hidden + layer_in "
+            "0.00 GB of no memory report\n") in capfd.readouterr().err
+
+    # a device whose state leaves room for the first name alone
+    fits = choice["bytes"]["layer_in"] + choice["bytes"]["attn_out"]
+    in_use = 5_000_000
+    monkeypatch.setattr(remat, "_memory_held", lambda: in_use)
+    monkeypatch.setattr(remat, "_memory_limit",
+                        lambda: in_use + int((fits + 1) / remat.KEEP_SHARE))
+    trainer, state = make_trainer(task, tmp_path / "small")
+    with remat.remat_keeps() as outer:
+        trainer._load_step(trainer._train_step, state, LOOPED_BATCH, "t")
+    (choice,) = outer
+    assert choice["kept"] == ("attn_out",)
+    assert choice["dropped"] == ("qkv", "mlp_hidden")
+    assert choice["memory_held"] == in_use
+    err = capfd.readouterr().err
+    assert re.search(
+        r"\[step_load\] remat keeps: attn_out \+ layer_in 0\.00 GB of "
+        r"0\.01 \(dropped qkv,mlp_hidden: qkv would make 0\.00 GB of 0\.00, "
+        r"0\.6 of what 0\.01 GB in use leave\)", err), err
+    assert not remat._KEEP_TALLIES and not remat._EXCHANGES

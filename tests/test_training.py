@@ -1,7 +1,9 @@
 """End-to-end training-slice tests (SURVEY §4 plan items d, e)."""
 
 import dataclasses
+import gc
 import os
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +44,33 @@ def test_fast_dev_run(tmp_path):
     state = trainer.fit()
     assert trainer.global_step == 1
     assert np.isfinite(float(state.step))
+
+
+def test_a_dropped_trainer_and_its_state_leave_at_once(tmp_path):
+    """Nothing the trainer makes refers back to it (its jitted steps
+    close over the optimizer, not over the trainer): when the caller
+    drops the trainer and the state, the state's buffers leave the
+    device then, not when the cycle collector next runs. What follows
+    a fit on a full chip counts on the room (``ouro_train``'s float32
+    reference: PERF.md, PR 32)."""
+    dm = MNISTDataModule(data_dir=str(tmp_path / "nope"), batch_size=16,
+                         synthetic_train_size=64, synthetic_test_size=32)
+    gc.collect()
+    gc.disable()
+    try:
+        trainer = Trainer(small_image_task(), dm,
+                          TrainerConfig(fast_dev_run=True,
+                                        default_root_dir=str(tmp_path / "l"),
+                                        exec_cache_dir=str(tmp_path / "e"),
+                                        enable_checkpointing=False),
+                          optimizer_init=ADAMW)
+        state = trainer.fit()
+        gone = [weakref.ref(trainer),
+                weakref.ref(jax.tree.leaves(state.params)[0])]
+        del trainer, state
+        assert [ref() for ref in gone] == [None, None]
+    finally:
+        gc.enable()
 
 
 def test_overfit_batches_loss_decreases(tmp_path):
