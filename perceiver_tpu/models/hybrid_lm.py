@@ -1,0 +1,273 @@
+"""A hybrid state-space / mixture-of-experts language model: a decoder
+stack whose layers are of three kinds in a published order (the
+``nemotron_h`` family, NVIDIA Nemotron-H, arXiv:2504.03624, and
+Nemotron 3 Nano; ``hybrid_override_pattern``)::
+
+    h0 = E[ids]
+    for each character of the pattern:  h = h + mixer(rms(h))
+    logits = rms_f(h) Wh                                  (head untied)
+
+``M`` is a Mamba-2 mixer (``ops/ssm.py``), ``E`` an expert layer
+(``ops/moe.py``), ``*`` causal attention with grouped queries and **no
+rotary embedding** (the Mamba layers carry position). A layer is one
+RMSNorm with a scale, one mixer and a residual: there is no separate MLP
+after ``M`` or ``*``. No linear layer has a bias.
+
+The attention layer runs on the cores every causal call takes
+(``ops.attention.mha_apply``): its ``num_kv_heads`` key/value heads are
+repeated to the query heads before the call (query head ``i`` reads
+key/value head ``i // (heads / kv_heads)``), so the fused kernels see
+the call they know.
+
+The expert layers hold ``held_experts`` of the ``num_experts`` the
+router sees, from ``first_expert`` on: a chip's share under expert
+parallelism; all of them where ``held_experts`` is None. A call may say
+which share each expert layer holds (``first_experts``, one first
+expert an expert layer, values of the step and not of its program) in
+``first_expert``'s place.
+
+The layers differ, so they are unrolled (no scan over stacked
+parameters). With ``remat`` each layer is a ``jax.checkpoint`` of its
+own whose save list is the names ``ops/remat.choose_keeps`` keeps of
+``HYBRID_REMAT_NAMES``, reckoned over all the layers from the shapes
+each kind reports, against what the device has left.
+
+Training never materialises the ``(B, S, V)`` logits: the task takes
+``hidden_states`` and reads the head through
+``ops.fused_ce.fused_linear_nll``. ``apply`` gives the dense logits for
+tests, prediction and small sizes.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from perceiver_tpu.obs.trace import device_scope
+from perceiver_tpu.ops import remat
+from perceiver_tpu.ops.attention import data_shards, mha_apply, untallied
+from perceiver_tpu.ops.initializers import trunc_normal_clamped
+from perceiver_tpu.ops.linear import linear_apply, linear_init
+from perceiver_tpu.ops.moe import moe_apply, moe_init
+from perceiver_tpu.ops.norm import rms_norm_apply, rms_norm_init
+from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
+from perceiver_tpu.ops.ssm import ssm_mixer_apply, ssm_mixer_init
+
+_INIT_STD = 0.02
+LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn"}
+
+
+def gqa_init(key, dim: int, num_heads: int, num_kv_heads: int,
+             head_dim: int):
+    kq, kk, kv, ko = jax.random.split(key, 4)
+    return {
+        "q": linear_init(kq, dim, num_heads * head_dim, bias=False),
+        "k": linear_init(kk, dim, num_kv_heads * head_dim, bias=False),
+        "v": linear_init(kv, dim, num_kv_heads * head_dim, bias=False),
+        "out": linear_init(ko, num_heads * head_dim, dim, bias=False),
+    }
+
+
+def repeat_kv(x, num_kv_heads: int, num_heads: int):
+    """(B, S, kv_heads x D) -> (B, S, heads x D): each key/value head
+    side by side for the query heads that read it."""
+    rows, seq, width = x.shape
+    heads = x.reshape(rows, seq, num_kv_heads, 1, width // num_kv_heads)
+    return jnp.broadcast_to(
+        heads, (rows, seq, num_kv_heads, num_heads // num_kv_heads,
+                width // num_kv_heads)).reshape(rows, seq, -1)
+
+
+def gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
+              policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None):
+    """Causal attention with grouped queries, no position embedding."""
+    with device_scope("attn_proj"):
+        k, v = (repeat_kv(linear_apply(params[n], a, policy=policy),
+                          num_kv_heads, num_heads) for n in ("k", "v"))
+    return mha_apply(params, a, None, None, num_heads=num_heads,
+                     kv_heads=(k, v), causal=True, policy=policy, impl=impl)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridLM:
+    vocab_size: int
+    hidden_size: int
+    pattern: str                     # one of M, E, * a layer
+    # M
+    mamba_num_heads: int
+    mamba_head_dim: int
+    n_groups: int
+    ssm_state_size: int
+    conv_kernel: int
+    chunk_size: int
+    # *
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    # E
+    n_routed_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    moe_shared_expert_intermediate_size: int
+    routed_scaling_factor: float
+    max_seq_len: int
+    # the experts held here, from first_expert on; None: all of them
+    held_experts: Optional[int] = None
+    first_expert: int = 0
+    norm_eps: float = 1e-5
+    # recompute every layer on the backward pass, but for the dear
+    # values that fit the device (ops/remat.py)
+    remat: bool = False
+
+    def __post_init__(self):
+        if not self.pattern or set(self.pattern) - set(LAYER_KINDS):
+            raise ValueError(
+                f"pattern {self.pattern!r}: one of {sorted(LAYER_KINDS)} a "
+                "layer (M Mamba-2, E experts, * attention)")
+        if self.num_attention_heads % self.num_key_value_heads \
+                or self.mamba_num_heads % self.n_groups:
+            raise ValueError("query heads divide over the key/value heads, "
+                             "Mamba heads over the groups")
+        held = self.num_held_experts
+        if not 0 <= self.first_expert <= self.n_routed_experts - held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + held} "
+                f"are not among the router's {self.n_routed_experts}")
+
+    @property
+    def num_held_experts(self) -> int:
+        return (self.n_routed_experts if self.held_experts is None
+                else self.held_experts)
+
+    def layer_names(self):
+        """``00_ssm``, ``01_moe``, ...: the parameter tree's keys, in
+        the pattern's order."""
+        return [f"{i:02d}_{LAYER_KINDS[kind]}"
+                for i, kind in enumerate(self.pattern)]
+
+    def _mixer_init(self, key, kind: str):
+        c = self.hidden_size
+        if kind == "M":
+            return ssm_mixer_init(
+                key, c, num_heads=self.mamba_num_heads,
+                head_dim=self.mamba_head_dim, n_groups=self.n_groups,
+                state_size=self.ssm_state_size,
+                conv_kernel=self.conv_kernel)
+        if kind == "E":
+            return moe_init(
+                key, c, num_experts=self.n_routed_experts,
+                held_experts=self.num_held_experts,
+                expert_hidden=self.moe_intermediate_size,
+                shared_hidden=self.moe_shared_expert_intermediate_size)
+        return gqa_init(key, c, self.num_attention_heads,
+                        self.num_key_value_heads, self.head_dim)
+
+    def init(self, key):
+        ke, kl, kh = jax.random.split(key, 3)
+        c = self.hidden_size
+        keys = jax.random.split(kl, len(self.pattern))
+        return {
+            "embed": {"embed": trunc_normal_clamped(
+                ke, (self.vocab_size, c), _INIT_STD)},
+            "layers": {
+                name: {"norm": rms_norm_init(c),
+                       "mixer": self._mixer_init(k, kind)}
+                for name, kind, k in zip(self.layer_names(), self.pattern,
+                                         keys)},
+            "norm": rms_norm_init(c),
+            "head": {"w": trunc_normal_clamped(
+                kh, (c, self.vocab_size), _INIT_STD)},
+        }
+
+    def _layer(self, kind: str, policy: Policy):
+        """``(layer_params, h, first) -> (h, load)`` of one kind;
+        ``first`` is an expert layer's first held expert (None:
+        ``first_expert``) and ``load`` its assignments a held expert,
+        both None elsewhere."""
+        def layer(p, h, first=None):
+            a = rms_norm_apply(p["norm"], h, self.norm_eps, policy)
+            load = None
+            if kind == "M":
+                out = ssm_mixer_apply(
+                    p["mixer"], a, num_heads=self.mamba_num_heads,
+                    head_dim=self.mamba_head_dim, n_groups=self.n_groups,
+                    state_size=self.ssm_state_size,
+                    chunk_size=self.chunk_size, eps=self.norm_eps,
+                    policy=policy)
+            elif kind == "E":
+                out, load = moe_apply(
+                    p["mixer"], a, top_k=self.num_experts_per_tok,
+                    first_expert=(self.first_expert if first is None
+                                  else first),
+                    scaling=self.routed_scaling_factor, policy=policy)
+            else:
+                out = gqa_apply(
+                    p["mixer"], a, num_heads=self.num_attention_heads,
+                    num_kv_heads=self.num_key_value_heads, policy=policy)
+            return h + out, load
+
+        return layer
+
+    def _remat_keeps(self, layers, params, h):
+        """The names every layer's checkpoint saves: one layer of each
+        kind traced for its shapes alone says what its names would
+        hold; ``choose_keeps`` takes the sum over the layers on one
+        device against what the device has left."""
+        held = dict.fromkeys(remat.HYBRID_REMAT_NAMES, 0)
+        first = {kind: named_layer for named_layer, kind
+                 in reversed(list(zip(layers, self.pattern)))}
+        with untallied():
+            for kind, (name, layer) in first.items():
+                named = remat.named_bytes(
+                    lambda p, x: layer(p, x)[0], params[name], h)
+                for n in held:
+                    held[n] += self.pattern.count(kind) * named[n]
+        shards = data_shards(h)
+        return remat.choose_keeps(
+            {n: v // shards for n, v in held.items()},
+            len(layers) * h.size * h.dtype.itemsize // shards,
+            names=remat.HYBRID_REMAT_NAMES)
+
+    def hidden_states(self, params, input_ids, *, first_experts=None,
+                      policy: Policy = DEFAULT_POLICY):
+        """``(the final normed state (B, S, C) in the compute dtype,
+        loads)``; ``loads`` (expert layers, held) int32: the
+        assignments each held expert computed, a row an expert layer.
+        ``first_experts`` (expert layers,) int32: each expert layer's
+        first held expert, in ``first_expert``'s place."""
+        seq = input_ids.shape[1]
+        if seq > self.max_seq_len:
+            raise ValueError(f"{seq} positions, max_seq_len "
+                             f"{self.max_seq_len}")
+        with device_scope("input_adapter"):
+            h = policy.cast_compute(params["embed"]["embed"][input_ids])
+        layers = [(name, self._layer(kind, policy))
+                  for name, kind in zip(self.layer_names(), self.pattern)]
+        loads = []
+        firsts = iter(() if first_experts is None else first_experts)
+        with device_scope("hybrid_stack"):
+            if self.remat:
+                policy_fn = jax.checkpoint_policies.save_only_these_names(
+                    *self._remat_keeps(layers, params["layers"], h))
+            for (name, layer), kind in zip(layers, self.pattern):
+                if self.remat:
+                    layer = jax.checkpoint(layer, policy=policy_fn)
+                h, load = layer(params["layers"][name], h,
+                                next(firsts, None) if kind == "E" else None)
+                if load is not None:
+                    loads.append(load)
+            h = rms_norm_apply(params["norm"], h, self.norm_eps, policy)
+        return h, (jnp.stack(loads) if loads else
+                   jnp.zeros((0, self.num_held_experts), jnp.int32))
+
+    def apply(self, params, input_ids, *, first_experts=None,
+              policy: Policy = DEFAULT_POLICY):
+        """Dense logits ``(B, S, V)`` float32."""
+        h, _ = self.hidden_states(params, input_ids,
+                                  first_experts=first_experts, policy=policy)
+        with device_scope("loss"):
+            return linear_apply(params["head"], h,
+                                policy=policy).astype(jnp.float32)

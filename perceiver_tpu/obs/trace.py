@@ -145,6 +145,13 @@ DEVICE_SCOPES = (
     "attn_core", "attn_proj", "mlp", "loss", "optimizer",
     # a weight-shared decoder stack run several times (models/looped_lm)
     "loop_stack", "decoder_layer", "exit_gate", "exit_loss",
+    # a stack of state-space, expert and attention layers
+    # (models/hybrid_lm): a whole Mamba-2 mixer and, inside it, the
+    # chunked scan alone; a whole expert layer and, inside it, what is
+    # not a matrix product (router, top-k, sort, gather, combine) and
+    # the grouped products over the held experts
+    "hybrid_stack", "ssm_mixer", "ssm_scan", "moe", "moe_route",
+    "moe_experts",
 )
 
 _SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES)

@@ -39,12 +39,10 @@ projected queries and keys) are what a decoder stack adds to the same
 
 from __future__ import annotations
 
-import collections
-import contextlib
 import math
 import warnings
 from functools import partial
-from typing import Iterator, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +54,7 @@ from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import layer_norm_init, layer_norm_apply
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
 from perceiver_tpu.ops.remat import dear
+from perceiver_tpu.ops.tally import Tally, untallied  # noqa: F401
 
 NEG_INF = -1e30  # large-negative bias; safe in fp32 softmax accumulation
 
@@ -310,31 +309,13 @@ def data_shards(x) -> int:
 # ("fused", None), ("materialized", "shape"), ("chunked", None), ...
 # A call site inside a scanned or rematerialised layer counts once per
 # trace of its body, not once per execution.
-_PATH_TALLIES = []
+_PATHS = Tally()
 
 
-@contextlib.contextmanager
-def attention_paths() -> Iterator[collections.Counter]:
+def attention_paths():
     """Count the attention call sites traced inside the block by the
     core they took, in the style of ``cache.compile_events()``."""
-    tally = collections.Counter()
-    _PATH_TALLIES.append(tally)
-    try:
-        yield tally
-    finally:
-        _PATH_TALLIES.remove(tally)
-
-
-@contextlib.contextmanager
-def untallied() -> Iterator[None]:
-    """A trace for shapes alone (a ``remat`` encoder reckons what its
-    names would hold from one): its call sites are the real trace's,
-    seen a second time, and are not counted."""
-    held, _PATH_TALLIES[:] = _PATH_TALLIES[:], []
-    try:
-        yield
-    finally:
-        _PATH_TALLIES[:] = held
+    return _PATHS.counting()
 
 
 def format_attention_paths(tally) -> str:
@@ -439,8 +420,7 @@ def mha_apply(params, q, k, v, *, num_heads: int,
         path, reason = "materialized", "impl"
     elif impl == "flash":
         path = "fused"
-    for tally in _PATH_TALLIES:
-        tally[path, reason] += 1
+    _PATHS.add((path, reason))
     if impl == "flash":
         out = _fused_core(qh, kh, vh, num_heads, key_padding_mask, causal)
     else:

@@ -90,3 +90,34 @@ def gated_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
     up = dear(linear_apply(params["up"], x, policy=policy), "mlp_hidden")
     return linear_apply(params["down"], jax.nn.silu(gate) * up,
                         policy=policy)
+
+
+# --- relu-squared MLP --------------------------------------------------------
+# relu(x Wu)^2 Wd, no gate, no biases, no norm of its own (the layer
+# that uses it norms before): a shared expert and, with a grouped
+# ``product`` over rows sorted by expert, the routed experts of
+# ``ops/moe.py`` (``params`` then holds the experts stacked).
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def relu2_mlp_init(key, dim: int, hidden: int, dtype=jnp.float32):
+    ku, kd = jax.random.split(key)
+    return {
+        "up": linear_init(ku, dim, hidden, dtype, bias=False),
+        "down": linear_init(kd, hidden, dim, dtype, bias=False),
+    }
+
+
+@device_scope("mlp")
+def relu2_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY, *,
+                    product=linear_apply, name: str = "mlp_hidden"):
+    """``product(params[...], x, policy=policy)`` multiplies by one of
+    the two matrices; ``name`` is the hidden layer's ``dear`` name,
+    None where the caller recomputes it in any case."""
+    up = product(params["up"], x, policy=policy)
+    if name is not None:
+        up = dear(up, name)
+    return product(params["down"], relu2(up), policy=policy)

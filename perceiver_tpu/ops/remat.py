@@ -9,7 +9,7 @@ GELU, casts, slices, residual sums) and the one product whose saved
 copy would cost as much (the out-projection's). No arithmetic changes:
 a named value is the value, saved instead of computed again.
 
-Two stacks honour the names, each in the way its backward is made:
+Three stacks honour the names, each in the way its backward is made:
 
 - ``models/perceiver.PerceiverEncoder``: each attention layer (cross or
   self, with its MLP) is a ``jax.checkpoint`` of its own under
@@ -22,7 +22,11 @@ Two stacks honour the names, each in the way its backward is made:
   input, the backward builds the application's vjp with them handed
   back in (``vjp_handing``).
 
-Which names those are is one reckoning for both (``choose_keeps``).
+- ``models/hybrid_lm.HybridLM``: its layers differ and are unrolled,
+  each a ``jax.checkpoint`` under autodiff whose save list is the kept
+  names of its own, longer list (``HYBRID_REMAT_NAMES``).
+
+Which names those are is one reckoning for all (``choose_keeps``).
 
 ``REMAT_NAMES`` is the list in order of time bought per byte held
 (chip runs, PERF.md, PR 29: 3.0, 2.1 and 1.0 ms a step and GB in
@@ -69,6 +73,19 @@ from jax.ad_checkpoint import checkpoint_name
 #: the dear values, dearest per byte first
 REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 
+#: the list a stack with state-space and expert layers chooses from
+#: (``models/hybrid_lm.py``): the three above, then what its own mixers
+#: name. ``ssm_out`` is the chunked scan's output (``ops/ssm.py``: the
+#: scan is a checkpoint of its own, so with its output held the
+#: recomputed layer does not run it a second time), ``ssm_in`` the
+#: in-projection's product. The shared expert's hidden layer is an
+#: ``mlp_hidden``. The routed experts' hidden layer has no name: it
+#: lies inside a ``lax.cond`` over the sorted buffer's size
+#: (``ops/moe.py``), and a value that crosses one is held at the size
+#: of the larger branch, all that top-k allows; the routed part
+#: recomputes it under a checkpoint of its own.
+HYBRID_REMAT_NAMES = REMAT_NAMES + ("ssm_out", "ssm_in")
+
 #: The share of what the device has left that the kept values and the
 #: layers' inputs may take together. From chip runs (PERF.md, Findings,
 #: PR 29): ``lm_train`` (24 rows of 1024 x 512 latents, 39 layers)
@@ -95,8 +112,8 @@ def dear(x, name: str):
     ``name`` is kept and recomputed if not; under ``taking`` it is taken
     out, under ``vjp_handing`` the kept value stands in its place;
     anywhere else an identity (no operation is lowered for it)."""
-    if name not in REMAT_NAMES:
-        raise ValueError(f"{name!r} is not one of {REMAT_NAMES}")
+    if name not in HYBRID_REMAT_NAMES:
+        raise ValueError(f"{name!r} is not one of {HYBRID_REMAT_NAMES}")
     if _RECORDERS:
         _RECORDERS[-1][name] += x.size * x.dtype.itemsize
     x = checkpoint_name(x, name)
@@ -221,19 +238,20 @@ def vjp_handing(values: Mapping[str, Sequence], layer, *args):
 
 def pick_remat_keeps(bytes_by_name: Mapping[str, int], *,
                      layer_in_bytes: int, memory_limit: Optional[int],
-                     memory_held: int = 0
+                     memory_held: int = 0,
+                     names: Tuple[str, ...] = REMAT_NAMES
                      ) -> Tuple[Tuple[str, ...], Optional[str]]:
-    """``(kept, why_not_all)``: the longest prefix of ``REMAT_NAMES``
-    whose bytes fit, beside the layers' inputs, in ``KEEP_SHARE`` of
-    what ``memory_held`` leaves of ``memory_limit``; the reason names
-    the first one dropped. All bytes are one device's, over all layer
-    applications. ``memory_limit`` None (a backend that reports no
-    limit) keeps every name."""
+    """``(kept, why_not_all)``: the longest prefix of ``names`` (the
+    list the stack chooses from) whose bytes fit, beside the layers'
+    inputs, in ``KEEP_SHARE`` of what ``memory_held`` leaves of
+    ``memory_limit``; the reason names the first one dropped. All bytes
+    are one device's, over all layer applications. ``memory_limit``
+    None (a backend that reports no limit) keeps every name."""
     if memory_limit is None:
-        return REMAT_NAMES, None
+        return names, None
     budget = KEEP_SHARE * (memory_limit - memory_held)
     total = layer_in_bytes
-    for i, name in enumerate(REMAT_NAMES):
+    for i, name in enumerate(names):
         total += bytes_by_name.get(name, 0)
         if total > budget:
             why = (f"{name} would make {total / 1e9:.2f} GB of "
@@ -241,8 +259,8 @@ def pick_remat_keeps(bytes_by_name: Mapping[str, int], *,
             if memory_held:
                 why += (f", {KEEP_SHARE:g} of what {memory_held / 1e9:.2f} "
                         "GB in use leave")
-            return REMAT_NAMES[:i], why
-    return REMAT_NAMES, None
+            return names[:i], why
+    return names, None
 
 
 def _memory_limit() -> Optional[int]:
@@ -280,19 +298,20 @@ def remat_keeps() -> Iterator[list]:
         _KEEP_TALLIES.remove(choices)
 
 
-def choose_keeps(bytes_by_name: Mapping[str, int], layer_in_bytes: int
-                 ) -> Tuple[str, ...]:
+def choose_keeps(bytes_by_name: Mapping[str, int], layer_in_bytes: int,
+                 names: Tuple[str, ...] = REMAT_NAMES) -> Tuple[str, ...]:
     """``pick_remat_keeps`` against this process's device, tallied."""
     limit, held = _memory_limit(), _memory_held()
     kept, why = pick_remat_keeps(bytes_by_name,
                                  layer_in_bytes=layer_in_bytes,
-                                 memory_limit=limit, memory_held=held)
+                                 memory_limit=limit, memory_held=held,
+                                 names=names)
     choice = {
         "kept": kept,
-        "dropped": tuple(n for n in REMAT_NAMES if n not in kept),
+        "dropped": tuple(n for n in names if n not in kept),
         "why": why,
         "bytes": {"layer_in": layer_in_bytes,
-                  **{n: bytes_by_name.get(n, 0) for n in REMAT_NAMES}},
+                  **{n: bytes_by_name.get(n, 0) for n in names}},
         "memory_limit": limit,
         "memory_held": held,
     }

@@ -6,3 +6,4 @@ from perceiver_tpu.tasks.text import TextClassifierTask  # noqa: F401
 from perceiver_tpu.tasks.mlm import MaskedLanguageModelTask  # noqa: F401
 from perceiver_tpu.tasks.segmentation import SegmentationTask  # noqa: F401
 from perceiver_tpu.tasks.causal_lm import CausalLMTask  # noqa: F401
+from perceiver_tpu.tasks.hybrid_lm import HybridLMTask  # noqa: F401

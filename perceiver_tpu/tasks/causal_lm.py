@@ -39,6 +39,26 @@ from perceiver_tpu.ops.fused_ce import fused_linear_nll
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 
 
+def next_token_targets(batch):
+    """``(labels (B, S), mask (B, S) float32)`` of a causal-LM batch:
+    position i is labelled with id i+1; a row's last position, a
+    padding position and one whose next token is padding have no label
+    (``pad_mask``: right padding), nor has a row that ``valid`` marks
+    as filler."""
+    ids = batch["input_ids"]
+    b, s = ids.shape
+    labels = jnp.concatenate([ids[:, 1:], jnp.zeros((b, 1), ids.dtype)],
+                             axis=1)
+    labelled = jnp.broadcast_to(jnp.arange(s) < s - 1, (b, s))
+    if "pad_mask" in batch:   # right padding: no label from a pad
+        pad = batch["pad_mask"]
+        labelled = labelled & ~jnp.concatenate(
+            [pad[:, 1:], jnp.ones((b, 1), bool)], axis=1) & ~pad
+    if "valid" in batch:
+        labelled = labelled & batch["valid"].astype(bool)[:, None]
+    return labels, labelled.astype(jnp.float32)
+
+
 @dataclasses.dataclass(frozen=True)
 class CausalLMTask:
     vocab_size: int = 49152
@@ -92,17 +112,7 @@ class CausalLMTask:
         ids = batch["input_ids"]
         b, s = ids.shape
         t = model.total_ut_steps
-        # position i is labelled with id i+1; the last has none
-        labels = jnp.concatenate([ids[:, 1:], jnp.zeros((b, 1), ids.dtype)],
-                                 axis=1)
-        labelled = jnp.broadcast_to(jnp.arange(s) < s - 1, (b, s))
-        if "pad_mask" in batch:   # right padding: no label from a pad
-            pad = batch["pad_mask"]
-            labelled = labelled & ~jnp.concatenate(
-                [pad[:, 1:], jnp.ones((b, 1), bool)], axis=1) & ~pad
-        if "valid" in batch:
-            labelled = labelled & batch["valid"].astype(bool)[:, None]
-        mask = labelled.astype(jnp.float32)                     # (B, S)
+        labels, mask = next_token_targets(batch)                # (B, S)
 
         states = model.hidden_states(params, ids, policy=policy)
         p, log_p = exit_distribution(model.gate_logits(params, states))

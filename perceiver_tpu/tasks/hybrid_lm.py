@@ -1,0 +1,102 @@
+"""Causal language-model task for a hybrid state-space /
+mixture-of-experts stack (``models/hybrid_lm.py``): mean next-token
+cross-entropy over the positions that have a label, one pass, the
+labels and the fused head reading of ``tasks/causal_lm.py``.
+
+The fields are the published ``config.json``'s under its own names
+(``nemotron_h``); the defaults are NVIDIA-Nemotron-3-Nano-30B-A3B's.
+``held_experts`` and ``first_expert`` say which of the
+``n_routed_experts`` this chip holds (None: all): the router keeps its
+width and its experts a token, and what the absent experts would have
+added is left out of every expert layer's result. A batch may name
+each expert layer's share itself (``first_experts``, (rows, expert
+layers) int32, every row alike: the first expert held in each expert
+layer), in ``first_expert``'s place: which share a chip plays is then a
+value of the step, not of its program. ``vocab_size`` may be a slice of
+the published vocabulary: ids, logits and loss are over it.
+
+The balancing buffer ``e_score_correction_bias`` is held at 0, outside
+the parameter tree, and there is no auxiliary loss. Every step's
+metrics carry ``moe_assignments`` (the (token, held expert) pairs the
+expert layers computed) and ``moe_load_max_over_mean`` (the fullest
+held expert's load over the mean of the held, the largest over the
+expert layers: the imbalance the no-drop rule is there for).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax.numpy as jnp
+
+from perceiver_tpu.models.hybrid_lm import HybridLM
+from perceiver_tpu.ops.fused_ce import fused_linear_nll
+from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
+from perceiver_tpu.tasks.causal_lm import next_token_targets
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridLMTask:
+    vocab_size: int = 131072
+    hidden_size: int = 2688
+    hybrid_override_pattern: str = (
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    routed_scaling_factor: float = 2.5
+    norm_eps: float = 1e-5
+    max_seq_len: int = 4096
+    # the experts this chip holds, from first_expert on; None: all
+    held_experts: Optional[int] = None
+    first_expert: int = 0
+    # recompute every layer on the backward pass
+    remat: bool = False
+    # positions a chunk of the head projection + CE
+    ce_chunk_size: int = 2048
+
+    def build(self, mesh=None) -> HybridLM:
+        del mesh   # one device or pure GSPMD: nothing to wire
+        fields = {f.name for f in dataclasses.fields(HybridLM)}
+        return HybridLM(
+            pattern=self.hybrid_override_pattern,
+            **{k: v for k, v in dataclasses.asdict(self).items()
+               if k in fields})
+
+    def batch_partition(self, name: str, ndim: int, mesh) -> tuple:
+        """Rows over 'data' only: the scan and the causal kernels see
+        whole rows."""
+        return ()
+
+    def loss_and_metrics(self, model: HybridLM, params, batch, *, rng=None,
+                         deterministic: bool = True,
+                         policy: Policy = DEFAULT_POLICY):
+        del rng, deterministic   # no dropout, no masking to draw
+        labels, mask = next_token_targets(batch)
+        firsts = batch.get("first_experts")
+        h, loads = model.hidden_states(
+            params, batch["input_ids"], policy=policy,
+            first_experts=None if firsts is None else firsts[0])
+        count = jnp.maximum(mask.sum(), 1.0)
+        nll = fused_linear_nll(
+            params["head"], h.reshape(-1, h.shape[-1]), labels.reshape(-1),
+            chunk_size=self.ce_chunk_size, policy=policy).reshape(mask.shape)
+        loss = (nll * mask).sum() / count
+        metrics = {"loss": loss}
+        if loads.shape[0]:
+            loads = loads.astype(jnp.float32)
+            metrics["moe_assignments"] = loads.sum()
+            metrics["moe_load_max_over_mean"] = (
+                loads.max(-1) / jnp.maximum(loads.mean(-1), 1.0)).max()
+        return loss, metrics

@@ -463,9 +463,13 @@ class Trainer:
             attention_paths,
             format_attention_paths,
         )
+        from perceiver_tpu.ops.moe import moe_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
+        from perceiver_tpu.ops.ssm import scan_paths
+        from perceiver_tpu.ops.tally import format_tally
         with span("train/step_load"), attention_paths() as paths, \
-                remat_keeps() as keeps:
+                remat_keeps() as keeps, scan_paths.counting() as scans, \
+                moe_paths.counting() as experts:
             try:
                 if self._exec_cache is None:
                     step_fn.lower(state, sharded)
@@ -479,9 +483,13 @@ class Trainer:
                 print(f"[step_load] not loaded ahead of time: {e!r}",
                       file=sys.stderr, flush=True)
         self._step_loaded = True
-        print(f"[step_load] attention call sites: "
-              f"{format_attention_paths(paths)}\n"
-              f"[step_load] remat keeps: {format_remat_keeps(keeps)}",
+        lines = [f"attention call sites: {format_attention_paths(paths)}",
+                 f"remat keeps: {format_remat_keeps(keeps)}"]
+        if scans:    # a stack with state-space layers (ops/ssm.py)
+            lines.append(f"selective scans: {format_tally(scans)}")
+        if experts:  # a stack with expert layers (ops/moe.py)
+            lines.append(f"expert layers: {format_tally(experts)}")
+        print("\n".join(f"[step_load] {line}" for line in lines),
               file=sys.stderr, flush=True)
         return step_fn
 

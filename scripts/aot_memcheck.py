@@ -12,21 +12,28 @@ Compiles (compile ONLY — no execution) the full train step of:
    (``benchmarks/configs/ouro_2p6b.json``: 8 layers x 4 passes at the
    published widths, 2 rows of 4096),
 
+5. (``nemotron``) the hybrid state-space / mixture-of-experts LM of
+   the benchmark's ``nemotron_train``
+   (``benchmarks/configs/nemotron3_nano_30b.json``: 9 layers, 8 of 128
+   experts held, 4 rows of 4096),
+
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm``, ``224`` and ``ouro`` run ``remat: true``: beside XLA's sizes
-they print which dear values the layers keep and the bytes reckoned
+``lm``, ``224``, ``ouro`` and ``nemotron`` run ``remat: true``: beside
+XLA's sizes they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
 (the fused attention core; the chip's memory, ``DESCRIBED_MEMORY``; in
 use on it, the parameters and optimizer state the step is handed).
 
-Usage: python scripts/aot_memcheck.py [224 | lm | seg | ouro | all] [rows]
-       (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` in
-       place of the preset's; ``all`` leaves ``ouro`` out)
+Usage: python scripts/aot_memcheck.py
+           [224 | lm | seg | ouro | nemotron | all] [rows]
+       (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` /
+       ``nemotron`` in place of the preset's; ``all`` leaves ``ouro``
+       and ``nemotron`` out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -79,6 +86,7 @@ def _topology_sharding():
     from jax.experimental import topologies
 
     import perceiver_tpu.ops.attention as attention
+    import perceiver_tpu.ops.moe as moe
     import perceiver_tpu.ops.remat as remat
 
     topo = topologies.get_topology_desc(name, platform="tpu")
@@ -88,6 +96,7 @@ def _topology_sharding():
     # a described chip is no backend: what the program reads off the
     # backend is given as that chip would report it
     attention._backend = lambda: "tpu"
+    moe._backend = lambda: "tpu"
     remat._memory_limit = lambda: DESCRIBED_MEMORY[kind]
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
@@ -212,6 +221,12 @@ def check_seg(batch: int = 2, side: int = 512):
     return _compile_train_step(task, batch_arrs, f"seg{side}_b{batch}")
 
 
+def _benchmark_model(name: str) -> dict:
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", f"{name}.json")) as f:
+        return json.load(f)["model"]
+
+
 def check_ouro(per_chip_batch: int = 2):
     """The benchmark's ``ouro_2p6b`` as ``ouro_train`` runs it: the
     ``model`` group of its configuration file, full rows."""
@@ -219,12 +234,27 @@ def check_ouro(per_chip_batch: int = 2):
 
     from perceiver_tpu.tasks import CausalLMTask
 
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                           "configs", "ouro_2p6b.json")) as f:
-        model = json.load(f)["model"]
+    model = _benchmark_model("ouro_2p6b")
     batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
                                     jnp.int32)}
     return _compile_train_step(CausalLMTask(**model), batch, "ouro")
+
+
+def check_nemotron(per_chip_batch: int = 4):
+    """The benchmark's ``nemotron3_nano_30b`` as ``nemotron_train`` runs
+    it: the ``model`` group of its configuration file, full rows, each
+    expert layer's share named by the batch."""
+    import jax.numpy as jnp
+
+    from perceiver_tpu.tasks import HybridLMTask
+
+    model = _benchmark_model("nemotron3_nano_30b")
+    batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
+                                    jnp.int32),
+             "first_experts": jnp.zeros(
+                 (per_chip_batch, model["hybrid_override_pattern"].count("E")),
+                 jnp.int32)}
+    return _compile_train_step(HybridLMTask(**model), batch, "nemotron")
 
 
 def main():
@@ -246,6 +276,8 @@ def main():
         out["seg_512_262k_queries"] = check_seg()
     if which == "ouro":
         out["ouro_2p6b_8_layers"] = check_ouro(**rows)
+    if which == "nemotron":
+        out["nemotron3_nano_30b_9_layers"] = check_nemotron(**rows)
     print(json.dumps(out, indent=2))
 
 

@@ -124,7 +124,7 @@ def test_none_on_the_cpu_is_the_materialized_core():
     assert attn.format_attention_paths(inner) == "materialized[backend]=2"
     with attn.attention_paths() as later:
         pass
-    assert not later and not attn._PATH_TALLIES
+    assert not later and not attn._PATHS._open
 
 
 def test_a_model_step_tallies_every_call_site(monkeypatch):

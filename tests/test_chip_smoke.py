@@ -8,6 +8,7 @@ of its last line and of what it leaves on disk is held.
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -15,18 +16,36 @@ import jax
 import pytest
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SMOKE = os.path.join(REPO_ROOT, "chip_smoke.py")
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)  # chip_smoke.py lives there
 
 
-def _tree_files():
-    """Every file under the checkout, outside git's own directory, the
-    compile cache and Python's bytecode."""
+# what building, testing and running leave in a checkout (.gitignore):
+# not part of the tree the smoke is given
+_LEFT_BEHIND = (".git", ".jax_cache", "__pycache__", ".bench_cache",
+                ".cache", ".scratch", "chiprun_out", "logs",
+                ".pytest_cache", ".hypothesis")
+
+
+def _private_checkout(dest):
+    """A copy of the checkout for the smoke's child alone. The other
+    workers of a parallel run write into the shared one while the child
+    runs (the tokenizer's library beside its source on first use, the
+    benchmark tests' ``chiprun_out/`` and ``.bench_cache/``, ``logs/``):
+    compared there, a file of theirs reads as one the smoke left."""
+    shutil.copytree(
+        REPO_ROOT, dest, symlinks=True,
+        ignore=lambda d, names: [n for n in names if n in _LEFT_BEHIND
+                                 or n.endswith(".so")])
+    return str(dest)
+
+
+def _tree_files(root):
+    """Every file under ``root``, outside the compile cache and Python's
+    bytecode."""
     out = set()
-    for d, dirs, files in os.walk(REPO_ROOT):
-        dirs[:] = [x for x in dirs
-                   if x not in (".git", ".jax_cache", "__pycache__")]
+    for d, dirs, files in os.walk(root):
+        dirs[:] = [x for x in dirs if x not in (".jax_cache", "__pycache__")]
         out.update(os.path.join(d, f) for f in files)
     return out
 
@@ -44,22 +63,25 @@ def cache_config():
         jax.config.update(n, v)
 
 
-def _run_smoke(*argv, env=None, devices=1):
+def _run_smoke(*argv, env=None, devices=1, root=REPO_ROOT):
     child_env = {k: v for k, v in os.environ.items()
                  if k != "JAX_ENABLE_COMPILATION_CACHE"}
     child_env["JAX_PLATFORMS"] = "cpu"
     child_env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={devices}"
     child_env.update(env or {})
-    return subprocess.run([sys.executable, SMOKE, *argv], env=child_env,
-                          capture_output=True, text=True, timeout=600,
-                          cwd=REPO_ROOT)
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py"), *argv],
+        env=child_env, capture_output=True, text=True, timeout=600,
+        cwd=root)
 
 
 def test_rehearsal_runs_every_phase_and_leaves_the_tree_alone(tmp_path):
     cache = tmp_path / "cache"
-    before = _tree_files()
-    proc = _run_smoke("--rehearse",
+    root = _private_checkout(tmp_path / "checkout")
+    before = _tree_files(root)
+    assert os.path.join(root, "chip_smoke.py") in before and len(before) > 300
+    proc = _run_smoke("--rehearse", root=root,
                       env={"JAX_COMPILATION_CACHE_DIR": str(cache)})
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
@@ -76,7 +98,7 @@ def test_rehearsal_runs_every_phase_and_leaves_the_tree_alone(tmp_path):
     # the compile cache went where the variable says and nowhere else;
     # checkpoints, logs and exec-cache blobs left with the temp dir
     assert any(cache.iterdir())
-    assert _tree_files() == before
+    assert _tree_files(root) == before
 
 
 def test_without_a_tpu_the_plain_command_fails_and_prints_no_result():

@@ -256,3 +256,74 @@ def test_looped_exit_loss_gate_and_optimizer_are_scoped(looped_ops):
     update = [n for _, n in ops if "optimizer" in looped_scopes(n)]
     assert len(update) > 30
     assert not any(set(looped_scopes(n)) - {"optimizer"} for n in update)
+
+
+# --- a stack of state-space, expert and attention layers ---------------------
+# (models/hybrid_lm.py): hybrid_stack around the layers; ssm_mixer around
+# a Mamba-2 mixer with ssm_scan around its chunked scan alone; moe around
+# an expert layer with moe_route (router, top-k, sort, gathers, combine)
+# and moe_experts (the grouped products) inside; the attention layer and
+# the shared expert carry the shared scopes.
+
+from perceiver_tpu.tasks import HybridLMTask  # noqa: E402
+
+HYBRID = HybridLMTask(
+    vocab_size=96, hidden_size=32, hybrid_override_pattern="ME*",
+    mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+    chunk_size=16, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    n_routed_experts=8, held_experts=4, first_expert=2,
+    num_experts_per_tok=2, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=48, max_seq_len=32, remat=True,
+    ce_chunk_size=64)
+HYBRID_BATCH = {"input_ids": np.ones((2, 32), np.int32),
+                "valid": np.ones((2,), bool)}
+HYBRID_SCOPES = ("hybrid_stack", "ssm_mixer", "ssm_scan", "moe",
+                 "moe_route", "moe_experts")
+
+
+@pytest.fixture(scope="module")
+def hybrid_ops(tmp_path_factory):
+    lowered = lower_step(HYBRID, HYBRID_BATCH,
+                         tmp_path_factory.mktemp("hybrid"))
+    ops = re.findall(
+        r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"',
+        lowered.compile().as_text())
+    assert len(ops) > 300
+    return ops
+
+
+@pytest.mark.parametrize("scope", HYBRID_SCOPES)
+def test_hybrid_scope_is_in_the_lowered_steps_name_stacks(hybrid_ops, scope):
+    """Each new scope is in the vocabulary and on operations of the
+    forward pass, the backward pass and what ``remat`` recomputes."""
+    assert scope in DEVICE_SCOPES
+    mine = [n for _, n in hybrid_ops if scope in looped_scopes(n)]
+    assert mine
+    assert any("transpose(" in n for n in mine)
+    assert any("rematted_computation" in n for n in mine)
+    assert any("transpose(" not in n and "rematted_computation" not in n
+               for n in mine)
+
+
+def test_hybrid_scopes_nest_as_the_readers_take_them(hybrid_ops):
+    heavy = [(code, n) for code, n in hybrid_ops if code in HEAVY]
+    assert len(heavy) > 40
+    for code, name in heavy:
+        found = looped_scopes(name)
+        if "ssm_scan" in found:
+            assert "ssm_mixer" in found, (code, name)
+        if set(found) & {"moe_route", "moe_experts"}:
+            assert "moe" in found, (code, name)
+        if set(found) & {"ssm_mixer", "moe", "attn_core", "attn_proj"}:
+            assert "hybrid_stack" in found, (code, name)
+        assert not {"ssm_mixer", "moe"} <= set(found), (code, name)
+    # the scan's products are under ssm_scan, the in- and out-projection
+    # under ssm_mixer alone; the experts' products under moe_experts,
+    # the router's under moe_route, the shared expert's under moe alone
+    dots = [looped_scopes(n) for code, n in hybrid_ops if code == "dot"]
+    assert any("ssm_scan" in f for f in dots)
+    assert any("ssm_mixer" in f and "ssm_scan" not in f for f in dots)
+    assert any("moe_route" in f for f in dots)
+    assert any("moe" in f and "mlp" in f and "moe_experts" not in f
+               for f in dots)
+    assert any("moe_experts" in f and "mlp" in f for f in dots)
