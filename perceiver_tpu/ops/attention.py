@@ -289,7 +289,7 @@ def _mesh_of(x):
     return None if mesh is None or mesh.empty else mesh
 
 
-def _mesh_devices(x) -> int:
+def mesh_devices(x) -> int:
     """Devices of the mesh ``x`` is laid out on; 1 on one device."""
     mesh = _mesh_of(x)
     return 1 if mesh is None else mesh.size
@@ -412,7 +412,7 @@ def mha_apply(params, q, k, v, *, num_heads: int,
             backend=_backend(), lq=qh.shape[1], lk=kh.shape[1],
             dropout_active=dropout_rate > 0.0 and not deterministic,
             has_attn_mask=attn_mask is not None,
-            mesh_devices=_mesh_devices(qh), causal=causal,
+            mesh_devices=mesh_devices(qh), causal=causal,
             has_key_padding_mask=key_padding_mask is not None)
         if path == "fused":
             impl = "flash"

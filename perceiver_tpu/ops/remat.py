@@ -75,9 +75,10 @@ REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 
 #: the list a stack with state-space and expert layers chooses from
 #: (``models/hybrid_lm.py``): the three above, then what its own mixers
-#: name. ``ssm_out`` is the chunked scan's output (``ops/ssm.py``: the
-#: scan is a checkpoint of its own, so with its output held the
-#: recomputed layer does not run it a second time), ``ssm_in`` the
+#: name. ``ssm_out`` is the selective scan's output (``ops/ssm.py``: the
+#: fused scan's hand-written backward takes the scan's operands alone,
+#: and the einsum form is a checkpoint of its own, so with the output
+#: held the recomputed layer runs neither a second time), ``ssm_in`` the
 #: in-projection's product. The shared expert's hidden layer is an
 #: ``mlp_hidden``. The routed experts' hidden layer has no name: it
 #: lies inside a ``lax.cond`` over the sorted buffer's size

@@ -28,11 +28,22 @@ decays, ``dt`` and the carried state are float32 whatever the compute
 dtype; the products take operands in the compute dtype and accumulate
 in float32.
 
-The backward pass is autodiff's of the same chunked form. The scan is
-a ``jax.checkpoint`` of its own: its residuals (a chunk's ``Q x Q``
-decays and scores for every head) live while its own backward runs and
-no longer, and a ``remat`` layer that holds its output (``dear``,
-``ssm_out``) does not run it a second time for the layer's sake.
+Two executors of that one form, picked a call from what it can
+observe (``pick_scan``, as ``ops.attention.pick_attention_core`` picks
+a core): the Pallas kernels of ``ops/pallas_ssm.py`` (``fused``: a
+chunk's ``Q x Q`` decays and scores and the carried state never leave
+VMEM, forward or backward) where the backend is a TPU, the operands lie
+on one device and the shapes tile; the einsums below (``chunked``)
+everywhere else, for the first reason that holds. Same chunk
+boundaries, same arithmetic.
+
+The fused scan's backward is written by hand and takes the scan's
+operands alone, so a ``remat`` layer that holds the scan's output
+(``dear``, ``ssm_out``) does not run the forward kernel a second time.
+The einsum form's backward is autodiff's; it is a ``jax.checkpoint`` of
+its own: its residuals (a chunk's ``Q x Q`` decays and scores for every
+head) live while its own backward runs and no longer, and with its
+output held the layer does not run it again for the layer's sake.
 """
 
 from __future__ import annotations
@@ -44,16 +55,44 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
+from perceiver_tpu.ops.attention import mesh_devices
 from perceiver_tpu.ops.initializers import uniform
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.tally import Tally
 
-#: which form the scan took at each call site: ``chunked[128x32]`` (32
-#: chunks of 128 positions), ``chunked[128x3+pad]`` where the last chunk
-#: is padded
+#: which executor the scan took at each call site: ``fused[128x32]`` (32
+#: chunks of 128 positions, the kernels), ``chunked[16x3+pad,backend]``
+#: (the einsums, the last chunk padded, and why not the kernels)
 scan_paths = Tally()
+
+#: why a call site took the einsums, in the order checked
+CHUNKED_REASONS = ("backend", "mesh", "shape")
+
+
+def pick_scan(*, backend: str, mesh_devices: int, chunk: int, state: int,
+              heads_per_group: int, head_dim: int):
+    """``("fused", None)`` or ``("chunked", reason)``, from what the
+    call site can observe."""
+    from perceiver_tpu.ops.pallas_ssm import fits
+    if backend != "tpu":
+        reason = "backend"
+    elif mesh_devices > 1:
+        # a Pallas call has no partitioning rule
+        reason = "mesh"
+    elif not fits(chunk=chunk, state=state, per=heads_per_group,
+                  width=head_dim):
+        reason = "shape"
+    else:
+        return "fused", None
+    return "chunked", reason
+
+
+def _backend() -> str:
+    """The backend the pick reads (a seam: a test that says ``tpu``
+    here gets the kernels, interpreted)."""
+    return jax.default_backend()
 
 
 def ssm_mixer_init(key, dim: int, *, num_heads: int, head_dim: int,
@@ -170,17 +209,25 @@ def _chunked_scan(x, dt, a, b, c, chunk: int):
 
 @device_scope("ssm_scan")
 def ssm_scan(x, dt, a, b, c, *, chunk_size: int):
-    """The selective scan in chunks of ``chunk_size``; a row whose
-    length is no multiple is padded at its end with ``dt = 0`` (no
-    decay, nothing written) and cut again."""
+    """The selective scan in chunks of ``chunk_size``, on the executor
+    ``pick_scan`` names; a row whose length is no multiple is padded at
+    its end with ``dt = 0`` (no decay, nothing written) and cut
+    again."""
     seq = x.shape[1]
     chunk = min(chunk_size, seq)
     pad = -seq % chunk
-    scan_paths.add(f"chunked[{chunk}x{(seq + pad) // chunk}"
-                   f"{'+pad' if pad else ''}]")
+    path, reason = pick_scan(
+        backend=_backend(), mesh_devices=mesh_devices(x), chunk=chunk,
+        state=b.shape[3], heads_per_group=x.shape[2] // b.shape[2],
+        head_dim=x.shape[3])
+    scan_paths.add(f"{path}[{chunk}x{(seq + pad) // chunk}"
+                   f"{'+pad' if pad else ''}{',' + reason if reason else ''}]")
     if pad:
         x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
                                * (v.ndim - 2)) for v in (x, dt, b, c))
+    if path == "fused":
+        from perceiver_tpu.ops.pallas_ssm import fused_scan
+        return fused_scan(x, dt, a, b, c, chunk=chunk)[:, :seq]
     return _chunked_scan(x, dt, a, b, c, chunk)[:, :seq]
 
 

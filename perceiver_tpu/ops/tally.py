@@ -50,7 +50,7 @@ def untallied() -> Iterator[None]:
 
 
 def format_tally(counts) -> str:
-    """``chunked[128x32]=4`` — one log line's worth; ``none traced``
+    """``fused[128x32]=4`` — one log line's worth; ``none traced``
     for an empty tally."""
     return " ".join(f"{key}={n}" for key, n in sorted(counts.items())) \
         or "none traced"

@@ -88,6 +88,7 @@ def _topology_sharding():
     import perceiver_tpu.ops.attention as attention
     import perceiver_tpu.ops.moe as moe
     import perceiver_tpu.ops.remat as remat
+    import perceiver_tpu.ops.ssm as ssm
 
     topo = topologies.get_topology_desc(name, platform="tpu")
     kind = topo.devices[0].device_kind
@@ -97,6 +98,7 @@ def _topology_sharding():
     # backend is given as that chip would report it
     attention._backend = lambda: "tpu"
     moe._backend = lambda: "tpu"
+    ssm._backend = lambda: "tpu"
     remat._memory_limit = lambda: DESCRIBED_MEMORY[kind]
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 

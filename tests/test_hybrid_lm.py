@@ -2,7 +2,9 @@
 (``models/hybrid_lm.py``, ``ops/ssm.py``, ``ops/moe.py``): each mixer's
 forward pass and gradient against the plain reference's, the chunked
 scan against the position-by-position recurrence (a length that is no
-multiple of the chunk, decays that underflow), the share tied to the
+multiple of the chunk, decays that underflow) on both its executors
+(the einsums; the Pallas kernels of ``ops/pallas_ssm.py``, interpreted),
+which of the two a call takes, the share tied to the
 model (16 shares of the experts add up to the uncut layer, the shared
 expert once), no dropped token under a forced imbalance, grouped queries
 against repeated keys and values, no position embedding, what ``remat``
@@ -89,7 +91,8 @@ def test_the_chunked_scan_is_the_recurrence(seq):
         got = ssm.ssm_scan(*args, chunk_size=16)
     chunk = min(16, seq)
     pad = "+pad" if seq % chunk else ""
-    assert dict(forms) == {f"chunked[{chunk}x{-(-seq // chunk)}{pad}]": 1}
+    assert dict(forms) == {
+        f"chunked[{chunk}x{-(-seq // chunk)}{pad},backend]": 1}
     want = recurrence(*args)
     assert got.shape == want.shape == args[0].shape
     assert rel(got, want) < 1e-5
@@ -121,6 +124,167 @@ def test_decays_that_underflow_do_so_quietly():
                        *(v.astype(jnp.bfloat16) for v in args[3:]),
                        chunk_size=16)
     assert low.dtype == jnp.bfloat16 and bool(jnp.isfinite(low).all())
+
+
+# --- the fused scan: the kernels, interpreted --------------------------------
+# The smallest shapes the pick admits: chunks of 128 positions, a state
+# of 128, eight heads a group in whole slabs of 128 lanes.
+
+FUSED = {
+    # eight heads of 16 share one slab; two chunks: a carried state
+    "one_slab": dict(seq=256, rows=1, heads=8, width=16, groups=1),
+    # the cell's layout in small: two heads of 64 a slab, four slabs a
+    # group, two groups, two rows
+    "four_slabs": dict(seq=256, rows=2, heads=16, width=64, groups=2),
+    # a padded last chunk
+    "padded_tail": dict(seq=200, rows=1, heads=8, width=16, groups=1),
+}
+
+
+def as_a_tpu(monkeypatch):
+    """The pick as a TPU would make it; off the chip the kernels run
+    interpreted (``utils/platform.resolve_interpret``)."""
+    monkeypatch.setattr(ssm, "_backend", lambda: "tpu")
+
+
+def fused_inputs(dtype=jnp.float32, dt_scale=0.1, **shape):
+    x, dt, a, b, c = scan_inputs(state=128, dt_scale=dt_scale, **shape)
+    # values the compute dtype holds, so that every form starts alike
+    return (x.astype(dtype), dt, a, b.astype(dtype) / 4, c.astype(dtype) / 4)
+
+
+def in_float32(args):
+    return tuple(v.astype(jnp.float32) for v in args)
+
+
+def value_and_grads(fn, args, w):
+    return jax.value_and_grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+        argnums=range(5))(*args)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", FUSED)
+def test_the_fused_scan_is_the_recurrence(case, dtype, monkeypatch):
+    """Forward and every gradient (x, dt, A, B, C) against the
+    position-by-position recurrence in float32; bfloat16 operands to
+    what bfloat16 products allow."""
+    as_a_tpu(monkeypatch)
+    args = fused_inputs(dtype, **FUSED[case])
+    seq = FUSED[case]["seq"]
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    with ssm.scan_paths.counting() as forms:
+        got = ssm.ssm_scan(*args, chunk_size=128)
+    assert dict(forms) == {
+        f"fused[128x2{'+pad' if seq % 128 else ''}]": 1}
+    assert got.shape == args[0].shape and got.dtype == dtype
+    _, grads = value_and_grads(
+        lambda *a: ssm.ssm_scan(*a, chunk_size=128), args, w)
+    want = recurrence(*in_float32(args))
+    _, want_grads = value_and_grads(recurrence, in_float32(args), w)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert rel(got, want) < tol
+    for g, r, v in zip(grads, want_grads, args):
+        assert g.shape == v.shape and g.dtype == v.dtype
+        assert rel(g, r) < tol
+
+
+def test_the_fused_scan_underflows_quietly(monkeypatch):
+    """As ``test_decays_that_underflow_do_so_quietly``, on the kernels:
+    the mask goes in before the ``exp`` there too, forward and
+    backward."""
+    as_a_tpu(monkeypatch)
+    args = fused_inputs(dt_scale=5.0, a_scale=8.0, **FUSED["one_slab"])
+    assert float((args[1] * args[2]).min()) < -40
+    got, vjp = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk_size=128), *args)
+    grads = vjp(jnp.ones_like(got))
+    assert all(bool(jnp.isfinite(g).all()) for g in (got, *grads))
+    assert rel(got, recurrence(*args)) < 1e-5
+    low = (args[0].astype(jnp.bfloat16), args[1], args[2],
+           *(v.astype(jnp.bfloat16) for v in args[3:]))
+    got, vjp = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk_size=128), *low)
+    grads = vjp(jnp.ones_like(got))
+    assert got.dtype == jnp.bfloat16
+    assert all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
+               for g in (got, *grads))
+
+
+def test_the_fused_scan_rounds_no_more_than_the_einsums(monkeypatch):
+    """At bfloat16 the kernels are no further from the float32
+    recurrence than the einsum form is: the forward is the same
+    arithmetic to the last bit or two; the backward rounds a cotangent
+    only where a product takes it as an operand (on the chip the einsum
+    form's products round their float32 operands too, which the CPU's
+    do not, hence a quarter of room on the gradients)."""
+    args = fused_inputs(jnp.bfloat16, **FUSED["four_slabs"])
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    want, want_grads = value_and_grads(recurrence, in_float32(args), w)
+
+    def errors(fn):
+        got, grads = value_and_grads(fn, args, w)
+        return [abs(float(got) - float(want)) / abs(float(want))] + [
+            float(jnp.linalg.norm((g.astype(jnp.float32) - r).ravel())
+                  / jnp.linalg.norm(r.ravel()))
+            for g, r in zip(grads, want_grads)]
+
+    chunked = errors(lambda *a: ssm.ssm_scan(*a, chunk_size=128))
+    as_a_tpu(monkeypatch)
+    fused = errors(lambda *a: ssm.ssm_scan(*a, chunk_size=128))
+    assert fused[0] <= chunked[0] + 1e-6
+    for f, c in zip(fused[1:], chunked[1:]):
+        assert f <= 1.25 * c
+
+
+# (backend, mesh devices, chunk, state, heads a group, head dim)
+SCAN_CHOICES = {
+    "nemotron_train": (("tpu", 1, 128, 128, 8, 64), ("fused", None)),
+    "heads_of_128": (("tpu", 1, 256, 256, 8, 128), ("fused", None)),
+    "eight_heads_of_16": (("tpu", 1, 128, 128, 8, 16), ("fused", None)),
+    "cpu": (("cpu", 1, 128, 128, 8, 64), ("chunked", "backend")),
+    "gpu": (("gpu", 1, 128, 128, 8, 64), ("chunked", "backend")),
+    "dp2_tp2_mesh": (("tpu", 4, 128, 128, 8, 64), ("chunked", "mesh")),
+    "chunk_of_16": (("tpu", 1, 16, 128, 8, 64), ("chunked", "shape")),
+    "row_shorter_than_a_chunk": (("tpu", 1, 40, 128, 8, 64),
+                                 ("chunked", "shape")),
+    "state_of_16": (("tpu", 1, 128, 16, 8, 64), ("chunked", "shape")),
+    "heads_of_48": (("tpu", 1, 128, 128, 8, 48), ("chunked", "shape")),
+    "half_a_slab": (("tpu", 1, 128, 128, 1, 64), ("chunked", "shape")),
+    "four_heads_a_group": (("tpu", 1, 128, 128, 4, 64),
+                           ("chunked", "shape")),
+    # the first reason in CHUNKED_REASONS' order wins
+    "backend_before_mesh": (("cpu", 4, 16, 16, 2, 8),
+                            ("chunked", "backend")),
+    "mesh_before_shape": (("tpu", 4, 16, 16, 2, 8), ("chunked", "mesh")),
+}
+
+
+@pytest.mark.parametrize("case", SCAN_CHOICES)
+def test_pick_scan(case):
+    (backend, mesh, chunk, state, per, width), want = SCAN_CHOICES[case]
+    got = ssm.pick_scan(backend=backend, mesh_devices=mesh, chunk=chunk,
+                        state=state, heads_per_group=per, head_dim=width)
+    assert got == want
+    assert got[1] is None or got[1] in ssm.CHUNKED_REASONS
+
+
+@pytest.mark.parametrize("case", ["chunk_of_16", "mesh"])
+def test_a_call_the_kernels_do_not_take_says_why(case, monkeypatch):
+    """On a TPU too the einsums run where the kernels cannot, and the
+    tally carries the reason beside the chunks."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    as_a_tpu(monkeypatch)
+    args = fused_inputs(**FUSED["one_slab"])
+    chunk = 16 if case == "chunk_of_16" else 128
+    if case == "mesh":
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        args = tuple(jax.device_put(v, NamedSharding(mesh, P()))
+                     for v in args)
+    with ssm.scan_paths.counting() as forms:
+        got = jax.jit(lambda *a: ssm.ssm_scan(*a, chunk_size=chunk))(*args)
+    reason = "shape" if case == "chunk_of_16" else "mesh"
+    assert dict(forms) == {f"chunked[{chunk}x{256 // chunk},{reason}]": 1}
+    assert rel(got, recurrence(*args)) < 1e-5
 
 
 # --- each mixer against the reference ----------------------------------------
@@ -365,6 +529,35 @@ def test_remat_changes_no_value_whatever_is_kept(toy, kept, monkeypatch):
     assert reckoned["qkv"] == rows * 64 and reckoned["attn_out"] == 0
 
 
+@pytest.mark.parametrize("kept", [HYBRID, HYBRID[:3]],
+                         ids=["ssm_out_kept", "ssm_out_dropped"])
+def test_a_remat_layer_launches_the_scans_forward_kernel_once(kept,
+                                                              monkeypatch):
+    """The fused scan's backward takes the scan's operands alone: with
+    ``ssm_out`` kept the recomputed layer does not run the forward
+    kernel again (one launch a layer and step); with it dropped the
+    layer recomputes it, as it recomputes anything else it does not
+    hold. The backward's two kernels run once either way."""
+    from tests.test_looped_lm import kernel_calls
+    as_a_tpu(monkeypatch)
+    monkeypatch.setattr(remat, "choose_keeps", lambda *a, **k: kept)
+    task = HybridLMTask(**{
+        **TOY, "hybrid_override_pattern": "M", "mamba_head_dim": 16,
+        "n_groups": 1, "ssm_state_size": 128, "chunk_size": 128,
+        "max_seq_len": 256, "remat": True})
+    model = task.build()
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    batch = {"input_ids": jax.ShapeDtypeStruct((2, 256), jnp.int32)}
+    with ssm.scan_paths.counting() as forms:
+        step = jax.make_jaxpr(jax.grad(lambda p, b: task.loss_and_metrics(
+            model, p, b, policy=FP32)[0]))(params, batch).jaxpr
+    assert set(forms) == {"fused[128x2]"}
+    assert kernel_calls(step, "ssm_scan_fwd")[0] == (
+        1 if "ssm_out" in kept else 2)
+    assert kernel_calls(step, "ssm_scan_bwd_states")[0] == 1
+    assert kernel_calls(step, "ssm_scan_bwd")[0] == 2   # the states' too
+
+
 def test_the_names_list_is_the_stacks_own():
     assert HYBRID[:3] == remat.REMAT_NAMES
     assert remat.pick_remat_keeps(
@@ -453,7 +646,7 @@ def test_the_trainer_says_the_forms_and_logs_the_counters(toy, tmp_path,
     assert ("[step_load] attention call sites: materialized[backend]=1\n"
             "[step_load] remat keeps: attn_out,qkv,mlp_hidden,ssm_out,"
             "ssm_in + layer_in 0.00 GB of no memory report\n"
-            "[step_load] selective scans: chunked[16x3+pad]=2\n"
+            "[step_load] selective scans: chunked[16x3+pad,backend]=2\n"
             "[step_load] expert layers: held 4/16=2 ragged_dot[cpu]x240=2 "
             "ragged_dot[cpu]x80=2\n"
             ) in err

@@ -129,6 +129,68 @@ def test_causal_forward_backward_compiles(one_chip, rows, seq, heads, dim):
     assert "flash_attention_fwd" not in text
 
 
+# --- the selective scan ------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows,seq,heads,dim,groups,state,chunk", [
+    (4, 4096, 64, 64, 8, 128, 128),   # nemotron_train: two heads a slab
+    (1, 512, 8, 16, 1, 128, 128),     # eight heads share one slab
+    (1, 512, 8, 128, 1, 256, 256),    # a slab a head, chunks of 256
+], ids=["nemotron_train", "eight_heads_of_16", "heads_of_128"])
+def test_ssm_scan_forward_backward_compiles(one_chip, rows, seq, heads, dim,
+                                            groups, state, chunk):
+    """The fused scan's three kernels (forward; the backward's states
+    pass and its reversed pass) at the cell's shapes (4 x 4,096, 64
+    heads of 64, 8 groups of state 128, chunks of 128) and at the other
+    two ways the heads lie on the lanes."""
+    from perceiver_tpu.ops.pallas_ssm import fused_scan
+
+    def loss(*args):
+        return fused_scan(*args, chunk=chunk,
+                          interpret=False).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    bc = s((rows, seq, groups, state), jnp.bfloat16)
+    # the value keeps the forward kernel live beside the backward pass
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        s((rows, seq, heads, dim), jnp.bfloat16),
+        s((rows, seq, heads), jnp.float32), s((heads,), jnp.float32),
+        bc, bc).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("ssm_scan_fwd", "ssm_scan_bwd_states", "ssm_scan_bwd"):
+        assert name in text
+
+
+def test_ssm_scan_kernels_lie_under_the_scans_scope(one_chip, monkeypatch):
+    """Picked as the trainer's step picks it (``ops.ssm.ssm_scan`` on a
+    TPU), all three kernels carry the scope ``ssm_scan`` in their name
+    stacks, the backward's two under ``transpose(``: what
+    ``ssm_scan_roofline``, ``model.ssm_pct`` and the pass split read."""
+    import re
+
+    import perceiver_tpu.utils.platform as platform
+    from perceiver_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "_backend", lambda: "tpu")
+    monkeypatch.setattr(platform, "default_interpret", lambda: False)
+
+    def loss(*args):
+        return ssm.ssm_scan(*args, chunk_size=128).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    bc = s((1, 512, 1, 128), jnp.bfloat16)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        s((1, 512, 8, 16), jnp.bfloat16), s((1, 512, 8), jnp.float32),
+        s((8,), jnp.float32), bc, bc).compile().as_text()
+    stacks = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "custom-call(" in line]
+    assert sorted(stacks) == [
+        "jit(loss)/jvp(ssm_scan)/ssm_scan_fwd/pallas_call",
+        "jit(loss)/transpose(jvp(ssm_scan))/ssm_scan_bwd/pallas_call",
+        "jit(loss)/transpose(jvp(ssm_scan))/ssm_scan_bwd_states/pallas_call"]
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 
