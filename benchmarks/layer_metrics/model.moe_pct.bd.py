@@ -1,0 +1,13 @@
+"""``model.moe_pct`` for the block-diffusion cell: share of the device's
+busy time in the traced window under ``moe``, everything inside the
+expert layers (the softmax router, sort, gathers, the gated experts'
+three grouped products), forward, recomputed and backward. A metric of
+its own name because ``model.moe_pct`` lists its cells and a test that
+is the benchmark's holds that list to ``nemotron_train``; the reading is
+the same. None where no operation carries the scope."""
+
+from benchmarks import scope_times
+
+
+def read(run):
+    return scope_times.scope_share(run, "moe") or None

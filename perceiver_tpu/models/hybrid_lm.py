@@ -8,10 +8,23 @@ Nemotron 3 Nano; ``hybrid_override_pattern``)::
     logits = rms_f(h) Wh                                  (head untied)
 
 ``M`` is a Mamba-2 mixer (``ops/ssm.py``), ``E`` an expert layer
-(``ops/moe.py``), ``*`` causal attention with grouped queries and **no
-rotary embedding** (the Mamba layers carry position). A layer is one
-RMSNorm with a scale, one mixer and a residual: there is no separate MLP
-after ``M`` or ``*``. No linear layer has a bias.
+(``ops/moe.py``), ``*`` causal attention with grouped queries and, in
+``nemotron_h``, **no rotary embedding** (the Mamba layers carry
+position). A layer is one RMSNorm with a scale, one mixer and a
+residual: there is no separate MLP after ``M`` or ``*``. No linear
+layer has a bias.
+
+A Qwen3-MoE decoder layer (``h += attn(rms(h)); h += moe(rms(h))``, the
+layer of SDAR) is two of these, ``*E``: its attention has rotary
+positions (``rope_theta``) and an RMSNorm over each head's channels of
+the projected queries and keys (``qk_norm``), its router is a softmax
+with the top-k renormalised (``router_scoring``, ``norm_topk_prob``),
+its experts are gated with three matrices (``gated_experts``) and it
+has no shared expert (``moe_shared_expert_intermediate_size`` 0). A
+call may run a row as **block diffusion** trains it
+(``block_diffusion=(L, B)``: ``L`` noised positions beside their ``L``
+clean ones, ``ops.attention.block_diffusion_mask``'s rules in the
+causal triangle's place, position ``j mod L`` for index ``j``).
 
 The attention layer runs on the cores every causal call takes
 (``ops.attention.mha_apply``): its ``num_kv_heads`` key/value heads are
@@ -45,10 +58,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops import remat
-from perceiver_tpu.ops.attention import data_shards, mha_apply, untallied
+from perceiver_tpu.ops.attention import (
+    data_shards,
+    head_rms_norm,
+    mha_apply,
+    untallied,
+)
+from perceiver_tpu.ops.fourier import rope_apply, rope_tables
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.moe import moe_apply, moe_init
@@ -61,14 +81,18 @@ LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn"}
 
 
 def gqa_init(key, dim: int, num_heads: int, num_kv_heads: int,
-             head_dim: int):
+             head_dim: int, qk_norm: bool = False):
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    params = {
         "q": linear_init(kq, dim, num_heads * head_dim, bias=False),
         "k": linear_init(kk, dim, num_kv_heads * head_dim, bias=False),
         "v": linear_init(kv, dim, num_kv_heads * head_dim, bias=False),
         "out": linear_init(ko, num_heads * head_dim, dim, bias=False),
     }
+    if qk_norm:   # one scale of head_dim each, for all the heads
+        params.update(q_norm=rms_norm_init(head_dim),
+                      k_norm=rms_norm_init(head_dim))
+    return params
 
 
 def repeat_kv(x, num_kv_heads: int, num_heads: int):
@@ -84,36 +108,69 @@ def repeat_kv(x, num_kv_heads: int, num_heads: int):
 def gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
               policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None):
     """Causal attention with grouped queries, no position embedding."""
+    return rotary_gqa_apply(params, a, num_heads=num_heads,
+                            num_kv_heads=num_kv_heads, policy=policy,
+                            impl=impl)
+
+
+def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
+                     policy: Policy = DEFAULT_POLICY,
+                     impl: Optional[str] = None, rope=None,
+                     norm_eps: float = 1e-6, block_diffusion=None):
+    """Attention with grouped queries under the causal mask, or under
+    the block-diffusion mask of ``block_diffusion=(L, B)``. Where the
+    tree holds ``q_norm`` / ``k_norm``, queries and keys take an RMSNorm
+    over each head's channels; ``rope`` (tables with a row a position of
+    ``a``) rotates them after it. The keys are normed and rotated on
+    their own ``num_kv_heads`` heads, before they are repeated."""
     with device_scope("attn_proj"):
-        k, v = (repeat_kv(linear_apply(params[n], a, policy=policy),
-                          num_kv_heads, num_heads) for n in ("k", "v"))
+        k = linear_apply(params["k"], a, policy=policy)
+        if "k_norm" in params:
+            k = head_rms_norm(params["k_norm"], k, num_kv_heads, norm_eps,
+                              policy)
+        if rope is not None:
+            k = rope_apply(k, *rope, num_kv_heads)
+        k = repeat_kv(k, num_kv_heads, num_heads)
+        v = repeat_kv(linear_apply(params["v"], a, policy=policy),
+                      num_kv_heads, num_heads)
     return mha_apply(params, a, None, None, num_heads=num_heads,
-                     kv_heads=(k, v), causal=True, policy=policy, impl=impl)
+                     kv_heads=(k, v), causal=block_diffusion is None,
+                     block_diffusion=block_diffusion, rope=rope,
+                     norm_eps=norm_eps, policy=policy, impl=impl)
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class HybridLM:
     vocab_size: int
     hidden_size: int
     pattern: str                     # one of M, E, * a layer
-    # M
-    mamba_num_heads: int
-    mamba_head_dim: int
-    n_groups: int
-    ssm_state_size: int
-    conv_kernel: int
-    chunk_size: int
+    max_seq_len: int
+    # M (a pattern without M needs none of them)
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 1
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    chunk_size: int = 128
     # *
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
+    # rotary positions at this base; None: no position embedding
+    rope_theta: Optional[float] = None
+    # an RMSNorm over each head's channels of the projected q and k
+    qk_norm: bool = False
     # E
     n_routed_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
-    moe_shared_expert_intermediate_size: int
-    routed_scaling_factor: float
-    max_seq_len: int
+    # 0: no shared expert
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    router_scoring: str = "sigmoid"  # or softmax (ops/moe.SCORINGS)
+    norm_topk_prob: bool = True      # the chosen scores over their sum
+    # (silu(a Wg) * (a Wu)) Wd, three matrices; else relu(a Wu)^2 Wd
+    gated_experts: bool = False
     # the experts held here, from first_expert on; None: all of them
     held_experts: Optional[int] = None
     first_expert: int = 0
@@ -131,6 +188,11 @@ class HybridLM:
                 or self.mamba_num_heads % self.n_groups:
             raise ValueError("query heads divide over the key/value heads, "
                              "Mamba heads over the groups")
+        if "M" in self.pattern and not (
+                self.mamba_num_heads and self.mamba_head_dim
+                and self.ssm_state_size):
+            raise ValueError("a pattern with M needs mamba_num_heads, "
+                             "mamba_head_dim and ssm_state_size")
         held = self.num_held_experts
         if not 0 <= self.first_expert <= self.n_routed_experts - held:
             raise ValueError(
@@ -161,9 +223,11 @@ class HybridLM:
                 key, c, num_experts=self.n_routed_experts,
                 held_experts=self.num_held_experts,
                 expert_hidden=self.moe_intermediate_size,
-                shared_hidden=self.moe_shared_expert_intermediate_size)
+                shared_hidden=self.moe_shared_expert_intermediate_size,
+                gated=self.gated_experts)
         return gqa_init(key, c, self.num_attention_heads,
-                        self.num_key_value_heads, self.head_dim)
+                        self.num_key_value_heads, self.head_dim,
+                        self.qk_norm)
 
     def init(self, key):
         ke, kl, kh = jax.random.split(key, 3)
@@ -182,11 +246,13 @@ class HybridLM:
                 kh, (c, self.vocab_size), _INIT_STD)},
         }
 
-    def _layer(self, kind: str, policy: Policy):
+    def _layer(self, kind: str, policy: Policy, rope=None,
+               block_diffusion=None):
         """``(layer_params, h, first) -> (h, load)`` of one kind;
         ``first`` is an expert layer's first held expert (None:
         ``first_expert``) and ``load`` its assignments a held expert,
-        both None elsewhere."""
+        both None elsewhere. ``rope`` and ``block_diffusion`` are the
+        attention layers' (``rotary_gqa_apply``)."""
         def layer(p, h, first=None):
             a = rms_norm_apply(p["norm"], h, self.norm_eps, policy)
             load = None
@@ -202,11 +268,15 @@ class HybridLM:
                     p["mixer"], a, top_k=self.num_experts_per_tok,
                     first_expert=(self.first_expert if first is None
                                   else first),
-                    scaling=self.routed_scaling_factor, policy=policy)
+                    scaling=self.routed_scaling_factor,
+                    scoring=self.router_scoring,
+                    renormalize=self.norm_topk_prob, policy=policy)
             else:
-                out = gqa_apply(
+                out = rotary_gqa_apply(
                     p["mixer"], a, num_heads=self.num_attention_heads,
-                    num_kv_heads=self.num_key_value_heads, policy=policy)
+                    num_kv_heads=self.num_key_value_heads, policy=policy,
+                    rope=rope, norm_eps=self.norm_eps,
+                    block_diffusion=block_diffusion)
             return h + out, load
 
         return layer
@@ -232,19 +302,36 @@ class HybridLM:
             names=remat.HYBRID_REMAT_NAMES)
 
     def hidden_states(self, params, input_ids, *, first_experts=None,
-                      policy: Policy = DEFAULT_POLICY):
+                      policy: Policy = DEFAULT_POLICY, block_diffusion=None):
         """``(the final normed state (B, S, C) in the compute dtype,
         loads)``; ``loads`` (expert layers, held) int32: the
         assignments each held expert computed, a row an expert layer.
         ``first_experts`` (expert layers,) int32: each expert layer's
-        first held expert, in ``first_expert``'s place."""
-        seq = input_ids.shape[1]
-        if seq > self.max_seq_len:
-            raise ValueError(f"{seq} positions, max_seq_len "
+        first held expert, in ``first_expert``'s place.
+        ``block_diffusion`` ``(L, B)``: ``input_ids`` are rows of ``2 L``
+        positions, the noised copy beside the clean one, under the
+        block-diffusion mask; index ``j`` has position ``j mod L``."""
+        seq = positions = input_ids.shape[1]
+        if block_diffusion is not None:
+            positions = block_diffusion[0]
+            if seq != 2 * positions:
+                raise ValueError(f"{seq} positions for a block-diffusion "
+                                 f"row of 2 x {positions}")
+        if positions > self.max_seq_len:
+            raise ValueError(f"{positions} positions, max_seq_len "
                              f"{self.max_seq_len}")
+        rope = None
+        if self.rope_theta is not None:
+            rope = rope_tables(positions, self.head_dim, self.rope_theta)
+            if block_diffusion is not None:
+                rope = tuple(np.concatenate([t, t]) for t in rope)
+            # one array a table, which every layer is handed: a numpy
+            # table is written into the step's text once a use (24 times
+            # 4 MB in a six-layer step of 8,192 positions)
+            rope = tuple(jnp.asarray(t) for t in rope)
         with device_scope("input_adapter"):
             h = policy.cast_compute(params["embed"]["embed"][input_ids])
-        layers = [(name, self._layer(kind, policy))
+        layers = [(name, self._layer(kind, policy, rope, block_diffusion))
                   for name, kind in zip(self.layer_names(), self.pattern)]
         loads = []
         firsts = iter(() if first_experts is None else first_experts)
@@ -264,10 +351,11 @@ class HybridLM:
                    jnp.zeros((0, self.num_held_experts), jnp.int32))
 
     def apply(self, params, input_ids, *, first_experts=None,
-              policy: Policy = DEFAULT_POLICY):
+              policy: Policy = DEFAULT_POLICY, block_diffusion=None):
         """Dense logits ``(B, S, V)`` float32."""
         h, _ = self.hidden_states(params, input_ids,
-                                  first_experts=first_experts, policy=policy)
+                                  first_experts=first_experts, policy=policy,
+                                  block_diffusion=block_diffusion)
         with device_scope("loss"):
             return linear_apply(params["head"], h,
                                 policy=policy).astype(jnp.float32)

@@ -152,6 +152,10 @@ DEVICE_SCOPES = (
     # the grouped products over the held experts
     "hybrid_stack", "ssm_mixer", "ssm_scan", "moe", "moe_route",
     "moe_experts",
+    # a block-diffusion step's own noising of its rows
+    # (tasks/block_diffusion_lm): the masking rates, the masks, the
+    # noised copy and the loss weights
+    "bd_noise",
 )
 
 _SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES)

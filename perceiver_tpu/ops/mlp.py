@@ -72,7 +72,9 @@ def mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
 
 # --- gated (SwiGLU) MLP ------------------------------------------------------
 # (silu(x Wg) * (x Wu)) Wd, no biases, no norm of its own: the decoder
-# layer that uses it norms before and after (models/looped_lm.py).
+# layer that uses it norms before and after (models/looped_lm.py); with
+# a grouped ``product`` over rows sorted by expert, the gated routed
+# experts of ``ops/moe.py`` (``params`` then holds the experts stacked).
 
 
 def gated_mlp_init(key, dim: int, hidden: int, dtype=jnp.float32):
@@ -85,11 +87,16 @@ def gated_mlp_init(key, dim: int, hidden: int, dtype=jnp.float32):
 
 
 @device_scope("mlp")
-def gated_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY):
-    gate = dear(linear_apply(params["gate"], x, policy=policy), "mlp_hidden")
-    up = dear(linear_apply(params["up"], x, policy=policy), "mlp_hidden")
-    return linear_apply(params["down"], jax.nn.silu(gate) * up,
-                        policy=policy)
+def gated_mlp_apply(params, x, policy: Policy = DEFAULT_POLICY, *,
+                    product=linear_apply, name: str = "mlp_hidden"):
+    """``product`` and ``name`` as ``relu2_mlp_apply``'s: a grouped
+    product over rows sorted by expert makes these the routed experts of
+    ``ops/moe.py``."""
+    gate, up = (product(params[n], x, policy=policy)
+                if name is None else
+                dear(product(params[n], x, policy=policy), name)
+                for n in ("gate", "up"))
+    return product(params["down"], jax.nn.silu(gate) * up, policy=policy)
 
 
 # --- relu-squared MLP --------------------------------------------------------

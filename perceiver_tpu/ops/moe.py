@@ -1,50 +1,70 @@
-"""Routed-expert layer (the ``nemotron_h`` layer ``E``) as pure
-init/apply functions: a sigmoid router over **all** ``num_experts``
-experts, the top ``top_k`` a token, relu-squared experts without a
-gate, one shared expert::
+"""Routed-expert layer (the layer ``E`` of ``models/hybrid_lm.py``) as
+pure init/apply functions: a router over **all** ``num_experts``
+experts, the top ``top_k`` a token, and the experts this layer holds.
+The router, the experts and the shared expert are the configuration's::
 
-    s = sigmoid(a W_r)                          (float32, all the experts)
+    s = score(a W_r)                            (float32, all the experts)
     chosen = top_k(s)
-    w_i = s_i / (sum_chosen s + 1e-20) * routed_scaling_factor
-    out = sum_chosen w_i f_i(a) + f_shared(a),  f(a) = relu(a W_up)^2 W_down
+    w_i = s_i / (sum_chosen s + 1e-20) * scaling      (s_i * scaling where
+                                                       not renormalised)
+    out = sum_chosen w_i f_i(a) [+ f_shared(a)]
 
-(The published layer adds a buffer, ``e_score_correction_bias``, to the
-scores it chooses by, and moves it by a balancing rule its
-``config.json`` does not give: here it is 0 and not read.)
+* ``score``: ``sigmoid`` (``nemotron_h``: with a scaling factor; its
+  published layer adds a buffer, ``e_score_correction_bias``, to the
+  scores it chooses by, and moves it by a balancing rule its
+  ``config.json`` does not give: here it is 0 and not read) or
+  ``softmax`` over all the experts (Qwen3-MoE, SDAR: the top-k
+  renormalised, ``norm_topk_prob``, no scaling). The ``1e-20`` guards a
+  sum of sigmoids; beside a softmax's top-k sum it is nothing in
+  float32.
+* ``f``: ``relu(a W_up)^2 W_down``, two matrices (a tree with ``up``
+  and ``down``), or gated, ``(silu(a W_gate) * (a W_up)) W_down``, three
+  (a tree with ``gate`` too).
+* a shared expert (a tree with ``shared``: relu-squared, every token)
+  or none.
 
 **The layer is told which experts it holds**: ``first_expert`` (an
 int, or a scalar of the step) and the number of experts in its
 parameter tree (a chip's share under expert parallelism). It routes
 over all the experts, computes the part of the result that its own
-experts give for the tokens routed to them, adds the shared expert, and
-leaves out what the absent experts would have added. Nothing stands in
-for the absent chips or their exchange.
+experts give for the tokens routed to them, adds the shared expert
+where there is one, and leaves out what the absent experts would have
+added. Nothing stands in for the absent chips or their exchange.
 
 **No token is dropped.** The ``T x top_k`` assignments are sorted by
 held expert, the assignments to absent experts last, and the first rows
 of that order, as many as the held experts were sent, are computed:
-whatever the imbalance, every (token, held expert) pair. The two
-products over the held experts are grouped (``grouped_product``): each
-expert multiplies the rows routed to it and no others.
+whatever the imbalance, every (token, held expert) pair. The products
+over the held experts are grouped (``grouped_product``): each expert
+multiplies the rows routed to it and no others.
 
-The buffer the sorted rows are gathered into has a static size: one
-row a token (``T`` rows: 2.7 times what an even router sends 8 held
-experts of 128 at top 6), and where a step's assignments do not fit
-(``lax.cond`` on their count) ``T x top_k`` rows, everything top-k
-allows: the same function at another size, so that the usual step moves
-thousands of rows and not a hundred thousand (gathering and weighting
-98,304 rows on every step cost 130 ms of 684; PERF.md, PR 33). The
-groups end at the last held assignment, so **the products' work is the
-router's**: the rows past it (zeros) belong to no group, no product
-reads them, the kernel leaves what it returns there uninitialised, and
-they are masked wherever a value leaves the buffer. A token's row goes
-to its assignments by a gather and comes back by a scatter-add;
-autodiff transposes each into the other.
+The buffer the sorted rows are gathered into has a static size, and
+**the usual size follows the share** (``usual_rows``). A token chooses
+an expert at most once, so an expert is sent at most ``T`` assignments,
+a row a token, and an untrained router sends about that to each of the
+few experts it favours (0.5 to 1.7 times the even load by the batch
+for a share of an untrained softmax router; PERF.md, PR 37). An even
+router fills ``top_k x held / experts`` such experts' worth; the buffer
+has room for twice that, in whole ones, and never less than one: ``T``
+rows for 8 held of 128 at top 6 (2.7 times the even load), ``2 T`` for
+16 held of 128 at top 8, where the even load is ``T`` itself. Where
+a step's assignments do not fit (``lax.cond`` on their count) the
+buffer is ``T x top_k`` rows, everything top-k allows: the same
+function at another size, so that the usual step moves thousands of
+rows and not a hundred thousand (gathering and weighting 98,304 rows on
+every step cost 130 ms of 684; PERF.md, PR 33). The groups end at the
+last held assignment, so **the products' work is the router's**: the
+rows past it (zeros) belong to no group, no product reads them, the
+kernel leaves what it returns there uninitialised, and they are masked
+wherever a value leaves the buffer. A token's row goes to its
+assignments by a gather and comes back by a scatter-add; autodiff
+transposes each into the other.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,26 +72,42 @@ import jax.numpy as jnp
 from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.initializers import torch_linear_uniform
 from perceiver_tpu.ops.linear import linear_init
-from perceiver_tpu.ops.mlp import relu2_mlp_apply, relu2_mlp_init
+from perceiver_tpu.ops.mlp import (
+    gated_mlp_apply,
+    relu2_mlp_apply,
+    relu2_mlp_init,
+)
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.tally import Tally
+from perceiver_tpu.ops.tiling import round_up
 
 #: which form the grouped products of a layer took, with the rows of the
 #: sorted buffer at each of its sizes (``megabloxx16384``,
-#: ``megabloxx98304``; ``ragged_dot[cpu]x240``), and how many experts of
-#: how many the layer holds (``held 8/128``)
+#: ``megabloxx98304``; ``ragged_dot[cpu]x240``; the usual size says so
+#: where the share and not the tokens set it,
+#: ``megabloxx32768[2 x even share]``), and how many experts of how many
+#: the layer holds (``held 8/128``)
 moe_paths = Tally()
+#: what a layer's router and experts are: ``softmax top 8 renormalised``,
+#: ``gated silu x3 products`` or ``relu2 x2 products``, ``no shared
+#: expert`` or ``shared expert``
+moe_kinds = Tally()
+
+SCORINGS = ("sigmoid", "softmax")
 
 # rows of the sorted buffer a tile of the grouped kernel takes
 _TILE_ROWS = 512
 
 
 def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
-             expert_hidden: int, shared_hidden: int, dtype=jnp.float32):
+             expert_hidden: int, shared_hidden: int, gated: bool = False,
+             dtype=jnp.float32):
     """The router over all ``num_experts``, the ``held_experts`` this
-    layer holds (stacked on a leading axis) and the shared expert."""
+    layer holds (stacked on a leading axis; with a ``gate`` matrix each
+    where ``gated``) and the shared expert (none at a ``shared_hidden``
+    of 0)."""
     kr, ku, kd, ks = jax.random.split(key, 4)
-    return {
+    params = {
         "router": linear_init(kr, dim, num_experts, dtype, bias=False),
         "experts": {
             "up": {"w": torch_linear_uniform(
@@ -80,8 +116,14 @@ def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
                 kd, (held_experts, expert_hidden, dim), expert_hidden,
                 dtype)},
         },
-        "shared": relu2_mlp_init(ks, dim, shared_hidden, dtype),
     }
+    if gated:
+        params["experts"]["gate"] = {"w": torch_linear_uniform(
+            jax.random.fold_in(ku, 1), (held_experts, dim, expert_hidden),
+            dim, dtype)}
+    if shared_hidden:
+        params["shared"] = relu2_mlp_init(ks, dim, shared_hidden, dtype)
+    return params
 
 
 # --- the grouped product -----------------------------------------------------
@@ -135,24 +177,44 @@ def grouped_product(params, x, group_sizes, *,
 
 
 @device_scope("moe_route")
-def route(params, a, *, top_k: int, scaling: float):
+def route(params, a, *, top_k: int, scaling: float,
+          scoring: str = "sigmoid", renormalize: bool = True):
     """``(chosen (T, top_k) int32, weights (T, top_k) float32)``: the
-    router in float32 over all the experts, ``a`` (T, C)."""
-    scores = jax.nn.sigmoid(jnp.einsum(
+    router in float32 over all the experts, ``a`` (T, C); ``scoring``
+    one of ``SCORINGS``, the chosen scores divided by their sum where
+    ``renormalize``."""
+    if scoring not in SCORINGS:
+        raise ValueError(f"router scoring {scoring!r} not in {SCORINGS}")
+    logits = jnp.einsum(
         "tc,ce->te", a.astype(jnp.float32),
         params["w"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     chosen = jax.lax.top_k(scores, top_k)[1]
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
-    return chosen, weights
+    if renormalize:
+        picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen, picked * scaling
+
+
+def usual_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
+    """Rows of the sorted buffer a usual step takes: ``tokens`` (what
+    one expert can be sent) times twice the ``top_k x held / experts``
+    an even router fills, in whole ones and never less than one; more
+    than one in whole tiles of the grouped kernel, and never more than
+    the ``tokens x top_k`` there are."""
+    full = max(1, 2 * top_k * held // experts)
+    if full == 1:
+        return tokens
+    return min(tokens * top_k, round_up(full * tokens, _TILE_ROWS))
 
 
 @functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
-def _routed(experts, a, weights, order, load, usual: bool, top_k: int,
+def _routed(experts, a, weights, order, load, rows: int, top_k: int,
             policy: Policy):
     """The held experts' part of the result, (T, C), from the first
-    rows of the sorted order: ``T`` of them in the ``usual`` buffer
+    ``rows`` rows of the sorted order: the usual buffer's
     (``load.sum()`` is no more), else all ``T x top_k``. A checkpoint
     of its own, as the scan of ``ops/ssm.py`` is: what its backward
     needs is made again from its arguments when the backward runs, so
@@ -160,14 +222,14 @@ def _routed(experts, a, weights, order, load, usual: bool, top_k: int,
     the ``lax.cond`` around this is held at the size of the larger
     branch)."""
     with device_scope("moe_route"):
-        rows = a.shape[0] * (1 if usual else top_k)
         order = order[:rows]
         token = order // top_k
         computed = (jnp.arange(rows) < load.sum())[:, None]
         taken = jnp.where(computed, a[token], 0)
         scale = weights.reshape(-1)[order][:, None]
     with device_scope("moe_experts"):
-        y = relu2_mlp_apply(
+        mlp = gated_mlp_apply if "gate" in experts else relu2_mlp_apply
+        y = mlp(
             experts, taken, policy, name=None,
             product=functools.partial(grouped_product, group_sizes=load))
     with device_scope("moe_route"):
@@ -183,16 +245,27 @@ def _routed(experts, a, weights, order, load, usual: bool, top_k: int,
 
 @device_scope("moe")
 def moe_apply(params, a, *, top_k: int, first_expert=0,
-              scaling: float = 1.0, policy: Policy = DEFAULT_POLICY):
+              scaling: float = 1.0, scoring: str = "sigmoid",
+              renormalize: bool = True,
+              policy: Policy = DEFAULT_POLICY):
     """a (B, S, C) -> ``(out (B, S, C), load)``; ``load`` (held,)
-    int32, the assignments each held expert computed."""
+    int32, the assignments each held expert computed. The experts'
+    kind and the shared expert are the parameter tree's."""
     shape, dim = a.shape, a.shape[-1]
     a = a.reshape(-1, dim)
     tokens = a.shape[0]
     held = params["experts"]["up"]["w"].shape[0]
-    moe_paths.add(f"held {held}/{params['router']['w'].shape[1]}")
+    experts = params["router"]["w"].shape[1]
+    moe_paths.add(f"held {held}/{experts}")
+    moe_kinds.add(f"{scoring} top {top_k}"
+                  + (" renormalised" if renormalize else ""))
+    moe_kinds.add("gated silu x3 products" if "gate" in params["experts"]
+                  else "relu2 x2 products")
+    moe_kinds.add("shared expert" if "shared" in params
+                  else "no shared expert")
     chosen, weights = route(params["router"], a, top_k=top_k,
-                            scaling=scaling)
+                            scaling=scaling, scoring=scoring,
+                            renormalize=renormalize)
     with device_scope("moe_route"):
         local = chosen.reshape(-1) - first_expert
         # the absent experts' assignments sort last, as group ``held``
@@ -200,15 +273,19 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
         load = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
 
-    def routed(usual):
-        return lambda *args: _routed(*args, usual, top_k, policy)
+    def routed(rows):
+        return lambda *args: _routed(*args, rows, top_k, policy)
 
+    usual = usual_rows(tokens, top_k, held, experts)
     label = pick_grouped_product()[1]
     # counted here: the routed part is traced once a size, however
     # often it is differentiated
-    moe_paths.add(f"{label}x{tokens}")
+    moe_paths.add(f"{label}x{usual}" + (
+        "[2 x even share]" if usual > tokens else ""))
     moe_paths.add(f"{label}x{tokens * top_k}")
-    out = jax.lax.cond(load.sum() <= tokens, routed(True), routed(False),
+    out = jax.lax.cond(load.sum() <= usual, routed(usual),
+                       routed(tokens * top_k),
                        params["experts"], a, weights, order, load)
-    out = out + relu2_mlp_apply(params["shared"], a, policy)
+    if "shared" in params:
+        out = out + relu2_mlp_apply(params["shared"], a, policy)
     return out.reshape(shape), load

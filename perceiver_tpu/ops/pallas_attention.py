@@ -70,6 +70,37 @@ so the mask covers them too. The calls are named
 counts a ``flash_attention_*`` call in full never sees them. A
 non-causal call traces exactly what it traced before the mode existed.
 
+Block-diffusion mode (``block_diffusion=(L, B)``, square scores over
+``2 L`` positions, no key bias): a row is its noised copy beside its
+clean copy, blocks of ``B`` positions, and query ``j`` sees key ``l``
+
+* ``j <  L, l <  L``: where both lie in one block (a noised block sees
+  itself, both ways);
+* ``j <  L, l >= L``: where ``l``'s block lies before ``j``'s (and the
+  clean blocks before it);
+* ``j >= L, l >= L``: where ``l``'s block is ``j``'s or lies before it
+  (a clean block sees the clean blocks up to itself);
+* ``j >= L, l <  L``: never.
+
+Each ``(query tile, key tile)`` is *skipped*, *masked* or *plain*
+(``ops/tiling.diffusion_tiles``, from the rules over the tile's index
+ranges, on the host): the kinds and the tile to hold while one is skipped reach
+the kernel and its index maps as two prefetched scalar tables. With
+``L`` a multiple of the tiles the noised-noised quadrant runs its
+diagonal tiles only (masked), the noised-clean and clean-clean
+quadrants their diagonal tiles masked and the tiles below plain, the
+clean-noised quadrant nothing; any other ``L`` is classified the same
+way, tile by tile. A masked tile's mask is built from a column of
+query numbers against a row of key numbers. Padded positions count as
+clean ones past the row's end: no real query sees a padded key. The
+calls are named ``block_diffusion_attention_fwd`` /
+``block_diffusion_attention_bwd``.
+
+So the kernels know three masks, by the name of their calls: none
+(``flash_attention_*``, with an optional key bias), the causal
+triangle (``causal_attention_*``) and the block-diffusion mask
+(``block_diffusion_attention_*``).
+
 On non-TPU backends the kernels run in Pallas interpreter mode, so
 tests exercise the identical code path on CPU.
 """
@@ -85,7 +116,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from perceiver_tpu.ops.remat import dear
-from perceiver_tpu.ops.tiling import round_up as _round_up
+from perceiver_tpu.ops.tiling import (
+    MASKED,
+    PLAIN,
+    diffusion_tiles,
+    held_tiles,
+    round_up as _round_up,
+)
 
 from perceiver_tpu.ops.chunked_attention import NEG_INF
 
@@ -110,8 +147,9 @@ _CAUSAL_BLOCK_K = 1024
 
 def pick_blocks(lq: int, lk: int, causal: bool = False):
     """``(block_q, block_k)`` for ``Lq`` queries over ``Lk`` keys. A
-    causal call streams its keys from ``_CAUSAL_BLOCK_K`` up: only
-    blocks can be skipped, and one block of 2048 keys skips none."""
+    causal call (a block-diffusion call too) streams its keys from
+    ``_CAUSAL_BLOCK_K`` up: only blocks can be skipped, and one block of
+    2048 keys skips none."""
     lk_p = _round_up(lk, _LANES)
     if causal:
         block_k = min(lk_p, _CAUSAL_BLOCK_K)
@@ -177,13 +215,46 @@ def _when_needed(needed, crossed, body):
         lambda: body(False))
 
 
+# --- the block-diffusion mask ------------------------------------------------
+
+def _diffusion_mask(s, first_q, first_k, half: int, block: int,
+                    transposed: bool):
+    """``s`` with NEG_INF where the block-diffusion rules hide the key
+    from the query; ``s`` (queries, keys), or (keys, queries) where
+    ``transposed``. A column of query numbers meets a row of key
+    numbers (the other way round where transposed): per position, the
+    clean blocks a query sees end before ``limit`` and its own noised
+    block is ``own``; two comparisons a pair."""
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    q_shape = tuple(s.shape[a] if a == q_axis else 1 for a in (0, 1))
+    k_shape = tuple(s.shape[a] if a == k_axis else 1 for a in (0, 1))
+    q = first_q + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
+    k = first_k + jax.lax.broadcasted_iota(jnp.int32, k_shape, k_axis)
+    q_noised, k_noised = q < half, k < half
+    q_block = jax.lax.div(jnp.where(q_noised, q, q - half), block)
+    k_block = jax.lax.div(jnp.where(k_noised, k, k - half), block)
+    limit = jnp.where(q_noised, q_block, q_block + 1)
+    own = jnp.where(q_noised, q_block, -1)
+    k_clean = jnp.where(k_noised, jnp.int32(2 ** 30), k_block)
+    k_own = jnp.where(k_noised, k_block, -2)
+    return jnp.where((k_clean < limit) | (k_own == own), s, NEG_INF)
+
+
+def _when_kind(kind, body):
+    """Run ``body(masked)`` for a block-diffusion tile of ``kind``."""
+    pl.when(kind == MASKED)(lambda: body(True))
+    pl.when(kind == PLAIN)(lambda: body(False))
+
+
 # --- forward -----------------------------------------------------------------
 
 
 def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
                 save_lse: bool, causal: bool = False, block_q: int = 0,
-                block_k: int = 0):
+                block_k: int = 0, diffusion=None):
     refs = iter(refs)
+    if diffusion:   # the tiles' kinds; the held tiles are the index maps'
+        kind_ref, _ = next(refs), next(refs)
     q_ref, k_ref, v_ref = next(refs), next(refs), next(refs)
     bias_ref = next(refs) if has_bias else None
     o_ref = next(refs)
@@ -203,7 +274,7 @@ def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
     k = k_ref[0]              # (block_k, W)
     v = v_ref[0]
     masks = _head_masks(q.shape[-1], group)
-    if causal:
+    if causal or diffusion:
         first_q = pl.program_id(2) * block_q
         first_k = ik * block_k
 
@@ -214,7 +285,9 @@ def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
                                     preferred_element_type=jnp.float32)
             if has_bias:
                 s = s + bias_ref[0]   # (1, block_k) key bias row
-            if masked:
+            if masked and diffusion:
+                s = _diffusion_mask(s, first_q, first_k, *diffusion, False)
+            elif masked:
                 s = _causal_mask(s, first_q, first_k, False)
             if nk == 1:
                 # every key in this block: a plain softmax, no running
@@ -243,7 +316,9 @@ def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
         if nk == 1:
             o_ref[0] = out.astype(o_ref.dtype)
 
-    if not causal:
+    if diffusion:
+        _when_kind(kind_ref[pl.program_id(2) * nk + ik], tile)
+    elif not causal:
         tile(False)
     elif nk == 1:
         tile(True)
@@ -328,9 +403,33 @@ def _geometry(lq: int, lk: int, e: int, h: int, block_q: int,
             _round_up(lq, block_q), _round_up(lk, block_k))
 
 
+def _call_name(causal: bool, diffusion, which: str) -> str:
+    """The custom call's name: by it a trace's reader knows the mask."""
+    mask = "block_diffusion" if diffusion else \
+        "causal" if causal else "flash"
+    return f"{mask}_attention_{which}"
+
+
+def _launch(kernel, tables, args, *, name: str, grid, in_specs, out_specs,
+            out_shape, scratch, interpret: bool):
+    """One kernel over ``grid``. ``tables`` (the block-diffusion mask's)
+    are prefetched to scalar memory ahead of the grid: the kernel's
+    first references and the index maps' last arguments."""
+    common = dict(out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
+                  interpret=interpret, name=name)
+    if not tables:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs, scratch_shapes=scratch,
+                              **common)(*args)
+    return pl.pallas_call(kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch), **common)(
+            *tables, *args)
+
+
 def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
                    block_k: int, interpret: bool, save_lse: bool,
-                   causal: bool = False):
+                   causal: bool = False, diffusion=None):
     """q (B, Lq, H·D), k/v (B, Lk, H·D) → ``o`` (B, Lq, H·D); with
     ``save_lse`` (the differentiated call) ``o`` in float32 as the
     kernel accumulated it and the per-row log-sum-exp as
@@ -346,21 +445,30 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
     q = _pad_rows(_pad_heads(q, h, dp), lq_p)
     k = _pad_rows(_pad_heads(k, h, dp), lk_p)
     v = _pad_rows(_pad_heads(v, h, dp), lk_p)
-    # the causal mask covers padded keys: they lie after every real row
-    bias = None if causal else _key_bias(bias, b, lk, lk_p)
+    # the causal mask covers padded keys: they lie after every real
+    # row; the block-diffusion mask's padded keys are clean positions
+    # past every real row's
+    bias = None if causal or diffusion else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
+    tables = ()
+    if diffusion:
+        kinds = diffusion_tiles(*diffusion, block_q, block_k, nq, nk)
+        tables = (jnp.asarray(kinds.ravel()), jnp.asarray(
+            held_tiles(kinds).ravel()))
 
-    def k_index(ib, ih, iq, ik):
+    def k_index(ib, ih, iq, ik, *tables):
         if causal:
             # above the diagonal hold the last block a query of this
             # block sees: the pipeline fetches nothing it has
             ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+        if tables:
+            ik = tables[1][iq * nk + ik]
         return ib, ik, ih
 
     in_specs = [
         pl.BlockSpec((1, block_q, width),
-                     lambda ib, ih, iq, ik: (ib, iq, ih)),
+                     lambda ib, ih, iq, ik, *_: (ib, iq, ih)),
         pl.BlockSpec((1, block_k, width), k_index),
         pl.BlockSpec((1, block_k, width), k_index),
     ]
@@ -370,13 +478,13 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
                                      lambda ib, ih, iq, ik: (ib, 0, ik)))
         args.append(bias[:, None, :])
     out_specs = [pl.BlockSpec((1, block_q, width),
-                              lambda ib, ih, iq, ik: (ib, iq, ih))]
+                              lambda ib, ih, iq, ik, *_: (ib, iq, ih))]
     out_shape = [jax.ShapeDtypeStruct(
         (b, lq_p, h * dp), jnp.float32 if save_lse else q.dtype)]
     if save_lse:
         out_specs.append(pl.BlockSpec(
             (1, group, 1, block_q),
-            lambda ib, ih, iq, ik: (ib, ih, 0, iq)))
+            lambda ib, ih, iq, ik, *_: (ib, ih, 0, iq)))
         out_shape.append(
             jax.ShapeDtypeStruct((b, h, 1, lq_p), jnp.float32))
     scratch = [] if nk == 1 else [
@@ -384,19 +492,15 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
         pltpu.VMEM((group, block_q, _LANES), jnp.float32),  # normalizer
         pltpu.VMEM((block_q, width), jnp.float32),   # unnormalized acc
     ]
-    out = pl.pallas_call(
+    out = _launch(
         functools.partial(_fwd_kernel, scale=scale, nk=nk, group=group,
                           has_bias=has_bias, save_lse=save_lse,
-                          causal=causal, block_q=block_q, block_k=block_k),
-        grid=(b, h // group, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name="causal_attention_fwd" if causal else "flash_attention_fwd",
-    )(*args)
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          diffusion=diffusion),
+        tables, args, name=_call_name(causal, diffusion, "fwd"),
+        grid=(b, h // group, nq, nk), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape, scratch=scratch,
+        interpret=interpret)
     o = _unpad_heads(out[0][:, :lq], h, d)
     return (o, out[1][..., :lq]) if save_lse else o
 
@@ -406,8 +510,10 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
 
 def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
                 group: int, has_bias: bool, causal: bool = False,
-                block_k: int = 0):
+                block_k: int = 0, diffusion=None):
     refs = iter(refs)
+    if diffusion:   # the tiles' kinds; the held tiles are the index maps'
+        kind_ref, _ = next(refs), next(refs)
     q_ref, k_ref, v_ref, do_ref = (next(refs) for _ in range(4))
     kbar_ref, vbar_ref = next(refs), next(refs)
     lse_ref, delta_ref = next(refs), next(refs)
@@ -421,6 +527,20 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
     ik = pl.program_id(2)
     # the first query block that sees this key block
     first_iq = (ik * block_k) // block_q if causal else 0
+    if diffusion:
+        # which tiles run is the table's to say: the accumulators start
+        # from zeros at the head of each sweep and every tile adds
+        if nq > 1:
+            @pl.when(iq == 0)
+            def _():
+                dk_acc[:] = jnp.zeros_like(dk_acc)
+                dv_acc[:] = jnp.zeros_like(dv_acc)
+        if nk > 1:
+            @pl.when(ik == 0)
+            def _():
+                rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+                dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
+                                               dq_ref.dtype)
 
     q = q_ref[0] * scale      # (block_q, W), operand dtype
     k = k_ref[0]              # (block_k, W)
@@ -442,7 +562,10 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
                                     preferred_element_type=jnp.float32)
             if has_bias:
                 s = s + bias_ref[0]   # (block_k, 1) key bias column
-            if masked:
+            if masked and diffusion:
+                s = _diffusion_mask(s, iq * block_q, ik * block_k,
+                                    *diffusion, True)
+            elif masked:
                 s = _causal_mask(s, iq * block_q, ik * block_k, True)
             p = jnp.exp(s - lse_ref[0, g])               # rows (1, block_q)
             dp = jax.lax.dot_general(vc, dog, _NT,
@@ -471,20 +594,26 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
             # float32 block of the whole (b, head group), resident
             # across the sweep; every query block sees key block 0
             rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
-
-            @pl.when(ik == 0)
-            def _():
-                dq_ref[0, rows, :] = dq
-
-            @pl.when(ik > 0)
-            def _():
+            if diffusion:
                 dq_ref[0, rows, :] += dq
+            else:
+                @pl.when(ik == 0)
+                def _():
+                    dq_ref[0, rows, :] = dq
+
+                @pl.when(ik > 0)
+                def _():
+                    dq_ref[0, rows, :] += dq
 
         if nq == 1:
             dk_ref[0] = dk.astype(dk_ref.dtype)
             dv_ref[0] = dv.astype(dv_ref.dtype)
             if has_bias:
                 db_ref[0, 0] = _col_to_row(db)   # a lane-dense row in HBM
+            return
+        if diffusion:
+            dk_acc[:] += dk
+            dv_acc[:] += dv
             return
 
         @pl.when(iq == first_iq)
@@ -501,7 +630,9 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
             if has_bias:
                 db_acc[:] += db
 
-    if not causal:
+    if diffusion:
+        _when_kind(kind_ref[ik * nq + iq], tile)
+    elif not causal:
         tile(False)
     elif nq == 1:
         tile(True)     # one query block: every key block writes its own
@@ -521,7 +652,7 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
 
 def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
                     block_q: int, block_k: int, interpret: bool,
-                    causal: bool = False):
+                    causal: bool = False, diffusion=None):
     """``dq, dk, dv`` (and ``dbias`` (B, Lk) where a bias was given)
     from the saved float32 output and log-sum-exp row; all
     (B, L, H·D)."""
@@ -551,9 +682,14 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
     k = _pad_rows(_pad_heads(k, h, dp), lk_p)
     v = _pad_rows(_pad_heads(v, h, dp), lk_p)
     kbar, vbar = _pad_heads(kbar, h, dp), _pad_heads(vbar, h, dp)
-    bias = None if causal else _key_bias(bias, b, lk, lk_p)
+    bias = None if causal or diffusion else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
+    tables = ()
+    if diffusion:   # the forward's tiles, swept keys first
+        kinds = diffusion_tiles(*diffusion, block_q, block_k, nq, nk).T
+        tables = (jnp.asarray(kinds.ravel()), jnp.asarray(
+            held_tiles(kinds).ravel()))
     if nk > 1 and lq_p * width * 4 > _DQ_RESIDENT_MAX:
         raise NotImplementedError(
             f"flash attention backward keeps dq ({lq_p} x {width} "
@@ -561,22 +697,26 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
             f"{_DQ_RESIDENT_MAX} bytes use impl='chunked', or chunk the "
             "queries")
 
-    def seen(ik, iq):
+    def seen(ik, iq, *tables):
         """The query block read at grid step (ik, iq): before the first
         that sees this key block, that first one (nothing new to
-        fetch)."""
+        fetch); where a table says, the block it holds."""
         if causal and nq > 1:
             iq = jnp.maximum(iq, (ik * block_k) // block_q)
+        if tables:
+            iq = tables[1][ik * nq + iq]
         return iq
 
-    q_spec = pl.BlockSpec((1, block_q, width),
-                          lambda ib, ih, ik, iq: (ib, seen(ik, iq), ih))
+    q_spec = pl.BlockSpec(
+        (1, block_q, width),
+        lambda ib, ih, ik, iq, *t: (ib, seen(ik, iq, *t), ih))
     k_spec = pl.BlockSpec((1, block_k, width),
-                          lambda ib, ih, ik, iq: (ib, ik, ih))
-    row_spec = pl.BlockSpec((1, group, 1, block_q),
-                            lambda ib, ih, ik, iq: (ib, ih, 0, seen(ik, iq)))
+                          lambda ib, ih, ik, iq, *_: (ib, ik, ih))
+    row_spec = pl.BlockSpec(
+        (1, group, 1, block_q),
+        lambda ib, ih, ik, iq, *t: (ib, ih, 0, seen(ik, iq, *t)))
     mean_spec = pl.BlockSpec((1, 1, width),
-                             lambda ib, ih, ik, iq: (ib, 0, ih))
+                             lambda ib, ih, ik, iq, *_: (ib, 0, ih))
     in_specs = [q_spec, k_spec, k_spec, q_spec, mean_spec, mean_spec,
                 row_spec, row_spec]
     args = [q, k, v, do, kbar, vbar, lse, delta]
@@ -589,7 +729,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         dq_spec, dq_dtype = q_spec, q.dtype
     else:
         dq_spec = pl.BlockSpec((1, lq_p, width),
-                               lambda ib, ih, ik, iq: (ib, 0, ih))
+                               lambda ib, ih, ik, iq, *_: (ib, 0, ih))
         dq_dtype = jnp.float32
     out_specs = [dq_spec, k_spec, k_spec]
     out_shape = [jax.ShapeDtypeStruct(q.shape, dq_dtype),
@@ -607,20 +747,15 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         if nq > 1:
             scratch.append(pltpu.VMEM((block_k, 1), jnp.float32))
 
-    dq, dk, dv, *db = pl.pallas_call(
+    dq, dk, dv, *db = _launch(
         functools.partial(_bwd_kernel, scale=scale, nq=nq, nk=nk,
                           block_q=block_q, group=group,
                           has_bias=has_bias, causal=causal,
-                          block_k=block_k),
-        grid=(b, h // group, nk, nq),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name="causal_attention_bwd" if causal else "flash_attention_bwd",
-    )(*args)
+                          block_k=block_k, diffusion=diffusion),
+        tables, args, name=_call_name(causal, diffusion, "bwd"),
+        grid=(b, h // group, nk, nq), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape, scratch=scratch,
+        interpret=interpret)
 
     def trim(x, rows):
         return _unpad_heads(x[:, :rows], h, d)
@@ -632,21 +767,22 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
 # --- the differentiable core -------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, bias, h, scale, block_q, block_k, interpret, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, bias, h, scale, block_q, block_k, interpret, causal,
+           diffusion):
     # forward-only use: no residual output leaves the kernel
     return _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
-                          interpret, False, causal)
+                          interpret, False, causal, diffusion)
 
 
 def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret,
-               causal):
+               causal, diffusion):
     # the residual output stays float32: the backward's delta =
     # rowsum(do * o) must cancel sum_k(dp * p) to float32 rounding, or
     # every key of a row gets the same push and dq drifts along the
     # keys' common component (seen as update_norm_gap, PERF.md PR 26)
     o, lse = _flash_forward(q, k, v, bias, h, scale, block_q, block_k,
-                            interpret, True, causal)
+                            interpret, True, causal, diffusion)
     # named for a ``remat`` layer's save list (ops/remat.py): with the
     # pair saved the backward does not run this kernel again; the bf16
     # output below is a cast of it and is recomputed, not held too
@@ -654,11 +790,12 @@ def _flash_fwd(q, k, v, bias, h, scale, block_q, block_k, interpret,
     return o.astype(q.dtype), (q, k, v, bias, o, lse)
 
 
-def _flash_bwd(h, scale, block_q, block_k, interpret, causal, res, g):
+def _flash_bwd(h, scale, block_q, block_k, interpret, causal, diffusion,
+               res, g):
     q, k, v, bias, o, lse = res
     dq, dk, dv, dbias = _flash_backward(
         q, k, v, bias, o, lse, g.astype(q.dtype), h, scale, block_q,
-        block_k, interpret, causal)
+        block_k, interpret, causal, diffusion)
     if dbias is not None:
         # a learned additive key bias trains the same as under
         # "chunked"/"einsum"; a mask's cotangent is dropped by its caller
@@ -670,7 +807,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention_channels(q, k, v, *, num_heads: int, bias=None,
-                             causal: bool = False,
+                             causal: bool = False, block_diffusion=None,
                              scale: Optional[float] = None,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
@@ -678,7 +815,10 @@ def flash_attention_channels(q, k, v, *, num_heads: int, bias=None,
     """Fused attention on heads as the projections leave them, side by
     side on the channel axis. q: (B, Lq, H·D); k, v: (B, Lk, H·D);
     bias: optional (B, Lk) additive key bias (NEG_INF at padding);
-    ``causal``: query i sees keys 0..i (Lq == Lk, no bias).
+    ``causal``: query i sees keys 0..i (Lq == Lk, no bias);
+    ``block_diffusion``: ``(L, B)``, a row of ``L`` noised positions
+    beside their ``L`` clean ones in blocks of ``B`` (Lq == Lk == 2 L,
+    no bias, not causal; the rules at the head of this file).
     Blocks come from the shapes (``pick_blocks``) unless given.
     Returns (B, Lq, H·D) in q's dtype."""
     from perceiver_tpu.utils.platform import resolve_interpret
@@ -690,13 +830,27 @@ def flash_attention_channels(q, k, v, *, num_heads: int, bias=None,
             "causal attention is over square scores with no key bias: "
             f"{q.shape[1]} queries, {k.shape[1]} keys, bias "
             f"{'given' if bias is not None else 'None'}")
+    if block_diffusion is not None:
+        half, block = (int(n) for n in block_diffusion)
+        if causal or bias is not None or half % block \
+                or not q.shape[1] == k.shape[1] == 2 * half:
+            raise ValueError(
+                "block-diffusion attention is over the square scores of "
+                f"2 x {half} positions in blocks of {block}, with no key "
+                f"bias and no causal mask beside it: {q.shape[1]} queries, "
+                f"{k.shape[1]} keys, bias "
+                f"{'given' if bias is not None else 'None'}, causal "
+                f"{causal}")
+        block_diffusion = (half, block)
     if scale is None:
         scale = 1.0 / ((q.shape[-1] // num_heads) ** 0.5)
-    auto_q, auto_k = pick_blocks(q.shape[1], k.shape[1], causal)
+    auto_q, auto_k = pick_blocks(q.shape[1], k.shape[1],
+                                 causal or block_diffusion is not None)
     return _flash(q, k, v, bias, int(num_heads), float(scale),
                   int(auto_q if block_q is None else block_q),
                   int(auto_k if block_k is None else block_k),
-                  resolve_interpret(interpret), bool(causal))
+                  resolve_interpret(interpret), bool(causal),
+                  block_diffusion)
 
 
 def flash_attention(q, k, v, **kwargs):

@@ -7,3 +7,6 @@ from perceiver_tpu.tasks.mlm import MaskedLanguageModelTask  # noqa: F401
 from perceiver_tpu.tasks.segmentation import SegmentationTask  # noqa: F401
 from perceiver_tpu.tasks.causal_lm import CausalLMTask  # noqa: F401
 from perceiver_tpu.tasks.hybrid_lm import HybridLMTask  # noqa: F401
+from perceiver_tpu.tasks.block_diffusion_lm import (  # noqa: F401
+    BlockDiffusionLMTask,
+)

@@ -463,13 +463,14 @@ class Trainer:
             attention_paths,
             format_attention_paths,
         )
-        from perceiver_tpu.ops.moe import moe_paths
+        from perceiver_tpu.ops.moe import moe_kinds, moe_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         from perceiver_tpu.ops.ssm import scan_paths
         from perceiver_tpu.ops.tally import format_tally
         with span("train/step_load"), attention_paths() as paths, \
                 remat_keeps() as keeps, scan_paths.counting() as scans, \
-                moe_paths.counting() as experts:
+                moe_paths.counting() as experts, \
+                moe_kinds.counting() as kinds:
             try:
                 if self._exec_cache is None:
                     step_fn.lower(state, sharded)
@@ -489,6 +490,7 @@ class Trainer:
             lines.append(f"selective scans: {format_tally(scans)}")
         if experts:  # a stack with expert layers (ops/moe.py)
             lines.append(f"expert layers: {format_tally(experts)}")
+            lines.append(f"expert kinds: {format_tally(kinds)}")
         print("\n".join(f"[step_load] {line}" for line in lines),
               file=sys.stderr, flush=True)
         return step_fn

@@ -17,12 +17,17 @@ Compiles (compile ONLY — no execution) the full train step of:
    (``benchmarks/configs/nemotron3_nano_30b.json``: 9 layers, 8 of 128
    experts held, 4 rows of 4096),
 
+6. (``sdar``) the block-diffusion mixture-of-experts LM of the
+   benchmark's ``sdar_train`` (``benchmarks/configs/sdar_30b_a3b.json``:
+   6 layers, 16 of 128 experts held, 2 rows of 4096 data tokens, twice
+   that through the stack),
+
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm``, ``224``, ``ouro`` and ``nemotron`` run ``remat: true``: beside
+``lm``, ``224``, ``ouro``, ``nemotron`` and ``sdar`` run ``remat: true``: beside
 XLA's sizes they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
@@ -30,10 +35,10 @@ that read the backend are made as the described chip would make them
 use on it, the parameters and optimizer state the step is handed).
 
 Usage: python scripts/aot_memcheck.py
-           [224 | lm | seg | ouro | nemotron | all] [rows]
+           [224 | lm | seg | ouro | nemotron | sdar | all] [rows]
        (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` /
-       ``nemotron`` in place of the preset's; ``all`` leaves ``ouro``
-       and ``nemotron`` out)
+       ``nemotron`` / ``sdar`` in place of the preset's; ``all`` leaves
+       ``ouro``, ``nemotron`` and ``sdar`` out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -259,6 +264,22 @@ def check_nemotron(per_chip_batch: int = 4):
     return _compile_train_step(HybridLMTask(**model), batch, "nemotron")
 
 
+def check_sdar(per_chip_batch: int = 2):
+    """The benchmark's ``sdar_30b_a3b`` as ``sdar_train`` runs it: the
+    ``model`` group of its configuration file, full rows (the step
+    doubles them), each expert layer's share named by the batch."""
+    import jax.numpy as jnp
+
+    from perceiver_tpu.tasks import BlockDiffusionLMTask
+
+    model = _benchmark_model("sdar_30b_a3b")
+    batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
+                                    jnp.int32),
+             "first_experts": jnp.zeros(
+                 (per_chip_batch, model["num_hidden_layers"]), jnp.int32)}
+    return _compile_train_step(BlockDiffusionLMTask(**model), batch, "sdar")
+
+
 def main():
     import jax
 
@@ -280,6 +301,8 @@ def main():
         out["ouro_2p6b_8_layers"] = check_ouro(**rows)
     if which == "nemotron":
         out["nemotron3_nano_30b_9_layers"] = check_nemotron(**rows)
+    if which == "sdar":
+        out["sdar_30b_a3b_6_layers"] = check_sdar(**rows)
     print(json.dumps(out, indent=2))
 
 

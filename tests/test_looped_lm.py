@@ -113,36 +113,59 @@ def test_a_linear_tree_without_b_is_a_projection_without_bias():
 # --- the causal kernel mode --------------------------------------------------
 
 
-def causal_reference(q, k, v, heads):
-    """The materialised core under a lower-triangular mask."""
+def causal_reference(q, k, v, heads, block_diffusion=None):
+    """The materialised core under a lower-triangular mask, or under
+    the block-diffusion mask written out pair by pair from its rules."""
     b, s, e = q.shape
-    bias = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
-                     attn.NEG_INF)[None, None]
+    sees = jnp.tril(jnp.ones((s, s), bool))
+    if block_diffusion is not None:
+        half, block = block_diffusion
+        sees = np.zeros((s, s), bool)
+        for j in range(s):
+            for l in range(s):   # noqa: E741
+                if j < half and l < half:
+                    sees[j, l] = j // block == l // block
+                elif j < half:
+                    sees[j, l] = (l - half) // block < j // block
+                elif l >= half:
+                    sees[j, l] = (l - half) // block <= (j - half) // block
+        assert (sees == ~np.asarray(
+            attn.block_diffusion_mask(half, block))).all()
+    bias = jnp.where(jnp.asarray(sees), 0.0, attn.NEG_INF)[None, None]
     split = [x.reshape(b, s, heads, e // heads) for x in (q, k, v)]
     out = attn._sdpa_core(1.0 / math.sqrt(e // heads), 0.0, jnp.float32,
                           *split, bias, None)
     return out.reshape(b, s, e)
 
 
-@pytest.mark.parametrize("seq,heads,dim,block_q,block_k", [
-    (256, 2, 128, 128, 128),    # whole blocks, a block a head
-    (200, 2, 64, 128, 128),     # a padded last block, two heads a block
-    (384, 4, 32, 128, 256),     # keys in wider blocks than queries
-    (512, 2, 128, 256, 128),    # queries in wider blocks than keys
-    (300, 2, 128, None, None),  # blocks from the shapes, ragged
-    (130, 2, 16, 128, 128),     # two rows into the second block
+@pytest.mark.parametrize("seq,heads,dim,block_q,block_k,block_diffusion", [
+    (256, 2, 128, 128, 128, None),    # whole blocks, a block a head
+    (200, 2, 64, 128, 128, None),     # a padded last block, two heads a block
+    (384, 4, 32, 128, 256, None),     # keys in wider blocks than queries
+    (512, 2, 128, 256, 128, None),    # queries in wider blocks than keys
+    (300, 2, 128, None, None, None),  # blocks from the shapes, ragged
+    (130, 2, 16, 128, 128, None),     # two rows into the second block
+    # the third mask: 2 L positions in blocks of B, L no multiple of a tile
+    (384, 2, 64, 128, 128, (192, 4)),     # the halves meet inside a tile
+    (320, 1, 128, 128, 256, (160, 32)),   # wide blocks, a padded last tile
+    (512, 2, 128, 128, 128, (256, 4)),    # L in whole tiles: 10 of 16 run
+    (200, 4, 32, None, None, (100, 4)),   # one tile of everything
 ], ids=["whole", "ragged_d64", "wide_keys", "wide_queries", "picked",
-        "barely_two_blocks"])
+        "barely_two_blocks", "block_diffusion_b4", "block_diffusion_b32",
+        "block_diffusion_whole_tiles", "block_diffusion_one_tile"])
 def test_causal_kernels_match_the_materialised_core(seq, heads, dim,
-                                                    block_q, block_k):
+                                                    block_q, block_k,
+                                                    block_diffusion):
     q, k, v, g = (normal(10 + i, (2, seq, heads * dim)) for i in range(4))
-    kw = dict(num_heads=heads, causal=True, block_q=block_q,
-              block_k=block_k)
+    kw = dict(num_heads=heads, block_q=block_q, block_k=block_k,
+              **(dict(causal=True) if block_diffusion is None
+                 else dict(block_diffusion=block_diffusion)))
+    heads = (heads, block_diffusion)
     assert rel(flash_attention_channels(q, k, v, **kw),
-               causal_reference(q, k, v, heads)) < 1e-5
+               causal_reference(q, k, v, *heads)) < 1e-5
     got = jax.grad(lambda *a: (flash_attention_channels(*a, **kw)
                                * g).sum(), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (causal_reference(*a, heads) * g).sum(),
+    want = jax.grad(lambda *a: (causal_reference(*a, *heads) * g).sum(),
                     (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         assert rel(a, b) < 1e-5

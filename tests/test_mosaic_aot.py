@@ -104,6 +104,35 @@ def test_flash_forward_backward_compiles_at_cell_shapes(one_chip, lq, lk,
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("rows,half,block,heads,dim", [
+    (2, 4096, 4, 32, 128),    # sdar_train: 1024 x 1024 tiles, 24 of 64 run
+    (1, 1000, 4, 8, 64),      # the halves meet inside a tile, two heads a block
+    (1, 1024, 32, 4, 128),    # blocks of 32
+], ids=["sdar_train", "ragged_d64", "blocks_of_32"])
+def test_block_diffusion_forward_backward_compiles(one_chip, rows, half,
+                                                   block, heads, dim):
+    """The block-diffusion mode (the tiles' kinds and the tiles to hold
+    prefetched to scalar memory, the masked tiles' mask from a column
+    of query numbers against a row of key numbers) on the chip's own
+    compiler, under its own kernel names."""
+    from perceiver_tpu.ops.pallas_attention import (
+        flash_attention_channels as flash_attention,
+    )
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, num_heads=heads,
+                               block_diffusion=(half, block),
+                               interpret=False).astype(jnp.float32).sum()
+
+    q = _struct(one_chip)((rows, 2 * half, heads * dim), jnp.bfloat16)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "block_diffusion_attention_fwd" in text
+    assert "block_diffusion_attention_bwd" in text
+    assert "causal_attention" not in text
+
+
 @pytest.mark.parametrize("rows,seq,heads,dim", [
     (2, 4096, 16, 128),     # ouro_train: 1024 x 1024 blocks, 10 of 16 run
     (2, 1000, 8, 64),       # a padded last block, two heads a lane block
