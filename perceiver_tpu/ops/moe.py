@@ -56,15 +56,27 @@ every step cost 130 ms of 684; PERF.md, PR 33). The groups end at the
 last held assignment, so **the products' work is the router's**: the
 rows past it (zeros) belong to no group, no product reads them, the
 kernel leaves what it returns there uninitialised, and they are masked
-wherever a value leaves the buffer. A token's row goes to its
-assignments by a gather and comes back by a scatter-add; autodiff
-transposes each into the other.
+wherever a value leaves the buffer.
+
+**The layer routes once a layer and step.** What says where rows go is
+a plan of integers (``routing_plan``: the chosen experts, the sorted
+order, the loads and the way back by token), made by two stable sorts
+and named ``moe_plan`` for a ``remat`` stack to keep: the recomputed
+layer makes the router's product and the scores again (the weights'
+gradient needs them) and reads the plan; it runs no second ``top_k``
+and no second sort. A token's row goes to its assignments by a gather
+(``dispatch``) and the rows come back as each token's sum
+(``combine``), each with a hand-written backward that is the other's
+forward, so that no pass scatter-adds rows whose indices repeat: a
+token's rows are one stretch of the way back, a tile of tokens reads
+its stretch by a gather and sums it by a 0/1 product in float32
+(``sum_by_token``).
 """
 
 from __future__ import annotations
 
 import functools
-import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +90,7 @@ from perceiver_tpu.ops.mlp import (
     relu2_mlp_init,
 )
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
+from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.tally import Tally
 from perceiver_tpu.ops.tiling import round_up
 
@@ -85,7 +98,7 @@ from perceiver_tpu.ops.tiling import round_up
 #: sorted buffer at each of its sizes (``megabloxx16384``,
 #: ``megabloxx98304``; ``ragged_dot[cpu]x240``; the usual size says so
 #: where the share and not the tokens set it,
-#: ``megabloxx32768[2 x even share]``), and how many experts of how many
+#: ``megabloxx32768[2 x even share]``), how many experts of how many
 #: the layer holds (``held 8/128``)
 moe_paths = Tally()
 #: what a layer's router and experts are: ``softmax top 8 renormalised``,
@@ -182,7 +195,10 @@ def route(params, a, *, top_k: int, scaling: float,
     """``(chosen (T, top_k) int32, weights (T, top_k) float32)``: the
     router in float32 over all the experts, ``a`` (T, C); ``scoring``
     one of ``SCORINGS``, the chosen scores divided by their sum where
-    ``renormalize``."""
+    ``renormalize``. ``chosen`` is part of the layer's plan
+    (``moe_plan``): a ``remat`` layer that keeps it makes the product
+    and the score again, which the weights' gradient needs, and reads
+    the chosen scores at the kept ``chosen``: no second ``top_k``."""
     if scoring not in SCORINGS:
         raise ValueError(f"router scoring {scoring!r} not in {SCORINGS}")
     logits = jnp.einsum(
@@ -191,8 +207,13 @@ def route(params, a, *, top_k: int, scaling: float,
         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    chosen = jax.lax.top_k(scores, top_k)[1]
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    chosen = dear(jax.lax.top_k(scores, top_k)[1], "moe_plan")
+    # the chosen scores by a select over the experts' axis: the TPU
+    # gathers scalars one at a time (``take_along_axis`` here cost
+    # 1.3 ms a layer and pass for 131,072 of them; PERF.md, PR 38)
+    picked = jnp.sum(jnp.where(
+        chosen[..., None] == jnp.arange(scores.shape[-1]),
+        scores[:, None, :], 0), -1)
     if renormalize:
         picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return chosen, picked * scaling
@@ -210,37 +231,206 @@ def usual_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
     return min(tokens * top_k, round_up(full * tokens, _TILE_ROWS))
 
 
-@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
-def _routed(experts, a, weights, order, load, rows: int, top_k: int,
-            policy: Policy):
+# --- the plan ----------------------------------------------------------------
+
+
+def _sorted_by(key):
+    """``(key sorted, its old places)`` for ``key`` (n,) int32, equal
+    keys in their old order: what a stable ``argsort`` gives, with the
+    sorted keys beside it."""
+    place = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, place), num_keys=1, is_stable=True)
+
+
+class Plan(NamedTuple):
+    """Where one layer's assignments go and how their rows come back,
+    made once a layer and step (``routing_plan``). ``N = T x top_k``;
+    assignment ``t top_k + j`` is token ``t``'s ``j``-th choice; the
+    sorted buffer's row ``r`` holds assignment ``order[r]``, and its
+    first ``load.sum()`` rows are the held experts'."""
+
+    order: jax.Array       # (N,) int32: the assignment a sorted row holds
+    load: jax.Array        # (held,) int32: assignments a held expert
+    back_row: jax.Array    # (N,) int32: the sorted rows by token
+    back_token: jax.Array  # (N,) int32: their tokens; T past the held
+
+    def token(self, rows: int, tokens: int):
+        """(rows,) int32: the token of each of the first ``rows`` sorted
+        rows, of ``tokens`` tokens."""
+        return self.order[:rows] // (self.order.size // tokens)
+
+
+@device_scope("moe_route")
+def routing_plan(chosen, first_expert, held: int) -> Plan:
+    """The plan of ``chosen`` (T, top_k) for the ``held`` experts from
+    ``first_expert`` on. An assignment's group is its held expert,
+    ``held`` for an absent one; ``order`` is what ``jnp.argsort(group,
+    stable=True)`` gives, element for element. ``back_row`` lists the
+    held experts' rows by token (a token's rows in the sorted order),
+    the rest after them under the token ``T``: the way back
+    (``sum_by_token``) reads a token tile's rows as one stretch of it.
+    Two stable sorts (``_sorted_by``) and a one-hot sum; all named
+    ``moe_plan`` (``ops/remat.dear``), so a ``remat`` layer that keeps
+    the name makes none of it again."""
+    tokens, top_k = chosen.shape
+    local = chosen.reshape(-1) - first_expert
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    group, order = _sorted_by(group)
+    load = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    back_token, back_row = _sorted_by(
+        jnp.where(group < held, order // top_k, tokens))
+    return Plan(*(dear(x, "moe_plan")
+                  for x in (order, load, back_row, back_token)))
+
+
+# --- rows to the sorted buffer and back --------------------------------------
+
+# tokens a tile of the way back: one side of its one-hot product
+_TOKEN_TILE = 128
+
+
+def _computed(rows: int, total):
+    """(rows, 1) bool: the sorted rows some group covers."""
+    return (jnp.arange(rows) < total)[:, None]
+
+
+def _tile_sums(z, plan: Plan, first, tokens: int, window: int):
+    """(tiles x _TOKEN_TILE, C) float32: every token's sum of its rows
+    of ``z``, where no tile of tokens has more than ``window`` rows:
+    ``window`` entries of the way back from each tile's first
+    (``first``), their rows of ``z`` gathered, and a 0/1 matrix (token
+    of the tile x entry) times them, summed in float32. An entry that
+    is no row of the tile reads row 0 and is multiplied by 0."""
+    def stretch(x, fill):
+        x = jnp.concatenate([x, jnp.full((window,), fill, x.dtype)])
+        return jax.vmap(lambda at: jax.lax.dynamic_slice(
+            x, (at,), (window,)))(first[:-1])
+
+    tiles = first.size - 1
+    # the token ``tokens`` is no token: the rows past the held ones
+    token = stretch(plan.back_token, tokens)
+    tile = jnp.arange(tiles)[:, None] * _TOKEN_TILE + jnp.arange(_TOKEN_TILE)
+    mine = token[:, None, :] == jnp.where(tile < tokens, tile, -1)[:, :, None]
+    rows = jnp.where(mine.any(1), stretch(plan.back_row, 0), 0)
+    exact = jax.lax.Precision.HIGHEST if z.dtype == jnp.float32 else None
+    return jnp.einsum(
+        "itw,iwc->itc", mine.astype(z.dtype), z[rows], precision=exact,
+        preferred_element_type=jnp.float32).reshape(-1, z.shape[1])
+
+
+def sum_by_token(z, plan: Plan, tokens: int):
+    """(T, C) float32: each token's sum of the rows of ``z`` (rows, C)
+    that are its held assignments' (the first ``plan.load.sum()`` of
+    the sorted order; what ``z`` holds past them is not read as a
+    number). **No scatter-add**: the TPU adds rows whose indices repeat
+    one at a time (2.9 ms for 32,768 rows of 2,048; PERF.md, PR 38).
+    The rows of a tile of ``_TOKEN_TILE`` tokens are one stretch of the
+    way back, as long as the router made it: the stretch is read at a
+    static length (``_tile_sums``), the usual one twice what the buffer
+    holds a tile, else ``_TOKEN_TILE x top_k``, all that top-k allows
+    (``lax.switch`` on the longest tile)."""
+    rows, top_k = z.shape[0], plan.order.size // tokens
+    tiles = -(-tokens // _TOKEN_TILE)
+    total = plan.load.sum()
+    # the tiles' first entries: held rows whose token is under the tile
+    first = jnp.sum(
+        plan.back_token[None, :rows]
+        < jnp.minimum(jnp.arange(tiles + 1) * _TOKEN_TILE, tokens)[:, None],
+        axis=1, dtype=jnp.int32)
+    most = _TOKEN_TILE * top_k
+    even = round_up(_TOKEN_TILE * rows // tokens, _TOKEN_TILE)
+    windows = sorted({min(even, most), min(2 * even, most), most})
+    longest = (first[1:] - first[:-1]).max()
+    sums = jax.lax.switch(
+        sum(longest > w for w in windows[:-1]),
+        [functools.partial(_tile_sums, plan=plan, first=first,
+                           tokens=tokens, window=w) for w in windows], z)
+    # with no held row at all, row 0 is no number either
+    return jnp.where(total > 0, sums[:tokens], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def dispatch(a, plan: Plan, rows: int, tokens: int):
+    """(rows, C): the first ``rows`` rows of the sorted order, each its
+    token's row of ``a`` (``tokens``, C); zeros past the last held
+    assignment. Backward, a token's gradient is the sum of its held
+    assignments' rows (``sum_by_token``), where autodiff of the gather
+    would scatter-add rows whose indices repeat."""
+    return jnp.where(_computed(rows, plan.load.sum()),
+                     a[plan.token(rows, tokens)], 0)
+
+
+def _dispatch_fwd(a, plan, rows, tokens):
+    return dispatch(a, plan, rows, tokens), plan
+
+
+def _dispatch_bwd(rows, tokens, plan, g):
+    with device_scope("moe_route"):
+        return sum_by_token(g, plan, tokens).astype(g.dtype), None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(y, weights, plan: Plan):
+    """(T, C) in ``y``'s dtype: each token's weighted sum of the rows of
+    ``y`` (rows, C) its held assignments sit in, ``weights`` (T, top_k)
+    float32: a row times its weight is rounded to ``y``'s dtype, a
+    token's rows are summed in float32 and rounded once
+    (``sum_by_token``). Backward, a row's gradient is its token's row
+    of the cotangent times its weight (the gather ``dispatch`` makes),
+    and a weight's is its row's dot with it."""
+    return _combine_fwd(y, weights, plan)[0]
+
+
+def _combine_fwd(y, weights, plan):
+    scale = weights.reshape(-1)[plan.order[:y.shape[0]]][:, None]
+    z = (y.astype(jnp.float32) * scale).astype(y.dtype)
+    return (sum_by_token(z, plan, weights.shape[0]).astype(y.dtype),
+            (y, scale, plan))
+
+
+def _combine_bwd(kept, g):
+    y, scale, plan = kept
+    rows, tokens = y.shape[0], g.shape[0]
+    with device_scope("moe_route"):
+        computed = _computed(rows, plan.load.sum())
+        came = g[plan.token(rows, tokens)].astype(jnp.float32)
+        dy = jnp.where(computed, came * scale, 0).astype(y.dtype)
+        dots = jnp.sum(jnp.where(computed, y.astype(jnp.float32) * came, 0),
+                       -1)
+        # an assignment sits in one row: a scatter of distinct places
+        dweights = jnp.zeros(plan.order.shape, jnp.float32).at[
+            plan.order[:rows]].set(dots, unique_indices=True)
+        return dy, dweights.reshape(tokens, -1), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4, 5))
+def _routed(experts, a, weights, plan: Plan, rows: int, policy: Policy):
     """The held experts' part of the result, (T, C), from the first
     ``rows`` rows of the sorted order: the usual buffer's
-    (``load.sum()`` is no more), else all ``T x top_k``. A checkpoint
-    of its own, as the scan of ``ops/ssm.py`` is: what its backward
-    needs is made again from its arguments when the backward runs, so
-    that nothing of a buffer's size waits for it (a value that crosses
-    the ``lax.cond`` around this is held at the size of the larger
-    branch)."""
+    (``plan.load.sum()`` is no more), else all ``T x top_k``. A
+    checkpoint of its own, as the scan of ``ops/ssm.py`` is: what its
+    backward needs is made again from its arguments when the backward
+    runs, so that nothing of a buffer's size waits for it (a value that
+    crosses the ``lax.cond`` around this is held at the size of the
+    larger branch)."""
     with device_scope("moe_route"):
-        order = order[:rows]
-        token = order // top_k
-        computed = (jnp.arange(rows) < load.sum())[:, None]
-        taken = jnp.where(computed, a[token], 0)
-        scale = weights.reshape(-1)[order][:, None]
+        taken = dispatch(a, plan, rows, a.shape[0])
     with device_scope("moe_experts"):
         mlp = gated_mlp_apply if "gate" in experts else relu2_mlp_apply
         y = mlp(
             experts, taken, policy, name=None,
-            product=functools.partial(grouped_product, group_sizes=load))
+            product=functools.partial(grouped_product,
+                                      group_sizes=plan.load))
     with device_scope("moe_route"):
-        # masked before it is weighted: what lies past the computed
-        # rows is no number (no group covers it), and 0 x nan is nan
-        # in the weights' gradient
-        y = (jnp.where(computed, y, 0).astype(jnp.float32) * scale) \
-            .astype(policy.compute_dtype)
-        # summed in the compute dtype, as the residual stream is: a
-        # float32 copy of the rows would be the largest buffer of the step
-        return jnp.zeros(a.shape, y.dtype).at[token].add(y)
+        # what lies past the computed rows is no number (no group covers
+        # it): ``combine`` reads no such row as one, forward or backward
+        return combine(y, weights, plan)
 
 
 @device_scope("moe")
@@ -266,15 +456,10 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
     chosen, weights = route(params["router"], a, top_k=top_k,
                             scaling=scaling, scoring=scoring,
                             renormalize=renormalize)
-    with device_scope("moe_route"):
-        local = chosen.reshape(-1) - first_expert
-        # the absent experts' assignments sort last, as group ``held``
-        group = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        load = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    plan = routing_plan(chosen, first_expert, held)
 
     def routed(rows):
-        return lambda *args: _routed(*args, rows, top_k, policy)
+        return lambda *args: _routed(*args, rows, policy)
 
     usual = usual_rows(tokens, top_k, held, experts)
     label = pick_grouped_product()[1]
@@ -283,9 +468,9 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
     moe_paths.add(f"{label}x{usual}" + (
         "[2 x even share]" if usual > tokens else ""))
     moe_paths.add(f"{label}x{tokens * top_k}")
-    out = jax.lax.cond(load.sum() <= usual, routed(usual),
+    out = jax.lax.cond(plan.load.sum() <= usual, routed(usual),
                        routed(tokens * top_k),
-                       params["experts"], a, weights, order, load)
+                       params["experts"], a, weights, plan)
     if "shared" in params:
         out = out + relu2_mlp_apply(params["shared"], a, policy)
-    return out.reshape(shape), load
+    return out.reshape(shape), plan.load

@@ -24,7 +24,9 @@ Three stacks honour the names, each in the way its backward is made:
 
 - ``models/hybrid_lm.HybridLM``: its layers differ and are unrolled,
   each a ``jax.checkpoint`` under autodiff whose save list is the kept
-  names of its own, longer list (``HYBRID_REMAT_NAMES``).
+  names of its own, longer list (``HYBRID_REMAT_NAMES``: the scan's
+  output and the in-projection's product of a state-space layer, the
+  routing plan of an expert layer).
 
 Which names those are is one reckoning for all (``choose_keeps``).
 
@@ -84,8 +86,13 @@ REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 #: lies inside a ``lax.cond`` over the sorted buffer's size
 #: (``ops/moe.py``), and a value that crosses one is held at the size
 #: of the larger branch, all that top-k allows; the routed part
-#: recomputes it under a checkpoint of its own.
-HYBRID_REMAT_NAMES = REMAT_NAMES + ("ssm_out", "ssm_in")
+#: recomputes it under a checkpoint of its own. ``moe_plan`` is an
+#: expert layer's routing plan (``ops/moe.routing_plan``: the chosen
+#: experts, the sorted order, the way back by token and the loads, all
+#: integers, 16 bytes an assignment): with it held the recomputed layer
+#: runs no ``top_k`` and no sort, only the router's product and score,
+#: which the weights' gradient needs.
+HYBRID_REMAT_NAMES = REMAT_NAMES + ("ssm_out", "ssm_in", "moe_plan")
 
 #: The share of what the device has left that the kept values and the
 #: layers' inputs may take together. From chip runs (PERF.md, Findings,

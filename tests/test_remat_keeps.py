@@ -22,7 +22,9 @@ import perceiver_tpu.ops.remat as remat
 from perceiver_tpu.ops.policy import Policy
 from perceiver_tpu.ops.remat import REMAT_NAMES, pick_remat_keeps
 from perceiver_tpu.tasks import (
+    BlockDiffusionLMTask,
     CausalLMTask,
+    HybridLMTask,
     ImageClassifierTask,
     MaskedLanguageModelTask,
 )
@@ -36,7 +38,9 @@ FUSED = dict(attention_impl="flash", decoder_attention_impl="flash")
 def rehearsal_task(name, **overrides):
     """The benchmark's configuration at its rehearsal sizes."""
     cls = {"perceiver_lm": MaskedLanguageModelTask,
-           "perceiver_img": ImageClassifierTask}[name]
+           "perceiver_img": ImageClassifierTask,
+           "nemotron3_nano_30b": HybridLMTask,
+           "sdar_30b_a3b": BlockDiffusionLMTask}[name]
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            f"{name}.json")) as f:
         config = json.load(f)
@@ -407,3 +411,35 @@ def test_the_trainer_says_what_the_looped_stack_keeps(tmp_path, capfd,
         r"0\.01 \(dropped qkv,mlp_hidden: qkv would make 0\.00 GB of 0\.00, "
         r"0\.6 of what 0\.01 GB in use leave\)", err), err
     assert not remat._KEEP_TALLIES and not remat._EXCHANGES
+
+
+# --- (f) an expert layer's routing plan --------------------------------------
+
+
+@pytest.mark.parametrize("name", ["nemotron3_nano_30b", "sdar_30b_a3b"])
+def test_the_hybrid_stack_keeps_the_routing_plan(name, tmp_path, capfd,
+                                                 monkeypatch):
+    """``moe_plan`` is a name like any other: reckoned (the chosen
+    experts, the sorted order and the way back, int32, 16 bytes an
+    assignment, and the loads), kept where it fits, and said on the
+    trainer's ``remat keeps`` line; where it does not fit the line
+    says that nothing is kept."""
+    task = rehearsal_task(name)
+    layers = task.build().pattern.count("E")
+    batch = {"input_ids": np.ones((2, 32), np.int32)}
+    trainer, state = make_trainer(task, tmp_path)
+    with remat.remat_keeps() as outer:
+        trainer._load_step(trainer._train_step, state, batch, "t")
+    (choice,) = outer
+    assert choice["kept"][-1] == "moe_plan" == remat.HYBRID_REMAT_NAMES[-1]
+    positions = 2 * 32 * (2 if name == "sdar_30b_a3b" else 1)
+    assert choice["bytes"]["moe_plan"] == layers * 4 * (
+        4 * positions * task.num_experts_per_tok + task.held_experts)
+    err = capfd.readouterr().err
+    assert re.search(r"remat keeps: \S*,moe_plan \+ layer_in", err), err
+
+    monkeypatch.setattr(remat, "_memory_limit", lambda: 1)
+    trainer, state = make_trainer(task, tmp_path / "small")
+    trainer._load_step(trainer._train_step, state, batch, "t")
+    err = capfd.readouterr().err
+    assert "remat keeps: nothing + layer_in" in err
