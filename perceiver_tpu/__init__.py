@@ -14,9 +14,17 @@ large-scale semantic-segmentation configuration.
 
 __version__ = "0.1.0"
 
-from perceiver_tpu.models.perceiver import (  # noqa: F401
-    PerceiverEncoder,
-    PerceiverDecoder,
-    PerceiverIO,
-    PerceiverMLM,
-)
+# first, so that the timeline starts where the process did and the
+# heavy imports below have spans (obs/process.py; standard library only)
+from perceiver_tpu.obs import process as _process  # noqa: E402
+
+_process.begin()
+with _process.import_span("jax"):
+    import jax  # noqa: F401
+with _process.import_span("perceiver_tpu.models"):
+    from perceiver_tpu.models.perceiver import (  # noqa: F401
+        PerceiverEncoder,
+        PerceiverDecoder,
+        PerceiverIO,
+        PerceiverMLM,
+    )

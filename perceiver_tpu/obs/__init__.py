@@ -22,6 +22,8 @@ from perceiver_tpu.obs.trace import (
     DEVICE_SCOPES,
     ENCLOSING_SPANS,
     PHASES,
+    PROCESS_PHASES,
+    TILED_PHASES,
     TRAIN_PHASES,
     SpanCollector,
     Timeline,
@@ -46,7 +48,9 @@ __all__ = [
     "DEVICE_SCOPES",
     "ENCLOSING_SPANS",
     "PHASES",
+    "PROCESS_PHASES",
     "SCHEMA",
+    "TILED_PHASES",
     "TRAIN_PHASES",
     "EventLog",
     "SpanCollector",

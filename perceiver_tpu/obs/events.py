@@ -60,6 +60,9 @@ SCHEMA: Dict[str, Tuple[str, ...]] = {
     "preempt_checkpoint": ("step",),
     "train_step": ("step", "loss"),
     "profile_capture": ("dir",),
+    # a step over its neighbours' median pace, taken apart by its
+    # leaves (training/pace.py; "phase" names the largest excess)
+    "slow_step": ("step", "interval_s", "median_s"),
     # distributed (multi-host groups; docs/RESILIENCE.md "Multi-host")
     "host_join": ("group", "rank"),
     "host_leave": ("group", "rank"),

@@ -4,7 +4,10 @@ One ``ThreadingHTTPServer`` on loopback serving:
 
 ``/metrics``            Prometheus exposition (aggregated fleet text,
                         or a single registry's render — whatever
-                        callable the owner wires in)
+                        callable the owner wires in), and after it
+                        ``obs_spans_dropped_total``: what this
+                        process's trace buffer refused and its step
+                        timeline overwrote
 ``/healthz``            JSON health snapshot (200 when the owner's
                         health callable says so, 503 otherwise)
 ``/traces``             JSON list of buffered trace ids
@@ -88,7 +91,8 @@ class ObsServer:
         path = parsed.path.rstrip("/") or "/"
         try:
             if path == "/metrics":
-                self._send(handler, 200, self._metrics_fn(),
+                self._send(handler, 200,
+                           self._metrics_fn() + self._dropped_spans(),
                            "text/plain; version=0.0.4; charset=utf-8")
             elif path == "/healthz":
                 health = self._health_fn()
@@ -122,6 +126,13 @@ class ObsServer:
                 self._send_json(handler, 500, {"error": str(e)})
             except OSError:
                 pass  # connection already unusable
+
+    def _dropped_spans(self) -> str:
+        dropped = self._buffer.dropped_spans + trace_mod.timeline().dropped
+        return ("# HELP obs_spans_dropped_total spans the trace buffer "
+                "refused and the step timeline overwrote\n"
+                "# TYPE obs_spans_dropped_total counter\n"
+                f"obs_spans_dropped_total {dropped}\n")
 
     def _profile(self, handler: BaseHTTPRequestHandler,
                  seconds: float) -> None:

@@ -61,6 +61,13 @@ class Telemetry:
             "training_host_busy_seconds_total",
             "seconds of the loop's own host work: shard, dispatch, "
             "guard sync, logging")
+        self._m_slow_steps = m.counter(
+            "training_slow_steps_total",
+            "steps over their neighbours' median pace by more than 5% "
+            "and 20 ms, those slow by design left out (training/pace.py)")
+        self._m_stall = m.counter(
+            "training_stall_seconds_total",
+            "seconds the slow steps took over the median pace")
         self._m_guard_skips = m.counter(
             "training_guard_skips_total", "non-finite steps skipped")
         self._m_rewinds = m.counter(
@@ -106,6 +113,16 @@ class Telemetry:
             except (TypeError, ValueError):
                 fields[k] = v
         return self._log.emit("train_step", **fields)
+
+    def slow_step(self, step: int, *, stalled_s: Optional[float],
+                  **fields) -> None:
+        """One step the slow-step rule named; ``stalled_s`` is its
+        excess over the median, None for a step slow by design, which
+        the counters leave out."""
+        if stalled_s is not None:
+            self._m_slow_steps.inc()
+            self._m_stall.inc(stalled_s)
+        self._log.emit("slow_step", step=int(step), **fields)
 
     def guard_skip(self, step: int, **fields) -> None:
         self._m_guard_skips.inc()

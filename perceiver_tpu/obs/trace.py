@@ -6,7 +6,7 @@ phase (``queue_wait``, ``batch_form``, ``pad_or_pack``, ``dispatch``,
 start/end measured in the process that did the work.  The context is
 created where the request enters the system (``api.submit`` /
 ``Fleet.submit``), rides the fleet RPC envelope as a tiny wire dict
-(``{"trace_id", "parent_id"}``), and the replica ships its locally
+(``{"trace_id"}``), and the replica ships its locally
 collected spans back in the dispatch reply so the router can absorb
 them into one trace.  A retried request therefore yields a SINGLE
 trace with the failed hop, the ``retry`` span, and the sibling's
@@ -34,9 +34,18 @@ were over before anybody knew, and stay host-only.
 *Annotations are leaves only.*  A reduction that gives each idle gap of
 the device to the host event covering most of it would give every gap
 to an enclosing ``train/step`` event.  So the spans of
-``ENCLOSING_SPANS`` go to the ring alone (as parents, carrying
-``step``) and only leaf phases are emitted as annotations, each with
-``step_num``; they tile their parent without nesting.
+``ENCLOSING_SPANS`` and ``TILED_PHASES`` go to the ring alone (as
+parents, carrying ``step``) and only leaf phases are emitted as
+annotations, each with ``step_num``; they tile their parent without
+nesting.  An enclosing span keeps its leaves' seconds as they close
+(``children``), so the trainer reads a step's phases from the step's
+own span.
+
+*The timeline has no holes.*  ``PROCESS_PHASES`` are what a process
+did before and beside its steps: ``proc/boot`` and ``proc/import``
+(:mod:`perceiver_tpu.obs.process`), ``proc/backend_init``, ``proc/gc``.
+They go to the ring alone too; those that were over before anybody
+could open them are written after the fact (:meth:`Timeline.record`).
 
 Clock caveat: span ``start``/``end`` are ``time.monotonic`` values and
 are only comparable *within* one process.  Cross-process ordering uses
@@ -65,7 +74,9 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 __all__ = [
     "PHASES",
     "TRAIN_PHASES",
+    "PROCESS_PHASES",
     "ENCLOSING_SPANS",
+    "TILED_PHASES",
     "DEVICE_SCOPES",
     "Timeline",
     "span",
@@ -109,7 +120,9 @@ PHASES = (
 #: The trainer's phases (``training/trainer.py``), a closed set like
 #: ``PHASES``.  Per dispatch: ``train/step`` holds ``input_wait``,
 #: ``shard``, ``dispatch``, ``guard_sync`` (armed guard only), ``fence``
-#: and ``log`` (logged steps), and ``step_load`` on the first.
+#: and ``log`` with its three leaves (logged steps), and ``step_load``
+#: on the first.  Its attrs at the close: ``interval_s`` and ``cpu_s``
+#: (``training/pace.py``).
 TRAIN_PHASES = (
     "train/step",         # one dispatch of the loop; ring only
     "train/input_wait",   # the pull from the (prefetching) loader
@@ -117,7 +130,14 @@ TRAIN_PHASES = (
     "train/dispatch",     # the call of the jitted step (async: host cost)
     "train/guard_sync",   # armed guard: per-step losses to the host
     "train/fence",        # the host waiting for the device's metrics
-    "train/log",          # print, summary writer, telemetry line
+    "train/log",          # a logged step's three writes; ring only
+    "train/log_console",  # the heartbeat line on standard error
+    "train/log_scalars",  # the summary writer's scalars, lr_fn's call
+    "train/log_telemetry",  # the telemetry line and its counters
+    "train/construct",    # Trainer(...): task.build, the log directory
+    "train/data_setup",   # the data module's prepare_data / setup
+    "train/io_setup",     # summary writer, telemetry, checkpoint hooks
+    "train/epoch_end",    # the task's on_validation_epoch_end hook
     "train/build_state",  # model.init + restore + optimizer; ring only
     "train/model_init",   # eager model.init
     "train/restore",      # task.restore_pretrained / checkpoint restore
@@ -127,9 +147,24 @@ TRAIN_PHASES = (
     "train/anchor",       # the guard's last-good anchor save
 )
 
+#: What a process did before and beside its steps; ring only, nesting
+#: allowed (an import pulls another; a collection runs inside a leaf).
+PROCESS_PHASES = (
+    "proc/boot",          # process start -> perceiver_tpu's first line
+    "proc/import",        # one heavy import (attr ``module``)
+    "proc/backend_init",  # the accelerator runtime's start (attr ``platform``)
+    "proc/gc",            # one collection of Python's collector over 1 ms
+)
+
 #: Spans that hold other spans: recorded in the ring, never emitted as
 #: profiler annotations (module docstring, "leaves only").
 ENCLOSING_SPANS = ("train/step", "train/build_state")
+
+#: Phases of a step that are themselves tiled by leaves: ring only and
+#: with ``children`` like an enclosing span, and still one of the step's
+#: phases for a reader that sums them by their parent
+#: (``benchmarks/scope_times.HOST_PHASES`` holds ``train/log``).
+TILED_PHASES = ("train/log",)
 
 #: ``jax.named_scope`` names on the train step's device operations, at
 #: the layer boundaries.  Layers (outer): the two adapters and the three
@@ -158,8 +193,9 @@ DEVICE_SCOPES = (
     "bd_noise",
 )
 
-_SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES)
-_RING_ONLY = frozenset(ENCLOSING_SPANS)
+_SPAN_NAMES = frozenset(PHASES + TRAIN_PHASES + PROCESS_PHASES)
+_ENCLOSING = frozenset(ENCLOSING_SPANS + TILED_PHASES)
+_RING_ONLY = _ENCLOSING | frozenset(PROCESS_PHASES)
 
 _enabled = True
 
@@ -194,7 +230,9 @@ class SpanCollector:
 
 
 class TraceBuffer:
-    """Bounded in-memory ring of traces (LRU-evicting, thread-safe)."""
+    """Bounded in-memory ring of traces (LRU-evicting, thread-safe).
+    ``dropped_spans`` counts the spans a full trace refused; the
+    endpoint's ``/metrics`` exports it (``obs_spans_dropped_total``)."""
 
     # spans arrive from every serving thread; the LRU OrderedDict and
     # the overflow counter move together under one lock
@@ -272,13 +310,11 @@ class TraceContext:
     :func:`region` context manager for the simple wrap case.
     """
 
-    __slots__ = ("trace_id", "parent_id", "origin", "_sink")
+    __slots__ = ("trace_id", "origin", "_sink")
 
     def __init__(self, trace_id: Optional[str] = None,
-                 parent_id: Optional[str] = None,
                  sink=None, origin: str = "") -> None:
         self.trace_id = trace_id or _new_id()
-        self.parent_id = parent_id
         self.origin = origin
         self._sink = sink if sink is not None else _default_buffer
 
@@ -310,10 +346,7 @@ class TraceContext:
 
     def wire(self) -> Dict[str, str]:
         """The cross-process envelope: small, picklable, stable."""
-        out = {"trace_id": self.trace_id}
-        if self.parent_id:
-            out["parent_id"] = self.parent_id
-        return out
+        return {"trace_id": self.trace_id}
 
     def absorb(self, spans: Iterable[dict], **extra_attrs) -> None:
         """Merge spans collected in another process into this trace
@@ -347,7 +380,6 @@ def from_wire(wire: Optional[dict], sink=None,
     if not _enabled or not wire or "trace_id" not in wire:
         return None
     return TraceContext(trace_id=str(wire["trace_id"]),
-                        parent_id=wire.get("parent_id"),
                         sink=sink, origin=origin)
 
 
@@ -411,11 +443,13 @@ class Timeline:
     ``time.monotonic``, ``parent`` the id of the span open around it on
     the same thread (or None), ``step`` its own or its parent's.  When
     the ring is full the oldest span is overwritten and ``dropped``
-    counts it."""
+    counts it.  The default holds a run: a 40 s window of 10 ms steps
+    with eight leaves each is 36 k spans, and the benchmark's readers
+    of set-up refuse a ring that dropped any."""
 
     _GUARDED = {"_slots": "_lock", "_next": "_lock", "dropped": "_lock"}
 
-    def __init__(self, capacity: int = 4096) -> None:
+    def __init__(self, capacity: int = 65536) -> None:
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
@@ -431,6 +465,28 @@ class Timeline:
                 self.dropped += 1
             self._slots[i] = record
             self._next += 1
+
+    def record(self, name: str, *, start: float, end: float,
+               step: Optional[int] = None, **attrs) -> Optional[int]:
+        """Write a span that was over before anybody could open it (the
+        process's boot, a collection the collector reports at its end):
+        ``start``/``end`` on the spans' clock.  Ring only, no
+        annotation, so inside an open leaf it is no second event of the
+        profile.  Its parent is the span open on this thread now, whose
+        step it takes where it has none.  Returns its id, or None with
+        tracing off."""
+        if not _enabled:
+            return None
+        if name not in _SPAN_NAMES:
+            raise ValueError(f"unknown span name {name!r}")
+        stack = getattr(_tls, "spans", None)
+        outer = stack[-1] if stack else None
+        if step is None and outer is not None:
+            step = outer.step
+        sid = next(_span_ids)
+        self._add((sid, outer.id if outer is not None else None, name,
+                   start, end, step, threading.get_ident(), attrs))
+        return sid
 
     def spans(self, name: Optional[str] = None, *,
               since: Optional[float] = None,
@@ -490,11 +546,14 @@ class _Span:
     keeps it out of the ring (a pull that found the epoch over)."""
 
     __slots__ = ("name", "attrs", "id", "parent", "step", "start", "end",
-                 "_annotation", "_cancelled")
+                 "children", "_annotation", "_cancelled")
 
     def __init__(self, name: str, attrs: dict) -> None:
         self.name, self.attrs = name, attrs
         self.start = self.end = 0.0
+        # an enclosing span's closed leaves, (name, seconds) in order of
+        # closing; an enclosing span inside it hands its own up
+        self.children = [] if name in _ENCLOSING else None
         self._annotation = None
         self._cancelled = False
 
@@ -529,7 +588,13 @@ class _Span:
         self.end = _now()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
-        _tls.spans.pop()
+        stack = _tls.spans
+        stack.pop()
+        if stack and stack[-1].children is not None:
+            if self.children is None:
+                stack[-1].children.append((self.name, self.end - self.start))
+            else:
+                stack[-1].children.extend(self.children)
         if not self._cancelled:
             _timeline._add((self.id, self.parent, self.name, self.start,
                             self.end, self.step,
@@ -541,6 +606,7 @@ class _NoSpan:
 
     __slots__ = ()
     seconds = 0.0
+    children = None
 
     def cancel(self) -> None:
         pass
@@ -557,10 +623,10 @@ _NOOP = _NoSpan()
 
 def span(name: str, **attrs):
     """Context manager: one process-level span called ``name`` (of
-    ``PHASES`` or ``TRAIN_PHASES``) around the block, into the
-    :func:`timeline` ring and, unless it is an enclosing span, the
-    profiler.  ``step=`` gives the span its step id; a span without one
-    takes its parent's.  Other keywords are the span's ``attrs`` (and
+    ``PHASES``, ``TRAIN_PHASES`` or ``PROCESS_PHASES``) around the
+    block, into the :func:`timeline` ring and, unless it is ring only,
+    the profiler.  ``step=`` gives the span its step id; a span without
+    one takes its parent's.  Other keywords are the span's ``attrs`` (and
     the annotation's).  With no capture running the cost is two clock
     reads, a ring write and an inactive ``TraceMe``."""
     if not _enabled:
@@ -568,7 +634,7 @@ def span(name: str, **attrs):
     if name not in _SPAN_NAMES:
         raise ValueError(
             f"unknown span name {name!r}; expected one of "
-            f"{PHASES + TRAIN_PHASES}")
+            f"{PHASES + TRAIN_PHASES + PROCESS_PHASES}")
     return _Span(name, attrs)
 
 

@@ -34,13 +34,15 @@ import numpy as np
 import optax
 
 from perceiver_tpu.obs import events as events_mod
+from perceiver_tpu.obs import trace as trace_mod
+from perceiver_tpu.obs.process import GcSpans
 from perceiver_tpu.obs.trace import device_scope, span
-from perceiver_tpu.obs.trace import enabled as tracing_enabled
 from perceiver_tpu.ops.policy import Policy
 from perceiver_tpu.resilience import faults
 from perceiver_tpu.resilience import guard as guard_mod
 from perceiver_tpu.training.checkpoint import CheckpointHook
 from perceiver_tpu.training.optim import create_optimizer
+from perceiver_tpu.training.pace import StepPace
 from perceiver_tpu.training.state import TrainState
 from perceiver_tpu.utils.tb import SummaryWriter
 from perceiver_tpu.utils.timing import fence
@@ -173,18 +175,24 @@ class TrainerConfig:
 
 def apply_accelerator(accelerator: str) -> None:
     """``--trainer.accelerator`` (reference README.md:42-43). "auto"
-    keeps whatever platform JAX picked and checks nothing; any other
-    value ("tpu", "cpu", "gpu") must be the platform the process ends
-    up on. Must run before any device use in this process."""
+    keeps whatever platform JAX picked; any other value ("tpu", "cpu",
+    "gpu") must be the platform the process ends up on. Must run before
+    any device use in this process: the look for devices here is then
+    the one that starts the accelerator's runtime, and has a span
+    (``proc/backend_init``; a caller that looked first keeps those
+    seconds under no span of the program)."""
     acc = str(accelerator).lower()
-    if acc == "auto":
-        return
-    if acc != "tpu":
+    if acc not in ("auto", "tpu"):
         jax.config.update("jax_platforms", acc)
+    start = trace_mod._now()
+    got = jax.devices()[0].platform
+    end = trace_mod._now()
+    if end - start > 1e-3:    # the runtime started here, not before
+        trace_mod.timeline().record("proc/backend_init", start=start,
+                                    end=end, platform=got)
     # A late update (after the backend initialized) silently no-ops, so
     # verify the selection actually took rather than trusting the call.
-    got = jax.devices()[0].platform
-    if got != acc:
+    if acc != "auto" and got != acc:
         raise RuntimeError(
             f"--trainer.accelerator={acc} had no effect (running on "
             f"{got!r}); select the accelerator before any other jax "
@@ -264,15 +272,15 @@ class Trainer:
 
         apply_accelerator(self.config.accelerator)
 
-        # the mesh reaches the model builder so tasks can wire the
-        # shard_map sequence-parallel attention impls to its axes
-        self.model = task.build(mesh=mesh)
+        with span("train/construct"):
+            # the mesh reaches the model builder so tasks can wire the
+            # shard_map sequence-parallel attention impls to its axes
+            self.model = task.build(mesh=mesh)
+            self.log_dir = _version_dir(self.config.default_root_dir,
+                                        self.config.experiment)
         self.policy = self.config.policy()
         self.global_step = 0
         self.current_epoch = 0
-
-        self.log_dir = _version_dir(self.config.default_root_dir,
-                                    self.config.experiment)
         self.writer: Optional[SummaryWriter] = None
         self._ckpt: Optional[CheckpointHook] = None
         self._train_step = None
@@ -520,11 +528,12 @@ class Trainer:
         if not self._preemption_pending():
             return False
         self._preempted = True  # skip the validation pass on stop
-        hook = CheckpointHook(
-            os.path.join(self.log_dir, "checkpoints-preempt"),
-            max_to_keep=1, monitor="", hparams=self._hparams())
-        hook.save(self.global_step, state, {})
-        hook.wait()
+        with span("train/checkpoint"):
+            hook = CheckpointHook(
+                os.path.join(self.log_dir, "checkpoints-preempt"),
+                max_to_keep=1, monitor="", hparams=self._hparams())
+            hook.save(self.global_step, state, {})
+            hook.wait()
         events_mod.emit("preempt_checkpoint", step=int(self.global_step))
         if self.telemetry is not None:
             self.telemetry.preempt_checkpoint(self.global_step)
@@ -616,42 +625,46 @@ class Trainer:
         return {f"{prefix}_{k}": v / count for k, v in totals.items()}
 
     def _log_step(self, metrics, *, dt: float, throughput: float,
-                  steps_since: int,
-                  phase_s: Dict[str, float]) -> None:
+                  steps_since: int, pace: StepPace, step_span) -> None:
         """One logged step: console heartbeat, summary scalars and the
-        telemetry line. The caller fenced ``metrics``, so nothing here
-        waits for the device's step."""
-        if jax.process_index() == 0:
-            # console heartbeat: progress visibility for interactive
-            # runs and a liveness signal for watchdogs (a stalled device
-            # shows up as this line going quiet)
-            print(f"[step {self.global_step}] "
-                  + " ".join(f"{k}={float(v):.4f}"
-                             for k, v in metrics.items())
-                  + f" samples/s={throughput:.1f}",
-                  file=sys.stderr, flush=True)
-        for k, v in metrics.items():
-            self.writer.add_scalar(f"train_{k}", float(v), self.global_step)
-        # MultiSteps advances the schedule once per accumulation
-        # window, not per micro-step
-        opt_step = (max(self.global_step - self._lr_step_offset, 0)
-                    // max(self.config.accumulate_grad_batches, 1))
-        self.writer.add_scalar("lr", float(self.lr_fn(opt_step)),
-                               self.global_step)
-        if steps_since > 0:
-            self.writer.add_scalar("samples_per_sec", throughput,
+        telemetry line, three blocking writes and a leaf each. The
+        caller fenced ``metrics``, so nothing here waits for the
+        device's step."""
+        with span("train/log_console"):
+            if jax.process_index() == 0:
+                # console heartbeat: progress visibility for interactive
+                # runs and a liveness signal for watchdogs (a stalled
+                # device shows up as this line going quiet)
+                print(f"[step {self.global_step}] "
+                      + " ".join(f"{k}={float(v):.4f}"
+                                 for k, v in metrics.items())
+                      + f" samples/s={throughput:.1f}",
+                      file=sys.stderr, flush=True)
+        with span("train/log_scalars"):
+            for k, v in metrics.items():
+                self.writer.add_scalar(f"train_{k}", float(v),
+                                       self.global_step)
+            # MultiSteps advances the schedule once per accumulation
+            # window, not per micro-step
+            opt_step = (max(self.global_step - self._lr_step_offset, 0)
+                        // max(self.config.accumulate_grad_batches, 1))
+            self.writer.add_scalar("lr", float(self.lr_fn(opt_step)),
                                    self.global_step)
-        if self._guard is not None:
-            self.writer.add_scalar("guard_skipped_steps",
-                                   float(self._guard.skipped_total),
-                                   self.global_step)
-        if self.telemetry is not None:
+            if steps_since > 0:
+                self.writer.add_scalar("samples_per_sec", throughput,
+                                       self.global_step)
+            if self._guard is not None:
+                self.writer.add_scalar("guard_skipped_steps",
+                                       float(self._guard.skipped_total),
+                                       self.global_step)
+        if self.telemetry is None:
+            return
+        with span("train/log_telemetry"):
             # the caller's fence() already pulled metrics to host:
             # telemetry adds zero device syncs. The phases' seconds are
-            # those since the last line, that line's own logging among
-            # them; left out when tracing is switched off
-            phases = ({f"{k}_s": v for k, v in phase_s.items()}
-                      if tracing_enabled() else {})
+            # the leaves' since the last line, that line's own logging
+            # among them; left out when tracing is switched off
+            phases = pace.phases_since_line(step_span)
             # the task's other scalars (a packed loss's overflow, a
             # looped model's exit gate) ride the same line
             phases.update((k, float(v)) for k, v in metrics.items()
@@ -667,6 +680,9 @@ class Trainer:
         self._preempted = False  # a prior preempted fit() must not leak
         if self.config.fault_plan:
             faults.arm(self.config.fault_plan)
+        # the timeline's one installed piece: the collector's callback,
+        # for as long as the steps run
+        self._gc_spans = GcSpans().install()
         installed, old_term = False, None
         if self.config.preempt_checkpoint:
             try:
@@ -687,6 +703,7 @@ class Trainer:
         try:
             return self._fit()
         finally:
+            self._gc_spans.uninstall()
             if uninstall_profiler is not None:
                 uninstall_profiler()
             if installed:
@@ -719,35 +736,38 @@ class Trainer:
         if cfg.detect_anomaly:
             jax.config.update("jax_debug_nans", True)
 
-        self._prepare_data()
-        self.datamodule.setup()
-        self.writer = (SummaryWriter(self.log_dir)
-                       if jax.process_index() == 0 else _NullWriter())
-        if cfg.telemetry_dir and jax.process_index() == 0:
-            from perceiver_tpu.obs.telemetry import Telemetry
-            self.telemetry = Telemetry(cfg.telemetry_dir)
-        if cfg.enable_checkpointing:
-            self._ckpt = CheckpointHook(
-                os.path.join(self.log_dir, "checkpoints"),
-                max_to_keep=cfg.save_top_k,
-                monitor=cfg.checkpoint_monitor,
-                hparams=self._hparams())
+        with span("train/data_setup"):
+            self._prepare_data()
+            self.datamodule.setup()
         self._guard = None
         self._guard_ckpt = None
         self._anchor_pos, self._anchor_step = (0, 0), -1
-        if self._guard_policy != guard_mod.OFF:
-            self._guard = guard_mod.StepGuard(
-                self._guard_policy,
-                streak_to_rewind=cfg.nonfinite_streak,
-                max_rewinds=cfg.nonfinite_max_rewinds)
-            if self._guard_policy == guard_mod.SKIP:
-                # synchronous: the anchor must snapshot the state AT
-                # this step — an async save of donated buffers can
-                # serialize a later step's contents under this label
-                self._guard_ckpt = CheckpointHook(
-                    cfg.guard_anchor_dir
-                    or os.path.join(self.log_dir, "checkpoints-guard"),
-                    max_to_keep=1, monitor="", enable_async=False)
+        with span("train/io_setup"):
+            self.writer = (SummaryWriter(self.log_dir)
+                           if jax.process_index() == 0 else _NullWriter())
+            if cfg.telemetry_dir and jax.process_index() == 0:
+                from perceiver_tpu.obs.telemetry import Telemetry
+                self.telemetry = Telemetry(cfg.telemetry_dir)
+            if cfg.enable_checkpointing:
+                self._ckpt = CheckpointHook(
+                    os.path.join(self.log_dir, "checkpoints"),
+                    max_to_keep=cfg.save_top_k,
+                    monitor=cfg.checkpoint_monitor,
+                    hparams=self._hparams())
+            if self._guard_policy != guard_mod.OFF:
+                self._guard = guard_mod.StepGuard(
+                    self._guard_policy,
+                    streak_to_rewind=cfg.nonfinite_streak,
+                    max_rewinds=cfg.nonfinite_max_rewinds)
+                if self._guard_policy == guard_mod.SKIP:
+                    # synchronous: the anchor must snapshot the state AT
+                    # this step — an async save of donated buffers can
+                    # serialize a later step's contents under this label
+                    self._guard_ckpt = CheckpointHook(
+                        cfg.guard_anchor_dir
+                        or os.path.join(self.log_dir, "checkpoints-guard"),
+                        max_to_keep=1, monitor="", enable_async=False)
+        pace = StepPace(self._gc_spans, self.telemetry)
 
         with span("train/build_state"):
             state = self._build_state()
@@ -795,7 +815,8 @@ class Trainer:
                        else cfg.overfit_batches or cfg.limit_train_batches)
         limit_val = 1 if cfg.fast_dev_run else cfg.limit_val_batches
 
-        train_loader = self.datamodule.train_dataloader()
+        with span("train/data_setup"):
+            train_loader = self.datamodule.train_dataloader()
         if cfg.overfit_batches:
             # Lightning semantics: overfit repeats the SAME batches every
             # epoch, so shuffling must be disabled
@@ -827,9 +848,6 @@ class Trainer:
 
         stop = False
         t0, samples_since, steps_since = time.time(), 0, 0
-        # seconds by phase since the last logged step (obs/trace.py:
-        # TRAIN_PHASES; "host" is shard + dispatch + guard_sync + log)
-        phase_s = {"input_wait": 0.0, "host": 0.0, "fence": 0.0}
         metrics = None
         epoch = 0
         replay_batches = 0  # rewind reposition within the next epoch
@@ -865,6 +883,7 @@ class Trainer:
                 batches_done, replay_batches = replay_batches, 0
             self._save_anchor(state, epoch, batches_done)
             rewound = False
+            pace.epoch_start()
             while True:
                 remaining = (cfg.max_steps - self.global_step
                              if cfg.max_steps > 0 else spe)
@@ -875,13 +894,13 @@ class Trainer:
                     break
                 with span("train/step",
                           step=self.global_step + 1) as step_span:
+                    pace.step_open()
                     # queue_depth 0: the producer starved the loop
                     depth = ({"queue_depth": train_loader.queue_depth()}
                              if cfg.prefetch_batches > 0 else {})
-                    with span("train/input_wait", **depth) as sp:
+                    with span("train/input_wait", **depth):
                         group = list(itertools.islice(batch_iter,
                                                       min(spe, remaining)))
-                    phase_s["input_wait"] += sp.seconds
                     if not group:
                         step_span.cancel()  # the epoch's end, not a step
                         break
@@ -903,7 +922,7 @@ class Trainer:
                     poison = faults.armed("train.nonfinite")
                     losses = None
                     if len(group) == spe and spe > 1:
-                        with span("train/shard") as sp:
+                        with span("train/shard"):
                             stacked = {
                                 key: np.stack([b[key] for b in group])
                                 for key in group[0]}
@@ -913,33 +932,30 @@ class Trainer:
                                         self._poison_batch(stacked, index=i)
                             sharded = self._shard_batch(stacked,
                                                         stacked=True)
-                        phase_s["host"] += sp.seconds
                         if first_step:
                             self._train_step_multi = self._load_step(
                                 self._train_step_multi, state, sharded,
                                 "trainer:train_step_multi")
-                        with span("train/dispatch") as sp:
+                        with span("train/dispatch"):
                             if self._guard is not None:
                                 state, metrics, losses = \
                                     self._train_step_multi(state, sharded)
                             else:
                                 state, metrics = self._train_step_multi(
                                     state, sharded)
-                        phase_s["host"] += sp.seconds
                     else:
                         # trailing (or single-step-mode) group, step by step
                         losses = [] if self._guard is not None else None
                         for b in group:
-                            with span("train/shard") as sp:
+                            with span("train/shard"):
                                 if poison and faults.fire("train.nonfinite"):
                                     self._poison_batch(b)
                                 sharded = self._shard_batch(b)
-                            phase_s["host"] += sp.seconds
                             if not self._step_loaded:
                                 self._train_step = self._load_step(
                                     self._train_step, state, sharded,
                                     "trainer:train_step")
-                            with span("train/dispatch") as sp:
+                            with span("train/dispatch"):
                                 if self._guard is not None:
                                     state, metrics, loss_i = \
                                         self._train_step(state, sharded)
@@ -947,7 +963,6 @@ class Trainer:
                                 else:
                                     state, metrics = self._train_step(
                                         state, sharded)
-                            phase_s["host"] += sp.seconds
                         self._single_step_ran = True
                     self.global_step += len(group)
                     batches_done += len(group)
@@ -964,13 +979,12 @@ class Trainer:
                         # per-dispatch host sync of the per-step losses:
                         # the cost of an armed guard, and the one detection
                         # path halt/skip/rewind all share
-                        with span("train/guard_sync") as sp:
+                        with span("train/guard_sync"):
                             if isinstance(losses, list):
                                 losses_host = np.concatenate(
                                     [np.asarray(x) for x in losses])
                             else:
                                 losses_host = np.asarray(losses)
-                        phase_s["host"] += sp.seconds
                         skips_before = self._guard.skipped_total
                         action = self._guard.observe(losses_host, prev_step)
                         if self.telemetry is not None:
@@ -995,9 +1009,8 @@ class Trainer:
                     if first_step or first_single:
                         # this dispatch paid a jit compilation; keep it
                         # out of the throughput measurement window
-                        with span("train/fence") as sp:
+                        with span("train/fence"):
                             fence(metrics)
-                        phase_s["fence"] += sp.seconds
                         t0, samples_since, steps_since = time.time(), 0, 0
 
                     crossed_log = (self.global_step // cfg.log_every_n_steps
@@ -1006,28 +1019,29 @@ class Trainer:
                         # async dispatch: sync on the device before taking
                         # dt, else the window measures host dispatch time
                         # and over-reports throughput
-                        with span("train/fence") as sp:
+                        with span("train/fence"):
                             fence(metrics)
-                        phase_s["fence"] += sp.seconds
                         dt = time.time() - t0
                         throughput = samples_since / max(dt, 1e-9)
-                        with span("train/log") as sp:
+                        with span("train/log"):
                             self._log_step(
                                 metrics, dt=dt, throughput=throughput,
-                                steps_since=steps_since,
-                                phase_s=phase_s)
-                            phase_s = dict.fromkeys(phase_s, 0.0)
-                        phase_s["host"] += sp.seconds
+                                steps_since=steps_since, pace=pace,
+                                step_span=step_span)
                         t0, samples_since, steps_since = time.time(), 0, 0
+                    # the step's pace: its two closing attrs, and the
+                    # slow-step rule over its leaves (training/pace.py)
+                    pace.step_close(step_span, steps=len(group),
+                                    queue_depth=depth.get("queue_depth"))
 
-                    if cfg.preempt_checkpoint and \
-                            self._handle_preemption(state):
-                        stop = True
-                        break
+                if cfg.preempt_checkpoint and \
+                        self._handle_preemption(state):
+                    stop = True
+                    break
 
-                    if cfg.max_steps > 0 and self.global_step >= cfg.max_steps:
-                        stop = True
-                        break
+                if cfg.max_steps > 0 and self.global_step >= cfg.max_steps:
+                    stop = True
+                    break
 
             if rewound:
                 # restart the loop at the anchor's epoch/batch without
@@ -1049,7 +1063,8 @@ class Trainer:
                 for k, v in val_metrics.items():
                     self.writer.add_scalar(k, v, self.global_step)
                 if hasattr(self.task, "on_validation_epoch_end"):
-                    self.task.on_validation_epoch_end(self, state)
+                    with span("train/epoch_end"):
+                        self.task.on_validation_epoch_end(self, state)
                 if self._ckpt is not None and val_metrics:
                     with span("train/checkpoint"):
                         self._ckpt.save(self.global_step, state,

@@ -168,8 +168,12 @@ def test_span_vocabularies_are_closed():
         trace_mod.span("train/warmup")
     with pytest.raises(ValueError, match="unknown device scope"):
         trace_mod.device_scope("attention")
-    assert set(trace_mod.ENCLOSING_SPANS) < set(trace_mod.TRAIN_PHASES)
+    assert set(trace_mod.ENCLOSING_SPANS + trace_mod.TILED_PHASES) < set(
+        trace_mod.TRAIN_PHASES)
     assert not set(trace_mod.TRAIN_PHASES) & set(trace_mod.PHASES)
+    assert not set(trace_mod.PROCESS_PHASES) & set(
+        trace_mod.TRAIN_PHASES + trace_mod.PHASES)
+    assert all(n.startswith("proc/") for n in trace_mod.PROCESS_PHASES)
     assert len(set(trace_mod.DEVICE_SCOPES)) == len(trace_mod.DEVICE_SCOPES)
     with trace_mod.span("decode_step"):   # request phases are span names too
         pass
@@ -191,6 +195,165 @@ def test_cancelled_span_stays_out_of_the_ring(timeline):
             pass
         step.cancel()
     assert [s["name"] for s in timeline.spans()] == ["train/input_wait"]
+
+
+def test_record_writes_a_span_after_the_fact(timeline):
+    """``Timeline.record``: a span that was over before anybody could
+    open it lands in the ring when it is written (after what closed
+    before, before what closes later), under the span open on this
+    thread, with the bounds it was given; ring only."""
+    with trace_mod.span("train/dispatch", step=1):
+        pass
+    with trace_mod.span("train/step", step=2) as step:
+        with trace_mod.span("train/fence") as fence:
+            sid = timeline.record("proc/gc", start=5.0, end=5.25,
+                                  generation=2, collected=7)
+    boot = timeline.record("proc/boot", start=1.0, end=3.0)
+    names = [s["name"] for s in timeline.spans()]
+    assert names == ["train/dispatch", "proc/gc", "train/fence",
+                     "train/step", "proc/boot"]       # the order of writing
+    gc_s, boot_s = timeline.spans("proc/gc")[0], timeline.spans()[-1]
+    assert (gc_s["id"], gc_s["parent"], gc_s["step"]) == (sid, fence.id, 2)
+    assert (gc_s["start"], gc_s["end"], gc_s["duration_s"]) == (5.0, 5.25,
+                                                                0.25)
+    assert gc_s["attrs"] == {"generation": 2, "collected": 7}
+    assert gc_s["thread"] == threading.get_ident()
+    assert (boot_s["id"], boot_s["parent"], boot_s["step"]) == (boot, None,
+                                                                None)
+    # it is no leaf of the step: the step's children are its own spans
+    assert [n for n, _ in step.children] == ["train/fence"]
+    assert timeline.dropped == 0
+    for i in range(8):          # a full ring drops the oldest, recorded or not
+        timeline.record("proc/gc", start=float(i), end=i + 0.5)
+    assert timeline.dropped == 5 and len(timeline) == 8
+    with pytest.raises(ValueError, match="unknown span name"):
+        timeline.record("proc/nap", start=0.0, end=1.0)
+    try:
+        trace_mod.set_enabled(False)
+        assert timeline.record("proc/gc", start=0.0, end=1.0) is None
+    finally:
+        trace_mod.set_enabled(True)
+    assert timeline.dropped == 5
+
+
+def test_enclosing_spans_keep_their_leaves_seconds(timeline):
+    with trace_mod.span("train/step", step=3) as step:
+        with trace_mod.span("train/input_wait") as wait:
+            pass
+        with trace_mod.span("train/log") as log:
+            with trace_mod.span("train/log_console") as console:
+                pass
+            with trace_mod.span("train/log_telemetry") as tele:
+                pass
+    # the step's leaves in order of closing, train/log's handed up
+    assert step.children == [("train/input_wait", wait.seconds),
+                             ("train/log_console", console.seconds),
+                             ("train/log_telemetry", tele.seconds)]
+    assert log.children == step.children[1:] and wait.children is None
+
+
+def test_gc_spans_for_a_long_collection_and_not_for_a_short_one(timeline):
+    import gc
+
+    from perceiver_tpu.obs.process import GcSpans
+
+    spans = GcSpans().install()
+    try:
+        assert spans in gc.callbacks
+        gc.collect()
+        timeline.__init__(capacity=8)        # what the first sweep wrote
+        gc.collect(0)                        # a young generation: no time
+        assert timeline.spans("proc/gc") == []
+        short = spans.seconds
+        assert 0.0 < short < 1.0             # summed all the same
+        gc.disable()                         # no sweep while it is made
+        try:
+            cycles = []
+            for _ in range(300_000):         # a large cycle of garbage
+                a, b = [], []
+                a.append(b), b.append(a)
+                cycles.append(a)
+            del cycles, a, b
+            with trace_mod.span("train/fence") as leaf:
+                gc.collect()
+        finally:
+            gc.enable()
+        (written,) = timeline.spans("proc/gc")
+        assert written["duration_s"] > 1e-3
+        assert written["attrs"]["generation"] == 2
+        assert written["attrs"]["collected"] >= 600_000
+        assert written["parent"] == leaf.id  # inside the leaf that was open
+        assert leaf.start <= written["start"] <= written["end"] <= leaf.end
+        assert spans.seconds >= short + written["duration_s"]
+    finally:
+        spans.uninstall()
+    assert spans not in gc.callbacks
+    spans.uninstall()                        # twice is no error
+
+
+def test_import_spans_nest_and_skip_what_was_loaded(timeline, tmp_path,
+                                                    monkeypatch):
+    """``import_span`` at an import site: ``proc/import`` spans that
+    nest where one import pulls another, nothing written for a module
+    that was loaded already, nothing installed anywhere."""
+    from perceiver_tpu.obs import process
+
+    (tmp_path / "heavy_outer.py").write_text(
+        "import time\n"
+        "from perceiver_tpu.obs.process import import_span\n"
+        "with import_span('heavy_inner'):\n"
+        "    import heavy_inner\n"
+        "time.sleep(0.003)\n")
+    (tmp_path / "heavy_inner.py").write_text("import time\n"
+                                             "time.sleep(0.003)\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    before = list(sys.meta_path)
+    try:
+        with process.import_span("heavy_outer"):
+            import heavy_outer  # noqa: F401
+        with process.import_span("heavy_outer"):     # loaded: no time
+            import heavy_outer  # noqa: F401,F811
+    finally:
+        for name in ("heavy_outer", "heavy_inner"):
+            sys.modules.pop(name, None)
+    assert sys.meta_path == before
+    inner, outer = timeline.spans()
+    assert [inner["attrs"], outer["attrs"]] == [
+        {"module": "heavy_inner"}, {"module": "heavy_outer"}]
+    assert inner["name"] == outer["name"] == "proc/import"
+    assert inner["parent"] == outer["id"] and outer["parent"] is None
+    assert outer["duration_s"] - inner["duration_s"] >= 0.003
+    # the boot is written once a process
+    monkeypatch.setattr(process, "_begun", False)
+    process.begin()
+    process.begin()
+    (boot,) = timeline.spans("proc/boot")
+    assert boot["duration_s"] > 0 and "jax" in boot["attrs"]["loaded"]
+    try:
+        trace_mod.set_enabled(False)
+        with process.import_span("json"):            # off: a no-op
+            import json  # noqa: F401
+    finally:
+        trace_mod.set_enabled(True)
+    assert len(timeline.spans()) == 3
+
+
+def test_process_start_is_the_kernel_s(timeline):
+    import time
+
+    from perceiver_tpu.obs.process import process_start
+
+    started = process_start()
+    assert started is not None
+    # the process began before this test did, and not a day ago
+    age = time.monotonic() - started
+    assert 0.0 < age < 86400.0
+    # the kernel's own word for it, to the tick
+    with open("/proc/self/stat") as f:
+        ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+    boot_age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+        - ticks / os.sysconf("SC_CLK_TCK")
+    assert age == pytest.approx(boot_age, abs=0.05)
 
 
 def test_spans_nest_per_thread(timeline):
@@ -591,7 +754,13 @@ def test_training_telemetry_registry_conforms(tmp_path):
     telemetry = Telemetry(str(tmp_path))
     telemetry.step(1, 2.5, steps_per_sec=4.0, samples_per_sec=128.0)
     telemetry.guard_skip(2)
+    telemetry.slow_step(3, stalled_s=0.25, interval_s=0.5, median_s=0.25)
+    telemetry.slow_step(4, stalled_s=None, interval_s=0.5, median_s=0.25)
     assert promparse.check_exposition(telemetry.registry.render()) == []
+    assert telemetry.registry.get("training_slow_steps_total").value == 1
+    assert telemetry.registry.get(
+        "training_stall_seconds_total").value == 0.25
+    assert [e["step"] for e in telemetry.events("slow_step")] == [3, 4]
 
 
 # --- HTTP endpoint -----------------------------------------------------------
@@ -611,9 +780,14 @@ def _get(url: str):
 def test_obs_server_endpoints():
     registry = MetricsRegistry()
     registry.gauge("fleet_size", "replicas").set(2)
-    buf = TraceBuffer()
+    buf = TraceBuffer(max_spans_per_trace=1)
     ctx = trace_mod.start_trace(sink=buf)
     ctx.record("submit", duration_s=0.001)
+    ctx.record("dispatch", duration_s=0.001)    # one span over the bound
+    tl = trace_mod.Timeline(capacity=1)
+    prev_tl = trace_mod.set_timeline(tl)
+    for _ in range(3):
+        tl.record("proc/gc", start=0.0, end=1.0)  # two overwritten
     healthy = {"flag": True}
     server = ObsServer(
         metrics_fn=registry.render,
@@ -623,6 +797,8 @@ def test_obs_server_endpoints():
         status, body, ctype = _get(f"{server.url}/metrics")
         assert status == 200 and "version=0.0.4" in ctype
         assert promparse.check_exposition(body) == []
+        # what the buffer refused and the timeline overwrote, one counter
+        assert "\nobs_spans_dropped_total 3\n" in body
 
         status, body, _ = _get(f"{server.url}/healthz")
         assert status == 200 and json.loads(body)["ok"] is True
@@ -649,6 +825,7 @@ def test_obs_server_endpoints():
         assert status == 501 and "profile_dir" in body
     finally:
         server.close()
+        trace_mod.set_timeline(prev_tl)
 
 
 # --- training telemetry ------------------------------------------------------
@@ -774,6 +951,38 @@ def test_tracing_overhead_within_pinned_bounds():
         trace_mod.set_timeline(prev)
     assert span_us < 50.0, span_us
     assert span_off_us < 10.0, span_off_us
+    # a whole logged step as the trainer writes it: the enclosing
+    # train/step and train/log, eight leaves, the pace's two attrs and
+    # its median (<500us pinned, ~60us in practice: under 0.1% of the
+    # shortest cell's 115 ms step)
+    from perceiver_tpu.training.pace import StepPace
+
+    leaves = ("train/input_wait", "train/shard", "train/dispatch",
+              "train/fence")
+    writes = ("train/log_console", "train/log_scalars",
+              "train/log_telemetry")
+    prev = trace_mod.set_timeline(trace_mod.Timeline())
+    try:
+        pace = StepPace()
+        pace.epoch_start()
+        t0 = time.perf_counter()
+        for i in range(n):
+            with trace_mod.span("train/step", step=i) as step:
+                pace.step_open()
+                for name in leaves:
+                    with trace_mod.span(name):
+                        pass
+                with trace_mod.span("train/log"):
+                    for name in writes:
+                        with trace_mod.span(name):
+                            pass
+                    pace.phases_since_line(step)
+                pace.step_close(step, queue_depth=2)
+        step_us = (time.perf_counter() - t0) / n * 1e6
+        assert trace_mod.timeline().dropped == 0
+    finally:
+        trace_mod.set_timeline(prev)
+    assert step_us < 500.0, step_us
 
 
 # --- integration gates -------------------------------------------------------

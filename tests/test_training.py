@@ -3,6 +3,8 @@
 import dataclasses
 import gc
 import os
+import sys
+import time
 import weakref
 
 import jax
@@ -546,11 +548,12 @@ def test_resume_falls_back_to_params_when_optimizer_config_changed(tmp_path):
 # --- the step timeline (obs/trace.py: TRAIN_PHASES) --------------------------
 
 
-def _fit_with_timeline(tmp_path, **overrides):
+def _fit_with_timeline(tmp_path, synthetic_train_size=64, **overrides):
     from perceiver_tpu.obs import trace
 
     dm = MNISTDataModule(data_dir=str(tmp_path / "nope"), batch_size=16,
-                         synthetic_train_size=64, synthetic_test_size=32)
+                         synthetic_train_size=synthetic_train_size,
+                         synthetic_test_size=32)
     cfg = dict(max_epochs=2, log_every_n_steps=1, num_sanity_val_steps=0,
                default_root_dir=str(tmp_path / "logs"),
                enable_checkpointing=False,
@@ -577,34 +580,63 @@ def phase_fit(tmp_path_factory):
 def test_fit_yields_leaf_phases_that_tile_each_step(phase_fit):
     from perceiver_tpu.obs import trace
 
-    _, trainer, spans = phase_fit
-    assert {s["name"] for s in spans} <= set(trace.TRAIN_PHASES)
+    _, trainer, every_span = phase_fit
+    assert {s["name"] for s in every_span} <= set(
+        trace.TRAIN_PHASES + trace.PROCESS_PHASES)
+    # a collection lies inside whatever was open: beside the tiling
+    spans = [s for s in every_span if s["name"] != "proc/gc"]
     by_id = {s["id"]: s for s in spans}
     steps = [s for s in spans if s["name"] == "train/step"]
     n = trainer.global_step                       # two epochs' batches
     assert n >= 6 and [s["step"] for s in steps] == list(range(1, n + 1))
     every = {"train/input_wait", "train/shard", "train/dispatch",
              "train/fence", "train/log"}
-    for step in steps:
-        kids = sorted((s for s in spans if s["parent"] == step["id"]),
+
+    def tiled(parent):
+        kids = sorted((s for s in spans if s["parent"] == parent["id"]),
                       key=lambda s: s["start"])
+        assert all(k["step"] == parent["step"] for k in kids)
+        # side by side inside the parent: no phase inside another
+        assert parent["start"] <= kids[0]["start"]
+        assert kids[-1]["end"] <= parent["end"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["end"] <= b["start"], (a["name"], b["name"])
+        return kids
+
+    for step in steps:
+        kids = tiled(step)
         names = [k["name"] for k in kids]
         first = step["step"] == 1
         assert set(names) == every | ({"train/step_load"} if first
                                       else set()), names
         assert names[:2] == ["train/input_wait", "train/shard"]
         assert names[-1] == "train/log"
-        assert all(k["step"] == step["step"] for k in kids)
-        # side by side inside the step: no phase inside another
-        assert step["start"] <= kids[0]["start"]
-        assert kids[-1]["end"] <= step["end"]
-        for a, b in zip(kids, kids[1:]):
-            assert a["end"] <= b["start"], (a["name"], b["name"])
-        assert not any(s["parent"] in {k["id"] for k in kids}
+        # train/log is tiled by its three writes, leaves themselves
+        writes = tiled(kids[-1])
+        assert [w["name"] for w in writes] == [
+            "train/log_console", "train/log_scalars", "train/log_telemetry"]
+        # (a log of under a millisecond here: the spans' own
+        # microseconds are more than a twentieth of it)
+        assert kids[-1]["duration_s"] - sum(w["duration_s"] for w in writes) \
+            <= max(0.05 * kids[-1]["duration_s"], 2e-4)
+        leaves = kids[:-1] + writes
+        assert not any(s["parent"] in {k["id"] for k in leaves}
                        for s in spans)
-        covered = sum(k["duration_s"] for k in kids)
+        # under no leaf: less than 5% of the step
+        covered = sum(k["duration_s"] for k in leaves)
         assert covered >= 0.95 * step["duration_s"], (names, covered,
                                                       step["duration_s"])
+    # the pace: close to close, None on an epoch's first; the thread's
+    # own seconds never over the wall's
+    per_epoch = n // 2
+    for i, step in enumerate(steps):
+        interval = step["attrs"]["interval_s"]
+        if i % per_epoch == 0:
+            assert interval is None
+        else:
+            assert interval == pytest.approx(
+                step["end"] - steps[i - 1]["end"], abs=1e-3)
+        assert 0 <= step["attrs"]["cpu_s"] <= step["duration_s"] + 1e-3
     # the prefetch queue's depth rides the pull
     waits = [s for s in spans if s["name"] == "train/input_wait"]
     assert all(0 <= s["attrs"]["queue_depth"] <= 2 for s in waits)
@@ -618,10 +650,18 @@ def test_fit_yields_leaf_phases_that_tile_each_step(phase_fit):
     assert inside == {"train/model_init", "train/restore"}
     assert sum(s["name"] == "train/eval" for s in spans) == 2
     assert sum(s["name"] == "train/step_load" for s in spans) == 1
+    # what lies around the steps has spans too, in this order
+    outside = [s["name"] for s in sorted(spans, key=lambda s: s["start"])
+               if s["parent"] is None and s["name"] not in
+               ("train/step", "train/input_wait", "train/eval",
+                "proc/backend_init")]   # the runtime's start, if here
+    assert outside == ["train/construct", "train/data_setup",
+                       "train/io_setup", "train/build_state",
+                       "train/data_setup"]
     # enclosing spans have children, leaves have none
     parents = {s["parent"] for s in spans}
     assert {by_id[p]["name"] for p in parents if p in by_id} == \
-        set(trace.ENCLOSING_SPANS)
+        set(trace.ENCLOSING_SPANS + trace.TILED_PHASES)
 
 
 def test_fit_writes_phase_seconds_to_telemetry(phase_fit):
@@ -690,6 +730,93 @@ def test_guard_sync_phase_only_under_an_armed_guard(tmp_path):
     syncs = [s for s in spans if s["name"] == "train/guard_sync"]
     assert len(syncs) == len(steps) >= 3
     assert {s["parent"] for s in syncs} == {s["id"] for s in steps}
+
+
+def _installed():
+    """What the timeline installs in the process while a fit runs."""
+    from perceiver_tpu.obs import process
+
+    return [c for c in gc.callbacks if isinstance(c, process.GcSpans)]
+
+
+@pytest.mark.parametrize("ending", ["returns", "raises"])
+def test_fit_leaves_no_callback_and_no_import_finder_installed(
+        tmp_path, ending):
+    seen = {}
+
+    class DM(MNISTDataModule):
+        def setup(self, stage=None):
+            seen["during"] = _installed()
+            if ending == "raises":
+                raise RuntimeError("no data today")
+            super().setup(stage)
+
+    dm = DM(data_dir=str(tmp_path / "nope"), batch_size=16,
+            synthetic_train_size=32, synthetic_test_size=16)
+    trainer = Trainer(small_image_task(), dm, TrainerConfig(
+        max_epochs=1, num_sanity_val_steps=0, enable_checkpointing=False,
+        default_root_dir=str(tmp_path / "logs")), optimizer_init=ADAMW)
+    finders = list(sys.meta_path)        # the import spans install none
+    assert _installed() == []
+    if ending == "raises":
+        with pytest.raises(RuntimeError, match="no data today"):
+            trainer.fit()
+    else:
+        trainer.fit()
+    # while it ran: the collector's callback, and no more of them after
+    assert len(seen["during"]) == 1
+    assert _installed() == [] and sys.meta_path == finders
+
+
+def test_a_planted_stall_is_found_and_named(tmp_path, monkeypatch):
+    """A console write that blocks for 0.2 s in one step of a real
+    ``fit()``: one ``slow_step`` for that step, naming
+    ``train/log_console``, in the events, the telemetry's file and on
+    standard error; the step's ``interval_s`` holds the 0.2 s."""
+    import io
+    import json
+
+    from perceiver_tpu.obs import events as events_mod
+
+    planted = 11
+
+    class SlowErr(io.StringIO):
+        def write(self, text):
+            if text.startswith(f"[step {planted}]"):
+                time.sleep(0.2)
+            return super().write(text)
+
+    err = SlowErr()
+    monkeypatch.setattr(sys, "stderr", err)
+    prev_log = events_mod.set_default_log(events_mod.EventLog())
+    try:
+        trainer, spans = _fit_with_timeline(
+            tmp_path, max_epochs=1, synthetic_train_size=16 * 18)
+        events = events_mod.default_log().events("slow_step")
+    finally:
+        events_mod.set_default_log(prev_log)
+    assert trainer.global_step > planted
+    (event,) = [e for e in events if e["step"] == planted]
+    assert event["phase"] == "train/log_console"
+    assert event["leaves"]["train/log_console"] >= 0.2
+    assert event["excess_s"] == pytest.approx(0.2, abs=0.1)
+    assert event["cpu_s"] < event["interval_s"] - 0.15   # it waited
+    lines = [ln for ln in err.getvalue().splitlines()
+             if ln.startswith(f"[slow_step] step {planted}:")]
+    assert len(lines) == 1
+    assert "largest excess train/log_console" in lines[0]
+    (step,) = [s for s in spans if s["name"] == "train/step"
+               and s["step"] == planted]
+    assert step["attrs"]["interval_s"] == pytest.approx(
+        event["interval_s"], abs=1e-5)
+    with open(tmp_path / "telemetry" / "telemetry.jsonl") as f:
+        written = [e for e in map(json.loads, f)
+                   if e["type"] == "slow_step"]
+    assert [e["phase"] for e in written if e["step"] == planted] == [
+        "train/log_console"]
+    stalls = trainer.telemetry.registry
+    assert stalls.get("training_slow_steps_total").value >= 1
+    assert stalls.get("training_stall_seconds_total").value >= 0.1
 
 
 def test_multi_step_dispatch_is_one_step_span(tmp_path):
