@@ -362,12 +362,29 @@ def attention_paths():
     return _PATHS.counting()
 
 
-def format_attention_paths(tally) -> str:
-    """``fused=39 materialized[shape]=1`` — one log line's worth."""
-    return " ".join(
+# ... and of what the fused call sites with a mask run: their tiles by
+# kind and the sub-tiles of the masked ones (``ops/tiling.tile_counts``),
+# keyed by the words of the log line.
+_MASKED_TILES = Tally()
+
+
+def masked_attention_tiles():
+    """Count the fused call sites with a mask traced inside the block
+    by what they run: ``block_diffusion tiles plain 12 masked 12,
+    sub-tiles 32/48``."""
+    return _MASKED_TILES.counting()
+
+
+def format_attention_paths(tally, tiles=None) -> str:
+    """``fused=39 materialized[shape]=1`` — one log line's worth; with
+    the masked call sites' ``tiles``, ``; causal tiles plain 6 masked
+    4, sub-tiles 12/16 x2`` after it."""
+    paths = " ".join(
         f"{path}[{reason}]={n}" if reason else f"{path}={n}"
         for (path, reason), n in sorted(
             tally.items(), key=lambda kv: (kv[0][0], kv[0][1] or "")))
+    return "; ".join([paths] + [f"{what} x{n}"
+                                for what, n in sorted((tiles or {}).items())])
 
 
 @device_scope("attn_proj")
@@ -547,6 +564,8 @@ def _fused_core(q, k, v, num_heads, key_padding_mask, causal=False,
     import perceiver_tpu.ops.pallas_attention as _pa
     bias = (_ca.pad_mask_to_bias(key_padding_mask)
             if key_padding_mask is not None else None)
+    if causal or block_diffusion is not None:
+        _MASKED_TILES.add(_pa.masked_call_tiles(q.shape[1], block_diffusion))
     return _pa.flash_attention_channels(q, k, v, num_heads=num_heads,
                                         bias=bias, causal=causal,
                                         block_diffusion=block_diffusion)

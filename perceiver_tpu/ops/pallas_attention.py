@@ -63,9 +63,10 @@ Causal mode (``causal=True``, square scores, no key bias): query ``i``
 sees keys ``0..i``. Blocks that lie wholly above the diagonal are
 neither loaded (the index maps hold the last block that is needed, so
 the pipeline fetches nothing new) nor computed (``pl.when``); blocks
-the diagonal crosses are masked in the kernel from two iotas; blocks
-below it run the unmasked body. Padded keys lie above every real row,
-so the mask covers them too. The calls are named
+the diagonal crosses are masked in the kernel from two iotas (the
+backward, sub-tile by sub-tile: below); blocks below it run the
+unmasked body. Padded keys lie above every real row, so the mask
+covers them too. The calls are named
 ``causal_attention_fwd`` / ``causal_attention_bwd``: a reader that
 counts a ``flash_attention_*`` call in full never sees them. A
 non-causal call traces exactly what it traced before the mode existed.
@@ -96,6 +97,35 @@ clean ones past the row's end: no real query sees a padded key. The
 calls are named ``block_diffusion_attention_fwd`` /
 ``block_diffusion_attention_bwd``.
 
+Skipped tiles and sub-tiles. A masked call loads a tile's operands
+inside the branch that runs it, so a skipped grid step costs the step
+alone (a call with no mask runs every tile and loads at the top). A
+plain tile runs whole, in one pass, in both kernels: that is where the
+MXU is best fed. The forward runs a masked tile whole too, under its
+mask. The backward classifies a *masked* tile of either mask once more
+on the host, by the same rules one level down
+(``ops/tiling.sub_tile_lists``): its sub-tiles of ``_SUB_TILE`` queries
+by ``_SUB_TILE`` keys that hold a visible pair, the masked ones and then
+the plain ones, as a list; tiles with one pattern (every diagonal tile
+of a quadrant, every diagonal tile of the causal triangle) share one
+list. Two more prefetched scalar tables carry where each tile's list
+lies and the lists. The kernel walks a masked tile's list in two
+``fori_loop``s, one whose body is a masked sub-tile and one whose body
+is a plain one — slices of ``q``, ``k``, ``v``, ``do`` and the
+statistics taken from their refs, the tile's own arithmetic on them,
+the results added into slices of the float32 accumulators — so what a
+call traces does not grow with the sub-tiles a tile holds (the tables
+do): every call site of an unrolled stack pays for a kernel's traced
+size when the step is loaded. At 512 a 1024 x 1024 tile on the diagonal
+of the causal triangle, or of the noised-clean and clean-clean
+quadrants, runs 3 of its 4 sub-tiles (2 of them masked); one on the
+noised-noised diagonal 2 of 4. A sub-tile adds into the accumulators,
+so a masked call's backward keeps them whatever its grid. The forward
+does not walk lists: each pass of a row's queries over some keys pays
+the softmax's serial chain again (running max, exponentials, sum,
+rescaled accumulator), which costs as much as the sub-tiles skipped
+(chip runs, PERF.md Findings PRs 40 and 41).
+
 So the kernels know three masks, by the name of their calls: none
 (``flash_attention_*``, with an optional key bias), the causal
 triangle (``causal_attention_*``) and the block-diffusion mask
@@ -122,6 +152,8 @@ from perceiver_tpu.ops.tiling import (
     diffusion_tiles,
     held_tiles,
     round_up as _round_up,
+    sub_tile_lists,
+    tile_counts,
 )
 
 from perceiver_tpu.ops.chunked_attention import NEG_INF
@@ -143,6 +175,13 @@ _STREAM_BLOCK_K = 1024
 _MAX_BLOCK_Q = 1024
 _SCORE_TILE = 1024 * 1024
 _CAUSAL_BLOCK_K = 1024
+# the backward runs a masked tile in sub-tiles of this many queries and
+# keys (the whole tile where its side is no multiple). Chip runs,
+# PERF.md Findings PRs 40 and 41: 512 beats 256 and 128 (a pass costs
+# 0.3-0.5 us beside its work); the forward runs a masked tile whole (a
+# pass there also pays a softmax's serial chain, 0.9 us whatever its
+# size, and three passes of 512 already lose to the tile)
+_SUB_TILE = 512
 
 
 def pick_blocks(lq: int, lk: int, causal: bool = False):
@@ -241,9 +280,69 @@ def _diffusion_mask(s, first_q, first_k, half: int, block: int,
 
 
 def _when_kind(kind, body):
-    """Run ``body(masked)`` for a block-diffusion tile of ``kind``."""
+    """Run ``body(masked)`` for a tile, or a sub-tile, of ``kind``."""
     pl.when(kind == MASKED)(lambda: body(True))
     pl.when(kind == PLAIN)(lambda: body(False))
+
+
+# --- a masked tile's sub-tiles ------------------------------------------------
+
+def _sub_tile(block: int) -> int:
+    """The side of a masked tile's sub-tiles along a side of ``block``."""
+    return _SUB_TILE if block % _SUB_TILE == 0 else block
+
+
+def _hide(s, first_q, first_k, diffusion, transposed: bool):
+    """``s`` under the call's mask: the block-diffusion mask of
+    ``diffusion``, or the causal triangle where that is None."""
+    if diffusion:
+        return _diffusion_mask(s, first_q, first_k, *diffusion, transposed)
+    return _causal_mask(s, first_q, first_k, transposed)
+
+
+def _each_sub_tile(span_ref, entry_ref, tile, sub, body):
+    """The masked tile ``tile`` (its place in the sweep's tables), one
+    listed sub-tile after another: a loop over its masked sub-tiles,
+    then one over its plain ones, the traced body of each one
+    sub-tile. ``body(rows, cols, row, col, masked)`` takes the
+    sub-tile's queries and keys as slices of the tile's and as their
+    first offsets in it."""
+    sub_q, sub_k = sub
+    first, masked = span_ref[3 * tile], span_ref[3 * tile + 1]
+
+    def walk(start, count, masked: bool):
+        def step(i, carry):
+            at = 2 * (start + i)
+            row = pl.multiple_of(entry_ref[at], sub_q)
+            col = pl.multiple_of(entry_ref[at + 1], sub_k)
+            body(pl.ds(row, sub_q), pl.ds(col, sub_k), row, col, masked)
+            return carry
+
+        jax.lax.fori_loop(0, count, step, 0)
+
+    walk(first, masked, True)
+    walk(first + masked, span_ref[3 * tile + 2], False)
+
+
+def _mask_tables(diffusion, block_q: int, block_k: int, nq: int, nk: int,
+                 sub, keys_first: bool):
+    """A masked call's prefetched scalar tables, for a sweep that runs
+    queries first (the forward) or keys first (the backward): for a
+    block-diffusion call the tiles' kinds and the tile to hold at each
+    step; where the sweep's masked tiles run in sub-tiles of ``sub``,
+    where each one's list lies and the lists
+    (``ops/tiling.sub_tile_lists``)."""
+    tables = []
+    if diffusion:
+        kinds = diffusion_tiles(*diffusion, block_q, block_k, nq, nk)
+        kinds = kinds.T if keys_first else kinds
+        tables = [kinds, held_tiles(kinds)]
+    if sub:
+        spans, entries = sub_tile_lists(diffusion, block_q, block_k, nq, nk,
+                                        *sub)
+        tables += [spans.transpose(1, 0, 2) if keys_first else spans,
+                   entries]
+    return tuple(jnp.asarray(t.ravel()) for t in tables)
 
 
 # --- forward -----------------------------------------------------------------
@@ -270,25 +369,28 @@ def _fwd_kernel(*refs, scale: float, nk: int, group: int, has_bias: bool,
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0] * scale      # (block_q, W), operand dtype
-    k = k_ref[0]              # (block_k, W)
-    v = v_ref[0]
-    masks = _head_masks(q.shape[-1], group)
+    def operands():
+        """q (block_q, W) scaled, k and v (block_k, W), operand dtype."""
+        return q_ref[0] * scale, k_ref[0], v_ref[0]
+
+    # a call with no mask runs every tile; a masked one loads a tile's
+    # operands where the tile runs: a skipped step costs its grid step
+    loaded = None if causal or diffusion else operands()
+    masks = _head_masks(q_ref.shape[-1], group)
     if causal or diffusion:
         first_q = pl.program_id(2) * block_q
         first_k = ik * block_k
 
     def tile(masked: bool):
+        q, k, v = loaded or operands()
         out = None
         for g, mask in enumerate(masks):
             s = jax.lax.dot_general(_only(mask, q), k, _NT,
                                     preferred_element_type=jnp.float32)
             if has_bias:
                 s = s + bias_ref[0]   # (1, block_k) key bias row
-            if masked and diffusion:
-                s = _diffusion_mask(s, first_q, first_k, *diffusion, False)
-            elif masked:
-                s = _causal_mask(s, first_q, first_k, False)
+            if masked:
+                s = _hide(s, first_q, first_k, diffusion, False)
             if nk == 1:
                 # every key in this block: a plain softmax, no running
                 # state
@@ -389,18 +491,39 @@ def _key_bias(bias, b: int, lk: int, lk_p: int):
     return _pad_rows(bias.astype(jnp.float32), lk_p, NEG_INF)
 
 
+def _clip_blocks(lq: int, lk: int, block_q: int, block_k: int,
+                 q_rows: int):
+    """``(block_q, block_k, lq_p, lk_p)``: the blocks clipped to the
+    shapes (queries to a multiple of ``q_rows``, keys to whole lanes)
+    and the padded lengths."""
+    block_q = min(block_q, _round_up(lq, q_rows))
+    block_k = _round_up(min(block_k, _round_up(lk, _LANES)), _LANES)
+    return block_q, block_k, _round_up(lq, block_q), _round_up(lk, block_k)
+
+
 def _geometry(lq: int, lk: int, e: int, h: int, block_q: int,
               block_k: int, q_rows: int):
     """How a call tiles: ``(d, group, dp, width, block_q, block_k,
     lq_p, lk_p)`` — head dim, heads a block, lanes a head, lanes a
-    block, the blocks clipped to the shapes (queries to a multiple of
-    ``q_rows``, keys to whole lanes) and the padded lengths."""
+    block, and ``_clip_blocks``' blocks and padded lengths."""
     d = e // h
     group, dp = _head_group(h, d)
-    block_q = min(block_q, _round_up(lq, q_rows))
-    block_k = _round_up(min(block_k, _round_up(lk, _LANES)), _LANES)
-    return (d, group, dp, group * dp, block_q, block_k,
-            _round_up(lq, block_q), _round_up(lk, block_k))
+    return (d, group, dp, group * dp,
+            *_clip_blocks(lq, lk, block_q, block_k, q_rows))
+
+
+def masked_call_tiles(length: int, block_diffusion=None) -> str:
+    """What a differentiated masked call over ``length`` positions runs
+    at the blocks its shapes pick — the causal triangle's, or the
+    block-diffusion mask's where ``block_diffusion = (L, B)`` is given —
+    in ``ops/tiling.tile_counts``' words: ``causal tiles plain 6 masked
+    4, sub-tiles 12/16``."""
+    block_q, block_k, lq_p, lk_p = _clip_blocks(
+        length, length, *pick_blocks(length, length, True), _LANES)
+    mask = "block_diffusion" if block_diffusion else "causal"
+    return f"{mask} tiles " + tile_counts(
+        block_diffusion, block_q, block_k, lq_p // block_q, lk_p // block_k,
+        _sub_tile(block_q), _sub_tile(block_k))
 
 
 def _call_name(causal: bool, diffusion, which: str) -> str:
@@ -412,7 +535,7 @@ def _call_name(causal: bool, diffusion, which: str) -> str:
 
 def _launch(kernel, tables, args, *, name: str, grid, in_specs, out_specs,
             out_shape, scratch, interpret: bool):
-    """One kernel over ``grid``. ``tables`` (the block-diffusion mask's)
+    """One kernel over ``grid``. ``tables`` (a masked call's)
     are prefetched to scalar memory ahead of the grid: the kernel's
     first references and the index maps' last arguments."""
     common = dict(out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
@@ -451,18 +574,14 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
     bias = None if causal or diffusion else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
-    tables = ()
-    if diffusion:
-        kinds = diffusion_tiles(*diffusion, block_q, block_k, nq, nk)
-        tables = (jnp.asarray(kinds.ravel()), jnp.asarray(
-            held_tiles(kinds).ravel()))
+    tables = _mask_tables(diffusion, block_q, block_k, nq, nk, None, False)
 
     def k_index(ib, ih, iq, ik, *tables):
         if causal:
             # above the diagonal hold the last block a query of this
             # block sees: the pipeline fetches nothing it has
             ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
-        if tables:
+        if diffusion:
             ik = tables[1][iq * nk + ik]
         return ib, ik, ih
 
@@ -510,67 +629,81 @@ def _flash_forward(q, k, v, bias, h: int, scale: float, block_q: int,
 
 def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
                 group: int, has_bias: bool, causal: bool = False,
-                block_k: int = 0, diffusion=None):
+                block_k: int = 0, diffusion=None, sub=None):
     refs = iter(refs)
     if diffusion:   # the tiles' kinds; the held tiles are the index maps'
         kind_ref, _ = next(refs), next(refs)
+    if sub:         # where a masked tile's list of sub-tiles lies; the lists
+        span_ref, entry_ref = next(refs), next(refs)
     q_ref, k_ref, v_ref, do_ref = (next(refs) for _ in range(4))
     kbar_ref, vbar_ref = next(refs), next(refs)
     lse_ref, delta_ref = next(refs), next(refs)
     bias_ref = next(refs) if has_bias else None
     dq_ref, dk_ref, dv_ref = next(refs), next(refs), next(refs)
     db_ref = next(refs) if has_bias else None
-    if nq > 1:
+    # one block of keys (of queries) and no mask: dq (dk, dv) written
+    # straight out; a masked call's tiles and sub-tiles add into
+    # accumulators whatever its grid
+    dq_direct = nk == 1 and not sub
+    dk_direct = nq == 1 and not sub
+    if not dk_direct:
         dk_acc, dv_acc = next(refs), next(refs)
         db_acc = next(refs) if has_bias else None
     iq = pl.program_id(3)
     ik = pl.program_id(2)
-    # the first query block that sees this key block
-    first_iq = (ik * block_k) // block_q if causal else 0
-    if diffusion:
-        # which tiles run is the table's to say: the accumulators start
+    if sub:
+        # which tiles run is the mask's to say: the accumulators start
         # from zeros at the head of each sweep and every tile adds
-        if nq > 1:
-            @pl.when(iq == 0)
-            def _():
-                dk_acc[:] = jnp.zeros_like(dk_acc)
-                dv_acc[:] = jnp.zeros_like(dv_acc)
-        if nk > 1:
-            @pl.when(ik == 0)
-            def _():
-                rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
-                dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
-                                               dq_ref.dtype)
+        @pl.when(iq == 0)
+        def _():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0] * scale      # (block_q, W), operand dtype
-    k = k_ref[0]              # (block_k, W)
-    do = do_ref[0]            # (block_q, W)
-    # keys and values about their means over the keys, for the two
-    # contractions a common component would spoil (_flash_backward)
-    kc = ((k - kbar_ref[0]) * scale).astype(k.dtype)
-    vc = (v_ref[0] - vbar_ref[0]).astype(k.dtype)
-    masks = _head_masks(q.shape[-1], group)
+        @pl.when(ik == 0)
+        def _():
+            rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+            dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
+                                           dq_ref.dtype)
 
-    def tile(masked: bool):
+    def centred(k, at):
+        """The keys ``k`` and the values (``v_ref[at]``) about their
+        means over the keys, for the two contractions a common
+        component would spoil (_flash_backward)."""
+        kc = ((k - kbar_ref[0]) * scale).astype(k.dtype)
+        return kc, (v_ref[at] - vbar_ref[0]).astype(k.dtype)
+
+    def operands():
+        """The tile's: q (scaled) and do (block_q, W), k (block_k, W)
+        and the centred k and v, operand dtype."""
+        q = q_ref[0] * scale
+        k = k_ref[0]
+        return q, do_ref[0], k, *centred(k, 0)
+
+    # as in the forward: a masked call loads where a tile runs
+    loaded = None if sub else operands()
+    masks = _head_masks(q_ref.shape[-1], group)
+
+    def grads(q, do, k, kc, vc, stat, hide=None):
+        """``dq, dk, dv, db`` of the queries ``q``/``do`` against the
+        keys ``k`` (``kc``, ``vc`` centred); ``stat(ref, g)`` reads head
+        ``g``'s row of the queries' log-sum-exp or delta; ``hide`` masks
+        the scores."""
         dq = dk = dv = db = None
         for g, mask in enumerate(masks):
             # this head's lanes of the small tiles, zeros elsewhere: its
             # gradients then land in its own lanes and the heads' add up
             qg, dog, kg = _only(mask, q), _only(mask, do), _only(mask, kc)
-            # scores transposed, keys on sublanes: (block_k, block_q)
+            # scores transposed, keys on sublanes: (keys, queries)
             s = jax.lax.dot_general(k, qg, _NT,
                                     preferred_element_type=jnp.float32)
             if has_bias:
                 s = s + bias_ref[0]   # (block_k, 1) key bias column
-            if masked and diffusion:
-                s = _diffusion_mask(s, iq * block_q, ik * block_k,
-                                    *diffusion, True)
-            elif masked:
-                s = _causal_mask(s, iq * block_q, ik * block_k, True)
-            p = jnp.exp(s - lse_ref[0, g])               # rows (1, block_q)
+            if hide is not None:
+                s = hide(s)
+            p = jnp.exp(s - stat(lse_ref, g))            # rows (1, queries)
             dp = jax.lax.dot_general(vc, dog, _NT,
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, g])
+            ds = p * (dp - stat(delta_ref, g))
             dsb = ds.astype(q.dtype)
             dv_g = jax.lax.dot_general(p.astype(do.dtype), dog, _NN,
                                        preferred_element_type=jnp.float32)
@@ -587,14 +720,36 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
                 # the key bias's gradient: every head's, a column here
                 db_g = jnp.sum(ds, axis=1, keepdims=True)
                 db = db_g if db is None else db + db_g
+        return dq, dk, dv, db
 
-        if nk == 1:
+    def sub_tile(rows, cols, row, col, masked: bool):
+        k = k_ref[0, cols, :]
+        hide = functools.partial(
+            _hide, first_q=iq * block_q + row, first_k=ik * block_k + col,
+            diffusion=diffusion, transposed=True) if masked else None
+        dq, dk, dv, _ = grads(
+            q_ref[0, rows, :] * scale, do_ref[0, rows, :], k,
+            *centred(k, (0, cols)),
+            lambda ref, g: ref[0, g, :, rows], hide)
+        at = pl.multiple_of(iq * block_q + row, sub[0])
+        dq_ref[0, pl.ds(at, sub[0]), :] += dq
+        dk_acc[cols, :] += dk
+        dv_acc[cols, :] += dv
+
+    def tile(masked: bool):
+        if masked:
+            return _each_sub_tile(span_ref, entry_ref, ik * nq + iq, sub,
+                                  sub_tile)
+        # the whole tile in one pass
+        dq, dk, dv, db = grads(*(loaded or operands()),
+                               lambda ref, g: ref[0, g])
+        if dq_direct:
             dq_ref[0] = dq.astype(dq_ref.dtype)
         else:
             # float32 block of the whole (b, head group), resident
             # across the sweep; every query block sees key block 0
             rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
-            if diffusion:
+            if sub:
                 dq_ref[0, rows, :] += dq
             else:
                 @pl.when(ik == 0)
@@ -605,25 +760,25 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
                 def _():
                     dq_ref[0, rows, :] += dq
 
-        if nq == 1:
+        if dk_direct:
             dk_ref[0] = dk.astype(dk_ref.dtype)
             dv_ref[0] = dv.astype(dv_ref.dtype)
             if has_bias:
                 db_ref[0, 0] = _col_to_row(db)   # a lane-dense row in HBM
             return
-        if diffusion:
+        if sub:
             dk_acc[:] += dk
             dv_acc[:] += dv
             return
 
-        @pl.when(iq == first_iq)
+        @pl.when(iq == 0)
         def _():
             dk_acc[:] = dk
             dv_acc[:] = dv
             if has_bias:
                 db_acc[:] = db
 
-        @pl.when(iq > first_iq)
+        @pl.when(iq > 0)
         def _():
             dk_acc[:] += dk
             dv_acc[:] += dv
@@ -632,14 +787,13 @@ def _bwd_kernel(*refs, scale: float, nq: int, nk: int, block_q: int,
 
     if diffusion:
         _when_kind(kind_ref[ik * nq + iq], tile)
-    elif not causal:
-        tile(False)
-    elif nq == 1:
-        tile(True)     # one query block: every key block writes its own
-    else:
-        _when_needed(iq >= first_iq,
+    elif causal:
+        # from the first query block that sees this key block on
+        _when_needed(iq * block_q + block_q - 1 >= ik * block_k,
                      ik * block_k + block_k - 1 > iq * block_q, tile)
-    if nq == 1:
+    else:
+        tile(False)
+    if dk_direct:
         return
 
     @pl.when(iq == nq - 1)
@@ -685,12 +839,13 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
     bias = None if causal or diffusion else _key_bias(bias, b, lk, lk_p)
     nq, nk = lq_p // block_q, lk_p // block_k
     has_bias = bias is not None
-    tables = ()
-    if diffusion:   # the forward's tiles, swept keys first
-        kinds = diffusion_tiles(*diffusion, block_q, block_k, nq, nk).T
-        tables = (jnp.asarray(kinds.ravel()), jnp.asarray(
-            held_tiles(kinds).ravel()))
-    if nk > 1 and lq_p * width * 4 > _DQ_RESIDENT_MAX:
+    tables, sub = (), None
+    if causal or diffusion:   # the forward's tiles, swept keys first
+        sub = _sub_tile(block_q), _sub_tile(block_k)
+        tables = _mask_tables(diffusion, block_q, block_k, nq, nk, sub,
+                              True)
+    dq_direct, dk_direct = nk == 1 and not sub, nq == 1 and not sub
+    if not dq_direct and lq_p * width * 4 > _DQ_RESIDENT_MAX:
         raise NotImplementedError(
             f"flash attention backward keeps dq ({lq_p} x {width} "
             f"float32) in VMEM while the keys stream; over "
@@ -703,7 +858,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         fetch); where a table says, the block it holds."""
         if causal and nq > 1:
             iq = jnp.maximum(iq, (ik * block_k) // block_q)
-        if tables:
+        if diffusion:
             iq = tables[1][ik * nq + iq]
         return iq
 
@@ -724,7 +879,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         in_specs.append(pl.BlockSpec((1, block_k, 1),
                                      lambda ib, ih, ik, iq: (ib, ik, 0)))
         args.append(bias[:, :, None])
-    if nk == 1:
+    if dq_direct:
         # one key block: no query block is skipped, seen() is iq
         dq_spec, dq_dtype = q_spec, q.dtype
     else:
@@ -735,7 +890,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
     out_shape = [jax.ShapeDtypeStruct(q.shape, dq_dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
-    scratch = [] if nq == 1 else [
+    scratch = [] if dk_direct else [
         pltpu.VMEM((block_k, width), jnp.float32),   # dk accumulator
         pltpu.VMEM((block_k, width), jnp.float32),   # dv accumulator
     ]
@@ -751,7 +906,7 @@ def _flash_backward(q, k, v, bias, o, lse, do, h: int, scale: float,
         functools.partial(_bwd_kernel, scale=scale, nq=nq, nk=nk,
                           block_q=block_q, group=group,
                           has_bias=has_bias, causal=causal,
-                          block_k=block_k, diffusion=diffusion),
+                          block_k=block_k, diffusion=diffusion, sub=sub),
         tables, args, name=_call_name(causal, diffusion, "bwd"),
         grid=(b, h // group, nk, nq), in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape, scratch=scratch,

@@ -11,10 +11,23 @@ def round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-# --- which tiles of a block-diffusion mask run ------------------------------
-# (``ops/pallas_attention.py``'s third mask; the rules are at its head)
+# --- which tiles of a masked attention call run ------------------------------
+# (``ops/pallas_attention.py``'s causal and block-diffusion masks; the
+# rules are at its head)
 
 SKIPPED, MASKED, PLAIN = 0, 1, 2
+
+
+def causal_tiles(block_q: int, block_k: int, nq: int, nk: int) -> np.ndarray:
+    """(nq, nk) int32 kinds under the causal triangle (query ``i`` sees
+    keys ``0..i``): ``SKIPPED`` above the diagonal, ``MASKED`` where it
+    crosses the tile, ``PLAIN`` below."""
+    first_q = np.arange(nq)[:, None] * block_q
+    first_k = np.arange(nk)[None, :] * block_k
+    some = first_k <= first_q + block_q - 1
+    every = first_k + block_k - 1 <= first_q
+    return np.where(every, PLAIN, np.where(some, MASKED, SKIPPED)).astype(
+        np.int32)
 
 
 def diffusion_tiles(half: int, block: int, block_q: int, block_k: int,
@@ -72,3 +85,56 @@ def held_tiles(kinds: np.ndarray) -> np.ndarray:
             last = i if kind else last
             out[i] = last
     return held
+
+
+def mask_tiles(diffusion, block_q: int, block_k: int, nq: int,
+               nk: int) -> np.ndarray:
+    """The tiles' kinds under a masked call's mask: the block-diffusion
+    mask of ``diffusion = (half, block)``, or the causal triangle where
+    that is None."""
+    if diffusion:
+        return diffusion_tiles(*diffusion, block_q, block_k, nq, nk)
+    return causal_tiles(block_q, block_k, nq, nk)
+
+
+def sub_tile_lists(diffusion, block_q: int, block_k: int, nq: int, nk: int,
+                   sub_q: int, sub_k: int):
+    """One level down: the same rules over the ``sub_q x sub_k``
+    sub-tiles of each *masked* tile. ``(spans, entries)``: ``spans``
+    (nq, nk, 3) int32, for a masked tile where its list starts in
+    ``entries`` and how many masked and how many plain sub-tiles it
+    holds (zeros for the other kinds of tile); ``entries`` (n, 2)
+    int32, a sub-tile that holds a visible pair each: its first query
+    and first key, counted from the tile's own. A tile's list holds its
+    masked sub-tiles, then its plain ones (wholly visible), each kind
+    query rows first, keys ascending in a row. Tiles with the same
+    pattern (every diagonal tile of one quadrant) share one list."""
+    rows, cols = block_q // sub_q, block_k // sub_k
+    coarse = mask_tiles(diffusion, block_q, block_k, nq, nk)
+    fine = mask_tiles(diffusion, sub_q, sub_k, nq * rows, nk * cols)
+    spans = np.zeros((nq, nk, 3), np.int32)
+    entries, lists = [], {}
+    for iq, ik in zip(*np.nonzero(coarse == MASKED)):
+        pattern = fine[iq * rows:(iq + 1) * rows, ik * cols:(ik + 1) * cols]
+        key = pattern.tobytes()
+        if key not in lists:
+            found = [np.argwhere(pattern == kind) * (sub_q, sub_k)
+                     for kind in (MASKED, PLAIN)]
+            lists[key] = (len(entries), *map(len, found))
+            entries.extend(np.concatenate(found))
+        spans[iq, ik] = lists[key]
+    return spans, np.asarray(entries, np.int32).reshape(-1, 2)
+
+
+def tile_counts(diffusion, block_q: int, block_k: int, nq: int, nk: int,
+                sub_q: int, sub_k: int) -> str:
+    """What a masked call runs, for a log line: ``plain 12 masked 12,
+    sub-tiles 32/48`` — its tiles by kind and, of the masked tiles'
+    sub-tiles, those that hold a visible pair."""
+    coarse = mask_tiles(diffusion, block_q, block_k, nq, nk)
+    spans, _ = sub_tile_lists(diffusion, block_q, block_k, nq, nk, sub_q,
+                              sub_k)
+    masked = int((coarse == MASKED).sum())
+    return (f"plain {int((coarse == PLAIN).sum())} masked {masked}, "
+            f"sub-tiles {int(spans[..., 1:].sum())}/"
+            f"{masked * (block_q // sub_q) * (block_k // sub_k)}")

@@ -470,12 +470,14 @@ class Trainer:
         from perceiver_tpu.ops.attention import (
             attention_paths,
             format_attention_paths,
+            masked_attention_tiles,
         )
         from perceiver_tpu.ops.moe import moe_kinds, moe_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         from perceiver_tpu.ops.ssm import scan_paths
         from perceiver_tpu.ops.tally import format_tally
         with span("train/step_load"), attention_paths() as paths, \
+                masked_attention_tiles() as tiles, \
                 remat_keeps() as keeps, scan_paths.counting() as scans, \
                 moe_paths.counting() as experts, \
                 moe_kinds.counting() as kinds:
@@ -492,7 +494,8 @@ class Trainer:
                 print(f"[step_load] not loaded ahead of time: {e!r}",
                       file=sys.stderr, flush=True)
         self._step_loaded = True
-        lines = [f"attention call sites: {format_attention_paths(paths)}",
+        lines = ["attention call sites: "
+                 + format_attention_paths(paths, tiles),
                  f"remat keeps: {format_remat_keeps(keeps)}"]
         if scans:    # a stack with state-space layers (ops/ssm.py)
             lines.append(f"selective scans: {format_tally(scans)}")
