@@ -1,5 +1,5 @@
 """A hybrid state-space / mixture-of-experts language model: a decoder
-stack whose layers are of three kinds in a published order (the
+stack whose layers are of four kinds in a published order (the
 ``nemotron_h`` family, NVIDIA Nemotron-H, arXiv:2504.03624, and
 Nemotron 3 Nano; ``hybrid_override_pattern``)::
 
@@ -8,7 +8,8 @@ Nemotron 3 Nano; ``hybrid_override_pattern``)::
     logits = rms_f(h) Wh                                  (head untied)
 
 ``M`` is a Mamba-2 mixer (``ops/ssm.py``), ``E`` an expert layer
-(``ops/moe.py``), ``*`` causal attention with grouped queries and, in
+(``ops/moe.py``), ``L`` a gated delta-rule mixer (below), ``*`` causal
+attention with grouped queries and, in
 ``nemotron_h``, **no rotary embedding** (the Mamba layers carry
 position). A layer is one RMSNorm with a scale, one mixer and a
 residual: there is no separate MLP after ``M`` or ``*``. No linear
@@ -25,6 +26,18 @@ call may run a row as **block diffusion** trains it
 (``block_diffusion=(L, B)``: ``L`` noised positions beside their ``L``
 clean ones, ``ops.attention.block_diffusion_mask``'s rules in the
 causal triangle's place, position ``j mod L`` for index ``j``).
+
+A ``qwen3_next`` decoder layer (Qwen3-Next) is two of these as well,
+``LE`` or ``*E`` (a published period of four is ``LELELE*E``): ``L`` is
+a gated delta-rule mixer (``ops/delta_rule.py``: linear attention,
+``linear_*``); its ``*`` projects each head's query beside a gate and
+multiplies the core's output by the gate's sigmoid
+(``attn_output_gate``), turns the first ``partial_rotary_factor`` of a
+head's channels only, and every RMSNorm of the stack (the layers', the
+final one, the q/k norms) is zero-centred, ``x / rms(x) * (1 + w)``
+(``zero_centered_norms``); its ``E`` adds a shared expert that is gated
+with three matrices under a sigmoid gate of one column
+(``shared_expert_kind``).
 
 The attention layer runs on the cores every causal call takes
 (``ops.attention.mha_apply``): its ``num_kv_heads`` key/value heads are
@@ -68,6 +81,7 @@ from perceiver_tpu.ops.attention import (
     mha_apply,
     untallied,
 )
+from perceiver_tpu.ops.delta_rule import delta_mixer_apply, delta_mixer_init
 from perceiver_tpu.ops.fourier import rope_apply, rope_tables
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.linear import linear_apply, linear_init
@@ -77,21 +91,26 @@ from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.ssm import ssm_mixer_apply, ssm_mixer_init
 
 _INIT_STD = 0.02
-LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn"}
+LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn", "L": "delta"}
 
 
 def gqa_init(key, dim: int, num_heads: int, num_kv_heads: int,
-             head_dim: int, qk_norm: bool = False):
+             head_dim: int, qk_norm: bool = False,
+             output_gate: bool = False, zero_centered: bool = False):
+    """``output_gate``: the query projection twice as wide, a head's
+    query beside its gate; ``zero_centered``: the q/k norms'."""
     kq, kk, kv, ko = jax.random.split(key, 4)
     params = {
-        "q": linear_init(kq, dim, num_heads * head_dim, bias=False),
+        "q": linear_init(kq, dim, num_heads * head_dim
+                         * (2 if output_gate else 1), bias=False),
         "k": linear_init(kk, dim, num_kv_heads * head_dim, bias=False),
         "v": linear_init(kv, dim, num_kv_heads * head_dim, bias=False),
         "out": linear_init(ko, num_heads * head_dim, dim, bias=False),
     }
     if qk_norm:   # one scale of head_dim each, for all the heads
-        params.update(q_norm=rms_norm_init(head_dim),
-                      k_norm=rms_norm_init(head_dim))
+        params.update(
+            q_norm=rms_norm_init(head_dim, zero_centered=zero_centered),
+            k_norm=rms_norm_init(head_dim, zero_centered=zero_centered))
     return params
 
 
@@ -116,13 +135,15 @@ def gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
 def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
                      policy: Policy = DEFAULT_POLICY,
                      impl: Optional[str] = None, rope=None,
-                     norm_eps: float = 1e-6, block_diffusion=None):
+                     norm_eps: float = 1e-6, block_diffusion=None,
+                     output_gate: bool = False):
     """Attention with grouped queries under the causal mask, or under
     the block-diffusion mask of ``block_diffusion=(L, B)``. Where the
     tree holds ``q_norm`` / ``k_norm``, queries and keys take an RMSNorm
     over each head's channels; ``rope`` (tables with a row a position of
     ``a``) rotates them after it. The keys are normed and rotated on
-    their own ``num_kv_heads`` heads, before they are repeated."""
+    their own ``num_kv_heads`` heads, before they are repeated.
+    ``output_gate`` as ``mha_apply``'s."""
     with device_scope("attn_proj"):
         k = linear_apply(params["k"], a, policy=policy)
         if "k_norm" in params:
@@ -136,14 +157,15 @@ def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
     return mha_apply(params, a, None, None, num_heads=num_heads,
                      kv_heads=(k, v), causal=block_diffusion is None,
                      block_diffusion=block_diffusion, rope=rope,
-                     norm_eps=norm_eps, policy=policy, impl=impl)
+                     norm_eps=norm_eps, policy=policy, impl=impl,
+                     output_gate=output_gate)
 
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class HybridLM:
     vocab_size: int
     hidden_size: int
-    pattern: str                     # one of M, E, * a layer
+    pattern: str                     # one of M, E, *, L a layer
     max_seq_len: int
     # M (a pattern without M needs none of them)
     mamba_num_heads: int = 0
@@ -152,20 +174,37 @@ class HybridLM:
     ssm_state_size: int = 0
     conv_kernel: int = 4
     chunk_size: int = 128
+    # L (a pattern without L needs none of them): value head j reads
+    # key head j // (value heads / key heads)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    delta_chunk_size: int = 64
     # *
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
     # rotary positions at this base; None: no position embedding
     rope_theta: Optional[float] = None
+    # the share of a head's channels, from the first, that the rotary
+    # tables turn
+    partial_rotary_factor: float = 1.0
     # an RMSNorm over each head's channels of the projected q and k
     qk_norm: bool = False
+    # a head's query projected beside a gate; the core's output times
+    # the gate's sigmoid before the output projection
+    attn_output_gate: bool = False
     # E
     n_routed_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
     # 0: no shared expert
     moe_shared_expert_intermediate_size: int = 0
+    # relu2, or gated: three matrices under a sigmoid gate of one
+    # column (ops/moe.SHARED_KINDS)
+    shared_expert_kind: str = "relu2"
     routed_scaling_factor: float = 1.0
     router_scoring: str = "sigmoid"  # or softmax (ops/moe.SCORINGS)
     norm_topk_prob: bool = True      # the chosen scores over their sum
@@ -175,6 +214,8 @@ class HybridLM:
     held_experts: Optional[int] = None
     first_expert: int = 0
     norm_eps: float = 1e-5
+    # every RMSNorm of the stack as x / rms(x) * (1 + w), w from 0
+    zero_centered_norms: bool = False
     # recompute every layer on the backward pass, but for the dear
     # values that fit the device (ops/remat.py)
     remat: bool = False
@@ -183,11 +224,22 @@ class HybridLM:
         if not self.pattern or set(self.pattern) - set(LAYER_KINDS):
             raise ValueError(
                 f"pattern {self.pattern!r}: one of {sorted(LAYER_KINDS)} a "
-                "layer (M Mamba-2, E experts, * attention)")
+                "layer (M Mamba-2, E experts, * attention, L gated "
+                "delta rule)")
         if self.num_attention_heads % self.num_key_value_heads \
                 or self.mamba_num_heads % self.n_groups:
             raise ValueError("query heads divide over the key/value heads, "
                              "Mamba heads over the groups")
+        if "L" in self.pattern and not (
+                self.linear_num_key_heads and self.linear_key_head_dim
+                and self.linear_value_head_dim
+                and self.linear_num_value_heads
+                and not self.linear_num_value_heads
+                % self.linear_num_key_heads):
+            raise ValueError(
+                "a pattern with L needs linear_num_key_heads, "
+                "linear_key_head_dim, linear_value_head_dim and "
+                "linear_num_value_heads, a multiple of the key heads")
         if "M" in self.pattern and not (
                 self.mamba_num_heads and self.mamba_head_dim
                 and self.ssm_state_size):
@@ -218,16 +270,29 @@ class HybridLM:
                 head_dim=self.mamba_head_dim, n_groups=self.n_groups,
                 state_size=self.ssm_state_size,
                 conv_kernel=self.conv_kernel)
+        if kind == "L":
+            return delta_mixer_init(
+                key, c, num_key_heads=self.linear_num_key_heads,
+                num_value_heads=self.linear_num_value_heads,
+                key_head_dim=self.linear_key_head_dim,
+                value_head_dim=self.linear_value_head_dim,
+                conv_kernel=self.linear_conv_kernel_dim)
         if kind == "E":
             return moe_init(
                 key, c, num_experts=self.n_routed_experts,
                 held_experts=self.num_held_experts,
                 expert_hidden=self.moe_intermediate_size,
                 shared_hidden=self.moe_shared_expert_intermediate_size,
-                gated=self.gated_experts)
+                gated=self.gated_experts,
+                shared_kind=self.shared_expert_kind)
         return gqa_init(key, c, self.num_attention_heads,
                         self.num_key_value_heads, self.head_dim,
-                        self.qk_norm)
+                        self.qk_norm, self.attn_output_gate,
+                        self.zero_centered_norms)
+
+    def _norm_init(self):
+        return rms_norm_init(self.hidden_size,
+                             zero_centered=self.zero_centered_norms)
 
     def init(self, key):
         ke, kl, kh = jax.random.split(key, 3)
@@ -237,11 +302,11 @@ class HybridLM:
             "embed": {"embed": trunc_normal_clamped(
                 ke, (self.vocab_size, c), _INIT_STD)},
             "layers": {
-                name: {"norm": rms_norm_init(c),
+                name: {"norm": self._norm_init(),
                        "mixer": self._mixer_init(k, kind)}
                 for name, kind, k in zip(self.layer_names(), self.pattern,
                                          keys)},
-            "norm": rms_norm_init(c),
+            "norm": self._norm_init(),
             "head": {"w": trunc_normal_clamped(
                 kh, (c, self.vocab_size), _INIT_STD)},
         }
@@ -263,6 +328,14 @@ class HybridLM:
                     state_size=self.ssm_state_size,
                     chunk_size=self.chunk_size, eps=self.norm_eps,
                     policy=policy)
+            elif kind == "L":
+                out = delta_mixer_apply(
+                    p["mixer"], a, num_key_heads=self.linear_num_key_heads,
+                    num_value_heads=self.linear_num_value_heads,
+                    key_head_dim=self.linear_key_head_dim,
+                    value_head_dim=self.linear_value_head_dim,
+                    chunk_size=self.delta_chunk_size, eps=self.norm_eps,
+                    policy=policy)
             elif kind == "E":
                 out, load = moe_apply(
                     p["mixer"], a, top_k=self.num_experts_per_tok,
@@ -276,7 +349,8 @@ class HybridLM:
                     p["mixer"], a, num_heads=self.num_attention_heads,
                     num_kv_heads=self.num_key_value_heads, policy=policy,
                     rope=rope, norm_eps=self.norm_eps,
-                    block_diffusion=block_diffusion)
+                    block_diffusion=block_diffusion,
+                    output_gate=self.attn_output_gate)
             return h + out, load
 
         return layer
@@ -322,7 +396,9 @@ class HybridLM:
                              f"{self.max_seq_len}")
         rope = None
         if self.rope_theta is not None:
-            rope = rope_tables(positions, self.head_dim, self.rope_theta)
+            rope = rope_tables(
+                positions, int(self.head_dim * self.partial_rotary_factor),
+                self.rope_theta)
             if block_diffusion is not None:
                 rope = tuple(np.concatenate([t, t]) for t in rope)
             # one array a table, which every layer is handed: a numpy

@@ -187,6 +187,12 @@ DEVICE_SCOPES = (
     # the grouped products over the held experts
     "hybrid_stack", "ssm_mixer", "ssm_scan", "moe", "moe_route",
     "moe_experts",
+    # a gated delta-rule (linear-attention) mixer (ops/delta_rule): the
+    # whole mixer and, inside it, the chunked rule alone (the solve
+    # inside a chunk, the chunk products, the carried state); the
+    # output gate of a gated softmax attention (the query's other half,
+    # its sigmoid and the product: under attn_proj)
+    "delta_mixer", "delta_rule", "attn_gate",
     # a block-diffusion step's own noising of its rows
     # (tasks/block_diffusion_lm): the masking rates, the masks, the
     # noised copy and the loss weights

@@ -409,7 +409,7 @@ def mha_apply(params, q, k, v, *, num_heads: int,
               policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None,
               kv_chunk_size: int = 1024, spmd=None, kv_heads=None,
               causal: bool = False, rope=None, block_diffusion=None,
-              norm_eps: float = 1e-6):
+              norm_eps: float = 1e-6, output_gate: bool = False):
     """Scaled dot-product multi-head attention.
 
     q: (B, Lq, q_dim); k: (B, Lk, k_dim); v: (B, Lk, v_dim).
@@ -425,7 +425,11 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     Where ``params`` holds ``q_norm`` and ``k_norm`` (a scale of ``D``
     each), q and k take an RMSNorm over each head's channels (eps
     ``norm_eps``) before the tables. ``kv_heads`` come as their caller
-    made them: normed and rotated there, if at all.
+    made them: normed and rotated there, if at all. output_gate: the
+    query projection is twice as wide, a head's query beside its gate
+    (``[q_h | gate_h]`` a head), and the core's output is multiplied by
+    ``sigmoid(gate)`` before the output projection, outside the core
+    (scope ``attn_gate`` under ``attn_proj``).
     impl: None (pick: the fused kernels where ``pick_attention_core``
     allows, else the materialized core), "einsum" (materialized
     weights, supports dropout and attn_mask), "chunked" (blockwise
@@ -470,9 +474,14 @@ def mha_apply(params, q, k, v, *, num_heads: int,
             f"and not the other beside it, not impl={impl!r}")
 
     qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
-    if qh.shape[-1] % num_heads:
+    if qh.shape[-1] % (2 * num_heads if output_gate else num_heads):
         raise ValueError(f"q_dim {qh.shape[-1]} not divisible by "
                          f"num_heads {num_heads}")
+    gate = None
+    if output_gate:
+        with device_scope("attn_proj"), device_scope("attn_gate"):
+            qh, gate = (x.reshape(*qh.shape[:2], -1) for x in jnp.split(
+                _split_heads(qh, num_heads), 2, axis=-1))
     if "q_norm" in params:
         with device_scope("attn_proj"):
             qh = head_rms_norm(params["q_norm"], qh, num_heads, norm_eps,
@@ -521,6 +530,10 @@ def mha_apply(params, q, k, v, *, num_heads: int,
                                      deterministic, policy)
         out = out.reshape(*out.shape[:2], -1)
     with device_scope("attn_proj"):
+        if gate is not None:
+            with device_scope("attn_gate"):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(policy.compute_dtype)
         return linear_apply(params["out"], out, policy=policy)
 
 

@@ -101,13 +101,21 @@ def rope_tables(seq_len: int, head_dim: int, theta: float
 
 def rope_apply(x, cos, sin, num_heads: int):
     """Rotate (B, L, H·D) heads-side-by-side channels by the tables
-    (L, D): ``x * cos + rotate_half(x) * sin`` with ``rotate_half`` of
-    a head ``[-x2, x1]``. fp32 inside, ``x``'s dtype out."""
+    (L, R): ``x * cos + rotate_half(x) * sin`` with ``rotate_half`` of
+    a head ``[-x2, x1]``. Tables narrower than a head (``R < D``, a
+    partial rotary factor) rotate its first ``R`` channels, pairs
+    ``(j, j + R/2)``, and leave the rest as they are. fp32 inside,
+    ``x``'s dtype out."""
     import jax.numpy as jnp
 
     b, l, e = x.shape
     xh = x.reshape(b, l, num_heads, e // num_heads).astype(jnp.float32)
-    x1, x2 = jnp.split(xh, 2, axis=-1)
+    turned, rest = xh, None
+    if cos.shape[-1] < xh.shape[-1]:
+        turned, rest = jnp.split(xh, [cos.shape[-1]], axis=-1)
+    x1, x2 = jnp.split(turned, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
-    out = xh * cos[None, :l, None, :] + rotated * sin[None, :l, None, :]
+    out = turned * cos[None, :l, None, :] + rotated * sin[None, :l, None, :]
+    if rest is not None:
+        out = jnp.concatenate([out, rest], axis=-1)
     return out.reshape(b, l, e).astype(x.dtype)

@@ -20,8 +20,11 @@ The router, the experts and the shared expert are the configuration's::
 * ``f``: ``relu(a W_up)^2 W_down``, two matrices (a tree with ``up``
   and ``down``), or gated, ``(silu(a W_gate) * (a W_up)) W_down``, three
   (a tree with ``gate`` too).
-* a shared expert (a tree with ``shared``: relu-squared, every token)
-  or none.
+* a shared expert (a tree with ``shared``, every token) or none:
+  relu-squared (``nemotron_h``), or gated with three matrices (a
+  ``shared`` with ``gate`` too) and multiplied by a sigmoid of a
+  one-column projection of the token (a tree with ``shared_gate``:
+  ``qwen3_next``'s ``sigmoid(a w_sg) * f_shared(a)``).
 
 **The layer is told which experts it holds**: ``first_expert`` (an
 int, or a scalar of the step) and the number of experts in its
@@ -83,9 +86,10 @@ import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.initializers import torch_linear_uniform
-from perceiver_tpu.ops.linear import linear_init
+from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.mlp import (
     gated_mlp_apply,
+    gated_mlp_init,
     relu2_mlp_apply,
     relu2_mlp_init,
 )
@@ -103,10 +107,12 @@ from perceiver_tpu.ops.tiling import round_up
 moe_paths = Tally()
 #: what a layer's router and experts are: ``softmax top 8 renormalised``,
 #: ``gated silu x3 products`` or ``relu2 x2 products``, ``no shared
-#: expert`` or ``shared expert``
+#: expert``, ``shared expert`` (relu-squared) or ``gated shared expert
+#: under a sigmoid gate``
 moe_kinds = Tally()
 
 SCORINGS = ("sigmoid", "softmax")
+SHARED_KINDS = ("relu2", "gated")
 
 # rows of the sorted buffer a tile of the grouped kernel takes
 _TILE_ROWS = 512
@@ -114,11 +120,15 @@ _TILE_ROWS = 512
 
 def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
              expert_hidden: int, shared_hidden: int, gated: bool = False,
-             dtype=jnp.float32):
+             shared_kind: str = "relu2", dtype=jnp.float32):
     """The router over all ``num_experts``, the ``held_experts`` this
     layer holds (stacked on a leading axis; with a ``gate`` matrix each
     where ``gated``) and the shared expert (none at a ``shared_hidden``
-    of 0)."""
+    of 0) of ``shared_kind``, one of ``SHARED_KINDS``: relu-squared, or
+    gated with its one-column ``shared_gate``."""
+    if shared_kind not in SHARED_KINDS:
+        raise ValueError(f"shared expert {shared_kind!r} not in "
+                         f"{SHARED_KINDS}")
     kr, ku, kd, ks = jax.random.split(key, 4)
     params = {
         "router": linear_init(kr, dim, num_experts, dtype, bias=False),
@@ -134,7 +144,11 @@ def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
         params["experts"]["gate"] = {"w": torch_linear_uniform(
             jax.random.fold_in(ku, 1), (held_experts, dim, expert_hidden),
             dim, dtype)}
-    if shared_hidden:
+    if shared_hidden and shared_kind == "gated":
+        params["shared"] = gated_mlp_init(ks, dim, shared_hidden, dtype)
+        params["shared_gate"] = linear_init(
+            jax.random.fold_in(ks, 1), dim, 1, dtype, bias=False)
+    elif shared_hidden:
         params["shared"] = relu2_mlp_init(ks, dim, shared_hidden, dtype)
     return params
 
@@ -440,7 +454,7 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
               policy: Policy = DEFAULT_POLICY):
     """a (B, S, C) -> ``(out (B, S, C), load)``; ``load`` (held,)
     int32, the assignments each held expert computed. The experts'
-    kind and the shared expert are the parameter tree's."""
+    kind and the shared expert's are the parameter tree's."""
     shape, dim = a.shape, a.shape[-1]
     a = a.reshape(-1, dim)
     tokens = a.shape[0]
@@ -451,8 +465,9 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
                   + (" renormalised" if renormalize else ""))
     moe_kinds.add("gated silu x3 products" if "gate" in params["experts"]
                   else "relu2 x2 products")
-    moe_kinds.add("shared expert" if "shared" in params
-                  else "no shared expert")
+    moe_kinds.add("no shared expert" if "shared" not in params else
+                  "gated shared expert under a sigmoid gate"
+                  if "shared_gate" in params else "shared expert")
     chosen, weights = route(params["router"], a, top_k=top_k,
                             scaling=scaling, scoring=scoring,
                             renormalize=renormalize)
@@ -471,6 +486,11 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
     out = jax.lax.cond(plan.load.sum() <= usual, routed(usual),
                        routed(tokens * top_k),
                        params["experts"], a, weights, plan)
-    if "shared" in params:
+    if "shared_gate" in params:
+        shared = gated_mlp_apply(params["shared"], a, policy)
+        gate = jax.nn.sigmoid(linear_apply(
+            params["shared_gate"], a, policy=policy).astype(jnp.float32))
+        out = out + (shared.astype(jnp.float32) * gate).astype(out.dtype)
+    elif "shared" in params:
         out = out + relu2_mlp_apply(params["shared"], a, policy)
     return out.reshape(shape), plan.load

@@ -76,14 +76,20 @@ def layer_norm_apply(params, x, eps: float = 1e-5,
 
 
 # --- RMSNorm -----------------------------------------------------------------
-# x / sqrt(mean(x^2) + eps) * scale: no mean subtracted, no bias.
+# x / sqrt(mean(x^2) + eps) * scale: no mean subtracted, no bias. In the
+# zero-centred form (``qwen3_next``) the parameter is what the
+# multiplier departs from 1 by, x / sqrt(mean(x^2) + eps) * (1 + w),
+# initialised 0 and pulled to 0 by weight decay: a tree that holds
+# ``bias`` (that departure) and no ``scale``.
 # Statistics in fp32 as above. No custom VJP: its callers recompute a
 # whole layer on the backward pass (``remat``), so autodiff's fp32
 # residuals live for one layer at a time; a trace has not asked for
 # more.
 
 
-def rms_norm_init(dim: int, dtype=jnp.float32):
+def rms_norm_init(dim: int, dtype=jnp.float32, zero_centered: bool = False):
+    if zero_centered:
+        return {"bias": jnp.zeros((dim,), dtype)}
     return {"scale": jnp.ones((dim,), dtype)}
 
 
@@ -92,5 +98,6 @@ def rms_norm_apply(params, x, eps: float = 1e-6,
     xf = x.astype(jnp.float32)
     rstd = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                          + eps)
-    y = xf * rstd * params["scale"].astype(jnp.float32)
-    return y.astype(policy.compute_dtype)
+    scale = params["scale"].astype(jnp.float32) if "scale" in params \
+        else 1.0 + params["bias"].astype(jnp.float32)
+    return (xf * rstd * scale).astype(policy.compute_dtype)

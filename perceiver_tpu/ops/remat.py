@@ -81,7 +81,12 @@ REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 #: fused scan's hand-written backward takes the scan's operands alone,
 #: and the einsum form is a checkpoint of its own, so with the output
 #: held the recomputed layer runs neither a second time), ``ssm_in`` the
-#: in-projection's product. The shared expert's hidden layer is an
+#: in-projection's product; ``delta_out`` and ``delta_in`` are the same
+#: two of a gated delta-rule mixer (``ops/delta_rule.py``: the chunked
+#: rule's output, (B, S, value heads x their width) in the compute
+#: dtype, with which the recomputed layer does not run the rule again,
+#: and the q/k/v/z in-projection's product before it is sliced). The
+#: shared expert's hidden layer is an
 #: ``mlp_hidden``. The routed experts' hidden layer has no name: it
 #: lies inside a ``lax.cond`` over the sorted buffer's size
 #: (``ops/moe.py``), and a value that crosses one is held at the size
@@ -92,7 +97,8 @@ REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 #: integers, 16 bytes an assignment): with it held the recomputed layer
 #: runs no ``top_k`` and no sort, only the router's product and score,
 #: which the weights' gradient needs.
-HYBRID_REMAT_NAMES = REMAT_NAMES + ("ssm_out", "ssm_in", "moe_plan")
+HYBRID_REMAT_NAMES = REMAT_NAMES + ("ssm_out", "ssm_in", "delta_out",
+                                    "delta_in", "moe_plan")
 
 #: The share of what the device has left that the kept values and the
 #: layers' inputs may take together. From chip runs (PERF.md, Findings,

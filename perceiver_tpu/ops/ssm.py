@@ -126,13 +126,14 @@ def ssm_mixer_init(key, dim: int, *, num_heads: int, head_dim: int,
 
 
 def causal_conv(params, x):
-    """Depthwise convolution over the last ``K`` positions, with bias:
-    ``out[t] = sum_k w[k] x[t - (K - 1) + k] + b``; ``x`` (B, S, C),
-    ``w`` (K, C). float32 sums, ``x``'s dtype out."""
+    """Depthwise convolution over the last ``K`` positions, with bias
+    where the tree has one: ``out[t] = sum_k w[k] x[t - (K - 1) + k] +
+    b``; ``x`` (B, S, C), ``w`` (K, C). float32 sums, ``x``'s dtype
+    out."""
     w = params["w"].astype(jnp.float32)
     taps, seq = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
-    out = params["bias"].astype(jnp.float32)
+    out = params["bias"].astype(jnp.float32) if "bias" in params else 0.0
     for k in range(taps):
         out = out + w[k] * padded[:, k:k + seq]
     return out.astype(x.dtype)

@@ -5,6 +5,13 @@ labels and the fused head reading of ``tasks/causal_lm.py``.
 
 The fields are the published ``config.json``'s under its own names
 (``nemotron_h``); the defaults are NVIDIA-Nemotron-3-Nano-30B-A3B's.
+A stack of another family's layers is the same task with its own
+pattern and its own keys: ``qwen3_next`` (Qwen3-Next) is ``LELELE*E`` a
+published period of four, a gated delta-rule mixer ``L`` (``linear_*``)
+three layers of four, a softmax attention with an output gate, partial
+rotary positions and q/k norms the fourth, zero-centred norms, a softmax
+router with the top-k renormalised, gated experts and a gated shared
+expert under a sigmoid gate (``scripts/configs/gated_delta_lm_1chip.yaml``).
 ``held_experts`` and ``first_expert`` say which of the
 ``n_routed_experts`` this chip holds (None: all): the router keeps its
 width and its experts a token, and what the absent experts would have
@@ -20,7 +27,11 @@ the parameter tree, and there is no auxiliary loss. Every step's
 metrics carry ``moe_assignments`` (the (token, held expert) pairs the
 expert layers computed) and ``moe_load_max_over_mean`` (the fullest
 held expert's load over the mean of the held, the largest over the
-expert layers: the imbalance the no-drop rule is there for).
+expert layers: the imbalance the no-drop rule is there for); under a
+softmax router, whose untrained loads are far from even, also
+``moe_full_buffer_layers`` (the expert layers of the step whose
+assignments did not fit the usual buffer, ``ops.moe.usual_rows``, and
+took the ``T x top_k`` one).
 """
 
 from __future__ import annotations
@@ -32,6 +43,7 @@ import jax.numpy as jnp
 
 from perceiver_tpu.models.hybrid_lm import HybridLM
 from perceiver_tpu.ops.fused_ce import fused_linear_nll
+from perceiver_tpu.ops.moe import usual_rows
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.tasks.causal_lm import next_token_targets
 
@@ -48,15 +60,35 @@ class HybridLMTask:
     ssm_state_size: int = 128
     conv_kernel: int = 4
     chunk_size: int = 128
+    # L, a gated delta-rule mixer (qwen3_next's keys; 0: no such layer)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    delta_chunk_size: int = 64
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
     head_dim: int = 128
+    # rotary positions at this base over the first partial_rotary_factor
+    # of a head's channels; None: no position embedding
+    rope_theta: Optional[float] = None
+    partial_rotary_factor: float = 1.0
+    qk_norm: bool = False
+    attn_output_gate: bool = False
     n_routed_experts: int = 128
     num_experts_per_tok: int = 6
     moe_intermediate_size: int = 1856
     moe_shared_expert_intermediate_size: int = 3712
     routed_scaling_factor: float = 2.5
+    router_scoring: str = "sigmoid"
+    norm_topk_prob: bool = True
+    gated_experts: bool = False
+    # relu2, or gated (three matrices under a sigmoid gate of a column)
+    shared_expert_kind: str = "relu2"
     norm_eps: float = 1e-5
+    # every RMSNorm as x / rms(x) * (1 + w)
+    zero_centered_norms: bool = False
     max_seq_len: int = 4096
     # the experts this chip holds, from first_expert on; None: all
     held_experts: Optional[int] = None
@@ -99,4 +131,10 @@ class HybridLMTask:
             metrics["moe_assignments"] = loads.sum()
             metrics["moe_load_max_over_mean"] = (
                 loads.max(-1) / jnp.maximum(loads.mean(-1), 1.0)).max()
+            if self.router_scoring == "softmax":
+                usual = usual_rows(mask.size, self.num_experts_per_tok,
+                                   model.num_held_experts,
+                                   self.n_routed_experts)
+                metrics["moe_full_buffer_layers"] = (
+                    loads.sum(-1) > usual).sum().astype(jnp.float32)
         return loss, metrics

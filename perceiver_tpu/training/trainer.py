@@ -472,6 +472,7 @@ class Trainer:
             format_attention_paths,
             masked_attention_tiles,
         )
+        from perceiver_tpu.ops.delta_rule import rule_paths
         from perceiver_tpu.ops.moe import moe_kinds, moe_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         from perceiver_tpu.ops.ssm import scan_paths
@@ -480,7 +481,8 @@ class Trainer:
                 masked_attention_tiles() as tiles, \
                 remat_keeps() as keeps, scan_paths.counting() as scans, \
                 moe_paths.counting() as experts, \
-                moe_kinds.counting() as kinds:
+                moe_kinds.counting() as kinds, \
+                rule_paths.counting() as rules:
             try:
                 if self._exec_cache is None:
                     step_fn.lower(state, sharded)
@@ -499,6 +501,8 @@ class Trainer:
                  f"remat keeps: {format_remat_keeps(keeps)}"]
         if scans:    # a stack with state-space layers (ops/ssm.py)
             lines.append(f"selective scans: {format_tally(scans)}")
+        if rules:    # a stack with linear-attention layers (ops/delta_rule.py)
+            lines.append(f"delta rules: {format_tally(rules)}")
         if experts:  # a stack with expert layers (ops/moe.py)
             lines.append(f"expert layers: {format_tally(experts)}")
             lines.append(f"expert kinds: {format_tally(kinds)}")

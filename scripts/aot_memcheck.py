@@ -22,12 +22,18 @@ Compiles (compile ONLY — no execution) the full train step of:
    6 layers, 16 of 128 experts held, 2 rows of 4096 data tokens, twice
    that through the stack),
 
+7. (``qwen3next``) the linear-attention mixture-of-experts LM of the
+   benchmark's ``qwen3next_train``
+   (``benchmarks/configs/qwen3_next_80b_a3b.json``: 4 layers, three
+   gated delta-rule mixers and one gated attention, 32 of 512 experts
+   held, 4 rows of 4096),
+
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm``, ``224``, ``ouro``, ``nemotron`` and ``sdar`` run ``remat: true``: beside
+``lm``, ``224``, ``ouro``, ``nemotron``, ``sdar`` and ``qwen3next`` run ``remat: true``: beside
 XLA's sizes they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
@@ -35,10 +41,11 @@ that read the backend are made as the described chip would make them
 use on it, the parameters and optimizer state the step is handed).
 
 Usage: python scripts/aot_memcheck.py
-           [224 | lm | seg | ouro | nemotron | sdar | all] [rows]
+           [224 | lm | seg | ouro | nemotron | sdar | qwen3next | all] [rows]
        (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` /
-       ``nemotron`` / ``sdar`` in place of the preset's; ``all`` leaves
-       ``ouro``, ``nemotron`` and ``sdar`` out)
+       ``nemotron`` / ``sdar`` / ``qwen3next`` in place of the preset's;
+       ``all`` leaves ``ouro``, ``nemotron``, ``sdar`` and ``qwen3next``
+       out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -247,21 +254,24 @@ def check_ouro(per_chip_batch: int = 2):
     return _compile_train_step(CausalLMTask(**model), batch, "ouro")
 
 
-def check_nemotron(per_chip_batch: int = 4):
+def check_nemotron(per_chip_batch: int = 4,
+                   config: str = "nemotron3_nano_30b",
+                   label: str = "nemotron"):
     """The benchmark's ``nemotron3_nano_30b`` as ``nemotron_train`` runs
-    it: the ``model`` group of its configuration file, full rows, each
-    expert layer's share named by the batch."""
+    it (or ``qwen3_next_80b_a3b`` as ``qwen3next_train`` does: the same
+    task, another pattern): the ``model`` group of its configuration
+    file, full rows, each expert layer's share named by the batch."""
     import jax.numpy as jnp
 
     from perceiver_tpu.tasks import HybridLMTask
 
-    model = _benchmark_model("nemotron3_nano_30b")
+    model = _benchmark_model(config)
     batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
                                     jnp.int32),
              "first_experts": jnp.zeros(
                  (per_chip_batch, model["hybrid_override_pattern"].count("E")),
                  jnp.int32)}
-    return _compile_train_step(HybridLMTask(**model), batch, "nemotron")
+    return _compile_train_step(HybridLMTask(**model), batch, label)
 
 
 def check_sdar(per_chip_batch: int = 2):
@@ -303,6 +313,9 @@ def main():
         out["nemotron3_nano_30b_9_layers"] = check_nemotron(**rows)
     if which == "sdar":
         out["sdar_30b_a3b_6_layers"] = check_sdar(**rows)
+    if which == "qwen3next":
+        out["qwen3_next_80b_a3b_4_layers"] = check_nemotron(
+            config="qwen3_next_80b_a3b", label="qwen3next", **rows)
     print(json.dumps(out, indent=2))
 
 

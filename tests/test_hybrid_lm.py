@@ -563,7 +563,8 @@ def test_the_names_list_is_the_stacks_own():
     assert remat.pick_remat_keeps(
         {"attn_out": 1, "ssm_out": 5, "ssm_in": 50}, layer_in_bytes=4,
         memory_limit=100, memory_held=50, names=HYBRID)[0] == HYBRID[:4]
-    assert HYBRID[3:] == ("ssm_out", "ssm_in", "moe_plan")
+    assert HYBRID[3:] == ("ssm_out", "ssm_in", "delta_out", "delta_in",
+                          "moe_plan")
     # a stack that asks for the three never hears of the others
     assert remat.pick_remat_keeps({}, layer_in_bytes=1, memory_limit=None
                                   )[0] == remat.REMAT_NAMES
@@ -645,7 +646,8 @@ def test_the_trainer_says_the_forms_and_logs_the_counters(toy, tmp_path,
     err = capfd.readouterr().err
     assert ("[step_load] attention call sites: materialized[backend]=1\n"
             "[step_load] remat keeps: attn_out,qkv,mlp_hidden,ssm_out,"
-            "ssm_in,moe_plan + layer_in 0.00 GB of no memory report\n"
+            "ssm_in,delta_out,delta_in,moe_plan + layer_in 0.00 GB of no "
+            "memory report\n"
             "[step_load] selective scans: chunked[16x3+pad,backend]=2\n"
             "[step_load] expert layers: held 4/16=2 ragged_dot[cpu]x240=2 "
             "ragged_dot[cpu]x80=2\n"

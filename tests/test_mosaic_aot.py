@@ -137,7 +137,8 @@ def test_block_diffusion_forward_backward_compiles(one_chip, rows, half,
     (2, 4096, 16, 128),     # ouro_train: 1024 x 1024 blocks, 10 of 16 run
     (2, 1000, 8, 64),       # a padded last block, two heads a lane block
     (1, 2048, 4, 128),      # streamed where the full kernels take one block
-], ids=["ouro_train", "ragged_d64", "two_blocks"])
+    (4, 4096, 16, 256),     # qwen3next_train: heads of 256, two lane blocks
+], ids=["ouro_train", "ragged_d64", "two_blocks", "qwen3next_train"])
 def test_causal_forward_backward_compiles(one_chip, rows, seq, heads, dim):
     """The causal mode (blocks above the diagonal skipped by clamped
     index maps and ``pl.when``, the crossed ones masked from iotas) on
