@@ -40,6 +40,17 @@ a sum of ``g <= 0`` over a span, masked **before** the ``exp`` where the
 span would run backwards: no ``exp`` of a positive number, and a decay
 that underflows is a quiet 0.
 
+**Two forms, one arithmetic; the call site's shapes say which runs**
+(``fits``): where the operands lie on one device, the heads are whole
+lanes (``Dk`` and ``Dv`` multiples of 128), the chunk is 16, 32, 64 or
+128 and q, k, v share a compute dtype the kernels take, the rule is the
+Pallas kernels of ``ops/pallas_delta_rule.py`` (``kernel[64x64]`` in
+``rule_paths``: a chunk's ``Q x Q`` matrices, its inverse and the
+carried state stay in VMEM, forward and backward; interpreted off a
+TPU); everywhere else it is the einsums below (``chunked[...]``), which
+are the kernels' oracle in the tests. A row is padded to whole chunks
+either way.
+
 **Precision.** ``g``, ``beta``, every decay, ``A``, the powers of ``A``
 and ``T`` are float32, the inverse's products at ``Precision.HIGHEST``
 (six bf16 passes on the MXU), and the carried state is float32. The
@@ -48,21 +59,30 @@ in float32: ``k k^T``, ``q k^T``, ``T (beta v)`` and ``T (beta k
 exp(G))`` (``T`` rounded to the compute dtype as an operand), ``W S``,
 ``(q exp(G)) S`` (the state rounded as an operand), the masked scores
 times ``v'`` and ``k^T v'``. Under a float32 policy everything is
-float32 at ``HIGHEST``.
+float32 at ``HIGHEST``. The kernels keep this or finer, nowhere
+coarser: they make the same ``T`` by blocks of 16 (float32 at
+``HIGHEST``; equal to float32 rounding), hand ``U`` on in float32 where
+the einsums round it to the compute dtype, and keep a cotangent float32
+until a product takes it as an operand.
 
-The backward is autodiff's of these products, recomputed: the rule is a
-``jax.checkpoint`` of its own a pass of heads (``pick_rule`` says how
-many value heads go through together: a chunk's ``Q x Q`` float32
-matrices for every head and chunk of a 4 x 4,096 row at 32 heads are
-134 MB each, and autodiff keeps a dozen), so its residuals live while
-its own backward runs and no longer; a ``remat`` layer that holds the
-rule's output (``dear``, ``delta_out``) does not run it again for the
-layer's sake. One scan over the chunks for all the heads, with the
-chunks' own work alone in passes, was tried and was slower on the chip
-(737 ms a step for 665, 7.5 GB reserved for 5.9: the chunks' work then
-runs three times, and the time is in the inverse's ten float32
-products, 135 ms of the rule's 253 a step, not in the scan's steps;
-PERF.md, PR 42).
+The einsum form's backward is autodiff's of these products, recomputed:
+the rule is a ``jax.checkpoint`` of its own a pass of heads
+(``pick_rule`` says how many value heads go through together: a chunk's
+``Q x Q`` float32 matrices for every head and chunk of a 4 x 4,096 row
+at 32 heads are 134 MB each, and autodiff keeps a dozen), so its
+residuals live while its own backward runs and no longer. The kernels'
+backward is written by hand and takes the rule's operands alone (a
+pass rebuilds the state each chunk found, the reversed pass carries its
+cotangent; the inverse's cotangent is ``T^T dT T^T``). Either way a
+``remat`` layer that holds the rule's output (``dear``, ``delta_out``)
+does not run it again for the layer's sake. One scan over the chunks
+for all the heads, with the chunks' own work alone in passes, was tried
+and was slower on the chip (737 ms a step for 665, 7.5 GB reserved for
+5.9: the chunks' work then runs three times, and the time is in the
+inverse's ten float32 products, 135 ms of the rule's 253 a step, not in
+the scan's steps; PERF.md, PR 42): no rearrangement of XLA operations
+keeps a chunk's matrices on the chip, hence the kernels (PERF.md,
+PR 43).
 """
 
 from __future__ import annotations
@@ -73,6 +93,7 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
+from perceiver_tpu.ops.attention import mesh_devices
 from perceiver_tpu.ops.initializers import uniform
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
@@ -80,10 +101,11 @@ from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.ssm import causal_conv
 from perceiver_tpu.ops.tally import Tally
 
-#: which form the rule took at each call site:
-#: ``chunked[64x64,8 heads a pass]`` (64 chunks of 64 positions, the
-#: einsums, eight value heads a checkpointed pass), ``+pad`` after the
-#: chunks where the last one is padded
+#: which form the rule took at each call site: ``kernel[64x64]`` (64
+#: chunks of 64 positions, the Pallas kernels) or
+#: ``chunked[64x64,8 heads a pass]`` (the einsums, eight value heads a
+#: checkpointed pass), ``+pad`` after the chunks where the last one is
+#: padded
 rule_paths = Tally()
 
 # head-chunks (rows x chunks x value heads) a pass may hold: each has a
@@ -107,6 +129,18 @@ def pick_rule(*, rows: int, seq: int, key_heads: int, value_heads: int,
     while key_heads % groups:
         groups -= 1
     return chunk, chunks, pad, groups * per
+
+
+def fits(q, v, chunk: int) -> bool:
+    """Whether a call takes the kernels of ``ops/pallas_delta_rule.py``,
+    from what the call site can observe: operands on one device (a
+    Pallas call has no partitioning rule), heads of whole lanes, a chunk
+    the kernels' inverse merges, q and v in one compute dtype the
+    kernels take."""
+    from perceiver_tpu.ops import pallas_delta_rule
+    return (mesh_devices(q) == 1 and q.dtype == v.dtype
+            and pallas_delta_rule.fits(chunk=chunk, key_dim=q.shape[3],
+                                       value_dim=v.shape[3], dtype=v.dtype))
 
 
 def delta_mixer_init(key, dim: int, *, num_key_heads: int,
@@ -268,13 +302,19 @@ def delta_rule(q, k, v, g, beta, *, chunk_size: int = 64):
     chunk, chunks, pad, at_once = pick_rule(
         rows=rows, seq=seq, key_heads=key_heads, value_heads=heads,
         chunk_size=chunk_size)
-    rule_paths.add(f"chunked[{chunk}x{chunks}{'+pad' if pad else ''},"
+    fused = fits(q, v, chunk)
+    rule_paths.add(f"kernel[{chunk}x{chunks}{'+pad' if pad else ''}]"
+                   if fused else
+                   f"chunked[{chunk}x{chunks}{'+pad' if pad else ''},"
                    f"{at_once} heads a pass]")
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
     g, beta = (x.astype(jnp.float32) for x in (g, beta))
+    if fused:
+        from perceiver_tpu.ops.pallas_delta_rule import fused_rule
+        return fused_rule(q, k, v, g, beta, chunk=chunk)[:, :seq]
     groups = at_once // per                  # key heads a pass
     passes = key_heads // groups
 

@@ -84,8 +84,10 @@ REMAT_NAMES = ("attn_out", "qkv", "mlp_hidden")
 #: in-projection's product; ``delta_out`` and ``delta_in`` are the same
 #: two of a gated delta-rule mixer (``ops/delta_rule.py``: the chunked
 #: rule's output, (B, S, value heads x their width) in the compute
-#: dtype, with which the recomputed layer does not run the rule again,
-#: and the q/k/v/z in-projection's product before it is sliced). The
+#: dtype: the kernels' hand-written backward takes the rule's operands
+#: alone, and the einsum form is a checkpoint of its own, so with the
+#: output held the recomputed layer runs neither a second time; and the
+#: q/k/v/z in-projection's product before it is sliced). The
 #: shared expert's hidden layer is an
 #: ``mlp_hidden``. The routed experts' hidden layer has no name: it
 #: lies inside a ``lax.cond`` over the sorted buffer's size

@@ -221,6 +221,78 @@ def test_ssm_scan_kernels_lie_under_the_scans_scope(one_chip, monkeypatch):
         "jit(loss)/transpose(jvp(ssm_scan))/ssm_scan_bwd_states/pallas_call"]
 
 
+# --- the gated delta rule ----------------------------------------------------
+
+
+@pytest.mark.parametrize("rows,seq,key_heads,heads,dim,chunk,dtype", [
+    (4, 4096, 16, 32, 128, 64, jnp.bfloat16),   # qwen3next_train
+    (1, 256, 2, 2, 128, 16, jnp.bfloat16),      # one block of the inverse
+    (1, 256, 1, 2, 128, 16, jnp.bfloat16),      # two heads on 32 lanes
+    (1, 256, 1, 4, 128, 32, jnp.bfloat16),      # four heads side by side
+    (1, 256, 1, 4, 256, 128, jnp.float32),      # wide heads, float32
+], ids=["qwen3next_train", "chunks_of_16", "two_heads_of_16_lanes",
+        "four_heads_a_tile", "float32_heads_of_256"])
+def test_delta_rule_forward_backward_compiles(one_chip, rows, seq, key_heads,
+                                              heads, dim, chunk, dtype):
+    """The delta rule's three kernels (forward; the backward's states
+    pass and its reversed pass) at the cell's shapes (4 x 4,096, 16 key
+    and 32 value heads of 128, chunks of 64, bf16) and at the edges of
+    what ``fits`` lets through."""
+    from perceiver_tpu.ops.pallas_delta_rule import fused_rule
+
+    def loss(*args):
+        return fused_rule(*args, chunk=chunk,
+                          interpret=False).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    qk = s((rows, seq, key_heads, dim), dtype)
+    gb = s((rows, seq, heads), jnp.float32)
+    # the value keeps the forward kernel live beside the backward pass
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, s((rows, seq, heads, dim), dtype), gb, gb
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("delta_rule_fwd", "delta_rule_bwd_states",
+                 "delta_rule_bwd"):
+        assert name in text
+
+
+def test_delta_rule_kernels_lie_under_the_rules_scope(one_chip, monkeypatch):
+    """Picked as the trainer's step picks it (``ops.delta_rule
+    .delta_rule`` at shapes that tile), all three kernels carry the
+    scope ``delta_rule`` in their name stacks, the backward's two under
+    ``transpose(``: what ``delta_rule_roofline``,
+    ``model.delta_rule_pct`` and the pass split read."""
+    import re
+
+    import perceiver_tpu.utils.platform as platform
+    from perceiver_tpu.ops import delta_rule
+
+    monkeypatch.setattr(platform, "default_interpret", lambda: False)
+
+    def loss(*args):
+        return delta_rule.delta_rule(
+            *args, chunk_size=64).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    qk = s((1, 256, 1, 128), jnp.bfloat16)
+    gb = s((1, 256, 2), jnp.float32)
+    with delta_rule.rule_paths.counting() as forms:
+        text = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+                qk, qk, s((1, 256, 2, 128), jnp.bfloat16), gb, gb
+            ).compile().as_text()
+    assert dict(forms) == {"kernel[64x4]": 1}
+    stacks = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "custom-call(" in line]
+    assert sorted(stacks) == [
+        "jit(loss)/jvp(delta_rule)/delta_rule_fwd/pallas_call",
+        "jit(loss)/transpose(jvp(delta_rule))/delta_rule_bwd/pallas_call",
+        "jit(loss)/transpose(jvp(delta_rule))/delta_rule_bwd_states/"
+        "pallas_call"]
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 
