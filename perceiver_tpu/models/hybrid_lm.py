@@ -1,5 +1,5 @@
 """A hybrid state-space / mixture-of-experts language model: a decoder
-stack whose layers are of four kinds in a published order (the
+stack whose layers are of seven kinds in a published order (the
 ``nemotron_h`` family, NVIDIA Nemotron-H, arXiv:2504.03624, and
 Nemotron 3 Nano; ``hybrid_override_pattern``)::
 
@@ -8,12 +8,13 @@ Nemotron 3 Nano; ``hybrid_override_pattern``)::
     logits = rms_f(h) Wh                                  (head untied)
 
 ``M`` is a Mamba-2 mixer (``ops/ssm.py``), ``E`` an expert layer
-(``ops/moe.py``), ``L`` a gated delta-rule mixer (below), ``*`` causal
-attention with grouped queries and, in
+(``ops/moe.py``), ``L`` a gated delta-rule mixer, ``K`` a Kimi Delta
+Attention mixer, ``A`` latent attention, ``D`` a dense gated MLP (all
+below), ``*`` causal attention with grouped queries and, in
 ``nemotron_h``, **no rotary embedding** (the Mamba layers carry
 position). A layer is one RMSNorm with a scale, one mixer and a
-residual: there is no separate MLP after ``M`` or ``*``. No linear
-layer has a bias.
+residual: there is no separate MLP after ``M`` or ``*`` unless the
+pattern names one (``D``). No linear layer has a bias.
 
 A Qwen3-MoE decoder layer (``h += attn(rms(h)); h += moe(rms(h))``, the
 layer of SDAR) is two of these, ``*E``: its attention has rotary
@@ -38,6 +39,24 @@ final one, the q/k norms) is zero-centred, ``x / rms(x) * (1 + w)``
 (``zero_centered_norms``); its ``E`` adds a shared expert that is gated
 with three matrices under a sigmoid gate of one column
 (``shared_expert_kind``).
+
+A ``kimi_linear`` decoder layer (Kimi Linear, arXiv:2510.26692) is two
+as well, ``KD``, ``KE`` or ``AE`` (its published layers 1 to 5 are
+``KDKEKEAEKE``): ``K`` is Kimi Delta Attention (``ops/delta_rule.py``'s
+``kda_mixer_*``, ``kda_*``: the delta rule with a decay that is a vector
+a key channel, from a low-rank projection, and a sigmoid-gated norm);
+``A`` is latent attention (MLA, ``mla_*`` below: keys and values from
+one normed latent of ``kv_lora_rank`` channels, ``qk_rope_head_dim``
+key channels shared by all heads, score heads of
+``qk_nope_head_dim + qk_rope_head_dim`` beside value heads of
+``v_head_dim``, and **no rotary embedding on any channel**:
+``mla_use_nope``, the KDA layers carry position; training runs it
+expanded to multi-head attention); ``D`` is the dense gated MLP of the
+leading layers (``first_k_dense_replace``; ``ops/mlp.gated_mlp_*`` at
+``intermediate_size``, scope ``mlp``); its ``E`` has a sigmoid router
+whose renormalised top-k is scaled, gated experts and a shared expert
+gated with three matrices and no gate column (``shared_expert_kind``
+``glu``).
 
 The attention layer runs on the cores every causal call takes
 (``ops.attention.mha_apply``): its ``num_kv_heads`` key/value heads are
@@ -81,17 +100,24 @@ from perceiver_tpu.ops.attention import (
     mha_apply,
     untallied,
 )
-from perceiver_tpu.ops.delta_rule import delta_mixer_apply, delta_mixer_init
+from perceiver_tpu.ops.delta_rule import (
+    delta_mixer_apply,
+    delta_mixer_init,
+    kda_mixer_apply,
+    kda_mixer_init,
+)
 from perceiver_tpu.ops.fourier import rope_apply, rope_tables
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.linear import linear_apply, linear_init
+from perceiver_tpu.ops.mlp import gated_mlp_apply, gated_mlp_init
 from perceiver_tpu.ops.moe import moe_apply, moe_init
 from perceiver_tpu.ops.norm import rms_norm_apply, rms_norm_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.ssm import ssm_mixer_apply, ssm_mixer_init
 
 _INIT_STD = 0.02
-LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn", "L": "delta"}
+LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn", "L": "delta",
+               "K": "kda", "A": "mla", "D": "mlp"}
 
 
 def gqa_init(key, dim: int, num_heads: int, num_kv_heads: int,
@@ -161,11 +187,61 @@ def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
                      output_gate=output_gate)
 
 
+def mla_init(key, dim: int, num_heads: int, *, kv_lora_rank: int,
+             qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int):
+    """Latent attention without a query latent (``q_lora_rank`` null):
+    ``q`` a head's ``nope + rope`` channels, ``kv_a`` the latent beside
+    the shared key channels, ``kv_norm`` the latent's RMSNorm, ``kv_b``
+    a head's ``nope`` key channels beside its ``v_head_dim`` value
+    channels, ``out`` from the value heads."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "q": linear_init(kq, dim, num_heads * (
+            qk_nope_head_dim + qk_rope_head_dim), bias=False),
+        "kv_a": linear_init(ka, dim, kv_lora_rank + qk_rope_head_dim,
+                            bias=False),
+        "kv_norm": rms_norm_init(kv_lora_rank),
+        "kv_b": linear_init(kb, kv_lora_rank, num_heads * (
+            qk_nope_head_dim + v_head_dim), bias=False),
+        "out": linear_init(ko, num_heads * v_head_dim, dim, bias=False),
+    }
+
+
+@device_scope("mla_mixer")
+def mla_apply(params, a, *, num_heads: int, kv_lora_rank: int,
+              qk_nope_head_dim: int, norm_eps: float = 1e-6,
+              policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None):
+    """Causal latent attention, expanded: ``[c | k_s] = a W_kva``,
+    ``[k_n | v] = rms(c) W_kvb`` a head, ``k_h = [k_n,h | k_s]`` (the
+    shared channels the same for every head), ``q = a W_q`` a head's
+    channels in the same order; no position embedding on any channel.
+    The core is ``mha_apply``'s, whose score heads and value heads may
+    differ in width; the scale is ``1 / sqrt(nope + rope)``."""
+    rows, seq, _ = a.shape
+    with device_scope("attn_proj"):
+        latent, shared = jnp.split(
+            linear_apply(params["kv_a"], a, policy=policy), [kv_lora_rank],
+            axis=-1)
+        kv = linear_apply(
+            params["kv_b"],
+            rms_norm_apply(params["kv_norm"], latent, norm_eps, policy),
+            policy=policy).reshape(rows, seq, num_heads, -1)
+        k = jnp.concatenate([
+            kv[..., :qk_nope_head_dim],
+            jnp.broadcast_to(shared[:, :, None, :],
+                             (rows, seq, num_heads, shared.shape[-1]))], -1)
+        v = kv[..., qk_nope_head_dim:]
+    return mha_apply(params, a, None, None, num_heads=num_heads,
+                     kv_heads=(k.reshape(rows, seq, -1),
+                               v.reshape(rows, seq, -1)),
+                     causal=True, policy=policy, impl=impl)
+
+
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class HybridLM:
     vocab_size: int
     hidden_size: int
-    pattern: str                     # one of M, E, *, L a layer
+    pattern: str                     # one of LAYER_KINDS a layer
     max_seq_len: int
     # M (a pattern without M needs none of them)
     mamba_num_heads: int = 0
@@ -182,6 +258,19 @@ class HybridLM:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     delta_chunk_size: int = 64
+    # K (a pattern without K needs none of them): heads of one width
+    # for q, k and v alike; the chunk is delta_chunk_size
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel_size: int = 4
+    # A (a pattern without A needs none of them): num_attention_heads
+    # heads, score heads of nope + rope beside value heads of v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # D: the dense gated MLP's width (0: no such layer)
+    intermediate_size: int = 0
     # *
     num_attention_heads: int
     num_key_value_heads: int
@@ -202,8 +291,8 @@ class HybridLM:
     moe_intermediate_size: int
     # 0: no shared expert
     moe_shared_expert_intermediate_size: int = 0
-    # relu2, or gated: three matrices under a sigmoid gate of one
-    # column (ops/moe.SHARED_KINDS)
+    # relu2; gated: three matrices under a sigmoid gate of one column;
+    # glu: the three matrices alone (ops/moe.SHARED_KINDS)
     shared_expert_kind: str = "relu2"
     routed_scaling_factor: float = 1.0
     router_scoring: str = "sigmoid"  # or softmax (ops/moe.SCORINGS)
@@ -225,7 +314,8 @@ class HybridLM:
             raise ValueError(
                 f"pattern {self.pattern!r}: one of {sorted(LAYER_KINDS)} a "
                 "layer (M Mamba-2, E experts, * attention, L gated "
-                "delta rule)")
+                "delta rule, K Kimi Delta Attention, A latent attention, "
+                "D dense gated MLP)")
         if self.num_attention_heads % self.num_key_value_heads \
                 or self.mamba_num_heads % self.n_groups:
             raise ValueError("query heads divide over the key/value heads, "
@@ -240,6 +330,17 @@ class HybridLM:
                 "a pattern with L needs linear_num_key_heads, "
                 "linear_key_head_dim, linear_value_head_dim and "
                 "linear_num_value_heads, a multiple of the key heads")
+        if "K" in self.pattern and not (
+                self.kda_num_heads and self.kda_head_dim):
+            raise ValueError("a pattern with K needs kda_num_heads and "
+                             "kda_head_dim")
+        if "A" in self.pattern and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.v_head_dim):
+            raise ValueError("a pattern with A needs kv_lora_rank, "
+                             "qk_nope_head_dim and v_head_dim")
+        if "D" in self.pattern and not self.intermediate_size:
+            raise ValueError("a pattern with D needs intermediate_size")
         if "M" in self.pattern and not (
                 self.mamba_num_heads and self.mamba_head_dim
                 and self.ssm_state_size):
@@ -277,6 +378,20 @@ class HybridLM:
                 key_head_dim=self.linear_key_head_dim,
                 value_head_dim=self.linear_value_head_dim,
                 conv_kernel=self.linear_conv_kernel_dim)
+        if kind == "K":
+            return kda_mixer_init(
+                key, c, num_heads=self.kda_num_heads,
+                head_dim=self.kda_head_dim,
+                conv_kernel=self.kda_conv_kernel_size)
+        if kind == "A":
+            return mla_init(
+                key, c, self.num_attention_heads,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim)
+        if kind == "D":
+            return gated_mlp_init(key, c, self.intermediate_size)
         if kind == "E":
             return moe_init(
                 key, c, num_experts=self.n_routed_experts,
@@ -336,6 +451,20 @@ class HybridLM:
                     value_head_dim=self.linear_value_head_dim,
                     chunk_size=self.delta_chunk_size, eps=self.norm_eps,
                     policy=policy)
+            elif kind == "K":
+                out = kda_mixer_apply(
+                    p["mixer"], a, num_heads=self.kda_num_heads,
+                    head_dim=self.kda_head_dim,
+                    chunk_size=self.delta_chunk_size, eps=self.norm_eps,
+                    policy=policy)
+            elif kind == "A":
+                out = mla_apply(
+                    p["mixer"], a, num_heads=self.num_attention_heads,
+                    kv_lora_rank=self.kv_lora_rank,
+                    qk_nope_head_dim=self.qk_nope_head_dim,
+                    norm_eps=self.norm_eps, policy=policy)
+            elif kind == "D":
+                out = gated_mlp_apply(p["mixer"], a, policy)
             elif kind == "E":
                 out, load = moe_apply(
                     p["mixer"], a, top_k=self.num_experts_per_tok,

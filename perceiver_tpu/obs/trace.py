@@ -193,6 +193,13 @@ DEVICE_SCOPES = (
     # output gate of a gated softmax attention (the query's other half,
     # its sigmoid and the product: under attn_proj)
     "delta_mixer", "delta_rule", "attn_gate",
+    # a Kimi Delta Attention mixer (ops/delta_rule: the delta rule with
+    # a decay that is a vector a key channel): the whole mixer and,
+    # inside it, the rule alone (the decays, the solve inside a chunk,
+    # the chunk products, the carried state); a latent-attention (MLA)
+    # layer (models/hybrid_lm), whole, with attn_proj and attn_core
+    # inside it
+    "kda_mixer", "kda_rule", "mla_mixer",
     # a block-diffusion step's own noising of its rows
     # (tasks/block_diffusion_lm): the masking rates, the masks, the
     # noised copy and the loss weights

@@ -64,6 +64,7 @@ from perceiver_tpu.ops.norm import (
 from perceiver_tpu.ops.policy import Policy, DEFAULT_POLICY
 from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.tally import Tally, untallied  # noqa: F401
+from perceiver_tpu.ops.tiling import round_up
 
 NEG_INF = -1e30  # large-negative bias; safe in fp32 softmax accumulation
 
@@ -425,7 +426,17 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     Where ``params`` holds ``q_norm`` and ``k_norm`` (a scale of ``D``
     each), q and k take an RMSNorm over each head's channels (eps
     ``norm_eps``) before the tables. ``kv_heads`` come as their caller
-    made them: normed and rotated there, if at all. output_gate: the
+    made them: normed and rotated there, if at all, and **their widths
+    may differ** (latent attention, MLA: score heads ``q_dim / H`` =
+    ``k_dim / H`` wide beside narrower value heads; the scale is the
+    score heads'; the result has the value heads' width and
+    ``params["out"]`` takes it): the materialized core takes them as
+    they are, the fused kernels take one width, so every head is
+    zero-padded to the next whole lanes that hold both (zero columns
+    change neither scores nor outputs) under the score heads' scale and
+    the result cut to the value heads (``two_widths`` in
+    ``attention_paths``: ``192|128`` or, padded, ``192|128 as 256``).
+    output_gate: the
     query projection is twice as wide, a head's query beside its gate
     (``[q_h | gate_h]`` a head), and the core's output is multiplied by
     ``sigmoid(gate)`` before the output projection, outside the core
@@ -510,6 +521,11 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     elif impl == "flash":
         path = "fused"
     _PATHS.add((path, reason))
+    if vh.shape[-1] != kh.shape[-1]:
+        widths = f"{kh.shape[-1] // num_heads}|{vh.shape[-1] // num_heads}"
+        _PATHS.add(("two_widths", widths + (
+            f" as {_fused_width(kh, vh, num_heads)}" if impl == "flash"
+            else "")))
     if impl == "flash":
         out = _fused_core(qh, kh, vh, num_heads, key_padding_mask, causal,
                           block_diffusion)
@@ -568,20 +584,38 @@ def _project(params, q, k, v, policy, kv_heads):
             linear_apply(params["v"], v, policy=policy))
 
 
+def _fused_width(k, v, num_heads: int) -> int:
+    """Lanes a head of the fused kernels' one width that holds score
+    heads ``k`` and value heads ``v`` (B, L, H·D) of two widths."""
+    return round_up(max(k.shape[-1], v.shape[-1]) // num_heads, 128)
+
+
 @device_scope("attn_core")
 def _fused_core(q, k, v, num_heads, key_padding_mask, causal=False,
                 block_diffusion=None):
     """The fused kernels, on the projections as they are: (B, L, H·D)
-    in and out, blocks from the shapes."""
+    in and out, blocks from the shapes. Score heads and value heads of
+    two widths go in zero-padded to one (``_fused_width``) under the
+    score heads' scale, and the value heads' width comes out."""
     import perceiver_tpu.ops.chunked_attention as _ca
     import perceiver_tpu.ops.pallas_attention as _pa
     bias = (_ca.pad_mask_to_bias(key_padding_mask)
             if key_padding_mask is not None else None)
     if causal or block_diffusion is not None:
         _MASKED_TILES.add(_pa.masked_call_tiles(q.shape[1], block_diffusion))
-    return _pa.flash_attention_channels(q, k, v, num_heads=num_heads,
-                                        bias=bias, causal=causal,
-                                        block_diffusion=block_diffusion)
+    scale = value_width = None
+    if v.shape[-1] != k.shape[-1]:
+        scale = 1.0 / math.sqrt(q.shape[-1] // num_heads)
+        value_width = v.shape[-1] // num_heads
+        width = _fused_width(k, v, num_heads)
+        q, k, v = (_pa._pad_heads(x, num_heads, width) for x in (q, k, v))
+    out = _pa.flash_attention_channels(q, k, v, num_heads=num_heads,
+                                       bias=bias, causal=causal,
+                                       block_diffusion=block_diffusion,
+                                       scale=scale)
+    if value_width is not None:
+        out = _pa._unpad_heads(out, num_heads, value_width)
+    return out
 
 
 @device_scope("attn_core")

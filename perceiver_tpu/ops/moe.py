@@ -21,10 +21,12 @@ The router, the experts and the shared expert are the configuration's::
   and ``down``), or gated, ``(silu(a W_gate) * (a W_up)) W_down``, three
   (a tree with ``gate`` too).
 * a shared expert (a tree with ``shared``, every token) or none:
-  relu-squared (``nemotron_h``), or gated with three matrices (a
+  relu-squared (``nemotron_h``), gated with three matrices (a
   ``shared`` with ``gate`` too) and multiplied by a sigmoid of a
   one-column projection of the token (a tree with ``shared_gate``:
-  ``qwen3_next``'s ``sigmoid(a w_sg) * f_shared(a)``).
+  ``qwen3_next``'s ``sigmoid(a w_sg) * f_shared(a)``), or gated with
+  three matrices and no such column (``kimi_linear``: the ``shared``
+  with ``gate``, no ``shared_gate``).
 
 **The layer is told which experts it holds**: ``first_expert`` (an
 int, or a scalar of the step) and the number of experts in its
@@ -107,12 +109,14 @@ from perceiver_tpu.ops.tiling import round_up
 moe_paths = Tally()
 #: what a layer's router and experts are: ``softmax top 8 renormalised``,
 #: ``gated silu x3 products`` or ``relu2 x2 products``, ``no shared
-#: expert``, ``shared expert`` (relu-squared) or ``gated shared expert
-#: under a sigmoid gate``
+#: expert``, ``shared expert`` (relu-squared), ``gated shared expert
+#: under a sigmoid gate`` or ``gated shared expert, no gate column``;
+#: ``weights x2.446`` where the chosen weights are scaled
 moe_kinds = Tally()
 
 SCORINGS = ("sigmoid", "softmax")
-SHARED_KINDS = ("relu2", "gated")
+# relu-squared; gated under a one-column sigmoid gate; gated alone
+SHARED_KINDS = ("relu2", "gated", "glu")
 
 # rows of the sorted buffer a tile of the grouped kernel takes
 _TILE_ROWS = 512
@@ -124,8 +128,9 @@ def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
     """The router over all ``num_experts``, the ``held_experts`` this
     layer holds (stacked on a leading axis; with a ``gate`` matrix each
     where ``gated``) and the shared expert (none at a ``shared_hidden``
-    of 0) of ``shared_kind``, one of ``SHARED_KINDS``: relu-squared, or
-    gated with its one-column ``shared_gate``."""
+    of 0) of ``shared_kind``, one of ``SHARED_KINDS``: relu-squared,
+    gated with its one-column ``shared_gate``, or gated without
+    (``glu``)."""
     if shared_kind not in SHARED_KINDS:
         raise ValueError(f"shared expert {shared_kind!r} not in "
                          f"{SHARED_KINDS}")
@@ -144,10 +149,11 @@ def moe_init(key, dim: int, *, num_experts: int, held_experts: int,
         params["experts"]["gate"] = {"w": torch_linear_uniform(
             jax.random.fold_in(ku, 1), (held_experts, dim, expert_hidden),
             dim, dtype)}
-    if shared_hidden and shared_kind == "gated":
+    if shared_hidden and shared_kind != "relu2":
         params["shared"] = gated_mlp_init(ks, dim, shared_hidden, dtype)
-        params["shared_gate"] = linear_init(
-            jax.random.fold_in(ks, 1), dim, 1, dtype, bias=False)
+        if shared_kind == "gated":
+            params["shared_gate"] = linear_init(
+                jax.random.fold_in(ks, 1), dim, 1, dtype, bias=False)
     elif shared_hidden:
         params["shared"] = relu2_mlp_init(ks, dim, shared_hidden, dtype)
     return params
@@ -467,7 +473,11 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
                   else "relu2 x2 products")
     moe_kinds.add("no shared expert" if "shared" not in params else
                   "gated shared expert under a sigmoid gate"
-                  if "shared_gate" in params else "shared expert")
+                  if "shared_gate" in params else
+                  "gated shared expert, no gate column"
+                  if "gate" in params["shared"] else "shared expert")
+    if scaling != 1.0:
+        moe_kinds.add(f"weights x{scaling:g}")
     chosen, weights = route(params["router"], a, top_k=top_k,
                             scaling=scaling, scoring=scoring,
                             renormalize=renormalize)
@@ -492,5 +502,7 @@ def moe_apply(params, a, *, top_k: int, first_expert=0,
             params["shared_gate"], a, policy=policy).astype(jnp.float32))
         out = out + (shared.astype(jnp.float32) * gate).astype(out.dtype)
     elif "shared" in params:
-        out = out + relu2_mlp_apply(params["shared"], a, policy)
+        mlp = gated_mlp_apply if "gate" in params["shared"] \
+            else relu2_mlp_apply
+        out = out + mlp(params["shared"], a, policy)
     return out.reshape(shape), plan.load

@@ -12,6 +12,13 @@ three layers of four, a softmax attention with an output gate, partial
 rotary positions and q/k norms the fourth, zero-centred norms, a softmax
 router with the top-k renormalised, gated experts and a gated shared
 expert under a sigmoid gate (``scripts/configs/gated_delta_lm_1chip.yaml``).
+``kimi_linear`` (Kimi Linear) is ``KD`` then ``KEKEAE`` a period: Kimi
+Delta Attention ``K`` (``kda_*``), latent attention without positions
+``A`` (``kv_lora_rank``, ``qk_*_head_dim``, ``v_head_dim``), a leading
+dense gated MLP ``D`` (``intermediate_size``), a sigmoid router whose
+renormalised top-k is scaled, gated experts and a shared expert gated
+with three matrices alone (``shared_expert_kind`` ``glu``;
+``scripts/configs/kimi_linear_lm_1chip.yaml``).
 ``held_experts`` and ``first_expert`` say which of the
 ``n_routed_experts`` this chip holds (None: all): the router keeps its
 width and its experts a token, and what the absent experts would have
@@ -67,6 +74,19 @@ class HybridLMTask:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     delta_chunk_size: int = 64
+    # K, Kimi Delta Attention (kimi_linear's linear_attn_config: num_heads,
+    # head_dim, short_conv_kernel_size; 0: no such layer)
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel_size: int = 4
+    # A, latent attention over num_attention_heads heads (kimi_linear's
+    # keys; 0: no such layer)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # D, a dense gated MLP's width (0: no such layer)
+    intermediate_size: int = 0
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
     head_dim: int = 128
@@ -84,7 +104,8 @@ class HybridLMTask:
     router_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     gated_experts: bool = False
-    # relu2, or gated (three matrices under a sigmoid gate of a column)
+    # relu2, gated (three matrices under a sigmoid gate of a column) or
+    # glu (the three matrices alone)
     shared_expert_kind: str = "relu2"
     norm_eps: float = 1e-5
     # every RMSNorm as x / rms(x) * (1 + w)

@@ -28,12 +28,17 @@ Compiles (compile ONLY — no execution) the full train step of:
    gated delta-rule mixers and one gated attention, 32 of 512 experts
    held, 4 rows of 4096),
 
+8. (``kimi``) the Kimi Linear LM of the benchmark's ``kimi_linear_train``
+   (``benchmarks/configs/kimi_linear_48b_a3b.json``: published layers 1
+   to 5, four Kimi Delta Attention mixers and one latent attention, a
+   leading dense MLP, 8 of 256 experts held, 4 rows of 4096),
+
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm``, ``224``, ``ouro``, ``nemotron``, ``sdar`` and ``qwen3next`` run ``remat: true``: beside
+``lm``, ``224``, ``ouro``, ``nemotron``, ``sdar``, ``qwen3next`` and ``kimi`` run ``remat: true``: beside
 XLA's sizes they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
@@ -41,11 +46,12 @@ that read the backend are made as the described chip would make them
 use on it, the parameters and optimizer state the step is handed).
 
 Usage: python scripts/aot_memcheck.py
-           [224 | lm | seg | ouro | nemotron | sdar | qwen3next | all] [rows]
+           [224 | lm | seg | ouro | nemotron | sdar | qwen3next | kimi | all]
+           [rows]
        (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` /
-       ``nemotron`` / ``sdar`` / ``qwen3next`` in place of the preset's;
-       ``all`` leaves ``ouro``, ``nemotron``, ``sdar`` and ``qwen3next``
-       out)
+       ``nemotron`` / ``sdar`` / ``qwen3next`` / ``kimi`` in place of the
+       preset's; ``all`` leaves ``ouro``, ``nemotron``, ``sdar``,
+       ``qwen3next`` and ``kimi`` out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -258,8 +264,9 @@ def check_nemotron(per_chip_batch: int = 4,
                    config: str = "nemotron3_nano_30b",
                    label: str = "nemotron"):
     """The benchmark's ``nemotron3_nano_30b`` as ``nemotron_train`` runs
-    it (or ``qwen3_next_80b_a3b`` as ``qwen3next_train`` does: the same
-    task, another pattern): the ``model`` group of its configuration
+    it (or ``qwen3_next_80b_a3b`` as ``qwen3next_train`` does, or
+    ``kimi_linear_48b_a3b`` as ``kimi_linear_train``: the same task,
+    another pattern): the ``model`` group of its configuration
     file, full rows, each expert layer's share named by the batch."""
     import jax.numpy as jnp
 
@@ -316,6 +323,9 @@ def main():
     if which == "qwen3next":
         out["qwen3_next_80b_a3b_4_layers"] = check_nemotron(
             config="qwen3_next_80b_a3b", label="qwen3next", **rows)
+    if which == "kimi":
+        out["kimi_linear_48b_a3b_5_layers"] = check_nemotron(
+            config="kimi_linear_48b_a3b", label="kimi", **rows)
     print(json.dumps(out, indent=2))
 
 

@@ -285,7 +285,8 @@ def test_the_router_and_expert_kinds_are_the_trees_and_the_calls(
     assert set(kinds) == {
         f"{scoring} top 3" + (" renormalised" if renormalize else ""),
         "gated silu x3 products" if gated else "relu2 x2 products",
-        "shared expert" if shared else "no shared expert"}
+        "shared expert" if shared else "no shared expert",
+        "weights x1.5"}
     got = jax.grad(lambda p, a: (layer(p, a) * w).sum(), (0, 1))(p, a)
     g_want = jax.grad(lambda p, a: (want(p, a) * w).sum(), (0, 1))(p, a)
     for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(g_want)):
