@@ -43,13 +43,15 @@ that underflows is a quiet 0.
 **Two forms, one arithmetic; the call site's shapes say which runs**
 (``fits``): where the operands lie on one device, the heads are whole
 lanes (``Dk`` and ``Dv`` multiples of 128), the chunk is 16, 32, 64 or
-128 and q, k, v share a compute dtype the kernels take, the rule is the
-Pallas kernels of ``ops/pallas_delta_rule.py`` (``kernel[64x64]`` in
-``rule_paths``: a chunk's ``Q x Q`` matrices, its inverse and the
-carried state stay in VMEM, forward and backward; interpreted off a
-TPU); everywhere else it is the einsums below (``chunked[...]``), which
-are the kernels' oracle in the tests. A row is padded to whole chunks
-either way.
+128 and q, k, v share a compute dtype the kernels take, the rule is
+Pallas kernels (``kernel[64x64]`` in ``rule_paths``: a chunk's ``Q x Q``
+matrices, its inverse and the carried state stay in VMEM, forward and
+backward; interpreted off a TPU): ``ops/pallas_delta_rule.py``'s for a
+decay that is a number a head, ``ops/pallas_kda_rule.py``'s for one
+that is a vector (below; ``kernel[64x64, by channel]``, and a value
+head a key head); everywhere else it is the einsums below
+(``chunked[...]``), which are the kernels' oracle in the tests. A row is
+padded to whole chunks either way.
 
 **A decay that is a vector** (Kimi Delta Attention, arXiv:2510.26692;
 the layer ``K`` of ``models/hybrid_lm.py``, ``kda_mixer_*`` below): ``g``
@@ -68,10 +70,14 @@ splits its span at the later one's first row ``r``
 its ``16 x 16 x Dk`` spans outright (masked before the ``exp``, summed
 over the channels in float32). ``g``'s shape says which runs; the
 inverse, the scan over the chunks and the passes of heads are the
-same. This form is XLA operations only (``fits`` sends it there;
-``chunked[..., by channel]`` in ``rule_paths``, scope ``kda_rule``),
-fewer heads a pass (``PASS_HEAD_CHUNKS_BY_CHANNEL``: a sub-block's
-spans for every head and chunk of a 4 x 4,096 row are 134 MB a head).
+same. The einsum form (``chunked[..., by channel]`` in ``rule_paths``,
+scope ``kda_rule`` either way) takes fewer heads a pass
+(``PASS_HEAD_CHUNKS_BY_CHANNEL``: a sub-block's spans for every head
+and chunk of a 4 x 4,096 row are 134 MB a head) and is 297 kinds of
+small XLA operation at the cell's shapes, 523 ms a step over four
+layers: the spans go through HBM a dozen times (PERF.md, PR 44). The
+kernels (PR 45) keep the same split, the spans on the vector units in
+VMEM, and make the running sum of ``g`` themselves.
 
 **Precision.** ``g``, ``beta``, every decay, ``A``, the powers of ``A``
 and ``T`` are float32, the inverse's products at ``Precision.HIGHEST``
@@ -95,7 +101,10 @@ at 32 heads are 134 MB each, and autodiff keeps a dozen), so its
 residuals live while its own backward runs and no longer. The kernels'
 backward is written by hand and takes the rule's operands alone (a
 pass rebuilds the state each chunk found, the reversed pass carries its
-cotangent; the inverse's cotangent is ``T^T dT T^T``). Either way a
+cotangent; the inverse's cotangent is ``T^T dT T^T``; a vector decay's
+log-decay takes ``q dq + k (dk_plus - dk_minus)``, no span of its own),
+so neither the checkpoint a pass nor the ``lax.map`` over the passes is
+there for them. Either way a
 ``remat`` layer that holds the rule's output (``dear``, ``delta_out``)
 does not run it again for the layer's sake. One scan over the chunks
 for all the heads, with the chunks' own work alone in passes, was tried
@@ -127,7 +136,8 @@ from perceiver_tpu.ops.tally import Tally
 #: chunks of 64 positions, the Pallas kernels) or
 #: ``chunked[64x64,8 heads a pass]`` (the einsums, eight value heads a
 #: checkpointed pass), ``+pad`` after the chunks where the last one is
-#: padded
+#: padded, ``, by channel`` last where the decay is a vector a key
+#: channel (``kernel[64x64, by channel]``: ``ops/pallas_kda_rule.py``'s)
 rule_paths = Tally()
 
 # head-chunks (rows x chunks x value heads) a pass may hold: each has a
@@ -162,17 +172,24 @@ def pick_rule(*, rows: int, seq: int, key_heads: int, value_heads: int,
 
 
 def fits(q, v, chunk: int, g=None) -> bool:
-    """Whether a call takes the kernels of ``ops/pallas_delta_rule.py``,
-    from what the call site can observe: a decay that is a number a head
-    (``g`` of (B, S, Hv), or not given: the kernels pull it out of the
-    sum over the channels), operands on one device (a Pallas call has no
+    """Whether a call takes the Pallas kernels, from what the call site
+    can observe: operands on one device (a Pallas call has no
     partitioning rule), heads of whole lanes, a chunk the kernels'
-    inverse merges, q and v in one compute dtype the kernels take."""
-    from perceiver_tpu.ops import pallas_delta_rule
-    return ((g is None or g.ndim == 3) and mesh_devices(q) == 1
-            and q.dtype == v.dtype
-            and pallas_delta_rule.fits(chunk=chunk, key_dim=q.shape[3],
-                                       value_dim=v.shape[3], dtype=v.dtype))
+    inverse merges, q and v in one compute dtype the kernels take; and
+    which kernels is ``g``'s to say: a number a head ((B, S, Hv), or
+    not given) ``ops/pallas_delta_rule.py``'s, which pull the decay out
+    of the sum over the channels; a number a head and key channel
+    ((B, S, Hv, Dk)) ``ops/pallas_kda_rule.py``'s, which also want a
+    value head a key head."""
+    from perceiver_tpu.ops import pallas_delta_rule, pallas_kda_rule
+    if mesh_devices(q) != 1 or q.dtype != v.dtype:
+        return False
+    sizes = dict(chunk=chunk, key_dim=q.shape[3], value_dim=v.shape[3],
+                 dtype=v.dtype)
+    if g is None or g.ndim == 3:
+        return pallas_delta_rule.fits(**sizes)
+    return pallas_kda_rule.fits(**sizes, heads=q.shape[2],
+                                value_heads=v.shape[2])
 
 
 def delta_mixer_init(key, dim: int, *, num_key_heads: int,
@@ -414,7 +431,8 @@ def _rule(q, k, v, g, beta, chunk_size: int):
         rows=rows, seq=seq, key_heads=key_heads, value_heads=heads,
         chunk_size=chunk_size, by_channel=by_channel)
     fused = fits(q, v, chunk, g)
-    rule_paths.add(f"kernel[{chunk}x{chunks}{'+pad' if pad else ''}]"
+    rule_paths.add(f"kernel[{chunk}x{chunks}{'+pad' if pad else ''}"
+                   f"{', by channel' if by_channel else ''}]"
                    if fused else
                    f"chunked[{chunk}x{chunks}{'+pad' if pad else ''},"
                    f"{at_once} heads a pass"
@@ -425,8 +443,9 @@ def _rule(q, k, v, g, beta, chunk_size: int):
             for x in (q, k, v, g, beta))
     g, beta = (x.astype(jnp.float32) for x in (g, beta))
     if fused:
-        from perceiver_tpu.ops.pallas_delta_rule import fused_rule
-        return fused_rule(q, k, v, g, beta, chunk=chunk)[:, :seq]
+        from perceiver_tpu.ops import pallas_delta_rule, pallas_kda_rule
+        kernels = pallas_kda_rule if by_channel else pallas_delta_rule
+        return kernels.fused_rule(q, k, v, g, beta, chunk=chunk)[:, :seq]
     groups = at_once // per                  # key heads a pass
     passes = key_heads // groups
 

@@ -107,6 +107,7 @@ def _topology_sharding():
     import perceiver_tpu.ops.moe as moe
     import perceiver_tpu.ops.remat as remat
     import perceiver_tpu.ops.ssm as ssm
+    import perceiver_tpu.utils.platform as platform
 
     topo = topologies.get_topology_desc(name, platform="tpu")
     kind = topo.devices[0].device_kind
@@ -117,6 +118,9 @@ def _topology_sharding():
     attention._backend = lambda: "tpu"
     moe._backend = lambda: "tpu"
     ssm._backend = lambda: "tpu"
+    # ... and its Pallas kernels are the chip's own, not the interpreter's
+    # loops (a kernel that asks its backend directly: the delta rules')
+    platform.default_interpret = lambda: False
     remat._memory_limit = lambda: DESCRIBED_MEMORY[kind]
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 

@@ -185,10 +185,15 @@ def test_fewer_heads_go_through_together():
                         chunk_size=64, by_channel=True) == (64, 64, 0, 2)
     assert dr.pick_rule(rows=4, seq=4096, key_heads=32, value_heads=32,
                         chunk_size=64) == (64, 64, 0, 8)
-    # and the kernels are a scalar decay's
+    # the kernels take either decay at heads of whole lanes (a vector's
+    # at a value head a key head), and neither at narrow heads
     q = jnp.zeros((1, 128, 2, 128), jnp.bfloat16)
     assert dr.fits(q, q, 64, jnp.zeros((1, 128, 2))) and dr.fits(q, q, 64)
-    assert not dr.fits(q, q, 64, jnp.zeros((1, 128, 2, 128)))
+    assert dr.fits(q, q, 64, jnp.zeros((1, 128, 2, 128)))
+    assert not dr.fits(q[:, :, :1], q, 64, jnp.zeros((1, 128, 2, 128)))
+    narrow = jnp.zeros((1, 128, 2, 16), jnp.bfloat16)
+    assert not dr.fits(narrow, narrow, 64, jnp.zeros((1, 128, 2, 16)))
+    assert not dr.fits(narrow, narrow, 64, jnp.zeros((1, 128, 2)))
 
 
 def test_the_rule_runs_under_its_own_scope():

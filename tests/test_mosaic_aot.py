@@ -293,6 +293,76 @@ def test_delta_rule_kernels_lie_under_the_rules_scope(one_chip, monkeypatch):
         "pallas_call"]
 
 
+@pytest.mark.parametrize("rows,seq,heads,dim,chunk,dtype", [
+    (4, 4096, 32, 128, 64, jnp.bfloat16),    # kimi_linear_train
+    (1, 256, 2, 128, 16, jnp.bfloat16),      # one sub-block a chunk
+    (1, 256, 1, 128, 32, jnp.bfloat16),      # two: one product before
+    (1, 256, 1, 256, 128, jnp.float32),      # eight; wide heads, float32
+], ids=["kimi_linear_train", "chunks_of_16", "chunks_of_32",
+        "float32_heads_of_256"])
+def test_kda_rule_forward_backward_compiles(one_chip, rows, seq, heads, dim,
+                                            chunk, dtype):
+    """The vector-decay rule's three kernels (forward; the backward's
+    states pass and its reversed pass) at the cell's shapes (4 x 4,096,
+    32 heads of 128, chunks of 64, bf16, ``g`` a number a head and key
+    channel) and at the edges of what ``fits`` lets through."""
+    from perceiver_tpu.ops.pallas_kda_rule import fused_rule
+
+    def loss(*args):
+        return fused_rule(*args, chunk=chunk,
+                          interpret=False).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    qkv = s((rows, seq, heads, dim), dtype)
+    # the value keeps the forward kernel live beside the backward pass
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, s((rows, seq, heads, dim), jnp.float32),
+        s((rows, seq, heads), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("kda_rule_fwd", "kda_rule_bwd_states", "kda_rule_bwd"):
+        assert name in text
+
+
+def test_kda_rule_kernels_lie_under_the_rules_scope(one_chip, monkeypatch):
+    """Picked as the trainer's step picks it (``ops.delta_rule
+    .delta_rule`` with ``g`` of (B, S, H, Dk) at shapes that tile), all
+    three kernels carry the scope ``kda_rule`` in their name stacks,
+    the backward's two under ``transpose(``: what
+    ``kda_rule_roofline``, ``model.kda_rule_pct`` and the pass split
+    read."""
+    import re
+
+    import perceiver_tpu.utils.platform as platform
+    from perceiver_tpu.ops import delta_rule
+
+    monkeypatch.setattr(platform, "default_interpret", lambda: False)
+
+    def loss(*args):
+        return delta_rule.delta_rule(
+            *args, chunk_size=64).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    qkv = s((1, 256, 2, 128), jnp.bfloat16)
+    with delta_rule.rule_paths.counting() as forms:
+        text = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+                qkv, qkv, qkv, s((1, 256, 2, 128), jnp.float32),
+                s((1, 256, 2), jnp.float32)).compile().as_text()
+    assert dict(forms) == {"kernel[64x4, by channel]": 1}
+    stacks = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "custom-call(" in line]
+    # (each direction a jitted function: its kernels are traced and
+    # lowered once for all the call sites of a step)
+    assert sorted(stacks) == [
+        "jit(loss)/jvp(kda_rule)/jit(_rule_forward)/kda_rule_fwd/"
+        "pallas_call",
+        "jit(loss)/transpose(jvp(kda_rule))/jit(_rule_backward)/"
+        "kda_rule_bwd/pallas_call",
+        "jit(loss)/transpose(jvp(kda_rule))/jit(_rule_backward)/"
+        "kda_rule_bwd_states/pallas_call"]
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 
