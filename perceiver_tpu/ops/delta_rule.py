@@ -129,7 +129,7 @@ from perceiver_tpu.ops.initializers import uniform
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.remat import dear
-from perceiver_tpu.ops.ssm import causal_conv
+from perceiver_tpu.ops.pallas_short_conv import short_conv
 from perceiver_tpu.ops.tally import Tally
 
 #: which form the rule took at each call site: ``kernel[64x64]`` (64
@@ -500,12 +500,8 @@ def delta_mixer_apply(params, u, *, num_key_heads: int, num_value_heads: int,
     # the write strength and the decay stay float32 from the product on
     ba = _float32_product(u, params["in_proj_ba"]["w"], policy)
     b, alpha = jnp.split(ba, 2, axis=-1)
-    qkv = jax.nn.silu(causal_conv(params["conv"], qkv))
-    q, k, v = jnp.split(qkv, [key_dim, 2 * key_dim], axis=-1)
-    q = (l2_norm(q.reshape(rows, seq, num_key_heads, key_head_dim))
-         / math.sqrt(key_head_dim)).astype(qkv.dtype)
-    k = l2_norm(k.reshape(rows, seq, num_key_heads, key_head_dim)).astype(
-        qkv.dtype)
+    q, k, v = short_conv([params["conv"]], qkv, head_dim=key_head_dim,
+                         scaled=key_dim, normed=key_dim, cut_from=(qkvz, 0))
     beta = jax.nn.sigmoid(b)
     g = -jnp.exp(params["A_log"]["bias"].astype(jnp.float32)) \
         * jax.nn.softplus(alpha + params["dt"]["bias"].astype(jnp.float32))
@@ -535,14 +531,19 @@ def delta_mixer_apply(params, u, *, num_key_heads: int, num_value_heads: int,
 #     out = (rms(o) * scale * sigmoid((u W_ga) W_gb)) W_o
 #
 # The three projections are one product over the matrices side by side
-# (the same function); the convolutions stay three: a convolution's
-# backward holds its taps' float32 copies of its input, 768 MB each over
-# all 12,288 channels of a 4 x 4,096 row where a third at a time is 256.
+# (the same function). The convolutions are three trees and one call of
+# ``short_conv``: where its kernels run (``ops/pallas_short_conv.py``) each
+# of q, k and v is a pass that reads its third of the product where it
+# lies and holds nothing float32 in HBM; as XLA's operations they stay
+# three convolutions, because one's backward holds its taps' float32
+# copies of its input, 768 MB each over all 12,288 channels of a
+# 4 x 4,096 row where a third at a time is 256 (PERF.md, PR 44).
 # A ``remat`` layer is offered the rule's output (``delta_out``) and not
 # the projection's product: at 602 M parameters the five-layer stack's
-# step does not fit a 16 GB chip with four such 384 MB buffers held
+# step did not fit a 16 GB chip with four such 384 MB buffers held
 # (a described-v5e compile: 16.0 GB of 15.75; PERF.md, PR 44), and
-# making the product again is 1.3% of the step's operations.
+# making the product again is 1.3% of the step's operations; the memory
+# the rule's kernels freed since (PR 45) is recorded, not spent.
 
 
 def kda_mixer_init(key, dim: int, *, num_heads: int, head_dim: int,
@@ -579,15 +580,13 @@ def kda_mixer_apply(params, u, *, num_heads: int, head_dim: int,
     """u (B, S, C) -> (B, S, C)."""
     rows, seq, _ = u.shape
     heads = (rows, seq, num_heads, head_dim)
+    width = num_heads * head_dim
     names = ("q", "k", "v")
     qkv = linear_apply(
         {"w": jnp.concatenate([params[n]["w"] for n in names], axis=1)},
         u, policy=policy)
-    q, k, v = (
-        jax.nn.silu(causal_conv(params[f"{n}_conv"], x)).reshape(heads)
-        for n, x in zip(names, jnp.split(qkv, 3, axis=-1)))
-    q = (l2_norm(q) / math.sqrt(head_dim)).astype(qkv.dtype)
-    k = l2_norm(k).astype(qkv.dtype)
+    q, k, v = short_conv([params[f"{n}_conv"] for n in names], qkv,
+                         head_dim=head_dim, scaled=width, normed=width)
     # the decay and the write strength stay float32 from the product on
     alpha = _float32_product(
         _float32_product(u, params["f_a"]["w"], policy),

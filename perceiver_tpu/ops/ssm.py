@@ -59,6 +59,7 @@ from perceiver_tpu.ops.attention import mesh_devices
 from perceiver_tpu.ops.initializers import uniform
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
+from perceiver_tpu.ops.pallas_short_conv import short_conv
 from perceiver_tpu.ops.remat import dear
 from perceiver_tpu.ops.tally import Tally
 
@@ -243,8 +244,8 @@ def ssm_mixer_apply(params, u, *, num_heads: int, head_dim: int,
     zxbcdt = dear(linear_apply(params["in_proj"], u, policy=policy),
                   "ssm_in")
     z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], axis=-1)
-    xbc = jax.nn.silu(causal_conv(params["conv"], xbc))
-    x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
+    x, b, c = short_conv([params["conv"]], xbc, rest=(inner, bc, bc),
+                         cut_from=(zxbcdt, inner))
     x = x.reshape(rows, seq, num_heads, head_dim)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt"]["bias"].astype(jnp.float32))

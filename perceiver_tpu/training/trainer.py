@@ -474,6 +474,7 @@ class Trainer:
         )
         from perceiver_tpu.ops.delta_rule import rule_paths
         from perceiver_tpu.ops.moe import moe_kinds, moe_paths
+        from perceiver_tpu.ops.pallas_short_conv import conv_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         from perceiver_tpu.ops.ssm import scan_paths
         from perceiver_tpu.ops.tally import format_tally
@@ -482,7 +483,8 @@ class Trainer:
                 remat_keeps() as keeps, scan_paths.counting() as scans, \
                 moe_paths.counting() as experts, \
                 moe_kinds.counting() as kinds, \
-                rule_paths.counting() as rules:
+                rule_paths.counting() as rules, \
+                conv_paths.counting() as convs:
             try:
                 if self._exec_cache is None:
                     step_fn.lower(state, sharded)
@@ -503,6 +505,8 @@ class Trainer:
             lines.append(f"selective scans: {format_tally(scans)}")
         if rules:    # a stack with linear-attention layers (ops/delta_rule.py)
             lines.append(f"delta rules: {format_tally(rules)}")
+        if convs:    # ... whose mixers have a short convolution
+            lines.append(f"short convolutions: {format_tally(convs)}")
         if experts:  # a stack with expert layers (ops/moe.py)
             lines.append(f"expert layers: {format_tally(experts)}")
             lines.append(f"expert kinds: {format_tally(kinds)}")

@@ -5,6 +5,9 @@ for a traced run of a benchmark cell::
     python3 scripts/scope_ops.py moe_route --workload sdar_train \
         --seed 7 --seconds 40 --trace 1
 
+(``kda_mixer-kda_rule`` for a scope: the mixer's operations outside its
+rule.)
+
 The benchmark removes a run's trace with its work directory; this runs
 the same run (``benchmarks/harness.py``, nothing of it changed) and,
 before the directory goes, reads the trace's device planes once more:
@@ -35,9 +38,12 @@ _NUMBERED = re.compile(r"^[a-z_\-]+(\.[0-9]+)+ ")
 
 def table(planes, scope: str):
     """``(whole steps, rows)``; a row is ``(pass, primitive, instruction,
-    calls a step, ms a step)``, dearest first."""
+    calls a step, ms a step)``, dearest first. ``scope`` may be
+    ``outer-inner``: the operations under ``outer`` and not under
+    ``inner`` (``kda_mixer-kda_rule``: a mixer outside its rule)."""
     from benchmarks import scope_times, trace_reduce
 
+    scope, _, less = scope.partition("-")
     steps, rows = 0, {}
     for plane in planes:
         # a step's marker, as ``hybrid_costs.whole_steps`` finds it: the
@@ -58,12 +64,16 @@ def table(planes, scope: str):
             calls[meta] = calls.get(meta, 0) + 1
         for meta, ps in scope_times.self_ps_by_metadata(inside).items():
             stack = plane.op_names.get(meta, "")
-            if scope not in scope_times.names_of(stack):
+            names = scope_times.names_of(stack)
+            if scope not in names or less in names:
                 continue
             what = trace_reduce.short_name(plane.names.get(meta, "?"), 120)
             what = _NUMBERED.sub("", what)   # fusion.12 f32[8] fusion
-            key = (scope_times.pass_of(stack),
-                   stack.rstrip(":").rsplit("/", 1)[-1], what)
+            segments = stack.rstrip(":").split("/")
+            # a Pallas kernel by its own name, not ``pallas_call``
+            primitive = segments[-2] if segments[-2:-1] and \
+                segments[-1] == "pallas_call" else segments[-1]
+            key = (scope_times.pass_of(stack), primitive, what)
             n, s = rows.get(key, (0, 0.0))
             rows[key] = (n + calls[meta], s + ps / 1e9)
     return steps / len(planes), sorted(

@@ -537,6 +537,8 @@ def test_the_trainer_says_the_forms_and_logs_the_counters(toy, tmp_path,
     err = capfd.readouterr().err
     assert ("[step_load] attention call sites: materialized[backend]=2\n"
             in err)
+    # a stack of attention and expert layers alone: no short convolution
+    assert "short convolutions" not in err
     # 240 positions a step, 180 assignments if even: twice that is
     # under two experts' worth, so a row a token
     assert ("[step_load] expert layers: held 4/16=2 "

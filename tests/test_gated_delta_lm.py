@@ -432,6 +432,8 @@ def test_the_script_trains_the_pattern(tmp_path, capfd):
         "gate=4 gated silu x3 products=4 softmax top" in err
     assert re.search(r"remat keeps: \S*delta_out,delta_in,moe_plan \+ "
                      r"layer_in", err), err
+    assert "[step_load] short convolutions: xla[256ch, norm 128, backend]=3" \
+        in err
     assert "selective scans" not in err
     assert re.search(r"\[step 1\] loss=\d+\.\d+ .*moe_assignments=", out + err)
     assert "moe_full_buffer_layers" in out + err

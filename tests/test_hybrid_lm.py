@@ -649,6 +649,7 @@ def test_the_trainer_says_the_forms_and_logs_the_counters(toy, tmp_path,
             "ssm_in,delta_out,delta_in,moe_plan + layer_in 0.00 GB of no "
             "memory report\n"
             "[step_load] selective scans: chunked[16x3+pad,backend]=2\n"
+            "[step_load] short convolutions: xla[128ch, backend]=2\n"
             "[step_load] expert layers: held 4/16=2 ragged_dot[cpu]x240=2 "
             "ragged_dot[cpu]x80=2\n"
             ) in err

@@ -429,6 +429,8 @@ def test_the_script_trains_the_pattern(tmp_path, capfd):
     out, err = capfd.readouterr()
     assert "[step_load] delta rules: chunked[40x1,4 heads a pass, by " \
         "channel]=1" in err, err
+    assert "[step_load] short convolutions: xla[192ch, norm 128, backend]=1" \
+        in err
     assert "two_widths[24|16]=1" in err
     assert "[step_load] expert kinds: gated shared expert, no gate " \
         "column=1 gated silu x3 products=1 sigmoid top" in err

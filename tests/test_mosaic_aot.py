@@ -363,6 +363,100 @@ def test_kda_rule_kernels_lie_under_the_rules_scope(one_chip, monkeypatch):
         "kda_rule_bwd_states/pallas_call"]
 
 
+# --- the mixers' short convolution -------------------------------------------
+
+
+@pytest.mark.parametrize("wide,first,channels,head,scaled,normed,bias", [
+    (4096, 0, 4096, 128, 4096, 0, False),      # a KDA layer's q alone
+    (4096, 0, 4096, 0, 0, 0, False),           # ... and its v
+    (12288, 0, 12288, 128, 4096, 4096, False),  # kimi_linear_train: q, k, v
+    (12288, 0, 8192, 128, 2048, 2048, False),  # qwen3next_train: of [q k v z]
+    (10304, 4096, 6144, 0, 0, 0, True),        # nemotron_train: of [z xBC dt]
+], ids=["kda_q", "kda_v", "kda_qkv", "delta_qkv_of_qkvz", "ssm_xbc_of_zxbcdt"])
+def test_short_conv_forward_backward_compiles(one_chip, wide, first, channels,
+                                              head, scaled, normed, bias):
+    """The short convolution's two kernels at the three mixer cells'
+    shapes (4 x 4,096 positions, bf16; a call a part, each reading its
+    channels where they lie in the projection's product), and the VMEM
+    each asks for (the custom call's own scoped size, after the limit it
+    was allowed)."""
+    import re
+
+    from perceiver_tpu.ops.pallas_short_conv import fused_short_conv
+
+    def loss(params, x):
+        return sum(part.astype(jnp.float32).sum() for part in fused_short_conv(
+            params, x, head_dim=head, scaled=scaled, normed=normed,
+            first=first, interpret=False))
+
+    s = _struct(one_chip)
+    params = {"w": s((4, channels), jnp.float32)}
+    if bias:
+        params["bias"] = s((channels,), jnp.float32)
+    # the value keeps the forward kernels live beside the backward pass
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, s((4, 4096, wide), jnp.bfloat16)).compile().as_text()
+    asked, limit = {}, 64 * 2**20
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and "custom-call(" in line:
+            name = re.search(r"short_conv_(fwd|bwd)", line).group(0)
+            # two scoped sizes a call: the limit it was allowed, and what
+            # it asks (laid after the limit where XLA keeps an operand in
+            # the same memory)
+            asked.setdefault(name, []).append(min(
+                int(n) % limit or limit for n in re.findall(
+                    r'\\?"size\\?":\\?"(\d+)', line)))
+    print(f"short_conv {channels}ch of {wide}: VMEM asked "
+          + ", ".join(f"{k} {max(v) / 2**20:.2f} MiB x{len(v)}"
+                      for k, v in sorted(asked.items())))
+    parts = bool(scaled) + bool(normed) + (channels > scaled + normed)
+    assert {k: len(v) for k, v in asked.items()} == {
+        "short_conv_bwd": parts, "short_conv_fwd": parts}
+    # far under a v5e's 128 MiB, and under the 16 MiB a call gets unasked
+    assert max(max(v) for v in asked.values()) < 16 * 2**20
+
+
+def test_short_conv_kernels_lie_under_the_mixers_scope(one_chip, monkeypatch):
+    """Picked as the trainer's step picks it (``short_conv`` from a
+    mixer on a TPU), both kernels carry the mixer's scope in their name
+    stacks, the backward's under ``transpose(``: what
+    ``model.delta_mixer_pct`` and the pass split read; each direction a
+    jitted function, traced once for a step's call sites."""
+    import re
+
+    import perceiver_tpu.utils.platform as platform
+    from perceiver_tpu.ops import delta_rule, pallas_short_conv
+    from perceiver_tpu.ops.policy import Policy
+
+    monkeypatch.setattr(pallas_short_conv, "_backend", lambda: "tpu")
+    monkeypatch.setattr(platform, "default_interpret", lambda: False)
+    sizes = dict(num_key_heads=1, num_value_heads=2, key_head_dim=128,
+                 value_head_dim=128)
+    params = jax.eval_shape(lambda: delta_rule.delta_mixer_init(
+        jax.random.key(0), 256, **sizes))
+    s = _struct(one_chip)
+
+    def loss(params, u):
+        return delta_rule.delta_mixer_apply(
+            params, u, policy=Policy.bf16(), **sizes).astype(
+                jnp.float32).sum()
+
+    with pallas_short_conv.conv_paths.counting() as forms:
+        text = jax.jit(jax.grad(loss)).lower(
+            jax.tree.map(lambda x: s(x.shape, x.dtype), params),
+            s((1, 256, 256), jnp.bfloat16)).compile().as_text()
+    assert dict(forms) == {"fused[512ch, norm 256]": 1}
+    stacks = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "custom-call(" in line]
+    # a call a part: q, k and v
+    assert sorted(s for s in stacks if "short_conv" in s) == [
+        "jit(loss)/jvp(delta_mixer)/jit(_conv_forward)/short_conv_fwd/"
+        "pallas_call"] * 3 + [
+        "jit(loss)/transpose(jvp(delta_mixer))/jit(_conv_backward)/"
+        "short_conv_bwd/pallas_call"] * 3
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 

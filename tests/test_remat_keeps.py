@@ -273,6 +273,8 @@ def test_the_trainer_says_what_its_remat_layers_keep(tmp_path, capfd,
     assert ("[step_load] attention call sites: materialized[backend]=3\n"
             "[step_load] remat keeps: attn_out,qkv,mlp_hidden + layer_in "
             "0.00 GB of no memory report\n") in err
+    # a Perceiver has no mixer with a short convolution
+    assert "short convolutions" not in err and "delta rules" not in err
     assert not remat._KEEP_TALLIES and not remat._RECORDERS
 
     # a chip too small for the list: the line says what went and why
