@@ -277,7 +277,7 @@ class IMDBDataModule:
         # every resume of a long run); the arrays are cheap to store.
         # Keyed by the tokenizer file's digest + seq_len + a corpus
         # fingerprint: the tokenizer digest alone misses an in-place
-        # corpus rewrite (harvest_text.py regenerates .cache/aclImdb
+        # corpus rewrite (a corpus regenerated under .cache/aclImdb
         # without touching the tokenizer json — ADVICE r2), which would
         # silently serve stale ids AND stale labels.
         cache = (tok_path.replace(".json", f"-ids-L{self.max_seq_len}.npz")

@@ -14,12 +14,12 @@ import os
 os.environ.setdefault("PERCEIVER_TPU_OFFLINE", "1")
 
 # Tests and their children always compile fresh. A persistent XLA
-# compilation cache breaks two tier-1 gates: chaos determinism replays
-# get executables compiled under foreign flags (near-tied logits flip)
-# and the shared-prefix bench's cold arm stops paying compiles (its
-# warm/cold TTFT gate measures exactly that cost). Entry points call
-# cache.enable_compile_cache(), so the cache is switched off whole
-# (the variable reaches children; JAX reads it at import).
+# compilation cache breaks two kinds of tier-1 case: chaos determinism
+# replays get executables compiled under foreign flags (near-tied
+# logits flip), and the tests that count compiles (jax.monitoring
+# events, the exec-cache's cold and warm runs) would count none. Entry
+# points call cache.enable_compile_cache(), so the cache is switched
+# off whole (the variable reaches children; JAX reads it at import).
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
@@ -28,11 +28,44 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+
+# A comparison's program runs once, so LLVM's optimisation of it is the
+# larger part of what the case costs. These two options switch off
+# LLVM-level work only: XLA's own HLO passes, fusion, buffer assignment
+# and memory analysis stay as they are (tests/test_suite_env.py pins
+# that). They are given a program at a time and not through XLA_FLAGS:
+# process-wide, bfloat16 programs that run long (the decode engine's
+# step) are ten times slower, and a benchmark rehearsal that runs for a
+# fixed number of seconds then serves too few requests for its control
+# to show (PR 47).
+RUNS_ONCE = {"xla_backend_optimization_level": 0,
+             "xla_llvm_disable_expensive_passes": True}
+
+
+def jit_once(fn, **kwargs):
+    """``jax.jit`` for a program a test compiles to run once."""
+    return jax.jit(fn, compiler_options=RUNS_ONCE, **kwargs)
+
+
+def out_and_grads(fn, weight, *args):
+    """``fn(*args)`` and the gradients of its ``weight``-weighted sum to
+    every argument, from one jitted program: what a case costs is its
+    compiles, so each side of a comparison is one."""
+    def weighted(*a):
+        out = fn(*a)
+        return (out * weight).sum(), out
+
+    (_, out), grads = jit_once(jax.value_and_grad(
+        weighted, tuple(range(len(args))), has_aux=True))(*args)
+    return out, grads
+
 
 # --- CPU-backend multiprocess probe (shared skip gate) ----------------------
 # Not every jaxlib CPU wheel ships cross-process collectives (Gloo):

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 import perceiver_tpu.ops.attention as attn
 from perceiver_tpu.ops import mha_apply, mha_init
@@ -118,8 +119,8 @@ def test_call_sites_are_tallied_by_path_and_reason(site, monkeypatch):
 def test_none_on_the_cpu_is_the_materialized_core():
     with attn.attention_paths() as outer, attn.attention_paths() as inner:
         _call()
-        jax.jit(_call)()          # traced once more, tallied once more
-        jax.jit(_call)()          # a cache hit traces nothing
+        jit_once(_call)()          # traced once more, tallied once more
+        jit_once(_call)()          # a cache hit traces nothing
     assert dict(inner) == dict(outer) == {materialized("backend"): 2}
     assert attn.format_attention_paths(inner) == "materialized[backend]=2"
     with attn.attention_paths() as later:
@@ -192,7 +193,7 @@ def test_model_loss_and_gradients_fused_against_einsum(channels, policy,
                 deterministic=False, policy=policy)[0]
 
         with attn.attention_paths() as paths:
-            out = jax.jit(jax.value_and_grad(loss))(params)
+            out = jit_once(jax.value_and_grad(loss))(params)
         want = FUSED if impl == "flash" else materialized("impl")
         assert dict(paths) == {want: 3}
         return out

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import out_and_grads
 
 import perceiver_tpu.ops.attention as attn
 import perceiver_tpu.ops.pallas_attention as pa
@@ -202,12 +203,11 @@ def test_looped_kernels_match_the_materialised_core(case, sub_tiles_of_128):
     kw = dict(num_heads=heads, block_q=block_q, block_k=block_k,
               **(dict(causal=True) if diffusion is None
                  else dict(block_diffusion=diffusion)))
-    assert rel(pa.flash_attention_channels(q, k, v, **kw),
-               reference(q, k, v, heads, diffusion)) < 1e-5
-    got = jax.grad(lambda *a: (pa.flash_attention_channels(*a, **kw)
-                               * g).sum(), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (reference(*a, heads, diffusion) * g).sum(),
-                    (0, 1, 2))(q, k, v)
+    got_out, got = out_and_grads(
+        lambda *a: pa.flash_attention_channels(*a, **kw), g, q, k, v)
+    want_out, want = out_and_grads(
+        lambda *a: reference(*a, heads, diffusion), g, q, k, v)
+    assert rel(got_out, want_out) < 1e-5
     for a, b in zip(got, want):
         assert rel(a, b) < 1e-5
 

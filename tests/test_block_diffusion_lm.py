@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once, out_and_grads
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -125,7 +126,7 @@ def test_the_calls_carry_their_own_kernel_names_and_refuse_other_masks():
     q = normal(20, (1, 256, 128))
 
     def names(**kw):
-        text = jax.jit(jax.grad(lambda q: pa.flash_attention_channels(
+        text = jit_once(jax.grad(lambda q: pa.flash_attention_channels(
             q, q, q, num_heads=1, **kw).sum())).lower(q).as_text(
                 debug_info=True)
         return {n for n in ("flash_attention_fwd", "causal_attention_fwd",
@@ -186,19 +187,14 @@ def test_the_attention_layer_on_both_cores_against_the_reference(
     cfg = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                rms_norm_eps=1e-6, rope_theta=1e4, block_length=4)
     w = normal(34, a.shape)
-
-    def loss(f):
-        return lambda p, a: (f(p, a) * w).sum()
-
     fused, plain, want = (
         lambda p, a: hybrid_lm.rotary_gqa_apply(p, a, impl="flash", **kw),
         lambda p, a: hybrid_lm.rotary_gqa_apply(p, a, impl="einsum", **kw),
         lambda p, a: ref.attention_layer(p, a, cfg, "f32"))
-    assert rel(fused(params, a), want(params, a)) < 2e-5
-    assert rel(plain(params, a), want(params, a)) < 2e-5
-    g_want = jax.grad(loss(want), (0, 1))(params, a)
+    out_want, g_want = out_and_grads(want, w, params, a)
     for f in (fused, plain):
-        got = jax.grad(loss(f), (0, 1))(params, a)
+        out, got = out_and_grads(f, w, params, a)
+        assert rel(out, out_want) < 2e-5
         for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(g_want)):
             assert rel(x, y) < 1e-4
     # on a TPU the pick sends the call site to the kernels
@@ -281,14 +277,14 @@ def test_the_router_and_expert_kinds_are_the_trees_and_the_calls(
         return dense_moe(p, a.reshape(-1, 32), **kw).reshape(a.shape)
 
     with moe.moe_kinds.counting() as kinds:
-        assert rel(layer(p, a), want(p, a)) < 2e-5
+        out, got = out_and_grads(layer, w, p, a)
+    out_want, g_want = out_and_grads(want, w, p, a)
+    assert rel(out, out_want) < 2e-5
     assert set(kinds) == {
         f"{scoring} top 3" + (" renormalised" if renormalize else ""),
         "gated silu x3 products" if gated else "relu2 x2 products",
         "shared expert" if shared else "no shared expert",
         "weights x1.5"}
-    got = jax.grad(lambda p, a: (layer(p, a) * w).sum(), (0, 1))(p, a)
-    g_want = jax.grad(lambda p, a: (want(p, a) * w).sum(), (0, 1))(p, a)
     for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(g_want)):
         assert rel(x, y) < 1e-4
     with pytest.raises(ValueError, match="scoring"):
@@ -346,8 +342,13 @@ def test_both_sides_of_the_buffers_cond_give_the_layers_result(crowded):
         return moe.moe_apply(p, a, top_k=3, first_expert=4,
                              scoring="softmax", policy=FP32)
 
-    with moe.moe_paths.counting() as forms:
+    def with_load(p, a):    # the load rides beside the weighted sum
         out, load = layer(p, a)
+        return (out * w).sum(), (out, load)
+
+    with moe.moe_paths.counting() as forms:
+        (_, (out, load)), got = jit_once(jax.value_and_grad(
+            with_load, (0, 1), has_aux=True))(p, a)
     usual = moe.usual_rows(1024, 3, 6, 16)
     assert usual == 2048                       # the even load is 1152
     assert dict(forms) == {
@@ -356,10 +357,9 @@ def test_both_sides_of_the_buffers_cond_give_the_layers_result(crowded):
     assert (int(load.sum()) > usual) == crowded
     if crowded:
         assert int(load.sum()) == 1024 * 3     # every assignment is held
-    assert rel(out, dense_moe(p, a[0], **kw)[None]) < 2e-5
-    got = jax.grad(lambda p, a: (layer(p, a)[0] * w).sum(), (0, 1))(p, a)
-    want = jax.grad(lambda p, a: (dense_moe(p, a[0], **kw)[None]
-                                  * w).sum(), (0, 1))(p, a)
+    out_want, want = out_and_grads(
+        lambda p, a: dense_moe(p, a[0], **kw)[None], w, p, a)
+    assert rel(out, out_want) < 2e-5
     for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert rel(x, y) < 1e-4
 
@@ -426,9 +426,9 @@ def test_what_a_position_sees_is_the_masks(toy):
     task, model, params, batch = toy
     ids = batch["input_ids"][:1]
     noised = jnp.where(jnp.arange(40) % 3 == 0, 0, ids)
-    run = lambda xt, x: model.hidden_states(   # noqa: E731
+    run = jit_once(lambda xt, x: model.hidden_states(
         params, jnp.concatenate([xt, x], 1), policy=FP32,
-        block_diffusion=(40, 4))[0]
+        block_diffusion=(40, 4))[0])
     base = run(noised, ids)
     other = run(jnp.roll(noised, 5, axis=1), ids)
     assert rel(other[:, 40:], base[:, 40:]) < 1e-6
@@ -480,9 +480,9 @@ def test_loss_and_every_gradient_against_the_reference(toy):
         total, count = ref.loss_sum(p, ref_batch, cfg, "f32")
         return total / count
 
-    (loss, metrics), grads = jax.value_and_grad(program, has_aux=True)(
-        params)
-    want, want_grads = jax.value_and_grad(reference)(params)
+    (loss, metrics), grads = jit_once(jax.value_and_grad(
+        program, has_aux=True))(params)
+    want, want_grads = jit_once(jax.value_and_grad(reference))(params)
     assert abs(float(loss) - float(want)) < 1e-5 * float(want)
     got = jax.tree_util.tree_leaves_with_path(grads)
     for (path, g), w in zip(got, jax.tree.leaves(want_grads)):
@@ -496,11 +496,11 @@ def test_loss_and_every_gradient_against_the_reference(toy):
     assert float(metrics["moe_full_buffer_layers"]) in (0.0, 1.0, 2.0)
     # a batch that names the shares itself
     firsts = jnp.tile(jnp.asarray([[8, 0]], jnp.int32), (3, 1))
-    named, _ = task.loss_and_metrics(
-        model, params, {**batch, "first_experts": firsts}, rng=key,
-        deterministic=False, policy=FP32)
-    total, count = ref.loss_sum(
-        params, {**ref_batch, "first_experts": firsts}, cfg, "f32")
+    named, _ = jit_once(lambda p, b: task.loss_and_metrics(
+        model, p, b, rng=key, deterministic=False, policy=FP32))(
+            params, {**batch, "first_experts": firsts})
+    total, count = jit_once(lambda p, b: ref.loss_sum(p, b, cfg, "f32"))(
+        params, {**ref_batch, "first_experts": firsts})
     assert abs(float(named) - float(total / count)) < 1e-5 * float(named)
     assert abs(float(named) - float(loss)) > 1e-4
 
@@ -559,7 +559,7 @@ def test_the_noise_has_its_scope_in_the_compiled_step(toy):
         return jax.grad(lambda p: task.loss_and_metrics(
             model, p, {"input_ids": ids}, rng=key, policy=FP32)[0])(p)
 
-    text = jax.jit(step).lower(params, batch["input_ids"],
+    text = jit_once(step).lower(params, batch["input_ids"],
                                jax.random.key(0)).compile().as_text()
     stacks = [scope_times.names_of(line.split('op_name="')[1].split('"')[0])
               for line in text.splitlines() if 'op_name="' in line]
@@ -583,7 +583,7 @@ def test_a_rotary_table_is_written_into_the_step_once(toy):
             remat.build(), p, {"input_ids": ids}, rng=key,
             policy=FP32)[0])(p)
 
-    text = jax.jit(step).lower(params, batch["input_ids"],
+    text = jit_once(step).lower(params, batch["input_ids"],
                                jax.random.key(0)).as_text()
     tables = [line for line in text.splitlines()
               if "stablehlo.constant" in line

@@ -17,13 +17,14 @@ The load-bearing properties:
   blocking; deadlines shed typed; freed pages re-admit the queue.
 """
 
+import functools
 import threading
 import time
 
 import numpy as np
 import pytest
+from conftest import jit_once
 
-import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.cache import compile_events
@@ -250,31 +251,41 @@ def test_geometry_must_fit_model_position_table():
 # --- engine: parity against full recompute ----------------------------------
 
 
-def _reference_generate(model, params, policy, prompt, max_new):
-    """Full-recompute oracle: re-encode the WHOLE prefix for every
-    token, decode one query at the next position. O(T^2) on purpose —
-    this is the semantics the paged O(1) path must match exactly."""
+@functools.cache
+def _reference_next_token(model, policy):
+    """The oracle's step as a jitted function of ``(params, ids)``: one
+    program a prefix length, shared by every prompt of a model and
+    policy, where op by op each operation compiled at each length."""
     from perceiver_tpu.models.perceiver import cross_attention_layer_apply
     from perceiver_tpu.ops.linear import linear_apply
 
-    toks = [int(t) for t in prompt]
-    out = []
-    for _ in range(max_new):
-        ids = jnp.asarray(toks, jnp.int32)[None]
+    @jit_once
+    def next_token(params, ids):
         latents, _ = model.encoder.apply(params["encoder"], ids,
                                          policy=policy)
         pd = params["decoder"]
-        q = policy.cast_param(pd["query"])[len(toks)][None, None]
+        q = policy.cast_param(pd["query"])[ids.shape[1]][None, None]
         hidden = cross_attention_layer_apply(
             pd["cross"], q, latents,
             num_heads=model.decoder.num_cross_attention_heads,
             policy=policy)
         logits = linear_apply(pd["output_adapter"]["linear"], hidden,
                               policy=policy)[0, 0]
-        nxt = int(jnp.argmax(logits.astype(jnp.float32)))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+        return jnp.argmax(logits.astype(jnp.float32))
+
+    return next_token
+
+
+def _reference_generate(model, params, policy, prompt, max_new):
+    """Full-recompute oracle: re-encode the WHOLE prefix for every
+    token, decode one query at the next position. O(T^2) on purpose —
+    this is the semantics the paged O(1) path must match exactly."""
+    next_token = _reference_next_token(model, policy)
+    toks = [int(t) for t in prompt]
+    for _ in range(max_new):
+        toks.append(int(next_token(params,
+                                   jnp.asarray(toks, jnp.int32)[None])))
+    return toks[len(prompt):]
 
 
 @pytest.mark.parametrize("policy_name", ["fp32", "bf16"])

@@ -12,6 +12,7 @@ import sys
 import jax
 import numpy as np
 import pytest
+from conftest import RUNS_ONCE
 
 from perceiver_tpu.tasks import ImageClassifierTask, MaskedLanguageModelTask
 from perceiver_tpu.training import Trainer, TrainerConfig
@@ -76,7 +77,7 @@ def named_ops(request, tmp_path_factory):
     task, batch = TASKS[name]
     task = dataclasses.replace(task, remat=remat, **(FUSED if fused else {}))
     lowered = lower_step(task, batch, tmp_path_factory.mktemp("scopes"))
-    text = lowered.compile().as_text()
+    text = lowered.compile(compiler_options=RUNS_ONCE).as_text()
     ops = re.findall(
         r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"', text)
     assert len(ops) > 1000
@@ -188,7 +189,7 @@ def looped_ops(request, tmp_path_factory):
                          tmp_path_factory.mktemp("looped"))
     ops = re.findall(
         r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"',
-        lowered.compile().as_text())
+        lowered.compile(compiler_options=RUNS_ONCE).as_text())
     assert len(ops) > 500
     return request.param, ops
 
@@ -287,7 +288,7 @@ def hybrid_ops(tmp_path_factory):
                          tmp_path_factory.mktemp("hybrid"))
     ops = re.findall(
         r'= \S+ ([a-z][\w-]*)\(.*?metadata=\{op_name="([^"]*)"',
-        lowered.compile().as_text())
+        lowered.compile(compiler_options=RUNS_ONCE).as_text())
     assert len(ops) > 300
     return ops
 

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.ops import mha_init, mha_apply
 from perceiver_tpu.ops.chunked_attention import (
@@ -65,8 +66,8 @@ class TestChunked:
         def loss_ref(q, k, v):
             return _reference_attention(q, k, v).sum()
 
-        g1 = jax.grad(loss_chunked, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g1 = jit_once(jax.grad(loss_chunked, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jit_once(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -102,8 +103,8 @@ class TestFlash:
         def loss_ref(q, k, v):
             return _reference_attention(q, k, v).sum()
 
-        g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g1 = jit_once(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jit_once(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -121,14 +122,14 @@ class TestFlash:
         def loss_ref(b):
             return (_reference_attention(q, k, v, bias=b) ** 2).sum()
 
-        g1 = jax.grad(loss_flash)(bias0)
-        g2 = jax.grad(loss_ref)(bias0)
+        g1 = jit_once(jax.grad(loss_flash))(bias0)
+        g2 = jit_once(jax.grad(loss_ref))(bias0)
         assert float(jnp.abs(g1).max()) > 0
         np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
 
     def test_under_jit(self):
         q, k, v = _qkv(jax.random.key(7))
-        out = jax.jit(lambda *a: flash_attention(*a, block_q=8,
+        out = jit_once(lambda *a: flash_attention(*a, block_q=8,
                                                  block_k=64))(q, k, v)
         np.testing.assert_allclose(out, _reference_attention(q, k, v),
                                    atol=1e-5, rtol=1e-5)
@@ -152,8 +153,8 @@ class TestFlash:
         def loss_ref(q, k, v):
             return (_reference_attention(q, k, v, bias=bias) ** 2).sum()
 
-        g1 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g1 = jit_once(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jit_once(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -311,7 +312,7 @@ class TestDropoutTracesUnderEveryImpl:
 
         # trace + lower (no compile/run: the degrade fires at trace
         # time, which is where the old NotImplementedError lived)
-        jax.jit(step).lower(params)
+        jit_once(step).lower(params)
 
 
 class TestChunkedDropout:
@@ -346,7 +347,7 @@ class TestChunkedDropout:
         checked loosely over many independent masks."""
         q, k, v = _qkv(jax.random.key(6), b=1, h=1, lq=4, lk=32, d=8)
         base = chunked_attention(q, k, v, chunk_size=16)
-        one = jax.jit(lambda r: chunked_attention(
+        one = jit_once(lambda r: chunked_attention(
             q, k, v, chunk_size=16, dropout_rate=0.2, rng=r))
         outs = jax.vmap(one)(jax.random.split(jax.random.key(0), 200))
         np.testing.assert_allclose(jnp.mean(outs, axis=0), base, atol=0.08)
@@ -369,7 +370,7 @@ class TestChunkedDropout:
                                      dropout_rate=0.2,
                                      rng=jax.random.key(3)).sum()
 
-        grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        grads = jit_once(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
         for g in grads:
             assert jnp.all(jnp.isfinite(g))
             assert jnp.any(g != 0)
@@ -392,8 +393,8 @@ class TestQueryChunking:
         def loss_b(q, k, v):
             return _reference_attention(q, k, v).sum()
 
-        g1 = jax.grad(loss_a, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_b, argnums=(0, 1, 2))(q, k, v)
+        g1 = jit_once(jax.grad(loss_a, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jit_once(jax.grad(loss_b, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -462,8 +463,8 @@ def test_fused_gradients_match_materialised_core(family, masking, dtype):
     """dq, dk, dv of the kernel backward against ``_sdpa_bwd``, under
     one random cotangent."""
     fused, materialised, qkv, g, tol = _fused_case(family, masking, dtype)
-    grads = jax.vjp(fused, *qkv)[1](g)
-    refs = jax.vjp(materialised, *qkv)[1](g)
+    grads = jit_once(lambda *a: jax.vjp(fused, *a)[1](g))(*qkv)
+    refs = jit_once(lambda *a: jax.vjp(materialised, *a)[1](g))(*qkv)
     for name, a, b in zip("qkv", grads, refs):
         assert a.dtype == b.dtype, name
         scale = float(jnp.abs(b.astype(jnp.float32)).max())

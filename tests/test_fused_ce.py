@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.ops.fused_ce import (
     fused_linear_cross_entropy,
@@ -44,12 +45,12 @@ def problem():
 
 def test_fused_matches_dense(problem):
     params, hidden, labels, weight = problem
-    dense, gd = jax.value_and_grad(_dense_loss)(params, hidden, labels,
-                                                weight)
-    fused, gf = jax.value_and_grad(
+    dense, gd = jit_once(jax.value_and_grad(_dense_loss))(
+        params, hidden, labels, weight)
+    fused, gf = jit_once(jax.value_and_grad(
         lambda p: fused_linear_cross_entropy(p, hidden, labels, weight,
                                              chunk_size=32, policy=POLICY)
-    )(params)
+    ))(params)
     np.testing.assert_allclose(dense, fused, rtol=1e-6)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-6),
                  gd, gf)
@@ -71,9 +72,9 @@ def test_packed_matches_dense(problem):
         return fused_linear_cross_entropy(p, h, y, w, chunk_size=16,
                                           policy=POLICY)
 
-    dense, gd = jax.value_and_grad(_dense_loss)(params, hidden, labels,
-                                                weight)
-    packed, gp = jax.value_and_grad(packed_loss)(params)
+    dense, gd = jit_once(jax.value_and_grad(_dense_loss))(
+        params, hidden, labels, weight)
+    packed, gp = jit_once(jax.value_and_grad(packed_loss))(params)
     np.testing.assert_allclose(dense, packed, rtol=1e-6)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-6),
                  gd, gp)
@@ -121,9 +122,9 @@ def test_mlm_task_reports_overflow_at_small_batch():
         "input_ids": jnp.asarray(rng.integers(3, 64, (4, 24)), jnp.int32),
         "pad_mask": jnp.zeros((4, 24), bool),
     }
-    loss, metrics = task.loss_and_metrics(
-        model, params, batch, rng=jax.random.key(7), deterministic=True,
-        policy=POLICY)
+    loss, metrics = jit_once(lambda p, b: task.loss_and_metrics(
+        model, p, b, rng=jax.random.key(7), deterministic=True,
+        policy=POLICY))(params, batch)
     assert "ce_overflow" in metrics
     assert int(metrics["ce_overflow"]) > 0
     assert np.isfinite(float(loss))
@@ -137,9 +138,9 @@ def test_mlm_task_reports_overflow_at_small_batch():
         num_encoder_self_attention_heads=2,
         num_decoder_cross_attention_heads=2, loss_impl="packed",
         ce_chunk_size=32)
-    _, metrics = task_ok.loss_and_metrics(
-        model, params, batch, rng=jax.random.key(7), deterministic=True,
-        policy=POLICY)
+    _, metrics = jit_once(lambda p, b: task_ok.loss_and_metrics(
+        model, p, b, rng=jax.random.key(7), deterministic=True,
+        policy=POLICY))(params, batch)
     assert int(metrics["ce_overflow"]) == 0
 
 
@@ -152,8 +153,9 @@ def test_hidden_grad_matches(problem):
         return fused_linear_cross_entropy(params, hp, y, w, chunk_size=32,
                                           policy=POLICY)
 
-    gd = jax.grad(_dense_loss, argnums=1)(params, hidden, labels, weight)
-    gp = jax.grad(packed_loss)(hidden)
+    gd = jit_once(jax.grad(
+        _dense_loss, argnums=1))(params, hidden, labels, weight)
+    gp = jit_once(jax.grad(packed_loss))(hidden)
     np.testing.assert_allclose(gd, gp, atol=1e-6)
 
 
@@ -179,9 +181,9 @@ def test_mlm_task_loss_impls_agree(impl):
             "pad_mask": jnp.asarray(rng.random((4, 24)) < 0.1),
             "valid": jnp.asarray([True, True, True, False]),
         }
-        loss, _ = task.loss_and_metrics(
-            model, params, batch, rng=jax.random.key(7), deterministic=True,
-            policy=POLICY)
+        loss, _ = jit_once(lambda p, b: task.loss_and_metrics(
+            model, p, b, rng=jax.random.key(7), deterministic=True,
+            policy=POLICY))(params, batch)
         return float(loss)
 
     dense, other = task_loss("dense"), task_loss(impl)

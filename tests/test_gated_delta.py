@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -62,7 +63,7 @@ def out_and_grads(fn, args, w):
         out, vjp = jax.vjp(fn, *a)
         return out, vjp(w)
 
-    return jax.jit(both)(*args)
+    return jit_once(both)(*args)
 
 
 # --- the chunked rule --------------------------------------------------------
@@ -167,7 +168,7 @@ def test_how_many_heads_a_pass_holds(rows, seq, key_heads, heads, want):
 
 def test_heads_in_several_passes_are_the_same_rule(monkeypatch):
     args = rule_inputs(40, key_heads=4, heads=8)
-    whole = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=16))(*args)
+    whole = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=16))(*args)
     # room for one key head's two value heads a pass: four passes
     monkeypatch.setattr(dr, "PASS_HEAD_CHUNKS", 2 * 3 * 2)
     w = jax.random.normal(jax.random.key(3), whole.shape)
@@ -184,7 +185,7 @@ def test_bfloat16_operands_stay_near_the_float32_rule():
     """The products' operands in bfloat16, decays and the inverse in
     float32: a rounding's distance from the recurrence, not more."""
     q, k, v, g, beta = rule_inputs(64)
-    low = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=16))(
+    low = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=16))(
         *(x.astype(jnp.bfloat16) for x in (q, k, v)), g, beta)
     assert low.dtype == jnp.bfloat16
     assert rel(low.astype(jnp.float32), recurrence(q, k, v, g, beta)) < 0.03
@@ -231,11 +232,11 @@ def test_the_mixer_against_the_reference(mixer):
     def want_fn(p, a):
         return ref.delta_mixer(p, a, CFG, "f32")
 
-    got, got_g = jax.jit(jax.value_and_grad(
+    got, got_g = jit_once(jax.value_and_grad(
         lambda p, a: (got_fn(p, a) * w).sum(), argnums=(0, 1)))(p, a)
-    want, want_g = jax.jit(jax.value_and_grad(
+    want, want_g = jit_once(jax.value_and_grad(
         lambda p, a: (want_fn(p, a) * w).sum(), argnums=(0, 1)))(p, a)
-    assert rel(jax.jit(got_fn)(p, a), jax.jit(want_fn)(p, a)) < 2e-5
+    assert rel(jit_once(got_fn)(p, a), jit_once(want_fn)(p, a)) < 2e-5
     assert abs(got - want) < 2e-5 * abs(want) + 1e-6
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
                             jax.tree.leaves(want_g)):
@@ -271,10 +272,10 @@ def test_a_decay_rounded_to_bfloat16_is_not_the_mixer(mixer):
     float32 forms differ."""
     p, a = mixer
     q, k, v, g, beta = rule_inputs(128, decay=0.2)
-    rule = jax.jit(recurrence)
+    rule = jit_once(recurrence)
     want = rule(q, k, v, g, beta)
     low = rule(q, k, v, g.astype(jnp.bfloat16).astype(jnp.float32), beta)
-    exact = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=64))(
+    exact = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=64))(
         q, k, v, g, beta)
     assert rel(low, want) > 20 * rel(exact, want)
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -69,6 +70,21 @@ def toy():
     return task, model, params, {"input_ids": ids}
 
 
+@pytest.fixture(scope="module")
+def step(toy):
+    """``(params, ids) -> ((loss, (metrics, logits)), gradients)``, the
+    one jitted program the stack's cases share: the logits ride beside
+    the loss, the row of tokens is an operand."""
+    task, model, _, _ = toy
+
+    def loss_metrics_logits(p, ids):
+        loss, metrics = task.loss_and_metrics(model, p, {"input_ids": ids},
+                                              policy=FP32)
+        return loss, (metrics, model.apply(p, ids, policy=FP32))
+
+    return jit_once(jax.value_and_grad(loss_metrics_logits, has_aux=True))
+
+
 def mixer_case(toy, name):
     _, model, params, _ = toy
     p = params["layers"][name]["mixer"]
@@ -78,15 +94,20 @@ def mixer_case(toy, name):
 
 
 def assert_same_with_gradient(got_fn, want_fn, p, a, w, tol=2e-5):
-    got, got_g = jax.jit(jax.value_and_grad(
-        lambda p, a: (got_fn(p, a) * w).sum(), argnums=(0, 1)))(p, a)
-    want, want_g = jax.jit(jax.value_and_grad(
-        lambda p, a: (want_fn(p, a) * w).sum(), argnums=(0, 1)))(p, a)
+    def both(fn):    # value, output and gradients: one program a side
+        def weighted(p, a):
+            out = fn(p, a)
+            return (out * w).sum(), out
+        return jit_once(jax.value_and_grad(weighted, argnums=(0, 1),
+                                          has_aux=True))(p, a)
+
+    ((got, got_out), got_g), ((want, want_out), want_g) = \
+        both(got_fn), both(want_fn)
     assert abs(got - want) < tol * abs(want) + 1e-6
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
                             jax.tree.leaves(want_g)):
         assert rel(g, r) < 10 * tol, jax.tree_util.keystr(path)
-    assert rel(jax.jit(got_fn)(p, a), jax.jit(want_fn)(p, a)) < tol
+    assert rel(got_out, want_out) < tol
 
 
 # --- the tree ----------------------------------------------------------------
@@ -113,7 +134,7 @@ def test_the_tree_is_the_patterns(toy):
     assert set(experts["shared"]) == {"gate", "up", "down"}
     assert experts["shared_gate"]["w"].shape == (48, 1)
     assert experts["experts"]["gate"]["w"].shape == (4, 48, 40)
-    init = model.init(jax.random.key(3))
+    init = jit_once(model.init)(jax.random.key(3))
     assert jax.tree.structure(init) == jax.tree.structure(params)
     np.testing.assert_array_equal(init["norm"]["bias"], 0.0)
 
@@ -200,7 +221,7 @@ def test_the_gate_is_applied_outside_the_core(toy):
     core's operations carry neither the gate nor its scope."""
     model, p, a, _ = mixer_case(toy, "04_attn")
     rope = tuple(jnp.asarray(t) for t in rope_tables(40, 4, 1e4))
-    text = jax.jit(lambda p, a: hybrid_lm.rotary_gqa_apply(
+    text = jit_once(lambda p, a: hybrid_lm.rotary_gqa_apply(
         p, a, num_heads=4, num_kv_heads=2, policy=FP32, rope=rope,
         output_gate=True)).lower(p, a).compile().as_text()
     gated = [ln for ln in text.splitlines() if "attn_gate" in ln]
@@ -274,11 +295,14 @@ def test_sixteen_shares_add_up_to_the_uncut_layer(held):
     flat = a.reshape(-1, 48)
     shared = ref.shared_expert(whole, flat, "f32").reshape(a.shape)
     routed, loads = 0.0, 0
+    # the share's first expert is an operand: one program for all shares
+    share = jit_once(lambda part, first: moe.moe_apply(
+        part, a, top_k=3, first_expert=first, scoring="softmax",
+        policy=FP32))
     for first in range(0, 16, held):
         part = {**whole, "experts": jax.tree.map(
             lambda x: x[first:first + held], whole["experts"])}
-        out, load = moe.moe_apply(part, a, top_k=3, first_expert=first,
-                                  scoring="softmax", policy=FP32)
+        out, load = share(part, first)
         assert load.shape == (held,)
         routed, loads = routed + (out - shared), loads + int(load.sum())
         # the reference is given the same share
@@ -294,25 +318,23 @@ def test_sixteen_shares_add_up_to_the_uncut_layer(held):
 # --- the stack ---------------------------------------------------------------
 
 
-def test_logits_against_the_reference(toy):
+def test_logits_against_the_reference(toy, step):
     _, model, params, batch = toy
     ids = batch["input_ids"]
-    got = jax.jit(lambda p: model.apply(p, ids, policy=FP32))(params)
-    want = jax.jit(lambda p: ref.logits(p, ids, TOY))(params)
+    got = step(params, ids)[0][1][1]
+    want = jit_once(lambda p: ref.logits(p, ids, TOY))(params)
     assert got.shape == want.shape == (2, 40, 256)
     np.testing.assert_allclose(got, want, atol=5e-4, rtol=1e-4)
     # causal: a later token does not move an earlier position
     moved = ids.at[:, 30].set((ids[:, 30] + 1) % 256)
-    after = jax.jit(lambda p: model.apply(p, moved, policy=FP32))(params)
+    after = step(params, moved)[0][1][1]
     np.testing.assert_allclose(after[:, :30], got[:, :30], atol=1e-5)
     assert rel(after[:, 30:], got[:, 30:]) > 1e-3
 
 
-def test_loss_and_gradient_leaf_by_leaf_against_the_reference(toy):
+def test_loss_and_gradient_leaf_by_leaf_against_the_reference(toy, step):
     task, model, params, batch = toy
-    (loss, metrics), grads = jax.jit(jax.value_and_grad(
-        lambda p: task.loss_and_metrics(model, p, batch, policy=FP32),
-        has_aux=True))(params)
+    (loss, (metrics, _)), grads = step(params, batch["input_ids"])
     rb = bench_causal.reference_batches(
         [{"input_ids": np.asarray(batch["input_ids"])}], TOY, 0, 1)[0]
     want_loss, want = ref_steps.loss_and_grads(
@@ -345,7 +367,7 @@ def test_a_sigmoid_routed_stack_logs_no_full_buffer_counter():
         moe_intermediate_size=8, moe_shared_expert_intermediate_size=8,
         max_seq_len=16)
     model = task.build()
-    params = model.init(jax.random.key(0))
+    params = jax.eval_shape(model.init, jax.random.key(0))
     metrics = jax.eval_shape(lambda p: task.loss_and_metrics(
         model, p, {"input_ids": jnp.zeros((1, 16), jnp.int32)},
         policy=FP32)[1], params)
@@ -356,12 +378,12 @@ def test_a_sigmoid_routed_stack_logs_no_full_buffer_counter():
 def test_a_batch_may_name_each_expert_layers_share(toy):
     task, model, params, batch = toy
     firsts = jnp.asarray([[0, 8, 12]] * 2, jnp.int32)
-    got = jax.jit(lambda p, b: task.loss_and_metrics(
+    got = jit_once(lambda p, b: task.loss_and_metrics(
         model, p, b, policy=FP32)[0])(
             params, {**batch, "first_experts": firsts})
     rb = bench_causal.reference_batches(
         [{"input_ids": np.asarray(batch["input_ids"])}], TOY, 0, 1)[0]
-    loss_sum = jax.jit(lambda p, b: ref.loss_sum(p, b, TOY, "f32"))
+    loss_sum = jit_once(lambda p, b: ref.loss_sum(p, b, TOY, "f32"))
     s, n = loss_sum(params, {**rb, "first_experts": firsts})
     assert abs(got - s / n) < 2e-5 * float(s / n)
     s0, n0 = loss_sum(params, rb)
@@ -376,7 +398,7 @@ def test_remat_names_what_a_linear_layer_makes(toy):
     model = dataclasses.replace(task, remat=True).build()
     with remat.remat_keeps() as choices, \
             delta_rule.rule_paths.counting() as rules:
-        loss = jax.jit(lambda p: task.loss_and_metrics(
+        loss = jit_once(lambda p: task.loss_and_metrics(
             model, p, batch, policy=FP32)[0]).lower(params)
     del loss
     assert dict(rules) == {"chunked[16x3+pad,4 heads a pass]": 2}
@@ -394,15 +416,12 @@ def test_remat_names_what_a_linear_layer_makes(toy):
     assert choice["bytes"]["qkv"] == 4 * rows * 4 * 2 * 16
 
 
-def test_remat_gradients_are_the_plain_ones(toy):
+def test_remat_gradients_are_the_plain_ones(toy, step):
     task, _, params, batch = toy
     model = dataclasses.replace(task, remat=True).build()
-
-    def grad(m):
-        return jax.jit(jax.value_and_grad(lambda p: task.loss_and_metrics(
-            m, p, batch, policy=FP32)[0]))(params)
-
-    (kept, kept_g), (plain, plain_g) = grad(model), grad(task.build())
+    kept, kept_g = jit_once(jax.value_and_grad(lambda p: task.loss_and_metrics(
+        model, p, batch, policy=FP32)[0]))(params)
+    (plain, _), plain_g = step(params, batch["input_ids"])
     assert abs(plain - kept) < 1e-6 * abs(plain)
     for a, b in zip(jax.tree.leaves(kept_g), jax.tree.leaves(plain_g)):
         assert rel(a, b) < 1e-5
@@ -412,27 +431,30 @@ def test_remat_gradients_are_the_plain_ones(toy):
 
 
 def test_the_script_trains_the_pattern(tmp_path, capfd):
-    """``scripts/hybrid_lm.py fit`` with the tiny YAML: the stack
-    ``LELELE*E`` through ``Trainer.fit()``, and what the trainer says
-    while the step is loaded."""
+    """``scripts/hybrid_lm.py fit`` with the tiny YAML, cut to one layer
+    of each kind (``LE*E``, as the Kimi file cuts its own: what a layer
+    costs here is its compile, and the trainer says the same of one
+    linear layer as of three) through ``Trainer.fit()``, and what the
+    trainer says while the step is loaded."""
     sys.path.insert(0, os.path.join(ROOT, "scripts"))
     import hybrid_lm as cli
 
     cli.main([
         "fit", "--config",
         os.path.join(ROOT, "scripts", "configs", "gated_delta_lm_1chip.yaml"),
+        "--model.hybrid_override_pattern=LE*E",
         "--data.max_seq_len=40", "--data.batch_size=8",
         "--data.vocab_size=300", "--trainer.fast_dev_run=true",
         "--trainer.accelerator=cpu", "--trainer.precision=32",
         f"--trainer.default_root_dir={tmp_path}"])
     out, err = capfd.readouterr()
-    assert "[step_load] delta rules: chunked[40x1,4 heads a pass]=3" in err, \
+    assert "[step_load] delta rules: chunked[40x1,4 heads a pass]=1" in err, \
         err
     assert "[step_load] expert kinds: gated shared expert under a sigmoid " \
-        "gate=4 gated silu x3 products=4 softmax top" in err
+        "gate=2 gated silu x3 products=2 softmax top" in err
     assert re.search(r"remat keeps: \S*delta_out,delta_in,moe_plan \+ "
                      r"layer_in", err), err
-    assert "[step_load] short convolutions: xla[256ch, norm 128, backend]=3" \
+    assert "[step_load] short convolutions: xla[256ch, norm 128, backend]=1" \
         in err
     assert "selective scans" not in err
     assert re.search(r"\[step 1\] loss=\d+\.\d+ .*moe_assignments=", out + err)

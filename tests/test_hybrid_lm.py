@@ -2,13 +2,13 @@
 (``models/hybrid_lm.py``, ``ops/ssm.py``, ``ops/moe.py``): each mixer's
 forward pass and gradient against the plain reference's, the chunked
 scan against the position-by-position recurrence (a length that is no
-multiple of the chunk, decays that underflow) on both its executors
-(the einsums; the Pallas kernels of ``ops/pallas_ssm.py``, interpreted),
-which of the two a call takes, the share tied to the
-model (16 shares of the experts add up to the uncut layer, the shared
-expert once), no dropped token under a forced imbalance, grouped queries
-against repeated keys and values, no position embedding, what ``remat``
-keeps, and what the trainer says and logs."""
+multiple of the chunk, decays that underflow) as einsums (the Pallas
+kernels of ``ops/pallas_ssm.py`` have ``tests/test_pallas_ssm.py``),
+the share tied to the model (16 shares of the experts add up to the
+uncut layer, the shared expert once), no dropped token under a forced
+imbalance, grouped queries against repeated keys and values, no
+position embedding, what ``remat`` keeps, and what the trainer says and
+logs."""
 
 import dataclasses
 import json
@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -61,6 +62,17 @@ def toy():
     return task, model, params, {"input_ids": ids}
 
 
+@pytest.fixture(scope="module")
+def plain_step(toy):
+    """``((loss, metrics), gradients)`` of the toy as plain autodiff
+    gives them, without remat: the fixed side of every case that asks
+    whether something else is the same, computed once."""
+    task, model, params, batch = toy
+    return jit_once(jax.value_and_grad(
+        lambda p: task.loss_and_metrics(model, p, batch, policy=FP32),
+        has_aux=True))(params)
+
+
 def scan_inputs(seq, *, dt_scale=1.0, a_scale=1.0, rows=2, heads=4,
                 width=8, groups=2, state=16):
     k = jax.random.split(jax.random.key(seq), 5)
@@ -88,12 +100,12 @@ def test_the_chunked_scan_is_the_recurrence(seq):
     7 is shorter than one."""
     args = scan_inputs(seq)
     with ssm.scan_paths.counting() as forms:
-        got = ssm.ssm_scan(*args, chunk_size=16)
+        got = jit_once(lambda *a: ssm.ssm_scan(*a, chunk_size=16))(*args)
     chunk = min(16, seq)
     pad = "+pad" if seq % chunk else ""
     assert dict(forms) == {
         f"chunked[{chunk}x{-(-seq // chunk)}{pad},backend]": 1}
-    want = recurrence(*args)
+    want = jit_once(recurrence)(*args)
     assert got.shape == want.shape == args[0].shape
     assert rel(got, want) < 1e-5
 
@@ -101,12 +113,23 @@ def test_the_chunked_scan_is_the_recurrence(seq):
 def test_the_scans_gradient_is_the_recurrences():
     args = scan_inputs(40)
     w = jax.random.normal(jax.random.key(9), args[0].shape)
-    got = jax.grad(lambda *a: (ssm.ssm_scan(*a, chunk_size=16) * w).sum(),
-                   argnums=range(5))(*args)
-    want = jax.grad(lambda *a: (recurrence(*a) * w).sum(),
-                    argnums=range(5))(*args)
+    got = jit_once(jax.grad(
+        lambda *a: (ssm.ssm_scan(*a, chunk_size=16) * w).sum(),
+        argnums=range(5)))(*args)
+    want = jit_once(jax.grad(lambda *a: (recurrence(*a) * w).sum(),
+                            argnums=range(5)))(*args)
     for g, r in zip(got, want):
         assert rel(g, r) < 2e-5
+
+
+def out_and_cotangents_of_ones(scan, args):
+    """A scan's output and what its backward gives for a cotangent of
+    ones, as one jitted program."""
+    def run(*a):
+        out, vjp = jax.vjp(scan, *a)
+        return out, vjp(jnp.ones_like(out))
+
+    return jit_once(run)(*args)
 
 
 def test_decays_that_underflow_do_so_quietly():
@@ -115,176 +138,15 @@ def test_decays_that_underflow_do_so_quietly():
     backward, and the result is still the recurrence's."""
     args = scan_inputs(40, dt_scale=5.0, a_scale=8.0)
     assert float((args[1] * args[2]).min()) < -40
-    got, vjp = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk_size=16), *args)
-    grads = vjp(jnp.ones_like(got))
+    got, grads = out_and_cotangents_of_ones(
+        lambda *a: ssm.ssm_scan(*a, chunk_size=16), args)
     assert all(bool(jnp.isfinite(g).all()) for g in (got, *grads))
-    assert rel(got, recurrence(*args)) < 1e-5
+    assert rel(got, jit_once(recurrence)(*args)) < 1e-5
     # in the compute dtype of the chip too
     low = ssm.ssm_scan(args[0].astype(jnp.bfloat16), args[1], args[2],
                        *(v.astype(jnp.bfloat16) for v in args[3:]),
                        chunk_size=16)
     assert low.dtype == jnp.bfloat16 and bool(jnp.isfinite(low).all())
-
-
-# --- the fused scan: the kernels, interpreted --------------------------------
-# The smallest shapes the pick admits: chunks of 128 positions, a state
-# of 128, eight heads a group in whole slabs of 128 lanes.
-
-FUSED = {
-    # eight heads of 16 share one slab; two chunks: a carried state
-    "one_slab": dict(seq=256, rows=1, heads=8, width=16, groups=1),
-    # the cell's layout in small: two heads of 64 a slab, four slabs a
-    # group, two groups, two rows
-    "four_slabs": dict(seq=256, rows=2, heads=16, width=64, groups=2),
-    # a padded last chunk
-    "padded_tail": dict(seq=200, rows=1, heads=8, width=16, groups=1),
-}
-
-
-def as_a_tpu(monkeypatch):
-    """The pick as a TPU would make it; off the chip the kernels run
-    interpreted (``utils/platform.resolve_interpret``)."""
-    monkeypatch.setattr(ssm, "_backend", lambda: "tpu")
-
-
-def fused_inputs(dtype=jnp.float32, dt_scale=0.1, **shape):
-    x, dt, a, b, c = scan_inputs(state=128, dt_scale=dt_scale, **shape)
-    # values the compute dtype holds, so that every form starts alike
-    return (x.astype(dtype), dt, a, b.astype(dtype) / 4, c.astype(dtype) / 4)
-
-
-def in_float32(args):
-    return tuple(v.astype(jnp.float32) for v in args)
-
-
-def value_and_grads(fn, args, w):
-    return jax.value_and_grad(
-        lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
-        argnums=range(5))(*args)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", FUSED)
-def test_the_fused_scan_is_the_recurrence(case, dtype, monkeypatch):
-    """Forward and every gradient (x, dt, A, B, C) against the
-    position-by-position recurrence in float32; bfloat16 operands to
-    what bfloat16 products allow."""
-    as_a_tpu(monkeypatch)
-    args = fused_inputs(dtype, **FUSED[case])
-    seq = FUSED[case]["seq"]
-    w = jax.random.normal(jax.random.key(9), args[0].shape)
-    with ssm.scan_paths.counting() as forms:
-        got = ssm.ssm_scan(*args, chunk_size=128)
-    assert dict(forms) == {
-        f"fused[128x2{'+pad' if seq % 128 else ''}]": 1}
-    assert got.shape == args[0].shape and got.dtype == dtype
-    _, grads = value_and_grads(
-        lambda *a: ssm.ssm_scan(*a, chunk_size=128), args, w)
-    want = recurrence(*in_float32(args))
-    _, want_grads = value_and_grads(recurrence, in_float32(args), w)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    assert rel(got, want) < tol
-    for g, r, v in zip(grads, want_grads, args):
-        assert g.shape == v.shape and g.dtype == v.dtype
-        assert rel(g, r) < tol
-
-
-def test_the_fused_scan_underflows_quietly(monkeypatch):
-    """As ``test_decays_that_underflow_do_so_quietly``, on the kernels:
-    the mask goes in before the ``exp`` there too, forward and
-    backward."""
-    as_a_tpu(monkeypatch)
-    args = fused_inputs(dt_scale=5.0, a_scale=8.0, **FUSED["one_slab"])
-    assert float((args[1] * args[2]).min()) < -40
-    got, vjp = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk_size=128), *args)
-    grads = vjp(jnp.ones_like(got))
-    assert all(bool(jnp.isfinite(g).all()) for g in (got, *grads))
-    assert rel(got, recurrence(*args)) < 1e-5
-    low = (args[0].astype(jnp.bfloat16), args[1], args[2],
-           *(v.astype(jnp.bfloat16) for v in args[3:]))
-    got, vjp = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk_size=128), *low)
-    grads = vjp(jnp.ones_like(got))
-    assert got.dtype == jnp.bfloat16
-    assert all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
-               for g in (got, *grads))
-
-
-def test_the_fused_scan_rounds_no_more_than_the_einsums(monkeypatch):
-    """At bfloat16 the kernels are no further from the float32
-    recurrence than the einsum form is: the forward is the same
-    arithmetic to the last bit or two; the backward rounds a cotangent
-    only where a product takes it as an operand (on the chip the einsum
-    form's products round their float32 operands too, which the CPU's
-    do not, hence a quarter of room on the gradients)."""
-    args = fused_inputs(jnp.bfloat16, **FUSED["four_slabs"])
-    w = jax.random.normal(jax.random.key(9), args[0].shape)
-    want, want_grads = value_and_grads(recurrence, in_float32(args), w)
-
-    def errors(fn):
-        got, grads = value_and_grads(fn, args, w)
-        return [abs(float(got) - float(want)) / abs(float(want))] + [
-            float(jnp.linalg.norm((g.astype(jnp.float32) - r).ravel())
-                  / jnp.linalg.norm(r.ravel()))
-            for g, r in zip(grads, want_grads)]
-
-    chunked = errors(lambda *a: ssm.ssm_scan(*a, chunk_size=128))
-    as_a_tpu(monkeypatch)
-    fused = errors(lambda *a: ssm.ssm_scan(*a, chunk_size=128))
-    assert fused[0] <= chunked[0] + 1e-6
-    for f, c in zip(fused[1:], chunked[1:]):
-        assert f <= 1.25 * c
-
-
-# (backend, mesh devices, chunk, state, heads a group, head dim)
-SCAN_CHOICES = {
-    "nemotron_train": (("tpu", 1, 128, 128, 8, 64), ("fused", None)),
-    "heads_of_128": (("tpu", 1, 256, 256, 8, 128), ("fused", None)),
-    "eight_heads_of_16": (("tpu", 1, 128, 128, 8, 16), ("fused", None)),
-    "cpu": (("cpu", 1, 128, 128, 8, 64), ("chunked", "backend")),
-    "gpu": (("gpu", 1, 128, 128, 8, 64), ("chunked", "backend")),
-    "dp2_tp2_mesh": (("tpu", 4, 128, 128, 8, 64), ("chunked", "mesh")),
-    "chunk_of_16": (("tpu", 1, 16, 128, 8, 64), ("chunked", "shape")),
-    "row_shorter_than_a_chunk": (("tpu", 1, 40, 128, 8, 64),
-                                 ("chunked", "shape")),
-    "state_of_16": (("tpu", 1, 128, 16, 8, 64), ("chunked", "shape")),
-    "heads_of_48": (("tpu", 1, 128, 128, 8, 48), ("chunked", "shape")),
-    "half_a_slab": (("tpu", 1, 128, 128, 1, 64), ("chunked", "shape")),
-    "four_heads_a_group": (("tpu", 1, 128, 128, 4, 64),
-                           ("chunked", "shape")),
-    # the first reason in CHUNKED_REASONS' order wins
-    "backend_before_mesh": (("cpu", 4, 16, 16, 2, 8),
-                            ("chunked", "backend")),
-    "mesh_before_shape": (("tpu", 4, 16, 16, 2, 8), ("chunked", "mesh")),
-}
-
-
-@pytest.mark.parametrize("case", SCAN_CHOICES)
-def test_pick_scan(case):
-    (backend, mesh, chunk, state, per, width), want = SCAN_CHOICES[case]
-    got = ssm.pick_scan(backend=backend, mesh_devices=mesh, chunk=chunk,
-                        state=state, heads_per_group=per, head_dim=width)
-    assert got == want
-    assert got[1] is None or got[1] in ssm.CHUNKED_REASONS
-
-
-@pytest.mark.parametrize("case", ["chunk_of_16", "mesh"])
-def test_a_call_the_kernels_do_not_take_says_why(case, monkeypatch):
-    """On a TPU too the einsums run where the kernels cannot, and the
-    tally carries the reason beside the chunks."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    as_a_tpu(monkeypatch)
-    args = fused_inputs(**FUSED["one_slab"])
-    chunk = 16 if case == "chunk_of_16" else 128
-    if case == "mesh":
-        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
-        args = tuple(jax.device_put(v, NamedSharding(mesh, P()))
-                     for v in args)
-    with ssm.scan_paths.counting() as forms:
-        got = jax.jit(lambda *a: ssm.ssm_scan(*a, chunk_size=chunk))(*args)
-    reason = "shape" if case == "chunk_of_16" else "mesh"
-    assert dict(forms) == {f"chunked[{chunk}x{256 // chunk},{reason}]": 1}
-    assert rel(got, recurrence(*args)) < 1e-5
 
 
 # --- each mixer against the reference ----------------------------------------
@@ -298,15 +160,29 @@ def mixer_case(toy, name):
     return model, p, a, w
 
 
-def assert_same_with_gradient(got_fn, want_fn, p, a, w, tol=2e-5):
-    got, got_g = jax.value_and_grad(
-        lambda p, a: (got_fn(p, a) * w).sum(), argnums=(0, 1))(p, a)
-    want, want_g = jax.value_and_grad(
-        lambda p, a: (want_fn(p, a) * w).sum(), argnums=(0, 1))(p, a)
+def with_gradient(fn, w):
+    """``fn(p, a)``'s weighted sum, its output and both gradients as
+    one jitted program."""
+    def weighted(p, a):
+        out = fn(p, a)
+        return (out * w).sum(), out
+
+    return jit_once(jax.value_and_grad(weighted, argnums=(0, 1),
+                                      has_aux=True))
+
+
+def assert_programs_agree(got_step, want_step, p, a, tol=2e-5):
+    (got, got_out), got_g = got_step(p, a)
+    (want, want_out), want_g = want_step(p, a)
     assert abs(got - want) < tol * abs(want) + 1e-6
     for g, r in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         assert rel(g, r) < 10 * tol
-    assert rel(got_fn(p, a), want_fn(p, a)) < tol
+    assert rel(got_out, want_out) < tol
+
+
+def assert_same_with_gradient(got_fn, want_fn, p, a, w, tol=2e-5):
+    assert_programs_agree(with_gradient(got_fn, w), with_gradient(want_fn, w),
+                          p, a, tol)
 
 
 def test_the_ssm_mixer_against_the_reference(toy):
@@ -376,13 +252,15 @@ def test_sixteen_shares_add_up_to_the_uncut_layer(toy):
     a = jax.random.normal(jax.random.key(2), (2, 40, 48))
     uncut = ref.expert_layer(whole, a, {**TOY, "first_expert": 0}, "f32")
     shared = relu2_mlp_apply(whole["shared"], a, FP32)
+    # the share's first expert is an operand: one program a share size
+    share = jit_once(lambda part, first: moe.moe_apply(
+        part, a, top_k=3, first_expert=first, scaling=2.5, policy=FP32))
     for held in (1, 4):
         routed = 0.0
         for first in range(0, 16, held):
             part = {**whole, "experts": jax.tree.map(
                 lambda x: x[first:first + held], whole["experts"])}
-            out, load = moe.moe_apply(part, a, top_k=3, first_expert=first,
-                                      scaling=2.5, policy=FP32)
+            out, load = share(part, first)
             assert load.shape == (held,)
             routed = routed + (out - shared)
         assert rel(routed + shared, uncut) < 2e-5
@@ -409,27 +287,28 @@ def test_no_token_is_dropped_under_a_forced_imbalance(toy, backend,
         return moe.moe_apply(p, a, top_k=3, first_expert=4, scaling=2.5,
                              policy=FP32)
 
+    # the router's weights are operands: one pair of programs serves all
+    # three routers
+    programs = (with_gradient(lambda p, a: apply(p, a)[0], w),
+                with_gradient(lambda p, a: ref.expert_layer(p, a, TOY, "f32"),
+                              w))
+
     p = {**p, "router": {"w": forced}}
-    assert apply(p, a)[1].tolist() == [0, 80, 0, 0]
-    assert_same_with_gradient(
-        lambda p, a: apply(p, a)[0],
-        lambda p, a: ref.expert_layer(p, a, TOY, "f32"), p, a, w)
+    load_of = jit_once(lambda p, a: apply(p, a)[1])
+    assert load_of(p, a).tolist() == [0, 80, 0, 0]
+    assert_programs_agree(*programs, p, a)
     # every assignment top-k allows, all held: the whole of the larger
     # buffer is used
     p = {**p, "router": {"w": forced.at[:, 4].set(3.0).at[:, 6].set(3.5)
                          .at[:, :2].set(-4.0)}}
-    load = apply(p, a)[1]
+    load = load_of(p, a)
     assert load.tolist() == [80, 80, 80, 0] and int(load.sum()) == 80 * 3
-    assert_same_with_gradient(
-        lambda p, a: apply(p, a)[0],
-        lambda p, a: ref.expert_layer(p, a, TOY, "f32"), p, a, w)
+    assert_programs_agree(*programs, p, a)
     # and between the two: more than a row a token, less than all
     p = {**p, "router": {"w": forced.at[:, 4].set(3.0).at[:, :2].set(-4.0)}}
-    load = apply(p, a)[1]
+    load = load_of(p, a)
     assert 80 < int(load.sum()) < 240
-    assert_same_with_gradient(
-        lambda p, a: apply(p, a)[0],
-        lambda p, a: ref.expert_layer(p, a, TOY, "f32"), p, a, w)
+    assert_programs_agree(*programs, p, a)
 
 
 def test_the_router_is_float32_and_its_weights_sum_to_the_scaling(toy):
@@ -499,7 +378,8 @@ HYBRID = remat.HYBRID_REMAT_NAMES
 
 @pytest.mark.parametrize("kept", [(), HYBRID[:3], HYBRID],
                          ids=lambda k: "+".join(k) or "none")
-def test_remat_changes_no_value_whatever_is_kept(toy, kept, monkeypatch):
+def test_remat_changes_no_value_whatever_is_kept(toy, plain_step, kept,
+                                                 monkeypatch):
     task, model, params, batch = toy
     reckoned = {}
 
@@ -509,12 +389,10 @@ def test_remat_changes_no_value_whatever_is_kept(toy, kept, monkeypatch):
 
     monkeypatch.setattr(remat, "choose_keeps", choose)
 
-    def loss_and_grads(on):
-        t = dataclasses.replace(task, remat=on)
-        return jax.value_and_grad(lambda p: t.loss_and_metrics(
-            t.build(), p, batch, policy=FP32)[0])(params)
-
-    (loss, g), (plain, plain_g) = loss_and_grads(True), loss_and_grads(False)
+    t = dataclasses.replace(task, remat=True)
+    loss, g = jit_once(jax.value_and_grad(lambda p: t.loss_and_metrics(
+        t.build(), p, batch, policy=FP32)[0]))(params)
+    (plain, _), plain_g = plain_step
     assert abs(float(loss) - float(plain)) < 1e-5
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(plain_g)):
         assert rel(a, b) < 1e-4
@@ -527,35 +405,6 @@ def test_remat_changes_no_value_whatever_is_kept(toy, kept, monkeypatch):
     assert "moe_hidden" not in reckoned    # recomputed in any case
     assert reckoned["mlp_hidden"] == 2 * rows * 80
     assert reckoned["qkv"] == rows * 64 and reckoned["attn_out"] == 0
-
-
-@pytest.mark.parametrize("kept", [HYBRID, HYBRID[:3]],
-                         ids=["ssm_out_kept", "ssm_out_dropped"])
-def test_a_remat_layer_launches_the_scans_forward_kernel_once(kept,
-                                                              monkeypatch):
-    """The fused scan's backward takes the scan's operands alone: with
-    ``ssm_out`` kept the recomputed layer does not run the forward
-    kernel again (one launch a layer and step); with it dropped the
-    layer recomputes it, as it recomputes anything else it does not
-    hold. The backward's two kernels run once either way."""
-    from tests.test_looped_lm import kernel_calls
-    as_a_tpu(monkeypatch)
-    monkeypatch.setattr(remat, "choose_keeps", lambda *a, **k: kept)
-    task = HybridLMTask(**{
-        **TOY, "hybrid_override_pattern": "M", "mamba_head_dim": 16,
-        "n_groups": 1, "ssm_state_size": 128, "chunk_size": 128,
-        "max_seq_len": 256, "remat": True})
-    model = task.build()
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    batch = {"input_ids": jax.ShapeDtypeStruct((2, 256), jnp.int32)}
-    with ssm.scan_paths.counting() as forms:
-        step = jax.make_jaxpr(jax.grad(lambda p, b: task.loss_and_metrics(
-            model, p, b, policy=FP32)[0]))(params, batch).jaxpr
-    assert set(forms) == {"fused[128x2]"}
-    assert kernel_calls(step, "ssm_scan_fwd")[0] == (
-        1 if "ssm_out" in kept else 2)
-    assert kernel_calls(step, "ssm_scan_bwd_states")[0] == 1
-    assert kernel_calls(step, "ssm_scan_bwd")[0] == 2   # the states' too
 
 
 def test_the_names_list_is_the_stacks_own():
@@ -572,44 +421,55 @@ def test_the_names_list_is_the_stacks_own():
         remat.dear(jnp.ones(2), "ssm_state")
 
 
+@pytest.fixture(scope="module")
+def share_programs(toy):
+    """The step and both sides' logits with the shares as operands:
+    compiled by the first case, reused by the others."""
+    task, model, params, batch = toy
+    ids = batch["input_ids"]
+    return (
+        jit_once(jax.value_and_grad(
+            lambda p, b: task.loss_and_metrics(model, p, b, policy=FP32),
+            has_aux=True)),
+        jit_once(lambda p, f: model.apply(p, ids, first_experts=f,
+                                         policy=FP32)),
+        jit_once(lambda p, f: ref.logits(p, ids, TOY, first_experts=f)))
+
+
 @pytest.mark.parametrize("firsts", [(4, 4), (0, 12), (8, 0)])
 def test_a_share_named_by_the_batch_is_that_share_of_the_configuration(
-        toy, firsts):
+        toy, plain_step, share_programs, firsts):
     """``first_experts`` with the batch, one first expert an expert
     layer: the loss, its gradient and the loads are those of a model
     built with that share, and one program serves every share."""
     task, model, params, batch = toy
+    step, logits, reference_logits = share_programs
     named = {**batch, "first_experts": jnp.tile(
         jnp.asarray(firsts, jnp.int32), (2, 1))}
-    step = jax.jit(jax.value_and_grad(
-        lambda p, b: task.loss_and_metrics(model, p, b, policy=FP32),
-        has_aux=True))
     (loss, metrics), grads = step(params, named)
-    if firsts[0] == firsts[1]:
-        built = dataclasses.replace(task, first_expert=firsts[0])
-        (want, want_m), want_g = jax.value_and_grad(
-            lambda p: built.loss_and_metrics(
-                built.build(), p, batch, policy=FP32), has_aux=True)(params)
+    if firsts[0] == firsts[1]:    # the toy's own share, built into it
+        assert task.first_expert == firsts[0]
+        (want, want_m), want_g = plain_step
         assert float(loss) == pytest.approx(float(want), rel=1e-6)
         assert float(metrics["moe_assignments"]) \
             == float(want_m["moe_assignments"])
         for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_g)):
             assert rel(g, w) < 1e-5
     # the plain reference, told the same shares
-    want = ref.logits(params, batch["input_ids"], TOY,
-                      first_experts=jnp.asarray(firsts))
-    got = model.apply(params, batch["input_ids"],
-                      first_experts=jnp.asarray(firsts), policy=FP32)
-    np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-4)
+    np.testing.assert_allclose(
+        logits(params, jnp.asarray(firsts)),
+        reference_logits(params, jnp.asarray(firsts)), atol=2e-4, rtol=1e-4)
     assert step._cache_size() == 1
 
 
-def test_the_metrics_carry_the_assignments_and_the_imbalance(toy):
+def test_the_metrics_carry_the_assignments_and_the_imbalance(toy,
+                                                             plain_step):
     task, model, params, batch = toy
-    _, metrics = task.loss_and_metrics(model, params, batch, policy=FP32)
+    (_, metrics), _ = plain_step
     assert set(metrics) == {"loss", "moe_assignments",
                             "moe_load_max_over_mean"}
-    _, loads = model.hidden_states(params, batch["input_ids"], policy=FP32)
+    _, loads = jit_once(lambda p: model.hidden_states(
+        p, batch["input_ids"], policy=FP32))(params)
     assert loads.shape == (2, 4)
     assert float(metrics["moe_assignments"]) == float(loads.sum())
     # the first expert layer's load is the reference router's choice of

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -77,7 +78,7 @@ def out_and_grads(fn, args, w):
         out = fn(*a)
         return (out.astype(jnp.float32) * w).sum(), out
 
-    (total, out), grads = jax.jit(jax.value_and_grad(
+    (total, out), grads = jit_once(jax.value_and_grad(
         weighted, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
     return (total, grads), out
 
@@ -124,7 +125,7 @@ def test_decays_that_underflow_inside_a_chunk_stay_finite():
         assert rel(a, b) < F32_TOL, name
     # the fast channels matter: without them the output is another
     slow = (*args[:3], args[3].at[..., :3].set(-0.1), args[4])
-    assert rel(jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=64))(*slow),
+    assert rel(jit_once(lambda *a: dr.delta_rule(*a, chunk_size=64))(*slow),
                out) > 0.01
 
 
@@ -172,7 +173,7 @@ def test_a_decay_rounded_to_bfloat16_fails_the_float32_tolerance():
     float32: ``g`` through bfloat16 moves the float32 result by far
     more than ``F32_TOL``."""
     args, _ = operands(128)
-    rule = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=64))
+    rule = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=64))
     rounded = (*args[:3], args[3].astype(jnp.bfloat16).astype(jnp.float32),
                args[4])
     assert rel(rule(*rounded), rule(*args)) > 10 * F32_TOL
@@ -198,7 +199,7 @@ def test_fewer_heads_go_through_together():
 
 def test_the_rule_runs_under_its_own_scope():
     def scopes(*a):
-        text = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=16)).lower(
+        text = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=16)).lower(
             *a).as_text(debug_info=True)
         return {name for name in ("kda_rule", "delta_rule")
                 if f"/{name}/" in text}
@@ -212,8 +213,8 @@ def test_the_references_recurrence_is_the_scan():
     """``ref.recurrence`` (stretches of 128 positions, each a checkpoint)
     against the one scan above, on a row it pads."""
     args, _ = operands(37, key_heads=1)
-    np.testing.assert_allclose(jax.jit(ref.recurrence)(*args),
-                               jax.jit(recurrence)(*args), atol=1e-6)
+    np.testing.assert_allclose(jit_once(ref.recurrence)(*args),
+                               jit_once(recurrence)(*args), atol=1e-6)
 
 
 # --- the mixer ---------------------------------------------------------------
@@ -257,11 +258,11 @@ def test_the_mixer_against_the_reference(mixer):
     def reference(p, a):
         return ref.kda_mixer(p, a, CFG, "f32")
 
-    got, got_g = jax.jit(jax.value_and_grad(
+    got, got_g = jit_once(jax.value_and_grad(
         lambda p, a: (program(p, a) * w).sum(), argnums=(0, 1)))(p, a)
-    want, want_g = jax.jit(jax.value_and_grad(
+    want, want_g = jit_once(jax.value_and_grad(
         lambda p, a: (reference(p, a) * w).sum(), argnums=(0, 1)))(p, a)
-    assert rel(jax.jit(program)(p, a), jax.jit(reference)(p, a)) < F32_TOL
+    assert rel(jit_once(program)(p, a), jit_once(reference)(p, a)) < F32_TOL
     assert abs(got - want) < F32_TOL * abs(want) + 1e-6
     for (path, x), y in zip(
             jax.tree_util.tree_flatten_with_path(got_g)[0],

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -70,6 +71,21 @@ def toy():
     return task, model, params, {"input_ids": ids}
 
 
+@pytest.fixture(scope="module")
+def step(toy):
+    """``(params, ids) -> ((loss, (metrics, logits)), gradients)``, the
+    one jitted program the stack's cases share: the logits ride beside
+    the loss, the row of tokens is an operand."""
+    task, model, _, _ = toy
+
+    def loss_metrics_logits(p, ids):
+        loss, metrics = task.loss_and_metrics(model, p, {"input_ids": ids},
+                                              policy=FP32)
+        return loss, (metrics, model.apply(p, ids, policy=FP32))
+
+    return jit_once(jax.value_and_grad(loss_metrics_logits, has_aux=True))
+
+
 def mixer_case(toy, name):
     _, model, params, _ = toy
     p = params["layers"][name]["mixer"]
@@ -82,7 +98,7 @@ def assert_same_with_gradient(got_fn, want_fn, p, a, w, tol=TOL):
         def weighted(p, a):
             out = fn(p, a)
             return (out * w).sum(), out
-        return jax.jit(jax.value_and_grad(weighted, argnums=(0, 1),
+        return jit_once(jax.value_and_grad(weighted, argnums=(0, 1),
                                           has_aux=True))(p, a)
 
     ((got, got_out), got_g), ((want, want_out), want_g) = \
@@ -180,7 +196,7 @@ def test_padded_to_whole_lanes_is_unpadded(toy):
 
 def test_the_latent_call_runs_under_its_scopes(toy):
     _, p, a, _ = mixer_case(toy, "04_mla")
-    text = jax.jit(lambda p, a: hybrid_lm.mla_apply(
+    text = jit_once(lambda p, a: hybrid_lm.mla_apply(
         p, a, **MLA, policy=FP32)).lower(p, a).as_text(debug_info=True)
     assert "/mla_mixer/attn_proj/" in text
     assert "/mla_mixer/attn_core/" in text
@@ -231,7 +247,7 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer(held):
     shared = ref.gated_mlp(*(whole["shared"][n]["w"] for n in
                              ("gate", "up", "down")),
                            a.reshape(-1, 48), "f32").reshape(a.shape)
-    layer = jax.jit(expert_layer)
+    layer = jit_once(expert_layer)
     routed, loads = 0.0, 0
     for first in range(0, 32, held):
         part = {**whole, "experts": jax.tree.map(
@@ -268,11 +284,11 @@ def test_a_wrong_rule_fails_the_tolerance(toy, monkeypatch, fault):
         return rule(q, k, v, g, beta, **kw)
 
     def mixer(p, a):
-        return jax.jit(lambda p, a: delta_rule.kda_mixer_apply(
+        return jit_once(lambda p, a: delta_rule.kda_mixer_apply(
             p, a, num_heads=4, head_dim=8, chunk_size=16, eps=1e-5,
             policy=FP32))(p, a)
 
-    want = jax.jit(lambda p, a: ref.kda_mixer(p, a, TOY, "f32"))(p, a)
+    want = jit_once(lambda p, a: ref.kda_mixer(p, a, TOY, "f32"))(p, a)
     assert rel(mixer(p, a), want) < TOL
     monkeypatch.setattr(delta_rule, "delta_rule", planted)
     assert rel(mixer(p, a), want) > 10 * TOL
@@ -329,25 +345,23 @@ def test_a_gate_column_on_the_shared_expert_fails_the_tolerance(toy):
 # --- the stack ---------------------------------------------------------------
 
 
-def test_logits_against_the_reference(toy):
+def test_logits_against_the_reference(toy, step):
     _, model, params, batch = toy
     ids = batch["input_ids"]
-    got = jax.jit(lambda p: model.apply(p, ids, policy=FP32))(params)
-    want = jax.jit(lambda p: ref.logits(p, ids, TOY))(params)
+    got = step(params, ids)[0][1][1]
+    want = jit_once(lambda p: ref.logits(p, ids, TOY))(params)
     assert got.shape == want.shape == (2, 40, 256)
     np.testing.assert_allclose(got, want, atol=5e-4, rtol=1e-4)
     # causal: a later token does not move an earlier position
     moved = ids.at[:, 30].set((ids[:, 30] + 1) % 256)
-    after = jax.jit(lambda p: model.apply(p, moved, policy=FP32))(params)
+    after = step(params, moved)[0][1][1]
     np.testing.assert_allclose(after[:, :30], got[:, :30], atol=1e-5)
     assert rel(after[:, 30:], got[:, 30:]) > 1e-3
 
 
-def test_loss_and_gradient_leaf_by_leaf_against_the_reference(toy):
+def test_loss_and_gradient_leaf_by_leaf_against_the_reference(toy, step):
     task, model, params, batch = toy
-    (loss, metrics), grads = jax.jit(jax.value_and_grad(
-        lambda p: task.loss_and_metrics(model, p, batch, policy=FP32),
-        has_aux=True))(params)
+    (loss, (metrics, _)), grads = step(params, batch["input_ids"])
     rb = bench_causal.reference_batches(
         [{"input_ids": np.asarray(batch["input_ids"])}], TOY, 0, 1)[0]
     want_loss, want = ref_steps.loss_and_grads(
@@ -370,12 +384,12 @@ def test_loss_and_gradient_leaf_by_leaf_against_the_reference(toy):
 def test_a_batch_may_name_each_expert_layers_share(toy):
     task, model, params, batch = toy
     firsts = jnp.asarray([[0, 20]] * 2, jnp.int32)
-    got = jax.jit(lambda p, b: task.loss_and_metrics(
+    got = jit_once(lambda p, b: task.loss_and_metrics(
         model, p, b, policy=FP32)[0])(
             params, {**batch, "first_experts": firsts})
     rb = bench_causal.reference_batches(
         [{"input_ids": np.asarray(batch["input_ids"])}], TOY, 0, 1)[0]
-    loss_sum = jax.jit(lambda p, b: ref.loss_sum(p, b, TOY, "f32"))
+    loss_sum = jit_once(lambda p, b: ref.loss_sum(p, b, TOY, "f32"))
     s, n = loss_sum(params, {**rb, "first_experts": firsts})
     assert abs(got - s / n) < 2e-5 * float(s / n)
     s0, n0 = loss_sum(params, rb)
@@ -390,7 +404,7 @@ def test_remat_names_what_the_new_layers_make(toy):
     model = dataclasses.replace(task, remat=True).build()
     with remat.remat_keeps() as choices, \
             delta_rule.rule_paths.counting() as rules:
-        jax.jit(lambda p: task.loss_and_metrics(
+        jit_once(lambda p: task.loss_and_metrics(
             model, p, batch, policy=FP32)[0]).lower(params)
     assert dict(rules) == {
         "chunked[16x3+pad,4 heads a pass, by channel]": 2}

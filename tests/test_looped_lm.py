@@ -5,12 +5,14 @@ kernels' causal mode, the per-position fused CE whose weights take a
 gradient, and the weight-shared loop with its hand-written backward."""
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once, out_and_grads
 from jax.extend.core import Literal
 
 import perceiver_tpu.models.looped_lm as looped_lm
@@ -129,8 +131,9 @@ def causal_reference(q, k, v, heads, block_diffusion=None):
                     sees[j, l] = (l - half) // block < j // block
                 elif l >= half:
                     sees[j, l] = (l - half) // block <= (j - half) // block
-        assert (sees == ~np.asarray(
-            attn.block_diffusion_mask(half, block))).all()
+        with jax.ensure_compile_time_eval():    # the caller may be jitted
+            assert (sees == ~np.asarray(
+                attn.block_diffusion_mask(half, block))).all()
     bias = jnp.where(jnp.asarray(sees), 0.0, attn.NEG_INF)[None, None]
     split = [x.reshape(b, s, heads, e // heads) for x in (q, k, v)]
     out = attn._sdpa_core(1.0 / math.sqrt(e // heads), 0.0, jnp.float32,
@@ -161,12 +164,11 @@ def test_causal_kernels_match_the_materialised_core(seq, heads, dim,
               **(dict(causal=True) if block_diffusion is None
                  else dict(block_diffusion=block_diffusion)))
     heads = (heads, block_diffusion)
-    assert rel(flash_attention_channels(q, k, v, **kw),
-               causal_reference(q, k, v, *heads)) < 1e-5
-    got = jax.grad(lambda *a: (flash_attention_channels(*a, **kw)
-                               * g).sum(), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (causal_reference(*a, *heads) * g).sum(),
-                    (0, 1, 2))(q, k, v)
+    got_out, got = out_and_grads(
+        lambda *a: flash_attention_channels(*a, **kw), g, q, k, v)
+    want_out, want = out_and_grads(
+        lambda *a: causal_reference(*a, *heads), g, q, k, v)
+    assert rel(got_out, want_out) < 1e-5
     for a, b in zip(got, want):
         assert rel(a, b) < 1e-5
 
@@ -175,7 +177,7 @@ def test_causal_calls_carry_their_own_kernel_names():
     q = normal(20, (1, 256, 128))
 
     def names(**kw):
-        text = jax.jit(jax.grad(lambda q: flash_attention_channels(
+        text = jit_once(jax.grad(lambda q: flash_attention_channels(
             q, q, q, num_heads=1, **kw).sum())).lower(q).as_text(
                 debug_info=True)
         return {n for n in ("flash_attention_fwd", "flash_attention_bwd",
@@ -264,9 +266,9 @@ def test_fused_nll_gradients_to_hidden_head_and_weights(bias):
         return (weights * nll).sum() / 7.0
 
     args = (head, hidden, weights)
-    assert abs(fused(*args) - dense(*args)) < 1e-5
-    got = jax.grad(fused, (0, 1, 2))(*args)
-    want = jax.grad(dense, (0, 1, 2))(*args)
+    fused_loss, got = jit_once(jax.value_and_grad(fused, (0, 1, 2)))(*args)
+    dense_loss, want = jit_once(jax.value_and_grad(dense, (0, 1, 2)))(*args)
+    assert abs(fused_loss - dense_loss) < 1e-5
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert rel(a, b) < 1e-5
     assert float(jnp.abs(got[2]).min()) > 0      # the weights' gradient
@@ -299,7 +301,7 @@ def test_exit_distribution_sums_to_one_and_reaches_the_gate():
 def toy():
     task = CausalLMTask(remat=True, **TOY)
     model = task.build()
-    params = model.init(jax.random.key(60))
+    params = jit_once(model.init)(jax.random.key(60))
     # norms away from 1 and a live gate, so that no leaf's gradient
     # hides behind a symmetric start
     params = jax.tree.map(lambda x: x + 0.05 * normal(61, x.shape), params)
@@ -307,10 +309,28 @@ def toy():
     return task, model, params, {"input_ids": ids}
 
 
+@pytest.fixture(scope="module")
+def plain(toy):
+    """``impl -> (loss, gradients)`` as plain autodiff gives them,
+    without remat: the fixed side of the backward's cases and of the
+    weight sharing's, one program an ``impl``, run once."""
+    task, _, params, batch = toy
+
+    @functools.cache
+    def loss_and_grads(impl):
+        t = dataclasses.replace(task, remat=False, attention_impl=impl)
+        return jit_once(jax.value_and_grad(lambda p: t.loss_and_metrics(
+            t.build(), p, batch, policy=FP32)[0]))(params)
+
+    return loss_and_grads
+
+
 def test_the_loss_is_the_expected_nll_minus_the_entropy(toy):
     task, model, params, batch = toy
-    loss, metrics = task.loss_and_metrics(model, params, batch, policy=FP32)
-    logits, p = model.apply(params, batch["input_ids"], policy=FP32)
+    loss, metrics = jit_once(lambda p: task.loss_and_metrics(
+        model, p, batch, policy=FP32))(params)
+    logits, p = jit_once(lambda p: model.apply(
+        p, batch["input_ids"], policy=FP32))(params)
     assert logits.shape == (4, 2, 24, 96) and p.shape == (4, 2, 24)
     ids = batch["input_ids"]
     nll = -jnp.take_along_axis(jax.nn.log_softmax(logits[:, :, :-1]),
@@ -338,21 +358,19 @@ def test_padding_and_invalid_rows_carry_no_label(toy):
     pad = jnp.arange(24)[None, :] >= jnp.array([[24], [10]])
     padded = {"input_ids": ids, "pad_mask": pad,
               "valid": jnp.array([True, True])}
-    loss, _ = task.loss_and_metrics(model, params, padded, policy=FP32)
+    loss_of = jit_once(lambda b: task.loss_and_metrics(   # a program a
+        model, params, b, policy=FP32)[0])                 # batch's tree
+    loss = loss_of(padded)
     # causal: row 1's first 10 tokens never see what follows them
     short = {"input_ids": jnp.concatenate(
         [ids[1:, :10], jnp.zeros((1, 14), ids.dtype)], 1), "pad_mask": pad[1:]}
-    l0, _ = task.loss_and_metrics(model, params, {"input_ids": ids[:1]},
-                                  policy=FP32)
-    l1, _ = task.loss_and_metrics(model, params, short, policy=FP32)
+    l0, l1 = loss_of({"input_ids": ids[:1]}), loss_of(short)
     assert abs(loss - (23 * l0 + 9 * l1) / 32) < 1e-5
-    only0, _ = task.loss_and_metrics(
-        model, params, {"input_ids": ids,
-                        "valid": jnp.array([True, False])}, policy=FP32)
+    only0 = loss_of({"input_ids": ids, "valid": jnp.array([True, False])})
     assert abs(only0 - l0) < 1e-5
 
 
-def test_weight_sharing_the_gradient_is_the_sum_over_the_passes(toy):
+def test_weight_sharing_the_gradient_is_the_sum_over_the_passes(toy, plain):
     """Four untied copies of the stack holding equal values, applied in
     turn, give per-copy gradients whose sum is the looped model's."""
     task, model, params, batch = toy
@@ -379,10 +397,9 @@ def test_weight_sharing_the_gradient_is_the_sum_over_the_passes(toy):
 
     rest = {k: v for k, v in params.items() if k != "layers"}
     copies = [params["layers"]] * 4
-    g_copies, g_rest = jax.grad(untied_loss, (0, 1))(copies, rest)
+    g_copies, g_rest = jit_once(jax.grad(untied_loss, (0, 1)))(copies, rest)
     summed = jax.tree.map(lambda *g: sum(g), *g_copies)
-    looped = jax.grad(lambda p: task.loss_and_metrics(
-        model, p, batch, policy=FP32)[0])(params)
+    _, looped = plain(task.attention_impl)
     for a, b in zip(jax.tree.leaves(looped["layers"]),
                     jax.tree.leaves(summed)):
         assert rel(a, b) < 1e-4
@@ -429,7 +446,7 @@ PREFIXES = [remat.REMAT_NAMES[:i] for i in range(len(remat.REMAT_NAMES) + 1)]
 
 @pytest.mark.parametrize("kept", PREFIXES, ids=lambda p: "+".join(p) or "none")
 @pytest.mark.parametrize("impl", ["einsum", "flash"])
-def test_the_hand_written_backward_is_autodiffs(toy, impl, kept,
+def test_the_hand_written_backward_is_autodiffs(toy, plain, impl, kept,
                                                 monkeypatch):
     """Whatever the backward is handed beside the layers' inputs, the
     loss and every gradient are plain autodiff's; a kept value is not
@@ -443,18 +460,20 @@ def test_the_hand_written_backward_is_autodiffs(toy, impl, kept,
 
     monkeypatch.setattr(remat, "choose_keeps", choose)
 
-    def loss_and_grads(remat_on, policy):
-        t = dataclasses.replace(task, remat=remat_on, attention_impl=impl)
-        return jax.value_and_grad(lambda p: t.loss_and_metrics(
-            t.build(), p, batch, policy=policy)[0])
+    def loss_and_grads(policy):
+        t = dataclasses.replace(task, remat=True, attention_impl=impl)
+        return jit_once(jax.value_and_grad(lambda p: t.loss_and_metrics(
+            t.build(), p, batch, policy=policy)[0]))
 
-    (loss, g), (plain_loss, plain_g) = (
-        loss_and_grads(on, FP32)(params) for on in (True, False))
+    # one trace gives the float32 step's jaxpr and its program
+    traced = loss_and_grads(FP32).trace(params)
+    loss, g = traced.lower().compile()(params)
+    plain_loss, plain_g = plain(impl)
     assert abs(float(loss) - float(plain_loss)) < 1e-5
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(plain_g)):
         assert rel(a, b) < 1e-4
     # in bfloat16 the accumulator is still float32, like the parameters
-    g16 = loss_and_grads(True, Policy.bf16())(params)[1]
+    g16 = loss_and_grads(Policy.bf16())(params)[1]
     assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(g16))
     for a, b in zip(jax.tree.leaves(g16), jax.tree.leaves(g)):
         assert rel(a, b) < 0.1
@@ -462,7 +481,7 @@ def test_the_hand_written_backward_is_autodiffs(toy, impl, kept,
     # the forward kernel: once an application with its output kept,
     # once more under the backward without
     applications = TOY["total_ut_steps"] * TOY["num_hidden_layers"]
-    step = jax.make_jaxpr(loss_and_grads(True, FP32))(params).jaxpr
+    step = traced.jaxpr.jaxpr
     fused = impl == "flash"
     assert kernel_calls(step, "causal_attention_fwd")[0] == fused * (
         applications if "attn_out" in kept else 2 * applications)

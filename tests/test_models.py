@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.adapters import (
     ClassificationOutputAdapter,
@@ -39,7 +40,7 @@ def make_image_io(num_layers=3):
 
 def test_perceiver_io_image_classifier_shapes():
     model = make_image_io()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 28, 28, 1))
     logits = model.apply(params, x, policy=FP32)
     assert logits.shape == (2, 10)
@@ -48,9 +49,10 @@ def test_perceiver_io_image_classifier_shapes():
 
 def test_encoder_returns_latent_and_pad_mask():
     model = make_image_io()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 28, 28, 1))
-    latent, pad = model.encoder.apply(params["encoder"], x, policy=FP32)
+    latent, pad = jit_once(lambda p, x: model.encoder.apply(
+        p, x, policy=FP32))(params["encoder"], x)
     assert latent.shape == (2, 32, 128)
     assert pad is None
 
@@ -59,19 +61,21 @@ def test_encoder_weight_shared_recurrence_changes_output():
     """num_layers=1 vs 3 must differ; layer_n params shared across
     iterations (reference model.py:162-166,185-187)."""
     m1, m3 = make_image_io(1), make_image_io(3)
-    p3 = m3.init(jax.random.key(0))
-    assert "layer_n" not in m1.init(jax.random.key(0))["encoder"]
+    p3 = jit_once(m3.init)(jax.random.key(0))
+    assert "layer_n" not in jit_once(m1.init)(jax.random.key(0))["encoder"]
     x = jax.random.normal(jax.random.key(1), (1, 28, 28, 1))
-    l3, _ = m3.encoder.apply(p3["encoder"], x, policy=FP32)
+    l3, _ = jit_once(lambda p, x: m3.encoder.apply(p, x, policy=FP32))(
+        p3["encoder"], x)
     # manually: one layer_1 pass only
     p1 = {k: v for k, v in p3["encoder"].items() if k != "layer_n"}
-    l1, _ = m1.encoder.apply(p1, x, policy=FP32)
+    l1, _ = jit_once(lambda p, x: m1.encoder.apply(p, x, policy=FP32))(
+        p1, x)
     assert not np.allclose(np.asarray(l1), np.asarray(l3), atol=1e-4)
 
 
 def test_latent_init_statistics():
     model = make_image_io()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     lat = np.asarray(params["encoder"]["latent"])
     assert lat.shape == (32, 128)
     assert np.all(np.abs(lat) <= 2.0)
@@ -80,7 +84,7 @@ def test_latent_init_statistics():
 
 def test_decoder_validates_latent_shape():
     model = make_image_io()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     try:
         model.decoder.apply(params["decoder"], jnp.zeros((2, 16, 128)),
                             policy=FP32)
@@ -96,7 +100,7 @@ def test_decoder_query_chunking_is_exact():
                                 latent_shape=(8, 32))
     dec_chunk = PerceiverDecoder(output_adapter=output_adapter,
                                  latent_shape=(8, 32), query_chunk_size=16)
-    params = dec_full.init(jax.random.key(0))
+    params = jit_once(dec_full.init)(jax.random.key(0))
     latent = jax.random.normal(jax.random.key(1), (2, 8, 32))
     y_full = dec_full.apply(params, latent, policy=FP32)
     y_chunk = dec_chunk.apply(params, latent, policy=FP32)
@@ -123,11 +127,11 @@ def make_mlm(vocab_size=100, max_seq_len=32):
 
 def test_mlm_forward_with_masking():
     model = make_mlm()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.randint(jax.random.key(1), (2, 20), 3, 100)
     pad = jnp.zeros((2, 20), bool).at[:, 16:].set(True)
-    logits, labels = model.apply(params, x, pad, rng=jax.random.key(2),
-                                 policy=FP32)
+    logits, labels = jit_once(lambda p, x, pad, rng: model.apply(
+        p, x, pad, rng=rng, policy=FP32))(params, x, pad, jax.random.key(2))
     # logits sliced to input length (reference model.py:316)
     assert logits.shape == (2, 20, 100)
     assert labels.shape == (2, 20)
@@ -135,9 +139,10 @@ def test_mlm_forward_with_masking():
 
 def test_mlm_forward_without_masking():
     model = make_mlm()
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.randint(jax.random.key(1), (2, 20), 3, 100)
-    logits, labels = model.apply(params, x, masking=False, policy=FP32)
+    logits, labels = jit_once(lambda p, x: model.apply(
+        p, x, masking=False, policy=FP32))(params, x)
     assert logits.shape == (2, 20, 100)
     assert labels is None
 
@@ -182,7 +187,7 @@ def test_text_masking_protects_pad_and_unk():
 def test_dropout_only_active_in_training():
     model = make_image_io()
     object.__setattr__(model.encoder, "dropout", 0.5)
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (1, 28, 28, 1))
     y1 = model.apply(params, x, policy=FP32)
     y2 = model.apply(params, x, policy=FP32)
@@ -196,8 +201,8 @@ def test_dropout_only_active_in_training():
 
 def test_model_under_jit():
     model = make_image_io()
-    params = model.init(jax.random.key(0))
-    fn = jax.jit(lambda p, x: model.apply(p, x, policy=FP32))
+    params = jit_once(model.init)(jax.random.key(0))
+    fn = jit_once(lambda p, x: model.apply(p, x, policy=FP32))
     x = jax.random.normal(jax.random.key(1), (2, 28, 28, 1))
     np.testing.assert_allclose(np.asarray(fn(params, x)),
                                np.asarray(model.apply(params, x,
@@ -219,7 +224,7 @@ def test_attention_impl_parity_through_model():
                            latent_shape=(16, 32),
                            num_cross_attention_heads=1)
     model = PerceiverIO(enc, dec)
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 14, 14, 1))
     ref = model.apply(params, x, policy=FP32)
 
@@ -232,10 +237,15 @@ def test_attention_impl_parity_through_model():
                                    atol=1e-4, rtol=1e-4)
 
 
-def _square_loss(model, x):
+def _output_and_gradients(model, params, x):
+    """The model's output and the gradients of its mean square, as one
+    jitted program."""
     def f(p):
-        return (model.apply(p, x, policy=FP32) ** 2).mean()
-    return f
+        out = model.apply(p, x, policy=FP32)
+        return (out ** 2).mean(), out
+
+    (_, out), grads = jit_once(jax.value_and_grad(f, has_aux=True))(params)
+    return out, grads
 
 
 @pytest.fixture(scope="module", params=[None, "flash"],
@@ -249,11 +259,10 @@ def without_remat(request):
     model = PerceiverIO(
         dataclasses.replace(model.encoder, attention_impl=request.param),
         model.decoder)
-    params = model.init(jax.random.key(0))
+    params = jit_once(model.init)(jax.random.key(0))
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(2, 28, 28, 1)), jnp.float32)
-    return (model, params, x, model.apply(params, x, policy=FP32),
-            jax.grad(_square_loss(model, x))(params))
+    return (model, params, x, *_output_and_gradients(model, params, x))
 
 
 @pytest.mark.parametrize("kept", range(4), ids=[
@@ -275,11 +284,10 @@ def test_remat_is_numerically_transparent(kept, without_remat, monkeypatch):
     remat_model = PerceiverIO(
         dataclasses.replace(model.encoder, remat=True), model.decoder)
 
-    out_b = remat_model.apply(params, x, policy=FP32)
+    out_b, gb = _output_and_gradients(remat_model, params, x)
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
                                rtol=1e-6, atol=1e-6)
 
-    gb = jax.grad(_square_loss(remat_model, x))(params)
     for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         # transparent up to fp32 reassociation: recomputation under
         # remat re-fuses the same ops, so ~1-ulp drift on small grad

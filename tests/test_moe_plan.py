@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu import tasks
 from perceiver_tpu.ops import moe, remat
@@ -78,7 +79,7 @@ def group_of(chosen):
 def test_the_plan_is_the_stable_sorts(case):
     chosen = PLANS[case]
     group = group_of(chosen)
-    plan = jax.jit(lambda c, first: moe.routing_plan(c, first, HELD))(
+    plan = jit_once(lambda c, first: moe.routing_plan(c, first, HELD))(
         chosen, FIRST)    # the first expert a value of the step
     assert all(x.dtype == jnp.int32 for x in plan)
     np.testing.assert_array_equal(
@@ -113,7 +114,7 @@ def test_sorted_by_is_the_stable_sort(n, most):
     sorted keys and their old places, equal keys in their old order."""
     key = jnp.asarray(np.random.default_rng(n).integers(
         0, min(most, 5) + 1, n) * (most // min(most, 5)), jnp.int32)
-    got_key, got_place = jax.jit(moe._sorted_by)(key)
+    got_key, got_place = jit_once(moe._sorted_by)(key)
     assert got_key.dtype == got_place.dtype == jnp.int32
     want = np.argsort(np.asarray(key), kind="stable")
     np.testing.assert_array_equal(got_place, want)
@@ -160,9 +161,10 @@ def test_dispatch_and_combine_are_the_gather_and_the_scatter_add(case, rows):
             lambda x: jnp.tanh(x @ mix) + nan_past, a, weights, plan, rows,
             jnp.float32) * w_out).sum()
 
-    got, got_g = jax.value_and_grad(new, argnums=(0, 1, 2))(a, mix, weights)
-    want, want_g = jax.value_and_grad(old, argnums=(0, 1, 2))(a, mix,
-                                                              weights)
+    got, got_g = jit_once(jax.value_and_grad(
+        new, argnums=(0, 1, 2)))(a, mix, weights)
+    want, want_g = jit_once(jax.value_and_grad(
+        old, argnums=(0, 1, 2)))(a, mix, weights)
     assert abs(got - want) <= 1e-5 * abs(want) + 1e-6
     for g, w in zip(got_g, want_g):
         assert bool(jnp.isfinite(g).all())
@@ -197,7 +199,7 @@ def test_the_way_back_at_each_of_its_windows(held_in_first_tile, dtype):
     assert total == 128 * held_in_first_tile
     z = jax.random.normal(jax.random.key(8), (tokens, 24)).astype(dtype)
     z = jnp.where((jnp.arange(tokens) < total)[:, None], z, jnp.nan)
-    got = jax.jit(lambda z: moe.sum_by_token(z, plan, tokens))(z)
+    got = jit_once(lambda z: moe.sum_by_token(z, plan, tokens))(z)
     want = np.zeros((tokens, 24), np.float32)
     np.add.at(want, np.asarray(plan.order[:total]) // K,
               np.asarray(z[:total].astype(jnp.float32)))
@@ -255,12 +257,12 @@ def test_the_layer_is_what_it_was(router, gated, shared):
     a = jax.random.normal(jax.random.key(3), (2, 40, 48))
     w_out = jax.random.normal(jax.random.key(4), a.shape)
     kw = dict(top_k=K, first_expert=FIRST, policy=FP32, **ROUTERS[router])
-    got, got_g = jax.value_and_grad(
+    got, got_g = jit_once(jax.value_and_grad(
         lambda p, a: (moe.moe_apply(p, a, **kw)[0] * w_out).sum(),
-        argnums=(0, 1))(params, a)
-    want, want_g = jax.value_and_grad(
+        argnums=(0, 1)))(params, a)
+    want, want_g = jit_once(jax.value_and_grad(
         lambda p, a: (layer_as_it_was(p, a, **kw) * w_out).sum(),
-        argnums=(0, 1))(params, a)
+        argnums=(0, 1)))(params, a)
     assert abs(got - want) <= 2e-5 * abs(want) + 1e-6
     for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         assert rel(g, w) < 1e-4

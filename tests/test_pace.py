@@ -1,9 +1,8 @@
 """The steps' pace (``perceiver_tpu/training/pace.py``): the two closing
 attrs of ``train/step`` and the slow-step rule, under a pinned clock
-(``obs.trace._now``), and on a real clock where the thread's own time
-is what is read."""
+(``obs.trace._now``, and ``time.thread_time`` where the thread's own
+time is what is read)."""
 
-import time
 import types
 
 import pytest
@@ -190,25 +189,26 @@ def test_another_group_size_starts_another_pace(clock):
     assert slow_events() == [] and sp.attrs["interval_s"] is not None
 
 
-def test_cpu_s_is_well_under_the_wall_time_of_a_step_that_slept():
-    prev = trace.set_timeline(trace.Timeline())
-    try:
-        pace = StepPace()
-        pace.epoch_start()
-        with trace.span("train/step", step=1) as sp:
-            pace.step_open()
-            with trace.span("train/fence"):
-                time.sleep(0.2)
-            pace.step_close(sp)
-        with trace.span("train/step", step=2) as busy:
-            pace.step_open()
-            with trace.span("train/dispatch"):
-                until = time.perf_counter() + 0.05
-                while time.perf_counter() < until:
-                    pass
-            pace.step_close(busy)
-    finally:
-        trace.set_timeline(prev)
+def test_cpu_s_is_well_under_the_wall_time_of_a_step_that_slept(
+        clock, monkeypatch):
+    """Both clocks pinned, the thread's beside the wall's: asleep the
+    wall clock moves alone, at work the two move together."""
+    now, cpu = clock, [50.0]
+    monkeypatch.setattr(pace_mod.time, "thread_time", lambda: cpu[0])
+    pace = StepPace()
+    pace.epoch_start()
+    with trace.span("train/step", step=1) as sp:
+        pace.step_open()
+        with trace.span("train/fence"):
+            now[0] += 0.2                 # asleep
+            cpu[0] += 0.001
+        pace.step_close(sp)
+    with trace.span("train/step", step=2) as busy:
+        pace.step_open()
+        with trace.span("train/dispatch"):
+            now[0] += 0.05                # at work
+            cpu[0] += 0.05
+        pace.step_close(busy)
     assert sp.seconds >= 0.2 and sp.attrs["cpu_s"] < 0.05
     assert busy.attrs["cpu_s"] > 0.02      # a thread that worked reads it
     assert busy.attrs["interval_s"] == pytest.approx(

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.ops.linear import linear_init
 from perceiver_tpu.ops.pallas_ce import pallas_linear_cross_entropy
@@ -37,11 +38,11 @@ def test_matches_dense_loss_and_grads(shape):
         return pallas_linear_cross_entropy(
             p, h, labels, weight, block_n=32, block_v=128, policy=POLICY)
 
-    dense, (gd_p, gd_h) = jax.value_and_grad(
+    dense, (gd_p, gd_h) = jit_once(jax.value_and_grad(
         lambda p, h: _dense_loss(p, h, labels, weight),
-        argnums=(0, 1))(params, hidden)
-    fused, (gp_p, gp_h) = jax.value_and_grad(
-        pallas_loss, argnums=(0, 1))(params, hidden)
+        argnums=(0, 1)))(params, hidden)
+    fused, (gp_p, gp_h) = jit_once(jax.value_and_grad(
+        pallas_loss, argnums=(0, 1)))(params, hidden)
 
     np.testing.assert_allclose(dense, fused, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(gd_h), np.asarray(gp_h),
@@ -63,13 +64,13 @@ def test_all_weights_zero_is_finite():
 def test_under_jit_and_grad():
     params, hidden, labels, weight = _problem()
 
-    @jax.jit
+    @jax.jit     # nested under the next line's: the options are the outer's
     def f(p):
         return pallas_linear_cross_entropy(
             p, hidden, labels, weight, block_n=32, block_v=128,
             policy=POLICY)
 
-    g = jax.jit(jax.grad(f))(params)
+    g = jit_once(jax.grad(f))(params)
     assert np.isfinite(float(f(params)))
     assert all(np.isfinite(np.asarray(x)).all() for x in jax.tree.leaves(g))
 
@@ -95,9 +96,9 @@ def test_mlm_task_pallas_impl_matches_dense():
             "pad_mask": jnp.asarray(rng.random((4, 24)) < 0.1),
             "valid": jnp.asarray([True, True, True, False]),
         }
-        loss, _ = task.loss_and_metrics(
-            model, params, batch, rng=jax.random.key(7),
-            deterministic=True, policy=POLICY)
+        loss, _ = jit_once(lambda p, b: task.loss_and_metrics(
+            model, p, b, rng=jax.random.key(7), deterministic=True,
+            policy=POLICY))(params, batch)
         return float(loss)
 
     np.testing.assert_allclose(task_loss("pallas"), task_loss("dense"),

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -181,7 +182,7 @@ def test_a_planted_fault_fails_the_float32_comparison(monkeypatch, fault):
         return kernels.fused_rule(*a, chunk=64)
 
     (_, want_grads), want = out_and_grads(recurrence, args, w)
-    assert rel(jax.jit(rule)(*args), want) < ROUNDING
+    assert rel(jit_once(rule)(*args), want) < ROUNDING
     name, planted = FAULTS[fault]
     monkeypatch.setattr(kernels, name, planted)
     try:
@@ -215,7 +216,7 @@ def test_the_shapes_say_which_form_runs(dk, dv, per, chunk, dtypes, label):
     low = (q.astype(kinds[dtypes[0]]), k.astype(kinds[dtypes[0]]),
            v.astype(kinds[dtypes[1]]))
     with dr.rule_paths.counting() as forms:
-        got = jax.jit(lambda *a: dr.delta_rule(*a, chunk_size=chunk))(
+        got = jit_once(lambda *a: dr.delta_rule(*a, chunk_size=chunk))(
             *low, g, beta)
     assert dict(forms) == {label: 1}
     assert dr.fits(low[0], low[2], min(chunk, 40), g) == label.startswith(
@@ -251,11 +252,11 @@ def test_the_mixer_runs_the_kernels_at_heads_of_whole_lanes():
             p, a, **sizes, chunk_size=16, policy=Policy.fp32())))
 
     with dr.rule_paths.counting() as forms:
-        got, got_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(p, a)
+        got, got_g = jit_once(jax.value_and_grad(loss, argnums=(0, 1)))(p, a)
     assert dict(forms) == {"kernel[16x4, by channel]": 1}
     with pytest.MonkeyPatch.context() as m:
         m.setattr(dr, "fits", lambda *_: False)
-        want, want_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(p, a)
+        want, want_g = jit_once(jax.value_and_grad(loss, argnums=(0, 1)))(p, a)
     assert abs(got - want) < 1e-5 * abs(want)
     for g, r in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         assert rel(g, r) < 1e-4

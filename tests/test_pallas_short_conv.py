@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.ops import delta_rule, ssm
 from perceiver_tpu.ops import pallas_short_conv as kernels
@@ -177,7 +178,7 @@ def test_fits_reads_backend_mesh_dtype_and_shape(monkeypatch):
     sharded = jax.device_put(x, jax.NamedSharding(
         make_mesh(2), jax.sharding.PartitionSpec("data")))
     seen = []
-    jax.jit(lambda x: seen.append(kernels.fits(x, 4, 256)) or x)(sharded)
+    jit_once(lambda x: seen.append(kernels.fits(x, 4, 256)) or x)(sharded)
     assert seen == ["mesh"]
 
 
@@ -323,7 +324,7 @@ def test_where_fits_says_no_the_mixers_text_is_the_parents(monkeypatch,
             return apply(params, u, policy=FP32, **sizes)
 
         with kernels.conv_paths.counting() as counts:
-            text = jax.jit(step).lower(params, u).as_text()
+            text = jit_once(step).lower(params, u).as_text()
         return text, list(counts)
 
     text, labels = lowered()

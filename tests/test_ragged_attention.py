@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.ops.ragged_attention import (
     ragged_cross_attention,
@@ -97,7 +98,7 @@ class TestRaggedCross:
     def test_under_jit(self):
         q, k, v, offs, lens = _cross_inputs(jax.random.key(5),
                                             [20, 44, 64])
-        fn = jax.jit(lambda *a: ragged_cross_attention(*a, block_k=64))
+        fn = jit_once(lambda *a: ragged_cross_attention(*a, block_k=64))
         out = fn(q, k, v, offs, lens)
         ref = ragged_cross_attention_reference(q, k, v, offs, lens)
         np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
@@ -147,7 +148,7 @@ class TestRaggedDecode:
 
     def test_under_jit(self):
         q, k, v, rows, n = self._inputs(jax.random.key(11), [25, 39])
-        fn = jax.jit(lambda *a: ragged_decode_attention(
+        fn = jit_once(lambda *a: ragged_decode_attention(
             *a, latents_per_row=n, block_q=16))
         out = fn(q, k, v, rows)
         ref = ragged_decode_attention_reference(q, k, v, rows,

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.parallel import make_mesh
 from perceiver_tpu.tasks import MaskedLanguageModelTask
@@ -38,9 +39,9 @@ def _batch(b=4, l=32):
 
 def _loss(task, model, batch):
     params = model.init(jax.random.key(0))
-    loss, _ = task.loss_and_metrics(model, params, batch,
-                                    rng=jax.random.key(7),
-                                    deterministic=True, policy=POLICY)
+    loss, _ = jit_once(lambda p, b: task.loss_and_metrics(
+        model, p, b, rng=jax.random.key(7), deterministic=True,
+        policy=POLICY))(params, batch)
     return float(loss)
 
 

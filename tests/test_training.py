@@ -440,7 +440,7 @@ def test_imdb_tokenized_array_cache(tmp_path):
     np.testing.assert_array_equal(dm4._train.fields["input_ids"], want)
 
     # re-plant, then rewrite the CORPUS in place without touching the
-    # tokenizer json (what harvest_text.py does — ADVICE r2): the
+    # tokenizer json (what regenerating a corpus does — ADVICE r2): the
     # corpus fingerprint mismatch must invalidate the cache; serving
     # the planted ids would mean stale token ids AND stale labels
     with np.load(npz[0], allow_pickle=False) as z:

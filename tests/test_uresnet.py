@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jit_once
 
 from perceiver_tpu.models.uresnet import UResNet
 from perceiver_tpu.ops.conv import (
@@ -58,14 +59,15 @@ def test_batch_norm_train_vs_eval():
 def tiny_uresnet():
     model = UResNet(num_classes=3, input_channels=1, inplanes=4,
                     head_kernels=4)
-    variables = model.init(jax.random.key(0))
+    variables = jit_once(model.init)(jax.random.key(0))
     return model, variables
 
 
 def test_uresnet_output_shape(tiny_uresnet):
     model, variables = tiny_uresnet
     x = jax.random.normal(jax.random.key(1), (2, 32, 32, 1))
-    logits, _ = model.apply(variables, x, train=False, policy=FP32)
+    logits, _ = jit_once(lambda v, x: model.apply(
+        v, x, train=False, policy=FP32))(variables, x)
     assert logits.shape == (2, 32, 32, 3)
     assert np.isfinite(np.asarray(logits)).all()
 
@@ -73,8 +75,8 @@ def test_uresnet_output_shape(tiny_uresnet):
 def test_uresnet_train_updates_bn_state(tiny_uresnet):
     model, (params, state) = tiny_uresnet
     x = jax.random.normal(jax.random.key(2), (2, 32, 32, 1)) * 2.0
-    logits, new_state = model.apply((params, state), x, train=True,
-                                    policy=FP32)
+    logits, new_state = jit_once(lambda v, x: model.apply(
+        v, x, train=True, policy=FP32))((params, state), x)
     before = state["stem1"]["bn"]["mean"]
     after = new_state["stem1"]["bn"]["mean"]
     assert not np.allclose(np.asarray(before), np.asarray(after))
@@ -89,7 +91,7 @@ def test_uresnet_gradients_flow(tiny_uresnet):
     x = jax.random.normal(jax.random.key(3), (2, 32, 32, 1))
     labels = jnp.zeros((2, 32, 32), jnp.int32)
 
-    @jax.jit
+    @jit_once
     def loss_fn(p):
         logits, _ = model.apply((p, state), x, train=True, policy=FP32)
         logp = jax.nn.log_softmax(logits)
