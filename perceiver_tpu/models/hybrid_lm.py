@@ -10,7 +10,8 @@ Nemotron 3 Nano; ``hybrid_override_pattern``)::
 ``M`` is a Mamba-2 mixer (``ops/ssm.py``), ``E`` an expert layer
 (``ops/moe.py``), ``L`` a gated delta-rule mixer, ``K`` a Kimi Delta
 Attention mixer, ``A`` latent attention, ``D`` a dense gated MLP (all
-below), ``*`` causal attention with grouped queries and, in
+below; a multi-token prediction module may follow the stack), ``*``
+causal attention with grouped queries and, in
 ``nemotron_h``, **no rotary embedding** (the Mamba layers carry
 position). A layer is one RMSNorm with a scale, one mixer and a
 residual: there is no separate MLP after ``M`` or ``*`` unless the
@@ -58,6 +59,23 @@ whose renormalised top-k is scaled, gated experts and a shared expert
 gated with three matrices and no gate column (``shared_expert_kind``
 ``glu``).
 
+A ``glm4_moe_lite`` decoder layer (GLM-4.7-Flash; DeepSeek-V3's layer,
+arXiv:2412.19437) is ``AE`` throughout: its ``A`` takes its queries from
+a normed latent too (``q_lora_rank``: ``q = rms(a W_qa) W_qb``) and
+carries **decoupled rotary positions** (``rope_theta``: the
+``qk_rope_head_dim`` shared key channels, turned once before they are
+handed to the heads, and each query head's last ``qk_rope_head_dim``
+channels turn with position; the ``nope`` channels and the values do
+not), with score heads as wide as value heads (192 + 64 | 256: the
+fused kernels take the call unpadded). After the stack it has a
+**multi-token prediction module** (``num_nextn_predict_layers`` 1,
+``params["mtp"]``; no letter of the pattern): the stack's final-normed
+state beside the embedding of the next id, each normed, through
+``eh_proj`` into one more ``A`` + ``E`` pair of the module's own and a
+norm, read by the stack's own head (``prediction_states``; the task
+adds its loss at a weight). The embedding table and the head are the
+stack's: their gradients are the sums of both readings'.
+
 The attention layer runs on the cores every causal call takes
 (``ops.attention.mha_apply``): its ``num_kv_heads`` key/value heads are
 repeated to the query heads before the call (query head ``i`` reads
@@ -85,6 +103,7 @@ tests, prediction and small sizes.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Optional
 
@@ -98,6 +117,7 @@ from perceiver_tpu.ops.attention import (
     data_shards,
     head_rms_norm,
     mha_apply,
+    tally_latent_call,
     untallied,
 )
 from perceiver_tpu.ops.delta_rule import (
@@ -114,10 +134,17 @@ from perceiver_tpu.ops.moe import moe_apply, moe_init
 from perceiver_tpu.ops.norm import rms_norm_apply, rms_norm_init
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.ssm import ssm_mixer_apply, ssm_mixer_init
+from perceiver_tpu.ops.tally import Tally
 
 _INIT_STD = 0.02
 LAYER_KINDS = {"M": "ssm", "E": "moe", "*": "attn", "L": "delta",
                "K": "kda", "A": "mla", "D": "mlp"}
+# the layers of a multi-token prediction module, in order
+MTP_KINDS = "AE"
+#: trace-time tally of the prediction modules a step's loss reads, by
+#: the words of the ``[step_load]`` line: depth, loss weight, what is
+#: shared with the stack
+prediction_modules = Tally()
 
 
 def gqa_init(key, dim: int, num_heads: int, num_kv_heads: int,
@@ -188,16 +215,27 @@ def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
 
 
 def mla_init(key, dim: int, num_heads: int, *, kv_lora_rank: int,
-             qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int):
-    """Latent attention without a query latent (``q_lora_rank`` null):
-    ``q`` a head's ``nope + rope`` channels, ``kv_a`` the latent beside
-    the shared key channels, ``kv_norm`` the latent's RMSNorm, ``kv_b``
-    a head's ``nope`` key channels beside its ``v_head_dim`` value
-    channels, ``out`` from the value heads."""
+             qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
+             q_lora_rank: int = 0):
+    """Latent attention: ``q`` a head's ``nope + rope`` channels or,
+    with a query latent (``q_lora_rank``), ``q_a`` to the latent,
+    ``q_a_norm`` its RMSNorm and ``q_b`` from it to the same channels;
+    ``kv_a`` the latent beside the shared key channels, ``kv_norm`` the
+    latent's RMSNorm, ``kv_b`` a head's ``nope`` key channels beside its
+    ``v_head_dim`` value channels, ``out`` from the value heads."""
     kq, ka, kb, ko = jax.random.split(key, 4)
+    q_width = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+    if q_lora_rank:
+        kqa, kqb = jax.random.split(kq)
+        queries = {
+            "q_a": linear_init(kqa, dim, q_lora_rank, bias=False),
+            "q_a_norm": rms_norm_init(q_lora_rank),
+            "q_b": linear_init(kqb, q_lora_rank, q_width, bias=False),
+        }
+    else:
+        queries = {"q": linear_init(kq, dim, q_width, bias=False)}
     return {
-        "q": linear_init(kq, dim, num_heads * (
-            qk_nope_head_dim + qk_rope_head_dim), bias=False),
+        **queries,
         "kv_a": linear_init(ka, dim, kv_lora_rank + qk_rope_head_dim,
                             bias=False),
         "kv_norm": rms_norm_init(kv_lora_rank),
@@ -207,21 +245,53 @@ def mla_init(key, dim: int, num_heads: int, *, kv_lora_rank: int,
     }
 
 
+def _mla_queries(params, a, num_heads: int, qk_nope_head_dim: int, rope,
+                 norm_eps: float, policy: Policy):
+    """The queries (B, S, H x (nope + rope)) of a latent attention
+    whose tree holds a query latent or whose rope channels turn:
+    ``q = rms(a W_qa; w_q) W_qb`` (or ``a W_q``), then each head's last
+    ``rope`` channels rotated by ``rope``'s tables."""
+    if "q_a" in params:
+        q = linear_apply(params["q_b"], rms_norm_apply(
+            params["q_a_norm"],
+            linear_apply(params["q_a"], a, policy=policy), norm_eps, policy),
+            policy=policy)
+    else:
+        q = linear_apply(params["q"], a, policy=policy)
+    if rope is not None:
+        rows, seq, _ = q.shape
+        still, turning = jnp.split(q.reshape(rows, seq, num_heads, -1),
+                                   [qk_nope_head_dim], axis=-1)
+        turned = rope_apply(turning.reshape(rows, seq, -1), *rope, num_heads)
+        q = jnp.concatenate(
+            [still, turned.reshape(turning.shape)], -1).reshape(q.shape)
+    return remat.dear(q, "qkv")
+
+
 @device_scope("mla_mixer")
 def mla_apply(params, a, *, num_heads: int, kv_lora_rank: int,
               qk_nope_head_dim: int, norm_eps: float = 1e-6,
-              policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None):
+              policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None,
+              rope=None):
     """Causal latent attention, expanded: ``[c | k_s] = a W_kva``,
     ``[k_n | v] = rms(c) W_kvb`` a head, ``k_h = [k_n,h | k_s]`` (the
-    shared channels the same for every head), ``q = a W_q`` a head's
-    channels in the same order; no position embedding on any channel.
-    The core is ``mha_apply``'s, whose score heads and value heads may
-    differ in width; the scale is ``1 / sqrt(nope + rope)``."""
+    shared channels the same for every head), ``q`` a head's channels
+    in the same order, ``a W_q`` or, where the tree holds a query
+    latent (``q_a``), ``rms(a W_qa) W_qb``. ``rope`` (tables
+    ``(cos, sin)`` of ``qk_rope_head_dim`` columns, a row a position):
+    decoupled rotary positions, the shared key channels turned once,
+    before they are handed to the heads, and each query head's last
+    ``rope`` channels; the ``nope`` channels and the values never turn.
+    None: no position embedding on any channel. The core is
+    ``mha_apply``'s, whose score heads and value heads may differ in
+    width; the scale is ``1 / sqrt(nope + rope)``."""
     rows, seq, _ = a.shape
     with device_scope("attn_proj"):
         latent, shared = jnp.split(
             linear_apply(params["kv_a"], a, policy=policy), [kv_lora_rank],
             axis=-1)
+        if rope is not None:
+            shared = rope_apply(shared, *rope, 1)
         kv = linear_apply(
             params["kv_b"],
             rms_norm_apply(params["kv_norm"], latent, norm_eps, policy),
@@ -231,10 +301,18 @@ def mla_apply(params, a, *, num_heads: int, kv_lora_rank: int,
             jnp.broadcast_to(shared[:, :, None, :],
                              (rows, seq, num_heads, shared.shape[-1]))], -1)
         v = kv[..., qk_nope_head_dim:]
+        q_heads = None
+        if "q_a" in params or rope is not None:
+            q_heads = _mla_queries(params, a, num_heads, qk_nope_head_dim,
+                                   rope, norm_eps, policy)
+    tally_latent_call(
+        f"{qk_nope_head_dim}+{shared.shape[-1]}"
+        f"{'r' if rope is not None else ''}|{v.shape[-1]}"
+        + (" query latent" if "q_a" in params else ""))
     return mha_apply(params, a, None, None, num_heads=num_heads,
                      kv_heads=(k.reshape(rows, seq, -1),
                                v.reshape(rows, seq, -1)),
-                     causal=True, policy=policy, impl=impl)
+                     q_heads=q_heads, causal=True, policy=policy, impl=impl)
 
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
@@ -269,15 +347,19 @@ class HybridLM:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # the query latent's rank; 0: q in one product. With rope_theta the
+    # qk_rope_head_dim shared key channels and each query head's turn
+    q_lora_rank: int = 0
     # D: the dense gated MLP's width (0: no such layer)
     intermediate_size: int = 0
     # *
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
-    # rotary positions at this base; None: no position embedding
+    # rotary positions at this base (of * and of A's rope channels);
+    # None: no position embedding
     rope_theta: Optional[float] = None
-    # the share of a head's channels, from the first, that the rotary
+    # the share of a * head's channels, from the first, that the rotary
     # tables turn
     partial_rotary_factor: float = 1.0
     # an RMSNorm over each head's channels of the projected q and k
@@ -308,6 +390,10 @@ class HybridLM:
     # recompute every layer on the backward pass, but for the dear
     # values that fit the device (ops/remat.py)
     remat: bool = False
+    # multi-token prediction modules after the stack (params["mtp"]:
+    # one A and one E layer of the kinds above behind two norms and a
+    # projection); 0: none. Depth 1 is what there is
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
         if not self.pattern or set(self.pattern) - set(LAYER_KINDS):
@@ -334,11 +420,21 @@ class HybridLM:
                 self.kda_num_heads and self.kda_head_dim):
             raise ValueError("a pattern with K needs kda_num_heads and "
                              "kda_head_dim")
-        if "A" in self.pattern and not (
-                self.kv_lora_rank and self.qk_nope_head_dim
-                and self.v_head_dim):
-            raise ValueError("a pattern with A needs kv_lora_rank, "
-                             "qk_nope_head_dim and v_head_dim")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                f"{self.num_nextn_predict_layers} prediction modules: "
+                "one, or none")
+        latent = "A" in self.pattern or self.num_nextn_predict_layers
+        if latent and not (self.kv_lora_rank and self.qk_nope_head_dim
+                           and self.v_head_dim):
+            raise ValueError(
+                "a pattern with A needs kv_lora_rank, qk_nope_head_dim and "
+                "v_head_dim, and so does a prediction module")
+        if latent and self.rope_theta is not None and (
+                not self.qk_rope_head_dim or self.qk_rope_head_dim % 2):
+            raise ValueError(
+                f"rotary positions pair A's qk_rope_head_dim channels: "
+                f"{self.qk_rope_head_dim} of them")
         if "D" in self.pattern and not self.intermediate_size:
             raise ValueError("a pattern with D needs intermediate_size")
         if "M" in self.pattern and not (
@@ -389,7 +485,7 @@ class HybridLM:
                 kv_lora_rank=self.kv_lora_rank,
                 qk_nope_head_dim=self.qk_nope_head_dim,
                 qk_rope_head_dim=self.qk_rope_head_dim,
-                v_head_dim=self.v_head_dim)
+                v_head_dim=self.v_head_dim, q_lora_rank=self.q_lora_rank)
         if kind == "D":
             return gated_mlp_init(key, c, self.intermediate_size)
         if kind == "E":
@@ -413,26 +509,43 @@ class HybridLM:
         ke, kl, kh = jax.random.split(key, 3)
         c = self.hidden_size
         keys = jax.random.split(kl, len(self.pattern))
-        return {
+
+        def layer(k, kind):
+            return {"norm": self._norm_init(),
+                    "mixer": self._mixer_init(k, kind)}
+
+        params = {
             "embed": {"embed": trunc_normal_clamped(
                 ke, (self.vocab_size, c), _INIT_STD)},
             "layers": {
-                name: {"norm": self._norm_init(),
-                       "mixer": self._mixer_init(k, kind)}
+                name: layer(k, kind)
                 for name, kind, k in zip(self.layer_names(), self.pattern,
                                          keys)},
             "norm": self._norm_init(),
             "head": {"w": trunc_normal_clamped(
                 kh, (c, self.vocab_size), _INIT_STD)},
         }
+        if self.num_nextn_predict_layers:
+            # a key of its own: the rest of the tree is the one a model
+            # without the module draws
+            kp, ka, kx = jax.random.split(jax.random.fold_in(key, 1), 3)
+            params["mtp"] = {
+                "enorm": self._norm_init(), "hnorm": self._norm_init(),
+                "eh_proj": linear_init(kp, 2 * c, c, bias=False),
+                **{LAYER_KINDS[kind]: layer(k, kind)
+                   for kind, k in zip(MTP_KINDS, (ka, kx))},
+                "norm": self._norm_init(),
+            }
+        return params
 
     def _layer(self, kind: str, policy: Policy, rope=None,
                block_diffusion=None):
         """``(layer_params, h, first) -> (h, load)`` of one kind;
         ``first`` is an expert layer's first held expert (None:
         ``first_expert``) and ``load`` its assignments a held expert,
-        both None elsewhere. ``rope`` and ``block_diffusion`` are the
-        attention layers' (``rotary_gqa_apply``)."""
+        both None elsewhere. ``rope`` is the kind's own tables (a ``*``
+        layer's, ``rotary_gqa_apply``; an ``A`` layer's rope channels',
+        ``mla_apply``), ``block_diffusion`` the ``*`` layers'."""
         def layer(p, h, first=None):
             a = rms_norm_apply(p["norm"], h, self.norm_eps, policy)
             load = None
@@ -462,7 +575,7 @@ class HybridLM:
                     p["mixer"], a, num_heads=self.num_attention_heads,
                     kv_lora_rank=self.kv_lora_rank,
                     qk_nope_head_dim=self.qk_nope_head_dim,
-                    norm_eps=self.norm_eps, policy=policy)
+                    norm_eps=self.norm_eps, policy=policy, rope=rope)
             elif kind == "D":
                 out = gated_mlp_apply(p["mixer"], a, policy)
             elif kind == "E":
@@ -484,20 +597,22 @@ class HybridLM:
 
         return layer
 
-    def _remat_keeps(self, layers, params, h):
+    def _remat_keeps(self, layers, h):
         """The names every layer's checkpoint saves: one layer of each
         kind traced for its shapes alone says what its names would
-        hold; ``choose_keeps`` takes the sum over the layers on one
-        device against what the device has left."""
+        hold; ``choose_keeps`` takes the sum over ``layers`` (``(kind,
+        layer, its parameters)`` an application: the stack's and a
+        prediction module's) on one device against what the device has
+        left."""
         held = dict.fromkeys(remat.HYBRID_REMAT_NAMES, 0)
-        first = {kind: named_layer for named_layer, kind
-                 in reversed(list(zip(layers, self.pattern)))}
+        first = {kind: (layer, p) for kind, layer, p in reversed(layers)}
+        count = collections.Counter(kind for kind, _, _ in layers)
         with untallied():
-            for kind, (name, layer) in first.items():
+            for kind, (layer, p) in first.items():
                 named = remat.named_bytes(
-                    lambda p, x: layer(p, x)[0], params[name], h)
+                    lambda p, x: layer(p, x)[0], p, h)
                 for n in held:
-                    held[n] += self.pattern.count(kind) * named[n]
+                    held[n] += count[kind] * named[n]
         shards = data_shards(h)
         return remat.choose_keeps(
             {n: v // shards for n, v in held.items()},
@@ -513,7 +628,35 @@ class HybridLM:
         first held expert, in ``first_expert``'s place.
         ``block_diffusion`` ``(L, B)``: ``input_ids`` are rows of ``2 L``
         positions, the noised copy beside the clean one, under the
-        block-diffusion mask; index ``j`` has position ``j mod L``."""
+        block-diffusion mask; index ``j`` has position ``j mod L``. A
+        prediction module is not run."""
+        h, _, loads = self._states(params, input_ids, None, first_experts,
+                                   policy, block_diffusion)
+        return h, loads
+
+    def prediction_states(self, params, input_ids, next_ids, *,
+                          first_experts=None,
+                          policy: Policy = DEFAULT_POLICY):
+        """``(h, z, loads)``: ``hidden_states``' state, and the
+        prediction module's beside it (DeepSeek-V3, arXiv:2412.19437,
+        section 2.2, depth 1)::
+
+            u_i = [rms(E[next_ids_i]; w_e) | rms(h_i; w_h)] W_eh
+            y = u + A(rms(u));  y = y + E_xp(rms(y));  z = rms(y; w_o)
+
+        ``E`` the stack's own embedding table, ``A`` and ``E_xp`` a
+        latent-attention and an expert layer of the stack's kinds with
+        the module's weights (``params["mtp"]``), position ``i``'s
+        rotary tables, and ``z`` read by the stack's own head:
+        ``z_i`` predicts the id after ``next_ids_i``. ``loads`` and
+        ``first_experts`` count the module's expert layer last."""
+        if not self.num_nextn_predict_layers:
+            raise ValueError("this model has no prediction module")
+        return self._states(params, input_ids, next_ids, first_experts,
+                            policy, None)
+
+    def _states(self, params, input_ids, next_ids, first_experts,
+                policy: Policy, block_diffusion):
         seq = positions = input_ids.shape[1]
         if block_diffusion is not None:
             positions = block_diffusion[0]
@@ -523,8 +666,8 @@ class HybridLM:
         if positions > self.max_seq_len:
             raise ValueError(f"{positions} positions, max_seq_len "
                              f"{self.max_seq_len}")
-        rope = None
-        if self.rope_theta is not None:
+        tables = {}     # a kind's rotary tables, where it has any
+        if self.rope_theta is not None and "*" in self.pattern:
             rope = rope_tables(
                 positions, int(self.head_dim * self.partial_rotary_factor),
                 self.rope_theta)
@@ -533,27 +676,51 @@ class HybridLM:
             # one array a table, which every layer is handed: a numpy
             # table is written into the step's text once a use (24 times
             # 4 MB in a six-layer step of 8,192 positions)
-            rope = tuple(jnp.asarray(t) for t in rope)
+            tables["*"] = tuple(jnp.asarray(t) for t in rope)
+        if self.rope_theta is not None and (
+                "A" in self.pattern or next_ids is not None):
+            tables["A"] = tuple(jnp.asarray(t) for t in rope_tables(
+                positions, self.qk_rope_head_dim, self.rope_theta))
         with device_scope("input_adapter"):
             h = policy.cast_compute(params["embed"]["embed"][input_ids])
-        layers = [(name, self._layer(kind, policy, rope, block_diffusion))
+        layers = [(kind, self._layer(kind, policy, tables.get(kind),
+                                     block_diffusion), params["layers"][name])
                   for name, kind in zip(self.layer_names(), self.pattern)]
+        module = [] if next_ids is None else [
+            (kind, self._layer(kind, policy, tables.get(kind)),
+             params["mtp"][LAYER_KINDS[kind]]) for kind in MTP_KINDS]
         loads = []
         firsts = iter(() if first_experts is None else first_experts)
-        with device_scope("hybrid_stack"):
-            if self.remat:
-                policy_fn = jax.checkpoint_policies.save_only_these_names(
-                    *self._remat_keeps(layers, params["layers"], h))
-            for (name, layer), kind in zip(layers, self.pattern):
+
+        def run(layers, h):
+            for kind, layer, p in layers:
                 if self.remat:
                     layer = jax.checkpoint(layer, policy=policy_fn)
-                h, load = layer(params["layers"][name], h,
+                h, load = layer(p, h,
                                 next(firsts, None) if kind == "E" else None)
                 if load is not None:
                     loads.append(load)
-            h = rms_norm_apply(params["norm"], h, self.norm_eps, policy)
-        return h, (jnp.stack(loads) if loads else
-                   jnp.zeros((0, self.num_held_experts), jnp.int32))
+            return h
+
+        with device_scope("hybrid_stack"):
+            if self.remat:
+                policy_fn = jax.checkpoint_policies.save_only_these_names(
+                    *self._remat_keeps(layers + module, h))
+            h = rms_norm_apply(params["norm"], run(layers, h),
+                               self.norm_eps, policy)
+        z = None
+        if module:
+            with device_scope("mtp"):
+                p = params["mtp"]
+                e = policy.cast_compute(params["embed"]["embed"][next_ids])
+                u = linear_apply(p["eh_proj"], jnp.concatenate([
+                    rms_norm_apply(p["enorm"], e, self.norm_eps, policy),
+                    rms_norm_apply(p["hnorm"], h, self.norm_eps, policy)],
+                    -1), policy=policy)
+                z = rms_norm_apply(p["norm"], run(module, u), self.norm_eps,
+                                   policy)
+        return h, z, (jnp.stack(loads) if loads else
+                      jnp.zeros((0, self.num_held_experts), jnp.int32))
 
     def apply(self, params, input_ids, *, first_experts=None,
               policy: Policy = DEFAULT_POLICY, block_diffusion=None):

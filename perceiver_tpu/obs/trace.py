@@ -200,6 +200,11 @@ DEVICE_SCOPES = (
     # layer (models/hybrid_lm), whole, with attn_proj and attn_core
     # inside it
     "kda_mixer", "kda_rule", "mla_mixer",
+    # a multi-token prediction module after the stack (models/hybrid_lm:
+    # its two norms, its projection, its latent-attention and expert
+    # layer with their own scopes inside, its last norm) and, inside
+    # loss, its reading of the stack's head (tasks/hybrid_lm)
+    "mtp", "mtp_loss",
     # a block-diffusion step's own noising of its rows
     # (tasks/block_diffusion_lm): the masking rates, the masks, the
     # noised copy and the loss weights

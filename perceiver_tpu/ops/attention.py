@@ -363,6 +363,14 @@ def attention_paths():
     return _PATHS.counting()
 
 
+def tally_latent_call(widths: str) -> None:
+    """One latent-attention call site (``models/hybrid_lm.mla_apply``)
+    by a head's widths and what turns, beside the core it took:
+    ``latent[192+64r|256 query latent]`` is score heads of 192 channels
+    and 64 rotated ones beside value heads of 256."""
+    _PATHS.add(("latent", widths))
+
+
 # ... and of what the fused call sites with a mask run: their tiles by
 # kind and the sub-tiles of the masked ones (``ops/tiling.tile_counts``),
 # keyed by the words of the log line.
@@ -410,7 +418,8 @@ def mha_apply(params, q, k, v, *, num_heads: int,
               policy: Policy = DEFAULT_POLICY, impl: Optional[str] = None,
               kv_chunk_size: int = 1024, spmd=None, kv_heads=None,
               causal: bool = False, rope=None, block_diffusion=None,
-              norm_eps: float = 1e-6, output_gate: bool = False):
+              norm_eps: float = 1e-6, output_gate: bool = False,
+              q_heads=None):
     """Scaled dot-product multi-head attention.
 
     q: (B, Lq, q_dim); k: (B, Lk, k_dim); v: (B, Lk, v_dim).
@@ -436,6 +445,10 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     change neither scores nor outputs) under the score heads' scale and
     the result cut to the value heads (``two_widths`` in
     ``attention_paths``: ``192|128`` or, padded, ``192|128 as 256``).
+    ``q_heads`` (beside ``kv_heads`` only): the queries (B, Lq, H·D) as
+    their caller made them too, projected, normed and rotated there
+    (latent attention with a query latent or rotary channels);
+    ``params["q"]`` is then not read and ``q`` may be None.
     output_gate: the
     query projection is twice as wide, a head's query beside its gate
     (``[q_h | gate_h]`` a head), and the core's output is multiplied by
@@ -484,7 +497,13 @@ def mha_apply(params, q, k, v, *, num_heads: int,
             "the materialized core and are their own mask: no attn_mask "
             f"and not the other beside it, not impl={impl!r}")
 
-    qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
+    if q_heads is not None:
+        if kv_heads is None or rope is not None or output_gate:
+            raise ValueError("q_heads come beside kv_heads, rotated and "
+                             "gated by their caller if at all")
+        qh, (kh, vh) = q_heads, kv_heads
+    else:
+        qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
     if qh.shape[-1] % (2 * num_heads if output_gate else num_heads):
         raise ValueError(f"q_dim {qh.shape[-1]} not divisible by "
                          f"num_heads {num_heads}")

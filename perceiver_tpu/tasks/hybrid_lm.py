@@ -19,6 +19,18 @@ dense gated MLP ``D`` (``intermediate_size``), a sigmoid router whose
 renormalised top-k is scaled, gated experts and a shared expert gated
 with three matrices alone (``shared_expert_kind`` ``glu``;
 ``scripts/configs/kimi_linear_lm_1chip.yaml``).
+``glm4_moe_lite`` (GLM-4.7-Flash) is ``AE`` a published layer: latent
+attention with a query latent (``q_lora_rank``) and rotary positions on
+its ``qk_rope_head_dim`` shared channels (``rope_theta``), the same
+experts at a scaling of 1.8, and after the stack a **multi-token
+prediction module** (``num_nextn_predict_layers`` 1; DeepSeek-V3,
+arXiv:2412.19437, section 2.2): position ``i`` of the module reads the
+stack's state there beside the embedding of id ``i + 1`` and predicts id
+``i + 2`` through the stack's own head, a second ``fused_linear_nll`` on
+``params["head"]``; ``loss = main_loss + mtp_loss_weight x mtp_loss``,
+each a mean over its own labelled positions (a row's last position has
+no next id, its last two none after that), and no gradient is stopped
+(``scripts/configs/glm_moe_lite_lm_1chip.yaml``).
 ``held_experts`` and ``first_expert`` say which of the
 ``n_routed_experts`` this chip holds (None: all): the router keeps its
 width and its experts a token, and what the absent experts would have
@@ -38,7 +50,10 @@ expert layers: the imbalance the no-drop rule is there for); under a
 softmax router, whose untrained loads are far from even, also
 ``moe_full_buffer_layers`` (the expert layers of the step whose
 assignments did not fit the usual buffer, ``ops.moe.usual_rows``, and
-took the ``T x top_k`` one).
+took the ``T x top_k`` one); with a prediction module also
+``main_loss``, ``mtp_loss`` and ``mtp_positions`` (the positions the
+module's loss read), and ``first_experts`` and the loads count the
+module's expert layer last.
 """
 
 from __future__ import annotations
@@ -48,7 +63,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from perceiver_tpu.models.hybrid_lm import HybridLM
+from perceiver_tpu.models.hybrid_lm import HybridLM, prediction_modules
+from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops.fused_ce import fused_linear_nll
 from perceiver_tpu.ops.moe import usual_rows
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
@@ -80,18 +96,21 @@ class HybridLMTask:
     kda_head_dim: int = 0
     kda_conv_kernel_size: int = 4
     # A, latent attention over num_attention_heads heads (kimi_linear's
-    # keys; 0: no such layer)
+    # and glm4_moe_lite's keys; 0: no such layer); q_lora_rank 0: no
+    # query latent; with rope_theta the rope channels turn
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0
     # D, a dense gated MLP's width (0: no such layer)
     intermediate_size: int = 0
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
     head_dim: int = 128
     # rotary positions at this base over the first partial_rotary_factor
-    # of a head's channels; None: no position embedding
+    # of a * head's channels and over A's qk_rope_head_dim; None: no
+    # position embedding
     rope_theta: Optional[float] = None
     partial_rotary_factor: float = 1.0
     qk_norm: bool = False
@@ -118,6 +137,11 @@ class HybridLMTask:
     remat: bool = False
     # positions a chunk of the head projection + CE
     ce_chunk_size: int = 2048
+    # multi-token prediction modules after the stack (0: none; depth 1
+    # is what there is) and the weight of their loss beside the
+    # next-token loss (DeepSeek-V3's 0.3; read with a module only)
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def build(self, mesh=None) -> HybridLM:
         del mesh   # one device or pure GSPMD: nothing to wire
@@ -138,15 +162,43 @@ class HybridLMTask:
         del rng, deterministic   # no dropout, no masking to draw
         labels, mask = next_token_targets(batch)
         firsts = batch.get("first_experts")
-        h, loads = model.hidden_states(
-            params, batch["input_ids"], policy=policy,
-            first_experts=None if firsts is None else firsts[0])
-        count = jnp.maximum(mask.sum(), 1.0)
-        nll = fused_linear_nll(
-            params["head"], h.reshape(-1, h.shape[-1]), labels.reshape(-1),
-            chunk_size=self.ce_chunk_size, policy=policy).reshape(mask.shape)
-        loss = (nll * mask).sum() / count
-        metrics = {"loss": loss}
+        firsts = None if firsts is None else firsts[0]
+
+        def mean_nll(state, labels, mask):
+            # the count before the reading: the order the step's text
+            # has had since before there was a second reading
+            count = jnp.maximum(mask.sum(), 1.0)
+            nll = fused_linear_nll(
+                params["head"], state.reshape(-1, state.shape[-1]),
+                labels.reshape(-1), chunk_size=self.ce_chunk_size,
+                policy=policy).reshape(mask.shape)
+            return (nll * mask).sum() / count
+
+        if not model.num_nextn_predict_layers:
+            h, loads = model.hidden_states(
+                params, batch["input_ids"], policy=policy,
+                first_experts=firsts)
+            loss = mean_nll(h, labels, mask)
+            metrics = {"loss": loss}
+        else:
+            # position i of the module reads the stack's state there and
+            # the embedding of id i+1 and predicts id i+2: the labels one
+            # further on, where both ids are there
+            h, z, loads = model.prediction_states(
+                params, batch["input_ids"], labels, policy=policy,
+                first_experts=firsts)
+            ahead = jnp.pad(labels[:, 1:], ((0, 0), (0, 1)))
+            ahead_mask = mask * jnp.pad(mask[:, 1:], ((0, 0), (0, 1)))
+            main = mean_nll(h, labels, mask)
+            with device_scope("loss"), device_scope("mtp_loss"):
+                ahead_loss = mean_nll(z, ahead, ahead_mask)
+            prediction_modules.add(
+                f"depth {model.num_nextn_predict_layers}, loss weight "
+                f"{self.mtp_loss_weight:g}, the stack's head and embedding")
+            loss = main + self.mtp_loss_weight * ahead_loss
+            metrics = {"loss": loss, "main_loss": main,
+                       "mtp_loss": ahead_loss,
+                       "mtp_positions": ahead_mask.sum()}
         if loads.shape[0]:
             loads = loads.astype(jnp.float32)
             metrics["moe_assignments"] = loads.sum()

@@ -472,6 +472,7 @@ class Trainer:
             format_attention_paths,
             masked_attention_tiles,
         )
+        from perceiver_tpu.models.hybrid_lm import prediction_modules
         from perceiver_tpu.ops.delta_rule import rule_paths
         from perceiver_tpu.ops.moe import moe_kinds, moe_paths
         from perceiver_tpu.ops.pallas_short_conv import conv_paths
@@ -484,7 +485,8 @@ class Trainer:
                 moe_paths.counting() as experts, \
                 moe_kinds.counting() as kinds, \
                 rule_paths.counting() as rules, \
-                conv_paths.counting() as convs:
+                conv_paths.counting() as convs, \
+                prediction_modules.counting() as modules:
             try:
                 if self._exec_cache is None:
                     step_fn.lower(state, sharded)
@@ -510,6 +512,8 @@ class Trainer:
         if experts:  # a stack with expert layers (ops/moe.py)
             lines.append(f"expert layers: {format_tally(experts)}")
             lines.append(f"expert kinds: {format_tally(kinds)}")
+        if modules:  # a multi-token prediction loss (tasks/hybrid_lm.py)
+            lines.append(f"prediction modules: {format_tally(modules)}")
         print("\n".join(f"[step_load] {line}" for line in lines),
               file=sys.stderr, flush=True)
         return step_fn

@@ -33,12 +33,19 @@ Compiles (compile ONLY — no execution) the full train step of:
    to 5, four Kimi Delta Attention mixers and one latent attention, a
    leading dense MLP, 8 of 256 experts held, 4 rows of 4096),
 
+9. (``glm``) the GLM-4.7-Flash LM of the benchmark's ``glm_flash_train``
+   (``benchmarks/configs/glm_4p7_flash.json``: published layers 44 to
+   47, four latent attentions with a query latent and rotary channels,
+   8 of 64 experts held, the multi-token prediction module and its
+   second reading of the head, 4 rows of 4096),
+
 on whatever single device is available, and reports XLA's HBM usage
 estimates (argument/output/temp/generated-code sizes). This validates
 that remat + query chunking keep the per-chip footprint inside a
 v5e/v5p chip's HBM before any pod time is spent.
 
-``lm``, ``224``, ``ouro``, ``nemotron``, ``sdar``, ``qwen3next`` and ``kimi`` run ``remat: true``: beside
+``lm``, ``224``, ``ouro``, ``nemotron``, ``sdar``, ``qwen3next``, ``kimi`` and
+``glm`` run ``remat: true``: beside
 XLA's sizes they print which dear values the layers keep and the bytes reckoned
 for them (``ops/remat.py``). Under ``MEMCHECK_TOPOLOGY`` the choices
 that read the backend are made as the described chip would make them
@@ -46,12 +53,12 @@ that read the backend are made as the described chip would make them
 use on it, the parameters and optimizer state the step is handed).
 
 Usage: python scripts/aot_memcheck.py
-           [224 | lm | seg | ouro | nemotron | sdar | qwen3next | kimi | all]
-           [rows]
+           [224 | lm | seg | ouro | nemotron | sdar | qwen3next | kimi | glm
+            | all] [rows]
        (``rows``: the per-chip batch of ``224`` / ``lm`` / ``ouro`` /
-       ``nemotron`` / ``sdar`` / ``qwen3next`` / ``kimi`` in place of the
-       preset's; ``all`` leaves ``ouro``, ``nemotron``, ``sdar``,
-       ``qwen3next`` and ``kimi`` out)
+       ``nemotron`` / ``sdar`` / ``qwen3next`` / ``kimi`` / ``glm`` in
+       place of the preset's; ``all`` leaves ``ouro``, ``nemotron``,
+       ``sdar``, ``qwen3next``, ``kimi`` and ``glm`` out)
 Env:   MEMCHECK_PLATFORM=cpu   (forces the CPU backend for smoke runs)
 """
 
@@ -271,9 +278,11 @@ def check_nemotron(per_chip_batch: int = 4,
                    label: str = "nemotron"):
     """The benchmark's ``nemotron3_nano_30b`` as ``nemotron_train`` runs
     it (or ``qwen3_next_80b_a3b`` as ``qwen3next_train`` does, or
-    ``kimi_linear_48b_a3b`` as ``kimi_linear_train``: the same task,
-    another pattern): the ``model`` group of its configuration
-    file, full rows, each expert layer's share named by the batch."""
+    ``kimi_linear_48b_a3b`` as ``kimi_linear_train``, or
+    ``glm_4p7_flash`` as ``glm_flash_train``: the same task, another
+    pattern): the ``model`` group of its configuration file, full rows,
+    each expert layer's share named by the batch, a prediction module's
+    last."""
     import jax.numpy as jnp
 
     from perceiver_tpu.tasks import HybridLMTask
@@ -282,8 +291,8 @@ def check_nemotron(per_chip_batch: int = 4,
     batch = {"input_ids": jnp.zeros((per_chip_batch, model["max_seq_len"]),
                                     jnp.int32),
              "first_experts": jnp.zeros(
-                 (per_chip_batch, model["hybrid_override_pattern"].count("E")),
-                 jnp.int32)}
+                 (per_chip_batch, model["hybrid_override_pattern"].count("E")
+                  + model.get("num_nextn_predict_layers", 0)), jnp.int32)}
     return _compile_train_step(HybridLMTask(**model), batch, label)
 
 
@@ -332,6 +341,9 @@ def main():
     if which == "kimi":
         out["kimi_linear_48b_a3b_5_layers"] = check_nemotron(
             config="kimi_linear_48b_a3b", label="kimi", **rows)
+    if which == "glm":
+        out["glm_4p7_flash_4_layers_and_mtp"] = check_nemotron(
+            config="glm_4p7_flash", label="glm", **rows)
     print(json.dumps(out, indent=2))
 
 
