@@ -115,7 +115,6 @@ from perceiver_tpu.obs.trace import device_scope
 from perceiver_tpu.ops import remat
 from perceiver_tpu.ops.attention import (
     data_shards,
-    head_rms_norm,
     mha_apply,
     tally_latent_call,
     untallied,
@@ -126,12 +125,13 @@ from perceiver_tpu.ops.delta_rule import (
     kda_mixer_apply,
     kda_mixer_init,
 )
-from perceiver_tpu.ops.fourier import rope_apply, rope_tables
+from perceiver_tpu.ops.fourier import rope_tables
 from perceiver_tpu.ops.initializers import trunc_normal_clamped
 from perceiver_tpu.ops.linear import linear_apply, linear_init
 from perceiver_tpu.ops.mlp import gated_mlp_apply, gated_mlp_init
 from perceiver_tpu.ops.moe import moe_apply, moe_init
 from perceiver_tpu.ops.norm import rms_norm_apply, rms_norm_init
+from perceiver_tpu.ops.pallas_head_rotary import head_norm_rotary
 from perceiver_tpu.ops.policy import DEFAULT_POLICY, Policy
 from perceiver_tpu.ops.ssm import ssm_mixer_apply, ssm_mixer_init
 from perceiver_tpu.ops.tally import Tally
@@ -199,11 +199,9 @@ def rotary_gqa_apply(params, a, *, num_heads: int, num_kv_heads: int,
     ``output_gate`` as ``mha_apply``'s."""
     with device_scope("attn_proj"):
         k = linear_apply(params["k"], a, policy=policy)
-        if "k_norm" in params:
-            k = head_rms_norm(params["k_norm"], k, num_kv_heads, norm_eps,
-                              policy)
-        if rope is not None:
-            k = rope_apply(k, *rope, num_kv_heads)
+        if "k_norm" in params or rope is not None:
+            k = head_norm_rotary(k, num_kv_heads, norm=params.get("k_norm"),
+                                 eps=norm_eps, rope=rope, policy=policy)
         k = repeat_kv(k, num_kv_heads, num_heads)
         v = repeat_kv(linear_apply(params["v"], a, policy=policy),
                       num_kv_heads, num_heads)
@@ -259,12 +257,8 @@ def _mla_queries(params, a, num_heads: int, qk_nope_head_dim: int, rope,
     else:
         q = linear_apply(params["q"], a, policy=policy)
     if rope is not None:
-        rows, seq, _ = q.shape
-        still, turning = jnp.split(q.reshape(rows, seq, num_heads, -1),
-                                   [qk_nope_head_dim], axis=-1)
-        turned = rope_apply(turning.reshape(rows, seq, -1), *rope, num_heads)
-        q = jnp.concatenate(
-            [still, turned.reshape(turning.shape)], -1).reshape(q.shape)
+        q = head_norm_rotary(q, num_heads, rope=rope,
+                             offset=qk_nope_head_dim, policy=policy)
     return remat.dear(q, "qkv")
 
 
@@ -291,7 +285,9 @@ def mla_apply(params, a, *, num_heads: int, kv_lora_rank: int,
             linear_apply(params["kv_a"], a, policy=policy), [kv_lora_rank],
             axis=-1)
         if rope is not None:
-            shared = rope_apply(shared, *rope, 1)
+            # (one head of ``rope`` channels, half a vector of lanes
+            # wide in the published shapes: XLA's, by ``fits``)
+            shared = head_norm_rotary(shared, 1, rope=rope, policy=policy)
         kv = linear_apply(
             params["kv_b"],
             rms_norm_apply(params["kv_norm"], latent, norm_eps, policy),

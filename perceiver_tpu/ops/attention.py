@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_tpu.obs.trace import device_scope
-from perceiver_tpu.ops.fourier import rope_apply
 from perceiver_tpu.ops.initializers import uniform, xavier_uniform
 from perceiver_tpu.ops.linear import linear_init, linear_apply
 from perceiver_tpu.ops.norm import (
@@ -434,7 +433,9 @@ def mha_apply(params, q, k, v, *, num_heads: int,
     ``ops.fourier.rope_tables``, applied to the projected q and k.
     Where ``params`` holds ``q_norm`` and ``k_norm`` (a scale of ``D``
     each), q and k take an RMSNorm over each head's channels (eps
-    ``norm_eps``) before the tables. ``kv_heads`` come as their caller
+    ``norm_eps``) before the tables (both in one pass each way, read
+    where q and k lie in the projection's product, where
+    ``ops/pallas_head_rotary.fits`` allows). ``kv_heads`` come as their caller
     made them: normed and rotated there, if at all, and **their widths
     may differ** (latent attention, MLA: score heads ``q_dim / H`` =
     ``k_dim / H`` wide beside narrower value heads; the scale is the
@@ -501,29 +502,34 @@ def mha_apply(params, q, k, v, *, num_heads: int,
         if kv_heads is None or rope is not None or output_gate:
             raise ValueError("q_heads come beside kv_heads, rotated and "
                              "gated by their caller if at all")
-        qh, (kh, vh) = q_heads, kv_heads
+        qh, (kh, vh), packed = q_heads, kv_heads, None
     else:
-        qh, kh, vh = _project(params, q, k, v, policy, kv_heads)
+        qh, kh, vh, packed = _project(params, q, k, v, policy, kv_heads)
     if qh.shape[-1] % (2 * num_heads if output_gate else num_heads):
         raise ValueError(f"q_dim {qh.shape[-1]} not divisible by "
                          f"num_heads {num_heads}")
-    gate = None
+    # where q and k lie in a wider product: (array, first channel,
+    # channels from a head's first to the next's; 0: side by side)
+    q_from = k_from = gate = None
+    if output_gate:
+        q_from = (qh, 0, qh.shape[-1] // num_heads)
+    elif packed is not None:
+        q_from, k_from = (packed, 0, 0), (packed, qh.shape[-1], 0)
     if output_gate:
         with device_scope("attn_proj"), device_scope("attn_gate"):
             qh, gate = (x.reshape(*qh.shape[:2], -1) for x in jnp.split(
                 _split_heads(qh, num_heads), 2, axis=-1))
-    if "q_norm" in params:
+    if "q_norm" in params or rope is not None:
+        # (imported here: that module reads this one's ``mesh_devices``)
+        from perceiver_tpu.ops.pallas_head_rotary import head_norm_rotary
         with device_scope("attn_proj"):
-            qh = head_rms_norm(params["q_norm"], qh, num_heads, norm_eps,
-                               policy)
+            qh = head_norm_rotary(
+                qh, num_heads, norm=params.get("q_norm"), eps=norm_eps,
+                rope=rope, policy=policy, cut_from=q_from)
             if kv_heads is None:
-                kh = head_rms_norm(params["k_norm"], kh, num_heads,
-                                   norm_eps, policy)
-    if rope is not None:
-        with device_scope("attn_proj"):
-            qh = rope_apply(qh, *rope, num_heads)
-            if kv_heads is None:
-                kh = rope_apply(kh, *rope, num_heads)
+                kh = head_norm_rotary(
+                    kh, num_heads, norm=params.get("k_norm"), eps=norm_eps,
+                    rope=rope, policy=policy, cut_from=k_from)
     path, reason = impl, None
     if impl is None:
         path, reason = pick_attention_core(
@@ -574,12 +580,13 @@ def mha_apply(params, q, k, v, *, num_heads: int,
 
 @device_scope("attn_proj")
 def _project(params, q, k, v, policy, kv_heads):
-    """q/k/v projections, (B, L, H·D) each: heads unsplit."""
+    """q/k/v projections, (B, L, H·D) each: heads unsplit; and the
+    packed product they are slices of, where there is one."""
     if kv_heads is not None:
         # pre-projected (kh, vh) from mha_kv_heads — the hoisted
         # loop-invariant path; only the q projection runs per call
         return (dear(linear_apply(params["q"], q, policy=policy), "qkv"),
-                *kv_heads)
+                *kv_heads, None)
     if k is q and v is q:
         # self-attention: pack the three projections into ONE matmul
         # (torch's in_proj). Identical numerics — the concatenated
@@ -597,10 +604,10 @@ def _project(params, q, k, v, policy, kv_heads):
         # to hold, not three slices the compiler may copy
         qkv = dear(linear_apply(packed, q, policy=policy), "qkv")
         e = qkv.shape[-1] // 3
-        return tuple(qkv[..., i * e:(i + 1) * e] for i in range(3))
+        return (*(qkv[..., i * e:(i + 1) * e] for i in range(3)), qkv)
     return (linear_apply(params["q"], q, policy=policy),
             linear_apply(params["k"], k, policy=policy),
-            linear_apply(params["v"], v, policy=policy))
+            linear_apply(params["v"], v, policy=policy), None)
 
 
 def _fused_width(k, v, num_heads: int) -> int:

@@ -475,6 +475,7 @@ class Trainer:
         from perceiver_tpu.models.hybrid_lm import prediction_modules
         from perceiver_tpu.ops.delta_rule import rule_paths
         from perceiver_tpu.ops.moe import moe_kinds, moe_paths
+        from perceiver_tpu.ops.pallas_head_rotary import rotary_paths
         from perceiver_tpu.ops.pallas_short_conv import conv_paths
         from perceiver_tpu.ops.remat import format_remat_keeps, remat_keeps
         from perceiver_tpu.ops.ssm import scan_paths
@@ -486,6 +487,7 @@ class Trainer:
                 moe_kinds.counting() as kinds, \
                 rule_paths.counting() as rules, \
                 conv_paths.counting() as convs, \
+                rotary_paths.counting() as rotaries, \
                 prediction_modules.counting() as modules:
             try:
                 if self._exec_cache is None:
@@ -503,6 +505,9 @@ class Trainer:
         lines = ["attention call sites: "
                  + format_attention_paths(paths, tiles),
                  f"remat keeps: {format_remat_keeps(keeps)}"]
+        if rotaries:  # attention with q/k norms or rotary positions
+            lines.insert(1, "head norms and rotations: "
+                         + format_tally(rotaries))
         if scans:    # a stack with state-space layers (ops/ssm.py)
             lines.append(f"selective scans: {format_tally(scans)}")
         if rules:    # a stack with linear-attention layers (ops/delta_rule.py)

@@ -112,6 +112,7 @@ def _topology_sharding():
 
     import perceiver_tpu.ops.attention as attention
     import perceiver_tpu.ops.moe as moe
+    import perceiver_tpu.ops.pallas_head_rotary as head_rotary
     import perceiver_tpu.ops.pallas_short_conv as short_conv
     import perceiver_tpu.ops.remat as remat
     import perceiver_tpu.ops.ssm as ssm
@@ -127,6 +128,7 @@ def _topology_sharding():
     moe._backend = lambda: "tpu"
     ssm._backend = lambda: "tpu"
     short_conv._backend = lambda: "tpu"
+    head_rotary._backend = lambda: "tpu"
     # ... and its Pallas kernels are the chip's own, not the interpreter's
     # loops (a kernel that asks its backend directly: the delta rules')
     platform.default_interpret = lambda: False

@@ -466,11 +466,10 @@ def test_the_sound_short_model_meets_the_reference(short, short_want):
 
 def test_an_unrotated_shared_key_fails_the_tolerance(
         short, short_want, monkeypatch):
-    turn = hybrid_lm.rope_apply
+    turn = hybrid_lm.head_norm_rotary
     monkeypatch.setattr(
-        hybrid_lm, "rope_apply",
-        lambda x, cos, sin, heads: x if heads == 1
-        else turn(x, cos, sin, heads))
+        hybrid_lm, "head_norm_rotary",
+        lambda x, heads, **kw: x if heads == 1 else turn(x, heads, **kw))
     assert miss(short_losses(*short), short_want) > 10 * TOL
 
 
@@ -478,11 +477,13 @@ def test_rotated_nope_channels_fail_the_tolerance(
         short, short_want, monkeypatch):
     """The tables over a head's first channels too, as a partial rotary
     of a plain attention would turn them."""
+    from perceiver_tpu.ops.fourier import rope_apply
+
     queries = hybrid_lm._mla_queries
 
     def planted(params, a, num_heads, nope, rope, norm_eps, policy):
         q = queries(params, a, num_heads, nope, rope, norm_eps, policy)
-        return hybrid_lm.rope_apply(q, *rope, num_heads)
+        return rope_apply(q, *rope, num_heads)
 
     monkeypatch.setattr(hybrid_lm, "_mla_queries", planted)
     assert miss(short_losses(*short), short_want) > 10 * TOL

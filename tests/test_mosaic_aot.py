@@ -457,6 +457,87 @@ def test_short_conv_kernels_lie_under_the_mixers_scope(one_chip, monkeypatch):
         "short_conv_bwd/pallas_call"] * 3
 
 
+# --- the head norm and rotary positions --------------------------------------
+
+
+@pytest.mark.parametrize(
+    "rows,seq,wide,heads,dim,rotated,offset,norm,first,stride", [
+        (2, 8192, 4096, 32, 128, 128, 0, True, 0, 0),      # sdar_train: q
+        (2, 8192, 512, 4, 128, 128, 0, True, 0, 0),        # ... and k
+        (4, 4096, 8192, 16, 256, 64, 0, True, 0, 512),     # q of [q | gate]
+        (2, 4096, 6144, 16, 128, 128, 0, False, 2048, 0),  # k of [q k v]
+        (4, 4096, 5120, 20, 256, 64, 192, False, 0, 0),    # [nope | rope]
+    ], ids=["sdar_q", "sdar_k", "qwen3next_q_beside_gate", "ouro_k_of_qkv",
+            "glm_flash_q_tail"])
+def test_head_rotary_forward_backward_compiles(
+        one_chip, rows, seq, wide, heads, dim, rotated, offset, norm, first,
+        stride):
+    """The norm and rotation's two kernels at the attention cells'
+    shapes (bf16, the heads read where they lie in the projection's
+    product), one call each way."""
+    from perceiver_tpu.ops.pallas_head_rotary import fused_head_rotary
+
+    def loss(x, scale, cos, sin):
+        return fused_head_rotary(
+            x, heads, dim, scale=scale if norm else None, rope=(cos, sin),
+            offset=offset, first=first, stride=stride,
+            interpret=False).astype(jnp.float32).sum()
+
+    s = _struct(one_chip)
+    table = s((seq, rotated), jnp.float32)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        s((rows, seq, wide), jnp.bfloat16), s((dim,), jnp.float32), table,
+        table).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert sorted("fwd" if "head_rotary_fwd" in c else "bwd" if
+                  "head_rotary_bwd" in c else c for c in calls) == [
+                      "bwd", "fwd"]
+
+
+def test_head_rotary_kernels_lie_under_the_layers_scope(one_chip,
+                                                        monkeypatch):
+    """Picked as the trainer's step picks it (``head_norm_rotary`` from
+    ``rotary_gqa_apply`` on a TPU), both kernels carry ``attn_proj`` in
+    their name stacks, the backward's under ``transpose(``: what
+    ``scripts/scope_ops.py attn_proj`` and the pass split read; each
+    direction a jitted function, traced once for a step's call sites."""
+    import re
+
+    import perceiver_tpu.utils.platform as platform
+    from perceiver_tpu.models import hybrid_lm
+    from perceiver_tpu.ops import pallas_head_rotary
+    from perceiver_tpu.ops.fourier import rope_tables
+    from perceiver_tpu.ops.policy import Policy
+
+    monkeypatch.setattr(pallas_head_rotary, "_backend", lambda: "tpu")
+    monkeypatch.setattr(platform, "default_interpret", lambda: False)
+    params = jax.eval_shape(lambda: hybrid_lm.gqa_init(
+        jax.random.key(0), 256, 2, 1, 128, qk_norm=True))
+    rope = rope_tables(256, 128, 1e6)
+    s = _struct(one_chip)
+
+    def loss(params, a):
+        return hybrid_lm.rotary_gqa_apply(
+            params, a, num_heads=2, num_kv_heads=1, rope=rope,
+            policy=Policy.bf16(), impl="einsum").astype(jnp.float32).sum()
+
+    with pallas_head_rotary.rotary_paths.counting() as forms:
+        text = jax.jit(jax.grad(loss)).lower(
+            jax.tree.map(lambda x: s(x.shape, x.dtype), params),
+            s((1, 256, 256), jnp.bfloat16)).compile().as_text()
+    assert dict(forms) == {"fused[1x128 norm+rot128]": 1,
+                           "fused[2x128 norm+rot128]": 1}
+    stacks = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line and "custom-call(" in line]
+    assert sorted(s for s in stacks if "head_rotary" in s) == [
+        "jit(loss)/jvp(attn_proj)/jit(_rotary_forward)/head_rotary_fwd/"
+        "pallas_call"] * 2 + [
+        "jit(loss)/transpose(jvp(attn_proj))/jit(_rotary_backward)/"
+        "head_rotary_bwd/pallas_call"] * 2
+
+
 # --- fused projection + cross-entropy ----------------------------------------
 
 
